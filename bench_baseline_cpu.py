@@ -1,8 +1,7 @@
 """Measure the reference-class CPU baselines bench.py compares against.
 
-The reference stack (DL4J 0.4 on nd4j-native CPU BLAS) publishes no numbers
-(BASELINE.md); torch-CPU implementations of the same three benchmark configs
-stand in as the reference-class CPU measurement.  Run this script in the
+The reference stack (DL4J 0.4 on nd4j-native CPU BLAS) publishes no numbers;
+torch-CPU implementations of the same three benchmark configs stand in as the reference-class CPU measurement.  Run this script in the
 image to (re)produce ``baseline_cpu.json`` — bench.py reads that file, so the
 comparison constants are reproducible, not hand-waved:
 

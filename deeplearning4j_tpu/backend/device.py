@@ -130,9 +130,15 @@ def _group_by_slice(devices: Sequence, n_slices: Optional[int]):
                 "equal groups")
         per = len(devices) // k
         return list(devices), per
+    if any(si is None for si in has_attr):
+        missing = [str(d) for d, si in zip(devices, has_attr) if si is None]
+        raise ValueError(
+            "some devices report a slice_index and others do not "
+            f"(without: {missing}); refusing to guess which slice they "
+            "belong to")
     groups: dict = {}
     for d, si in zip(devices, has_attr):
-        groups.setdefault(si if si is not None else -1, []).append(d)
+        groups.setdefault(si, []).append(d)
     if n_slices is not None and len(groups) != n_slices:
         raise ValueError(
             f"n_slices={n_slices} but the platform reports "

@@ -48,6 +48,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     # imports AFTER argparse: --help must not pay the jax tax
+    from deeplearning4j_tpu.backend.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from deeplearning4j_tpu.generation.engine import GenerationEngine
     from deeplearning4j_tpu.models.sequential import MultiLayerNetwork
     from deeplearning4j_tpu.models.zoo import transformer_char_lm

@@ -12,7 +12,9 @@ a replica that keeps dying stays down and stays drained.
 
 Stdlib-only on purpose (subprocess/socket/threading + the metrics
 registry): the supervisor must keep working while the thing it
-supervises is the part that is broken.
+supervises is the part that is broken.  Importing this module
+initialises no JAX backend, so a supervising parent can stay off the
+accelerator.
 """
 
 from __future__ import annotations
@@ -122,8 +124,15 @@ class ReplicaSupervisor:
 
     def _spawn(self, worker_id: str, port: int,
                args: List[str]) -> ReplicaProcess:
+        """Start one replica process.  Its JAX platform is whatever the
+        caller's environment says (``JAX_PLATFORMS``), exactly as for any
+        other process.  NOTE: no replica is given a chip of its own — an
+        accelerator belongs to one process, so on a TPU host every
+        replica spawned here reaches for the same default device, and a
+        parent that has touched JAX already holds it.  Per-replica device
+        assignment is not built yet (ROADMAP R6c); until then run
+        supervised fleets on the CPU platform, set from outside."""
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # the replica imports the package by name: make sure the repo
         # root wins however the parent was launched
         env["PYTHONPATH"] = _REPO_ROOT + os.pathsep + env.get(

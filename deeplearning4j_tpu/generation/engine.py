@@ -536,8 +536,7 @@ class GenerationEngine:
         if allocated_only:
             allocated = [p for p in range(1, self.cache.num_pages)
                          if self.cache.refcount(p) > 0]
-        return numerics.kv_page_ledger(
-            pools, self.cache.page_size, allocated=allocated)
+        return numerics.kv_page_ledger(pools, allocated=allocated)
 
     # ----------------------------------------------------------------- stats
     def stats(self) -> dict:

@@ -194,7 +194,7 @@ class GenerationPrograms:
 
     def _make_read_page(self):
         def read_page(pools, page):
-            """One page's [page_size, Hkv, D] K/V slice from every pool
+            """One page's [Hkv, page_size, D] K/V slice from every pool
             (the offload side of the host tier)."""
             def walk(c):
                 if isinstance(c, dict) and "pk" in c:
@@ -277,6 +277,35 @@ class GenerationPrograms:
         return total
 
     # --------------------------------------------------------------- warmup
+    def _compute_programs(self) -> Dict[str, Tuple]:
+        """``{name: (jitted, args after the pools)}`` for the programs that
+        run the model — each ``prefill_<bucket>`` and ``decode`` — with the
+        exact arguments they are warmed, and therefore served, with."""
+        s, maxp = self.slots, self.pages_per_slot
+        z = np.zeros
+        progs = {
+            f"prefill_{b}": (self._prefill[b], (
+                z((1, maxp), np.int32), z((1,), np.int32), np.int32(0),
+                z((1, b), np.int32), z((1, 2), np.uint32), z((1,), np.int32),
+                z((1,), np.float32), z((1,), np.int32),
+                np.ones((1,), np.float32)))
+            for b in self.prefill_buckets}
+        progs["decode"] = (self._decode, (
+            z((s, maxp), np.int32), z((s,), np.int32), z((s,), np.int32),
+            z((s, 2), np.uint32), z((s,), np.int32), z((s,), np.float32),
+            z((s,), np.int32), np.ones((s,), np.float32)))
+        return progs
+
+    def lowered(self) -> Dict[str, "jax.stages.Lowered"]:
+        """Each compute program lowered at its serving signature (abstract
+        pools; nothing executes, nothing is donated) — how a caller reads
+        which kernels a program was built from: ``.as_text()`` for the
+        kernel names, ``.compile().as_text()`` for the final HLO."""
+        head = (self.net.params, self.net.net_state,
+                jax.eval_shape(self.fresh_pools))
+        return {name: jitted.lower(*head, *tail)
+                for name, (jitted, tail) in self._compute_programs().items()}
+
     def warm(self) -> int:
         """AOT-compile every program on a SCRATCH pool (donation consumes
         it; the live pool is never touched) through the version's
@@ -295,48 +324,25 @@ class GenerationPrograms:
         ever while the opt-in collector is installed."""
         from deeplearning4j_tpu.observability import shardstats
 
-        s, maxp = self.slots, self.pages_per_slot
-        zeros_i = np.zeros
         pools = self.fresh_pools()
         shardstats.record_ledger(
             "generation",
             {"params": self.net.params, "net_state": self.net.net_state,
              "kv_pools": pools})
+        params, net_state = self.net.params, self.net.net_state
+        progs = self._compute_programs()
         coll = shardstats.active_collector()
         if coll is not None:
             # census at the exact warmup signatures; lower-only, so the
             # scratch pools below are still live for the real dispatches
-            coll.analyze_program(
-                self._decode, "generation.decode",
-                (self.net.params, self.net.net_state, pools,
-                 zeros_i((s, maxp), np.int32), zeros_i((s,), np.int32),
-                 zeros_i((s,), np.int32), zeros_i((s, 2), np.uint32),
-                 zeros_i((s,), np.int32), zeros_i((s,), np.float32),
-                 zeros_i((s,), np.int32), np.ones((s,), np.float32)))
-            for b in self.prefill_buckets:
-                coll.analyze_program(
-                    self._prefill[b], f"generation.prefill_{b}",
-                    (self.net.params, self.net.net_state, pools,
-                     zeros_i((1, maxp), np.int32), zeros_i((1,), np.int32),
-                     np.int32(0), zeros_i((1, b), np.int32),
-                     zeros_i((1, 2), np.uint32), zeros_i((1,), np.int32),
-                     zeros_i((1,), np.float32), zeros_i((1,), np.int32),
-                     np.ones((1,), np.float32)))
+            for name, (jitted, tail) in progs.items():
+                coll.analyze_program(jitted, f"generation.{name}",
+                                     (params, net_state, pools) + tail)
         for b in self.prefill_buckets:
-            pools, _ = self.prefill(
-                b, self.net.params, self.net.net_state, pools,
-                zeros_i((1, maxp), np.int32), zeros_i((1,), np.int32),
-                np.int32(0), zeros_i((1, b), np.int32),
-                zeros_i((1, 2), np.uint32), zeros_i((1,), np.int32),
-                zeros_i((1,), np.float32), zeros_i((1,), np.int32),
-                np.ones((1,), np.float32), expected=True)
-        pools, tok = self.decode(
-            self.net.params, self.net.net_state, pools,
-            zeros_i((s, maxp), np.int32), zeros_i((s,), np.int32),
-            zeros_i((s,), np.int32), zeros_i((s, 2), np.uint32),
-            zeros_i((s,), np.int32), zeros_i((s,), np.float32),
-            zeros_i((s,), np.int32), np.ones((s,), np.float32),
-            expected=True)
+            pools, _ = self.prefill(b, params, net_state, pools,
+                                    *progs[f"prefill_{b}"][1], expected=True)
+        pools, tok = self.decode(params, net_state, pools,
+                                 *progs["decode"][1], expected=True)
         payload = self.read_page(pools, 1, expected=True)
         pools = self.write_page(pools, 1, payload, expected=True)
         jax.block_until_ready(tok)

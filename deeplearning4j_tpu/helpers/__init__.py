@@ -22,11 +22,40 @@ exercises the same code path everywhere.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 from typing import Dict, Optional
 
 _enabled = os.environ.get("DL4J_TPU_DISABLE_HELPERS", "0") != "1"
 _registry: Dict[str, object] = {}
+_partition_mesh = contextvars.ContextVar("dl4j_tpu_partition_mesh",
+                                         default=None)
+
+
+@contextlib.contextmanager
+def auto_partitioned(mesh):
+    """Trace-time scope (context manager or decorator): the code inside is
+    traced into a program that XLA partitions BY ITSELF over ``mesh`` —
+    ``jax.jit`` with shardings, as the data/tensor-parallel masters build
+    their step.  A Pallas kernel cannot be partitioned that way (the TPU
+    lowering raises "Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map."), so inside the scope
+    ``get_helper`` offers only helpers that wrap themselves in
+    ``shard_map`` over :func:`partition_mesh` (flash attention); the rest
+    give way to the stock jnp path, which XLA partitions fine.  Code that
+    already runs under ``shard_map`` needs no scope.  A one-device mesh is
+    no partitioning at all and changes nothing."""
+    token = _partition_mesh.set(mesh if mesh.size > 1 else None)
+    try:
+        yield
+    finally:
+        _partition_mesh.reset(token)
+
+
+def partition_mesh():
+    """The mesh of the enclosing :func:`auto_partitioned` scope, or None."""
+    return _partition_mesh.get()
 
 
 def interpret_mode() -> bool:
@@ -66,4 +95,7 @@ def get_helper(kind: str) -> Optional[object]:
 
         pallas_ops.register_default_helpers()
         helper = _registry.get(kind)
+    if (partition_mesh() is not None
+            and not getattr(helper, "partitions_itself", False)):
+        return None
     return helper

@@ -40,21 +40,12 @@ from deeplearning4j_tpu.helpers import interpret_mode as _interpret
 LANES = 128
 NEG_INF = -1e30
 
-# jax-version seams (kernel-trust harness classifies these as
-# reference-setup divergences, not kernel bugs — docs/observability.md):
-# jax.typeof landed after 0.4.x; varying-mesh-axes metadata (vma) with it.
-_typeof = getattr(jax, "typeof", None)
-# the Pallas TPU params class was renamed TPUCompilerParams->CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def pick_blocks(t: int, block_q: Optional[int] = None,
                 block_k: Optional[int] = None) -> Optional[tuple]:
-    """Largest block sizes that tile T exactly, capped at the measured
-    sweet spot (bq 512, bk 1024 but at most T/2, on v5e — bk == T leaves
-    the sequential grid axis with a single step and measured ~5% slower at
-    T=1024; see PROFILE.md).  Returns None when T has no usable tiling."""
+    """Largest block sizes that tile T exactly, capped at bq 512 and
+    bk 1024 but at most T/2 (bk == T leaves the sequential grid axis with
+    a single step).  Returns None when T has no usable tiling."""
     def pk(cap):
         # lane-multiple candidates only: the [bq, bk] score tile wants its
         # minor dim on 128-lane boundaries
@@ -81,10 +72,7 @@ def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying the varying-mesh-axes set of ``like`` so
     the kernels also work inside ``shard_map`` (check_vma requires pallas
     out_shapes to declare how outputs vary — they vary like q does)."""
-    vma = getattr(_typeof(like), "vma", None) if _typeof is not None else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _dot_f32(a, b, trans_a=False, trans_b=False):
@@ -231,9 +219,10 @@ def _fwd_call(q, k, v, *, scale, causal, window, block_q, block_k,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 
@@ -323,9 +312,10 @@ def _bwd_call(q, k, v, o, lse, do, *, scale, causal, window, block_q,
         out_specs=qspec,
         out_shape=_sds((bh, t, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, do, lse, di)
 
     # k-major grid: swap the roles of the two minor axes
@@ -344,9 +334,10 @@ def _bwd_call(q, k, v, o, lse, do, *, scale, causal, window, block_q,
                    _sds((bh, t, d), q.dtype, q)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, di)
     return dq, dk, dv
 
@@ -389,8 +380,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Fused attention on ``[B, T, H, D]`` tensors (layer layout).
 
     Requires T to be a multiple of the block sizes (see :func:`supports`);
-    when blocks are not given the largest exact tiling up to the measured
-    sweet spot (512/1024) is chosen.  D is zero-padded to a 128-lane
+    when blocks are not given the largest exact tiling up to 512/1024 is
+    chosen.  D is zero-padded to a 128-lane
     multiple internally (exact, including gradients).  Softmax scale is
     1/sqrt(true D).
 
@@ -435,6 +426,9 @@ class FlashAttentionHelper:
     to exercise the routing end-to-end on the CPU tier.
     """
 
+    # offered inside helpers.auto_partitioned(): attend() shard_maps itself
+    partitions_itself = True
+
     def __init__(self, allow_interpret: bool = False):
         self.allow_interpret = allow_interpret
 
@@ -453,4 +447,27 @@ class FlashAttentionHelper:
 
     def attend(self, q, k, v, *, causal: bool = False,
                window: Optional[int] = None) -> jax.Array:
-        return flash_attention(q, k, v, causal=causal, window=window)
+        from jax.sharding import PartitionSpec as P
+
+        from deeplearning4j_tpu import helpers
+        from deeplearning4j_tpu.backend.device import AXIS_DATA, AXIS_MODEL
+
+        def attn(q, k, v):
+            return flash_attention(q, k, v, causal=causal, window=window)
+
+        mesh = helpers.partition_mesh()
+        if mesh is None:
+            return attn(q, k, v)
+        # inside an auto-partitioned program: attention is independent
+        # per batch row and per head, so each device runs the kernel on
+        # its own rows ('data') and heads ('model'); a dimension the axis
+        # does not divide stays whole on every device of that axis
+
+        def axis(name, size):
+            n = mesh.shape.get(name, 1)
+            return name if n > 1 and size % n == 0 else None
+
+        spec = P(axis(AXIS_DATA, q.shape[0]), None,
+                 axis(AXIS_MODEL, q.shape[2]), None)
+        return jax.shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec)(q, k, v)

@@ -47,7 +47,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.helpers import interpret_mode as _interpret
 
-_VMEM_BUDGET_ELEMS = 1 << 20   # single-block pass, same cap as pallas_ops
+# single-block whole-array VMEM pass: at this cap (1024x1024, f32, with
+# residual and mask) it compiles and matches on a v5e under jax 0.9.0
+_VMEM_BUDGET_ELEMS = 1 << 20
 
 
 def _pad2(x, row_mult=8, lane_mult=128):
@@ -113,6 +115,7 @@ def _drn_call(h2d, res2d, gamma, beta, maskf, eps, keep, has_res,
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * len(ops),
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         interpret=_interpret(),
+        name="fused_dropout_residual_norm",
     )(*ops)
     return y[:M, :C]
 

@@ -6,7 +6,7 @@ used to materialize every row's logical KV view from the page pool
 — a ``[B, MAXP*page_size, Hkv, D]`` round trip through HBM per layer
 per decode step, just to immediately reduce it through a softmax.  This
 module computes the same per-row causal attention DIRECTLY from the
-flattened page pool + int32 block tables, streaming pages block-by-block
+page pool + int32 block tables, streaming pages block-by-block
 with the online-softmax recurrence (running row-max ``m``, normaliser
 ``l`` — the flash-attention scheme, see ``helpers/flash_attention.py``),
 so the gathered view is never built.
@@ -15,16 +15,18 @@ Two implementations behind one public op:
 
 - ``impl="pallas"`` (default on TPU): a Pallas kernel on the grid
   ``(B, Hkv, MAXP)`` whose sequential page axis carries the softmax
-  scratch.  The block table and per-row positions ride scalar prefetch
-  (``PrefetchScalarGridSpec``), so each page's HBM->VMEM DMA is issued
-  straight off ``block[b, p]`` — the kernel IS the gather.  Pages that
+  scratch.  The block table and each row's highest query position ride
+  scalar prefetch (``PrefetchScalarGridSpec``), so each page's HBM->VMEM
+  DMA — one contiguous ``(page_size, D)`` tile of the
+  ``[P, Hkv, page_size, D]`` pool — is issued straight off
+  ``block[b, p]``: the kernel IS the gather.  Pages that
   lie wholly above every live position of a row batch are skipped:
   their compute is predicated off and their DMA index clamps to the
   last live page (the Pallas pipeline elides copies whose index did
   not change), so a 3-page row in a 32-page table pays for 3 pages.
 - ``impl="lax"`` (default elsewhere): a compiled ``lax.fori_loop`` over
   pages with the same online-softmax accumulator, gathering only one
-  ``[B, page_size, Hkv, D]`` page slab per iteration.  The loop bound
+  ``[B, Hkv, page_size, D]`` page slab per iteration.  The loop bound
   is the live-page watermark ``max(q_positions)//page_size + 1`` — a
   traced value (no recompiles; decode is inference-only so the dynamic
   ``while_loop`` lowering needs no reverse pass), which is where the
@@ -63,12 +65,6 @@ from deeplearning4j_tpu.helpers import interpret_mode as _interpret
 LANES = 128
 NEG_INF = -1e30
 
-# jax-version seams (same policy as helpers/flash_attention.py; the
-# kernel-trust harness classifies these as reference-setup divergences)
-_typeof = getattr(jax, "typeof", None)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 _VALID_MODES = ("fused", "gather")
 _mode = ("gather" if os.environ.get("DL4J_TPU_PAGED_GATHER", "0") == "1"
          else "fused")
@@ -93,11 +89,8 @@ def paged_attention_mode() -> str:
 
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying ``like``'s varying-mesh-axes set (see
-    flash_attention._sds; jax.typeof is post-0.4.x)."""
-    vma = getattr(_typeof(like), "vma", None) if _typeof is not None else None
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    flash_attention._sds)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _dot_f32(a, b, trans_b=False):
@@ -107,39 +100,34 @@ def _dot_f32(a, b, trans_b=False):
         preferred_element_type=jnp.float32)
 
 
-def _check_shapes(q, pk, pv, block, q_positions, page_size):
+def _check_shapes(q, pk, pv, block, q_positions):
     b, t, hq, d = q.shape
-    if pk.ndim != 3 or pk.shape != pv.shape:
+    if pk.ndim != 4 or pk.shape != pv.shape or pk.shape[3] != d:
         raise ValueError(
-            f"paged pools must be flattened [P*page_size, Hkv, D]; got "
+            f"paged pools must be [P, Hkv, page_size, D={d}]; got "
             f"pk {pk.shape}, pv {pv.shape}")
     hkv = pk.shape[1]
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
-    if pk.shape[0] % page_size:
-        raise ValueError(
-            f"pool rows {pk.shape[0]} not a multiple of page_size "
-            f"{page_size}")
     if block.shape[0] != b or block.ndim != 2:
         raise ValueError(
             f"block table {block.shape} does not match batch {b}")
     if q_positions.shape != (b, t):
         raise ValueError(
             f"q_positions {q_positions.shape} must be [B, T] = {(b, t)}")
-    return hkv, d
 
 
 # ---------------------------------------------------------------------------
 # lax fallback: fori_loop over live pages, online softmax
 # ---------------------------------------------------------------------------
 
-def _lax_paged(q, pk, pv, block, q_positions, page_size):
+def _lax_paged(q, pk, pv, block, q_positions):
     """Compiled page-streaming fallback for non-TPU backends.  One
-    ``[B, page_size, Hkv, D]`` slab in flight at a time; loop bound is
+    ``[B, Hkv, page_size, D]`` slab in flight at a time; loop bound is
     the dynamic live-page watermark (traced -> while_loop -> zero
     steady-state recompiles)."""
     b, t, hq, d = q.shape
-    hkv = pk.shape[1]
+    hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
     maxp = block.shape[1]
     acc_dt = jnp.promote_types(q.dtype, jnp.float32)
@@ -153,11 +141,10 @@ def _lax_paged(q, pk, pv, block, q_positions, page_size):
 
     def body(p, carry):
         m, l, acc = carry
-        slots = block[:, p][:, None] * page_size + offs[None]  # [B, ps]
-        k = pk[slots].astype(acc_dt)                  # [B, ps, Hkv, D]
-        v = pv[slots].astype(acc_dt)
+        k = pk[block[:, p]].astype(acc_dt)            # [B, Hkv, ps, D]
+        v = pv[block[:, p]].astype(acc_dt)
         kpos = p * page_size + offs
-        s = jnp.einsum("bthgd,bkhd->bhgtk", qg, k) * scale
+        s = jnp.einsum("bthgd,bhkd->bhgtk", qg, k) * scale
         keep = (q_positions[:, None, None, :, None]
                 >= kpos[None, None, None, None, :])
         s = jnp.where(keep, s, NEG_INF)
@@ -167,7 +154,7 @@ def _lax_paged(q, pk, pv, block, q_positions, page_size):
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + jnp.sum(p_exp, axis=-1)
         acc_new = (acc * alpha.transpose(0, 3, 1, 2)[..., None]
-                   + jnp.einsum("bhgtk,bkhd->bthgd", p_exp, v))
+                   + jnp.einsum("bhgtk,bhkd->bthgd", p_exp, v))
         return m_new, l_new, acc_new
 
     live = jnp.minimum(jnp.max(q_positions) // page_size + 1, maxp)
@@ -181,15 +168,8 @@ def _lax_paged(q, pk, pv, block, q_positions, page_size):
 # Pallas kernel: grid (B, Hkv, MAXP), scalar-prefetched block table
 # ---------------------------------------------------------------------------
 
-def _row_max_qpos(qp_ref, b, t):
-    m = qp_ref[b, 0]
-    for i in range(1, t):
-        m = jnp.maximum(m, qp_ref[b, i])
-    return m
-
-
-def _decode_kernel(blk_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, page_size, t):
+def _decode_kernel(blk_ref, qmax_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_scr, l_scr, acc_scr, *, scale, page_size):
     b = pl.program_id(0)
     p = pl.program_id(2)
     npages = pl.num_programs(2)
@@ -201,22 +181,16 @@ def _decode_kernel(blk_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # pages wholly above every row position contribute nothing; their
-    # DMA already clamped to the last live page (see _kv_index)
-    run = p * page_size <= _row_max_qpos(qp_ref, b, t)
+    # DMA already clamped to the last live page (kv_idx in _pallas_paged)
+    run = p * page_size <= qmax_ref[b]
 
     @pl.when(run)
     def _step():
-        s = _dot_f32(q_ref[:], k_ref[:], trans_b=True) * scale  # [GT, ps]
+        s = _dot_f32(q_ref[:], k_ref[:], trans_b=True) * scale  # [R, ps]
         kpos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
-        # q rows are laid out [G, T] flattened (t = row % T); per-row
-        # global positions come off the prefetched scalars
-        qpm = jnp.full(s.shape, qp_ref[b, 0], jnp.int32)
-        if t > 1:
-            rt = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % t
-            for i in range(1, t):
-                qpm = jnp.where(rt == i, qp_ref[b, i], qpm)
-        s = jnp.where(qpm >= kpos, s, NEG_INF)
+        # per-row global query positions, lane-broadcast like m/l
+        s = jnp.where(qp_ref[:, :1] >= kpos, s, NEG_INF)
         m_prev = m_scr[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -235,62 +209,79 @@ def _decode_kernel(blk_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[:] = (acc_scr[:] / safe).astype(o_ref.dtype)
 
 
-def _kv_index(page_size, t):
-    """K/V page index straight off the scalar-prefetched block table;
-    dead pages clamp to the last live one so their copies are elided."""
-    def idx(b, h, p, blk_ref, qp_ref):
-        hi = _row_max_qpos(qp_ref, b, t) // page_size
-        return (blk_ref[b, jnp.minimum(p, hi)], h, 0)
-    return idx
+def _sublanes(dtype) -> int:
+    """Rows of one TPU tile for ``dtype``: 8 at 32 bits, 16 at 16, 32 at
+    8 (narrower types pack along sublanes)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _pallas_paged(q, pk, pv, block, q_positions, page_size, interpret):
+def _pallas_paged(q, pk, pv, block, q_positions, interpret):
     b, t, hq, d = q.shape
-    hkv = pk.shape[1]
+    hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
     gt = g * t
     maxp = block.shape[1]
     scale = 1.0 / (d ** 0.5)
+    if not interpret and page_size % _sublanes(pk.dtype):
+        raise ValueError(
+            f"page_size={page_size} cannot tile a {pk.dtype} KV pool on "
+            f"TPU: one page is one (page_size, D) tile per kv head, so "
+            f"page_size must be a multiple of {_sublanes(pk.dtype)} for "
+            "this dtype")
     dp = (-d) % LANES
     if dp:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        pk = jnp.pad(pk, ((0, 0), (0, 0), (0, dp)))
-        pv = jnp.pad(pv, ((0, 0), (0, 0), (0, dp)))
+        pk = jnp.pad(pk, ((0, 0), (0, 0), (0, 0), (0, dp)))
+        pv = jnp.pad(pv, ((0, 0), (0, 0), (0, 0), (0, dp)))
     dpad = d + dp
-    # [B, Hkv, G*T, D]: one grid step owns one (batch row, kv head)
+    # [B, Hkv, G*T, D]: one grid step owns one (batch row, kv head); its
+    # q rows are laid out [G, T] flattened (t = row % T).  Rows pad up to
+    # whole tiles (MHA decode has G*T = 1); padded rows sit at position 0
+    # and are sliced off below.
+    rows = -(-gt // _sublanes(q.dtype)) * _sublanes(q.dtype)
     qb = (q.reshape(b, t, hkv, g, dpad).transpose(0, 2, 3, 1, 4)
           .reshape(b, hkv, gt, dpad))
-    block = block.astype(jnp.int32)
+    qb = jnp.pad(qb, ((0, 0), (0, 0), (0, rows - gt), (0, 0)))
     qpos = q_positions.astype(jnp.int32)
+    qrows = jnp.pad(jnp.tile(qpos, (1, g)), ((0, 0), (0, rows - gt)))
+    qrows = jnp.broadcast_to(qrows[:, :, None], (b, rows, LANES))
+    qmax = jnp.max(qpos, axis=1)
+
+    def kv_idx(bi, h, p, blk, qmax):
+        # dead pages clamp to the last live one so their copies are elided
+        return (blk[bi, jnp.minimum(p, qmax[bi] // page_size)], h, 0, 0)
+
+    def row_idx(bi, h, p, blk, qmax):
+        return (bi, h, 0, 0)
+
     kern = functools.partial(_decode_kernel, scale=scale,
-                             page_size=page_size, t=t)
-    kv_idx = _kv_index(page_size, t)
+                             page_size=page_size)
     o = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, maxp),
             in_specs=[
-                pl.BlockSpec((None, None, gt, dpad),
-                             lambda bi, h, p, blk, qp: (bi, h, 0, 0)),
-                pl.BlockSpec((page_size, None, dpad), kv_idx),
-                pl.BlockSpec((page_size, None, dpad), kv_idx),
+                pl.BlockSpec((None, rows, LANES),
+                             lambda bi, h, p, blk, qmax: (bi, 0, 0)),
+                pl.BlockSpec((None, None, rows, dpad), row_idx),
+                pl.BlockSpec((None, None, page_size, dpad), kv_idx),
+                pl.BlockSpec((None, None, page_size, dpad), kv_idx),
             ],
-            out_specs=pl.BlockSpec(
-                (None, None, gt, dpad),
-                lambda bi, h, p, blk, qp: (bi, h, 0, 0)),
+            out_specs=pl.BlockSpec((None, None, rows, dpad), row_idx),
             scratch_shapes=[
-                pltpu.VMEM((gt, LANES), jnp.float32),
-                pltpu.VMEM((gt, LANES), jnp.float32),
-                pltpu.VMEM((gt, dpad), jnp.float32),
+                pltpu.VMEM((rows, LANES), jnp.float32),
+                pltpu.VMEM((rows, LANES), jnp.float32),
+                pltpu.VMEM((rows, dpad), jnp.float32),
             ],
         ),
-        out_shape=_sds((b, hkv, gt, dpad), q.dtype, q),
-        compiler_params=_CompilerParams(
+        out_shape=_sds((b, hkv, rows, dpad), q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block, qpos, qb, pk, pv)
-    o = (o.reshape(b, hkv, g, t, dpad).transpose(0, 3, 1, 2, 4)
+        name="fused_paged_attention",
+    )(block.astype(jnp.int32), qmax, qrows, qb, pk, pv)
+    o = (o[:, :, :gt].reshape(b, hkv, g, t, dpad).transpose(0, 3, 1, 2, 4)
          .reshape(b, t, hq, dpad))
     return o[..., :d] if dp else o
 
@@ -301,13 +292,12 @@ def _pallas_paged(q, pk, pv, block, q_positions, page_size, interpret):
 
 def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
                            block: jax.Array, q_positions: jax.Array, *,
-                           page_size: int,
                            impl: Optional[str] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Per-row causal attention of ``q`` [B, T, Hq, D] directly over the
-    flattened page pool ``pk``/``pv`` [P*page_size, Hkv, D] through the
-    int32 block table ``block`` [B, MAXP] — never materializing the
-    gathered [B, MAXP*page_size, Hkv, D] view.
+    page pool ``pk``/``pv`` [P, Hkv, page_size, D] through the int32
+    block table ``block`` [B, MAXP] — never materializing the gathered
+    [B, MAXP*page_size, Hkv, D] view.
 
     A key's global position is its logical slot index
     ``p * page_size + i``; masking is ``q_positions >= key position``
@@ -320,24 +310,23 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
     bit-compatible oracle).  ``interpret`` only applies to the Pallas
     path (defaults to the package policy: interpret off-TPU).
     """
-    hkv, d = _check_shapes(q, pk, pv, block, q_positions, page_size)
+    _check_shapes(q, pk, pv, block, q_positions)
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "lax"
     if impl == "gather":
         from deeplearning4j_tpu.nn.layers.attention import (
             gather_pages, paged_attention)
 
-        gk = gather_pages(pk, block, page_size).astype(q.dtype)
-        gv = gather_pages(pv, block, page_size).astype(q.dtype)
+        gk = gather_pages(pk, block).astype(q.dtype)
+        gv = gather_pages(pv, block).astype(q.dtype)
         return paged_attention(q, gk, gv, q_positions)
     if impl == "lax":
-        return _lax_paged(q, pk, pv, block, q_positions, page_size)
+        return _lax_paged(q, pk, pv, block, q_positions)
     if impl != "pallas":
         raise ValueError(f"impl={impl!r} not one of pallas/lax/gather")
     if interpret is None:
         interpret = _interpret()
-    return _pallas_paged(q, pk, pv, block, q_positions, page_size,
-                         interpret)
+    return _pallas_paged(q, pk, pv, block, q_positions, interpret)
 
 
 class PagedAttentionHelper:
@@ -355,7 +344,5 @@ class PagedAttentionHelper:
     def supports(self, q, page_size: int) -> bool:
         return paged_attention_mode() == "fused"
 
-    def attend(self, q, pk, pv, block, q_positions, *,
-               page_size: int) -> jax.Array:
-        return paged_decode_attention(q, pk, pv, block, q_positions,
-                                      page_size=page_size)
+    def attend(self, q, pk, pv, block, q_positions) -> jax.Array:
+        return paged_decode_attention(q, pk, pv, block, q_positions)

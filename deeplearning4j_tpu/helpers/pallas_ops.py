@@ -278,16 +278,22 @@ _bn_training_vjp.defvjp(_bn_training_fwd_rule, _bn_training_bwd_rule)
 # ---------------------------------------------------------------------------
 
 # The kernels are single-block whole-array VMEM passes (no grid), so they
-# only apply below a VMEM budget: ~16 MiB/core shared by ~3 live f32 buffers.
-# Above it the layer's stock XLA path runs instead (which tiles fine).
-_VMEM_BUDGET_ELEMS = 1 << 20   # 4 MiB per f32 buffer
+# only apply below a VMEM budget; above it the layer's stock XLA path runs
+# instead (which tiles fine).  The budgets are what compiled on a v5e
+# under jax 0.9.0 / libtpu 0.0.34 (16 MiB scoped-VMEM limit): BN forward
+# and backward at 1 << 20 f32 elements per operand; LRN only at 1 << 19 —
+# at 6144x128 its backward fails with "Scoped allocation with size 22.49M
+# and limit 16.00M exceeded scoped vmem limit by 6.49M" (four operands
+# plus the window-sum temporaries).
+_BN_BUDGET_ELEMS = 1 << 20    # 4 MiB per f32 buffer
+_LRN_BUDGET_ELEMS = 1 << 19   # 2 MiB per f32 buffer
 
 
-def _fits_vmem(x) -> bool:
+def _fits_vmem(x, budget: int) -> bool:
     rows = int(np.prod(x.shape[:-1]))
     cols = x.shape[-1]
     padded = ((rows + 7) // 8 * 8) * ((cols + 127) // 128 * 128)
-    return padded <= _VMEM_BUDGET_ELEMS
+    return padded <= budget
 
 
 class PallasLRNHelper:
@@ -296,7 +302,7 @@ class PallasLRNHelper:
     name = "PallasLRNHelper"
 
     def supports(self, x) -> bool:
-        return _fits_vmem(x)
+        return _fits_vmem(x, _LRN_BUDGET_ELEMS)
 
     def apply(self, x, k, n, alpha, beta):
         shape = x.shape
@@ -310,7 +316,7 @@ class PallasBatchNormHelper:
     name = "PallasBatchNormHelper"
 
     def supports(self, x) -> bool:
-        return _fits_vmem(x)
+        return _fits_vmem(x, _BN_BUDGET_ELEMS)
 
     def apply_inference(self, x, mean, var, gamma, beta, eps):
         shape = x.shape

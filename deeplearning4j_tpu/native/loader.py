@@ -10,6 +10,7 @@ take over, so the framework never hard-depends on the .so.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,6 +20,10 @@ from typing import Optional
 _HERE = Path(__file__).resolve().parent
 _SRC = _HERE / "src" / "dl4j_tpu_native.cpp"
 _SO = _HERE / "_dl4j_tpu_native.so"
+# sha256 of the source the .so was built from.  A copied tree keeps no
+# meaningful mtimes, so freshness is decided by content: what loads was
+# built from the source that sits beside it.
+_SO_HASH = _HERE / "_dl4j_tpu_native.so.srchash"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -27,17 +32,32 @@ _tried = False
 ABI_VERSION = 1
 
 
+def _src_hash() -> str:
+    return hashlib.sha256(_SRC.read_bytes()).hexdigest()
+
+
+def _fresh() -> bool:
+    try:
+        return _SO.exists() and _SO_HASH.read_text().strip() == _src_hash()
+    except OSError:
+        return False
+
+
 def _build() -> bool:
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
         str(_SRC), "-o", str(_SO),
     ]
+    _SO_HASH.unlink(missing_ok=True)
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired):
         return False
-    return proc.returncode == 0 and _SO.exists()
+    if proc.returncode != 0 or not _SO.exists():
+        return False
+    _SO_HASH.write_text(_src_hash() + "\n")
+    return True
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -99,9 +119,8 @@ def lib() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("DL4J_TPU_DISABLE_NATIVE"):
             return None
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                return None
+        if not _fresh() and not _build():
+            return None
         try:
             candidate = _bind(ctypes.CDLL(str(_SO)))
             stale = candidate.dl4j_native_abi_version() != ABI_VERSION

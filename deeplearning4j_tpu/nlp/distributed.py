@@ -31,7 +31,6 @@ from typing import Iterable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from deeplearning4j_tpu.backend.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.backend import device as backend
@@ -137,7 +136,7 @@ class DistributedWord2Vec(Word2Vec):
         in_specs = (P(), P()) + (P(axis),) * n_sharded_args + (P(),)
         out_specs = (P(), P(), P())
 
-        @partial(shard_map, mesh=mesh, in_specs=in_specs,
+        @partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                  out_specs=out_specs)
         def stepped(a, b, *rest):
             *sharded, lr = rest
@@ -249,7 +248,7 @@ class DistributedGlove(Glove):
             self.batch_size = int(np.ceil(self.batch_size / ndev) * ndev)
         mesh_ = self.mesh
 
-        @partial(shard_map, mesh=mesh_,
+        @partial(jax.shard_map, mesh=mesh_,
                  in_specs=(P(),) * 8 + (P(axis),) * 4 + (P(),) * 3,
                  out_specs=(P(),) * 9)
         def stepped(w, wc, b, bc, hw, hwc, hb, hbc, rows, cols, xij, mask,

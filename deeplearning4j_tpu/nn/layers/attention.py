@@ -144,23 +144,23 @@ def dot_product_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), v)
 
 
-def gather_pages(pages: jax.Array, block: jax.Array,
-                 page_size: int) -> jax.Array:
+def gather_pages(pages: jax.Array, block: jax.Array) -> jax.Array:
     """Materialize one batch's logical KV view from a paged pool.
 
-    ``pages`` [P * page_size, Hkv, D] (the flattened pool), ``block``
+    ``pages`` [P, Hkv, page_size, D] (the pool, see
+    ``SelfAttentionLayer.init_paged_cache`` for the layout), ``block``
     [B, MAXP] int32 per-row page ids: returns [B, MAXP * page_size, Hkv,
     D] where flat position ``i`` of row ``b`` is global stream position
     ``i`` of that row's sequence.  This is the paged-gather seam — the
-    fused decode-attention helper (roadmap item 1,
-    ``helpers/paged_attention.py``) replaces exactly this gather + the
-    softmax that follows, and is the DEFAULT decode path; this function
-    + ``paged_attention`` remain the flag-selectable bit-compatible
-    oracle (``DL4J_TPU_PAGED_GATHER=1`` or
+    fused decode-attention helper (``helpers/paged_attention.py``)
+    replaces exactly this gather + the softmax that follows, and is the
+    DEFAULT decode path; this function + ``paged_attention`` remain the
+    flag-selectable bit-compatible oracle (``DL4J_TPU_PAGED_GATHER=1`` or
     ``set_paged_attention_mode("gather")``)."""
     b, maxp = block.shape
-    slots = block[:, :, None] * page_size + jnp.arange(page_size)[None, None]
-    return pages[slots.reshape(b, maxp * page_size)]
+    _, hkv, ps, d = pages.shape
+    return (pages[block].transpose(0, 1, 3, 2, 4)
+            .reshape(b, maxp * ps, hkv, d))
 
 
 def paged_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -319,7 +319,13 @@ class SelfAttentionLayer(Layer):
         engine passes per dispatch (``carry["block"]``/``carry["pos"]``
         alongside these pools).  Pool shapes are the ONLY shapes XLA ever
         sees, so slot count and pool size close the decode shape set.
-        Like the linear cache, GQA pools store the UNEXPANDED kv heads."""
+        Like the linear cache, GQA pools store the UNEXPANDED kv heads.
+
+        Layout ``[num_pages, Hkv, page_size, D]``: one (page, kv head) is
+        a contiguous ``(page_size, D)`` tile — the block the fused Pallas
+        kernel DMAs per grid step.  (Token-major ``[.., page_size, Hkv,
+        D]`` would make that block take 1 of Hkv rows in the tiled
+        second-minor dimension, which the TPU lowering rejects.)"""
         if self.window is not None:
             raise ValueError(
                 "paged KV caching does not support sliding-window "
@@ -332,7 +338,7 @@ class SelfAttentionLayer(Layer):
                 f"seq_axis (got causal={self.causal}, "
                 f"seq_axis={self.seq_axis})")
         d_head = self.n_out // self.n_heads
-        shape = (num_pages, page_size, self._kv_heads, d_head)
+        shape = (num_pages, self._kv_heads, page_size, d_head)
         return {"pk": jnp.zeros(shape, dtype), "pv": jnp.zeros(shape, dtype)}
 
     def _apply_paged(self, params, state, q, k, v, carry):
@@ -344,7 +350,7 @@ class SelfAttentionLayer(Layer):
         in-band keys, unlike the rolling ring) and makes the chunk's own
         keys visible to its own later queries."""
         block, pos = carry["block"], carry["pos"]      # [B, MAXP], [B]
-        ps = carry["pk"].shape[1]
+        ps = carry["pk"].shape[2]
         t_new = q.shape[1]
         new_pos = pos[:, None] + jnp.arange(t_new, dtype=pos.dtype)
         if self.rope:
@@ -352,13 +358,14 @@ class SelfAttentionLayer(Layer):
             # different points of their own streams)
             q = rope(q, new_pos, self.rope_theta)
             k = rope(k, new_pos, self.rope_theta)
-        page = jnp.take_along_axis(block, new_pos // ps, axis=1)
-        flat = (page * ps + new_pos % ps).reshape(-1)
+        page = jnp.take_along_axis(block, new_pos // ps, axis=1).reshape(-1)
+        off = (new_pos % ps).reshape(-1)
         hkv, dh = k.shape[2], k.shape[3]
-        pkf = carry["pk"].reshape(-1, hkv, dh)
-        pvf = carry["pv"].reshape(-1, hkv, dh)
-        pkf = pkf.at[flat].set(k.reshape(-1, hkv, dh).astype(pkf.dtype))
-        pvf = pvf.at[flat].set(v.reshape(-1, hkv, dh).astype(pvf.dtype))
+        # one [Hkv, D] slab per new token at (page, :, offset, :)
+        pk = carry["pk"].at[page, :, off].set(
+            k.reshape(-1, hkv, dh).astype(carry["pk"].dtype))
+        pv = carry["pv"].at[page, :, off].set(
+            v.reshape(-1, hkv, dh).astype(carry["pv"].dtype))
         from deeplearning4j_tpu.helpers import get_helper
 
         helper = get_helper("paged_attention")
@@ -366,15 +373,13 @@ class SelfAttentionLayer(Layer):
             # fused paged decode attention (roadmap item 1): attends
             # straight off the pool + block table, never materializing
             # the gathered [B, MAXP*page_size, Hkv, D] view
-            o = helper.attend(q, pkf, pvf, block, new_pos, page_size=ps)
+            o = helper.attend(q, pk, pv, block, new_pos)
         else:
             # legacy gather+softmax oracle (DL4J_TPU_PAGED_GATHER=1)
-            gk = gather_pages(pkf, block, ps).astype(q.dtype)
-            gv = gather_pages(pvf, block, ps).astype(q.dtype)
+            gk = gather_pages(pk, block).astype(q.dtype)
+            gv = gather_pages(pv, block).astype(q.dtype)
             o = paged_attention(q, gk, gv, new_pos)
-        new_carry = {"pk": pkf.reshape(carry["pk"].shape),
-                     "pv": pvf.reshape(carry["pv"].shape),
-                     "block": block, "pos": pos + t_new}
+        new_carry = {"pk": pk, "pv": pv, "block": block, "pos": pos + t_new}
         y = merge_heads(o) @ params["Wo"] + params["bo"]
         return activations.get(self.activation)(y), state, new_carry
 

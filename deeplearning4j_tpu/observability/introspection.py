@@ -115,8 +115,8 @@ def plan_for(net) -> Optional[IntrospectPlan]:
 def _layout(plan: IntrospectPlan) -> Dict[str, slice]:
     """Slice layout of the packed state vector.  ONE flat ``[N]`` array
     (not a dict of seven) keeps the per-step dispatch overhead at a
-    single extra buffer in/out of the jitted call — measurably cheaper
-    on dispatch-bound small models (PROFILE.md's ~1 ms floor)."""
+    single extra buffer in/out of the jitted call, which matters on
+    dispatch-bound small models."""
     L, A = len(plan.grad_names), len(plan.act_names)
     off = {"iteration": slice(0, 1),
            "grad_norm": slice(1, 1 + L),

@@ -25,8 +25,8 @@ runbook):
   kernel computes something different from the reference;
 - ``reference_setup`` — the config did not produce numbers at all
   because the HARNESS environment broke (jax API drift, missing
-  platform); the kernel itself is unjudged.  The 2025 incident where
-  18/37 flash-attention tests failed on jax 0.4.37 (``jax.typeof``,
+  platform); the kernel itself is unjudged.  The incident where 18/37
+  flash-attention tests failed under an older jax (``jax.typeof``,
   ``pltpu.CompilerParams``, ``jax.shard_map`` — all import/attribute
   drift, zero numerics involved) is the canonical example, recorded in
   ``FLASH_TEST_TRIAGE`` and embedded in every report.
@@ -81,16 +81,17 @@ FLASH_TEST_TRIAGE = {
             "root_cause": "jax.typeof (varying-mesh-axes metadata) landed "
                           "after 0.4.x; the helper assumed it "
                           "unconditionally",
-            "fix": "guard: _typeof = getattr(jax, 'typeof', None); plain "
-                   "ShapeDtypeStruct when absent",
+            "fix": "was a getattr guard; removed once the installed jax "
+                   "(0.9.0) had jax.typeof — the helpers call it directly",
         },
         {
             "symptom": "AttributeError: module 'jax.experimental.pallas."
                        "tpu' has no attribute 'CompilerParams'",
             "where": "helpers/flash_attention.py pallas_call sites (3)",
-            "root_cause": "the Pallas TPU params class is TPUCompilerParams "
+            "root_cause": "the Pallas TPU params class had another name "
                           "on 0.4.x (renamed CompilerParams later)",
-            "fix": "resolve whichever name exists at import time",
+            "fix": "was an import-time name lookup; removed — the helpers "
+                   "use pltpu.CompilerParams",
         },
         {
             "symptom": "ImportError: cannot import name 'shard_map' from "
@@ -98,7 +99,8 @@ FLASH_TEST_TRIAGE = {
             "where": "tests/test_flash_attention.py shard_map cases (2)",
             "root_cause": "top-level jax.shard_map is post-0.4.x; 0.4.37 "
                           "exposes it via jax.experimental.shard_map",
-            "fix": "import through deeplearning4j_tpu.backend.compat",
+            "fix": "was a version shim module; removed — callers use "
+                   "jax.shard_map",
         },
     ],
     "verdict": ("all 18 failures were harness/API drift between jax "
@@ -145,13 +147,13 @@ def _np_attention(q, k, v, *, causal=False, window=None,
     o = np.einsum("bhgqk,bkhd->bqhgd", w, v)
     return o.reshape(b, t, hq, d)
 
-def _np_gather_pages(pages, block, page_size: int) -> np.ndarray:
+def _np_gather_pages(pages, block) -> np.ndarray:
+    """[P, Hkv, ps, D] pool -> [B, MAXP*ps, Hkv, D] logical view."""
     pages = np.asarray(pages, np.float64)
     block = np.asarray(block)
-    per = pages.reshape((-1, page_size) + pages.shape[1:])
-    out = per[block]                                  # [B, MAXP, ps, ...]
+    out = pages[block].transpose(0, 1, 3, 2, 4)       # [B, MAXP, ps, Hkv, D]
     b, maxp = block.shape
-    return out.reshape((b, maxp * page_size) + pages.shape[1:])
+    return out.reshape((b, maxp * out.shape[2]) + out.shape[3:])
 
 def _np_dropout_residual_norm(h, res, gamma, beta, eps, mask,
                               keep) -> np.ndarray:
@@ -304,13 +306,13 @@ def _paged_configs(full: bool):
 def _run_gather(cfg) -> Tuple[Any, np.ndarray]:
     from deeplearning4j_tpu.nn.layers.attention import gather_pages
     dt = jnp.dtype(cfg["dtype"])
-    pool = _rng(cfg["pages"] * cfg["page_size"], cfg["hkv"], cfg["d"],
+    pool = _rng(cfg["pages"], cfg["hkv"], cfg["page_size"], cfg["d"],
                 dtype=dt, seed=3)
     rng = np.random.default_rng(4)
     block = jnp.asarray(
         rng.integers(0, cfg["pages"], size=(cfg["b"], 4)), jnp.int32)
-    out = gather_pages(pool, block, cfg["page_size"])
-    return out, _np_gather_pages(pool, block, cfg["page_size"])
+    out = gather_pages(pool, block)
+    return out, _np_gather_pages(pool, block)
 
 def _run_paged_attention(cfg) -> Tuple[Any, np.ndarray]:
     from deeplearning4j_tpu.nn.layers.attention import paged_attention
@@ -353,8 +355,8 @@ def _run_fused_paged(cfg) -> Tuple[Any, np.ndarray]:
         paged_decode_attention)
     dt = jnp.dtype(cfg["dtype"])
     ps, maxp, t = cfg["page_size"], cfg["maxp"], cfg["t"]
-    pool_k = _rng(cfg["pages"] * ps, cfg["hkv"], cfg["d"], dtype=dt, seed=20)
-    pool_v = _rng(cfg["pages"] * ps, cfg["hkv"], cfg["d"], dtype=dt, seed=21)
+    pool_k = _rng(cfg["pages"], cfg["hkv"], ps, cfg["d"], dtype=dt, seed=20)
+    pool_v = _rng(cfg["pages"], cfg["hkv"], ps, cfg["d"], dtype=dt, seed=21)
     q = _rng(cfg["b"], t, cfg["hq"], cfg["d"], dtype=dt, seed=22)
     rng = np.random.default_rng(23)
     block = rng.integers(1, cfg["pages"], size=(cfg["b"], maxp))
@@ -368,12 +370,11 @@ def _run_fused_paged(cfg) -> Tuple[Any, np.ndarray]:
     blockj = jnp.asarray(block, jnp.int32)
     qposj = jnp.asarray(qpos, jnp.int32)
     out_lax = paged_decode_attention(q, pool_k, pool_v, blockj, qposj,
-                                     page_size=ps, impl="lax")
+                                     impl="lax")
     out_pl = paged_decode_attention(q, pool_k, pool_v, blockj, qposj,
-                                    page_size=ps, impl="pallas",
-                                    interpret=True)
-    gk = _np_gather_pages(pool_k, block, ps)
-    gv = _np_gather_pages(pool_v, block, ps)
+                                    impl="pallas", interpret=True)
+    gk = _np_gather_pages(pool_k, block)
+    gv = _np_gather_pages(pool_v, block)
     ref = _np_attention(q, gk, gv, causal=True, q_positions=qpos)
     out = jnp.concatenate([out_lax.reshape(-1), out_pl.reshape(-1)])
     return out, np.concatenate([ref.reshape(-1), ref.reshape(-1)])

@@ -662,7 +662,7 @@ class NumericsMonitor:
 # paged-KV-cache page ledger (generation engine)
 # ---------------------------------------------------------------------------
 
-def kv_page_ledger(pools: Dict[str, Any], page_size: int,
+def kv_page_ledger(pools: Dict[str, Any],
                    allocated: Optional[Sequence[int]] = None
                    ) -> Dict[str, Any]:
     """Per-page dynamic-range stats over the generation engine's paged
@@ -670,10 +670,9 @@ def kv_page_ledger(pools: Dict[str, Any], page_size: int,
     item 3 (per-page scale = page max_abs / 127; a page is 'int8-ready'
     when at most half its nonzero values would quantize to zero).
 
-    ``pools``: ``{layer: {"pk": [P, page_size, Hkv, D], "pv": ...}}``
+    ``pools``: ``{layer: {"pk": [P, Hkv, page_size, D], "pv": ...}}``
     (the engine's live pools; nested sub-layer dicts are walked and
-    joined with ``/``, and a flat ``[P*page_size, ...]`` leading axis
-    also works).  ``allocated``: page ids to report (defaults to every
+    joined with ``/``).  ``allocated``: page ids to report (defaults to every
     non-trash page).  ONE device_get per pool leaf; host-side numpy
     reductions after that — this is an operator/report surface, never
     called inside the decode loop."""
@@ -692,15 +691,10 @@ def kv_page_ledger(pools: Dict[str, Any], page_size: int,
         layer_entry: Dict[str, Any] = {}
         for leaf_name, arr in pool.items():
             a = np.abs(np.asarray(jax.device_get(arr), np.float32))
-            if a.ndim >= 2 and a.shape[1] == page_size:
-                total = a.shape[0]            # [P, page_size, ...]
-            else:                             # flat [P*page_size, ...]
-                total = a.shape[0] // page_size
-                a = a[:total * page_size].reshape(
-                    (total, page_size) + a.shape[1:])
+            total = a.shape[0]
             pages = (list(allocated) if allocated is not None
                      else list(range(1, total)))   # page 0 = TRASH
-            per = a.reshape(total, page_size, -1)
+            per = a.reshape(total, -1)
             max_abs, under, nonzero = [], [], []
             for p in pages:
                 page = per[p]

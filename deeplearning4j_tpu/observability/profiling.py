@@ -61,15 +61,14 @@ _PEAK = "dl4j_backend_peak_flops"
 _CAPTURES = "dl4j_profile_captures_total"
 _STEP_PEAK_MEM = "dl4j_step_peak_memory_bytes"
 
-# peak dense matmul throughput per chip, bf16 FLOP/s (public spec sheets)
-# — the one owner of the table (bench.py imports it from here)
+# peak dense matmul throughput per chip, bf16 FLOP/s — the one owner of
+# the table (bench.py imports it from here).  Keyed by the EXACT
+# ``device_kind`` string the runtime reports, so a chip nobody measured
+# against can never inherit a neighbour's number.
 PEAK_FLOPS = {
-    "TPU v6": 918e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 197e12,   # v5 lite (v5e)
-    "TPU v4": 275e12,
-    "TPU v3": 123e12,
-    "TPU v2": 46e12,
+    # TPU v5e; reported by jax 0.9.0 / libtpu 0.0.34 on the chip machine.
+    # 197 TFLOP/s: Google Cloud documentation, "TPU v5e"
+    "TPU v5 lite": 197e12,
 }
 
 # ESTIMATE: one modern server socket sustains O(100) GFLOP/s fp32 through
@@ -81,23 +80,23 @@ CPU_PEAK_FLOPS_ESTIMATE = 1e11
 
 def peak_flops_for(device=None) -> Tuple[float, str]:
     """(peak FLOP/s, source) for a jax device (default: devices()[0]).
-    source: ``"table"`` (spec-sheet TPU number), ``"cpu-estimate"``
-    (documented estimate, see ``CPU_PEAK_FLOPS_ESTIMATE``), or
-    ``"unknown"`` (0.0 — MFU not computable)."""
+    source: ``"table"`` (spec-sheet number for that exact ``device_kind``)
+    or ``"cpu-estimate"`` (documented estimate, see
+    ``CPU_PEAK_FLOPS_ESTIMATE``).  An accelerator that is not in the
+    table raises: add its ``device_kind`` and published peak, with the
+    source, before computing utilization on it."""
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:
-            return 0.0, "unknown"
-    kind = getattr(device, "device_kind", "") or ""
-    for prefix, peak in PEAK_FLOPS.items():
-        if kind.startswith(prefix):
-            return peak, "table"
-    if getattr(device, "platform", "") == "cpu":
+        device = jax.devices()[0]
+    kind = device.device_kind
+    if kind in PEAK_FLOPS:
+        return PEAK_FLOPS[kind], "table"
+    if device.platform == "cpu":
         return CPU_PEAK_FLOPS_ESTIMATE, "cpu-estimate"
-    return 0.0, "unknown"
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {kind!r} (platform "
+        f"{device.platform!r}); known: {sorted(PEAK_FLOPS)}")
 
 
 # ------------------------------------------------------------ cost analysis

@@ -67,20 +67,15 @@ _SHARDED_BYTES = "dl4j_sharded_bytes"
 _REPLICATION = "dl4j_replication_factor"
 
 # ---------------------------------------------------------------- bandwidth
-# Per-chip interconnect (ICI) bandwidth, bytes/s, all links combined —
-# public spec-sheet figures (v5e: 1,600 Gbps/chip; v5p: 4,800; v4: 2,400;
-# v3: 700 per link x 4? the public per-chip figure is 656 Gbps x ...).
+# Per-chip interconnect (ICI) bandwidth, bytes/s, all links combined.
 # The ONE owner of the table: the comm roofline, the grad-sync CLI and
 # bench all import it from here (same single-owner discipline as
-# ``profiling.PEAK_FLOPS``).  Values are deliberately round spec numbers;
-# every consumer labels the derived seconds as estimates.
+# ``profiling.PEAK_FLOPS``, and like it keyed by the EXACT ``device_kind``
+# string the runtime reports).  Every consumer labels the derived seconds
+# as estimates.
 LINK_BANDWIDTH = {
-    "TPU v6": 448e9,     # Trillium: 3,584 Gbps/chip
-    "TPU v5p": 600e9,    # 4,800 Gbps/chip
-    "TPU v5": 200e9,     # v5 lite (v5e): 1,600 Gbps/chip
-    "TPU v4": 300e9,     # 2,400 Gbps/chip
-    "TPU v3": 112e9,     # ~900 Gbps/chip
-    "TPU v2": 62e9,      # ~500 Gbps/chip
+    # TPU v5e: 1,600 Gbit/s per chip (Google Cloud documentation, "TPU v5e")
+    "TPU v5 lite": 200e9,
 }
 
 # ESTIMATE: on the virtual host-platform mesh a "collective" is a memcpy
@@ -93,23 +88,21 @@ CPU_LINK_BANDWIDTH_ESTIMATE = 10e9
 
 def link_bandwidth_for(device=None) -> Tuple[float, str]:
     """(link bandwidth bytes/s, source) for a jax device (default:
-    ``devices()[0]``).  source: ``"table"`` (TPU spec sheet),
-    ``"cpu-estimate"`` (documented estimate), or ``"unknown"`` (0.0 —
-    comm seconds not computable)."""
+    ``devices()[0]``).  source: ``"table"`` (spec sheet, exact
+    ``device_kind``) or ``"cpu-estimate"`` (documented estimate).  An
+    accelerator that is not in the table raises."""
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:
-            return 0.0, "unknown"
-    kind = getattr(device, "device_kind", "") or ""
-    for prefix, bw in LINK_BANDWIDTH.items():
-        if kind.startswith(prefix):
-            return bw, "table"
-    if getattr(device, "platform", "") == "cpu":
+        device = jax.devices()[0]
+    kind = device.device_kind
+    if kind in LINK_BANDWIDTH:
+        return LINK_BANDWIDTH[kind], "table"
+    if device.platform == "cpu":
         return CPU_LINK_BANDWIDTH_ESTIMATE, "cpu-estimate"
-    return 0.0, "unknown"
+    raise ValueError(
+        f"no link bandwidth on record for device_kind {kind!r} (platform "
+        f"{device.platform!r}); known: {sorted(LINK_BANDWIDTH)}")
 
 
 def ring_wire_bytes(op: str, payload_bytes: float,

@@ -415,7 +415,6 @@ class ParallelWrapper:
         UNCHANGED (stale) — ZeRO's sharded update has no per-replica
         gradient view to measure; harvest reports whatever the last
         non-ZeRO refresh wrote (docs/observability.md "Numerics")."""
-        from deeplearning4j_tpu.backend.compat import shard_map
         from deeplearning4j_tpu.observability import introspection, numerics
         from deeplearning4j_tpu.resilience import stability
 
@@ -510,7 +509,7 @@ class ParallelWrapper:
                 out_specs.append(P(AX))
             if kw:
                 out_specs.append(P(AX))
-            out = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+            out = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                             out_specs=tuple(out_specs),
                             check_vma=False)(*args)
             g_all, losses_k, fin_k, new_ns_k = out[0], out[1], out[2], out[3]

@@ -63,7 +63,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.backend.compat import pcast, shard_map
 
 from deeplearning4j_tpu.models.common import notify_listeners
 from deeplearning4j_tpu.observability import (
@@ -606,9 +605,9 @@ class PipelineParallelTrainingMaster(TrainingMaster):
                 return br
 
             branches = [make_branch(s) for s in range(S)]
-            state0 = pcast(jnp.zeros((buf,), buf_dtype), ("pipe",),
+            state0 = lax.pcast(jnp.zeros((buf,), buf_dtype), ("pipe",),
                                to="varying")
-            loss0 = pcast(jnp.zeros(()), ("pipe",), to="varying")
+            loss0 = lax.pcast(jnp.zeros(()), ("pipe",), to="varying")
 
             def run_tick(state, t):
                 return lax.switch(idx, branches, state, t)
@@ -642,7 +641,7 @@ class PipelineParallelTrainingMaster(TrainingMaster):
             return lax.psum(loss, "pipe"), lax.psum(grads, "pipe")
 
         repl = P()
-        sharded = shard_map(spmd, mesh=self._mesh,
+        sharded = jax.shard_map(spmd, mesh=self._mesh,
                             in_specs=(repl, repl, repl),
                             out_specs=(repl, repl), check_vma=False)
         reg_layers = [l for ls in stage_layers for l in ls if l.has_params()]
@@ -712,7 +711,7 @@ class PipelineParallelTrainingMaster(TrainingMaster):
             loss, gflat = jax.value_and_grad(local_total)(flat_rows[0])
             return lax.psum(loss, "pipe"), gflat[None]
 
-        sharded = shard_map(spmd, mesh=self._mesh,
+        sharded = jax.shard_map(spmd, mesh=self._mesh,
                             in_specs=(P("pipe"), P(), P()),
                             out_specs=(P(), P("pipe")), check_vma=False)
 
@@ -878,7 +877,7 @@ class PipelineParallelTrainingMaster(TrainingMaster):
 
             def local_loss(pfx_p, blk_local, sfx_p):
                 state0 = jnp.zeros(probe.shape, probe.dtype)
-                state0 = pcast(state0, ("pipe",), to="varying")
+                state0 = lax.pcast(state0, ("pipe",), to="varying")
 
                 def run_tick(state, t):
                     a0 = prefix_fwd(pfx_p, xs[jnp.clip(t, 0, M - 1)])
@@ -901,7 +900,7 @@ class PipelineParallelTrainingMaster(TrainingMaster):
                     state = lax.ppermute(outv, "pipe", perm)
                     return (state, loss_sum), None
 
-                loss0 = pcast(jnp.zeros(()), ("pipe",), to="varying")
+                loss0 = lax.pcast(jnp.zeros(()), ("pipe",), to="varying")
                 (_, loss_sum), _ = lax.scan(
                     tick, (state0, loss0), jnp.arange(M + S - 1))
                 # LOCAL loss only (nonzero on the last stage).  Differentiating
@@ -918,7 +917,7 @@ class PipelineParallelTrainingMaster(TrainingMaster):
             return loss, gp, gb, gs
 
         repl, piped = P(), P("pipe")
-        sharded = shard_map(
+        sharded = jax.shard_map(
             spmd, mesh=mesh,
             in_specs=(repl, piped, repl, repl, repl),
             out_specs=(repl, repl, piped, repl),

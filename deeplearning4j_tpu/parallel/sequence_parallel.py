@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.backend.compat import pcast, shard_map
 
 from deeplearning4j_tpu.backend import device as backend
 from deeplearning4j_tpu.optimize import updaters as upd
@@ -72,9 +71,9 @@ def ring_attention(q, k, v, mask=None, *, axis_name: str,
     # over the ring axis so the scan carry typechecks under shard_map
     acc = jnp.promote_types(q.dtype, jnp.float32)
     qf = q.astype(acc)
-    o0 = pcast(jnp.zeros((b, h, t_local, d), acc), (axis_name,), to="varying")
-    l0 = pcast(jnp.zeros((b, h, t_local), acc), (axis_name,), to="varying")
-    m0 = pcast(jnp.full((b, h, t_local), _NEG, acc), (axis_name,), to="varying")
+    o0 = lax.pcast(jnp.zeros((b, h, t_local, d), acc), (axis_name,), to="varying")
+    l0 = lax.pcast(jnp.zeros((b, h, t_local), acc), (axis_name,), to="varying")
+    m0 = lax.pcast(jnp.full((b, h, t_local), _NEG, acc), (axis_name,), to="varying")
     scale = jnp.asarray(1.0 / np.sqrt(d), acc)
     perm = [(j, (j + 1) % n_shards) for j in range(n_shards)]
 
@@ -186,7 +185,7 @@ def ring_self_attention(q, k, v, mesh: Optional[Mesh] = None, *,
     mesh = mesh or backend.default_mesh()
     fn = ring_attention if impl == "ring" else ulysses_attention
     spec = P(None, seq_axis)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(fn, axis_name=seq_axis, causal=causal,
                           window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -259,7 +258,7 @@ class SequenceParallelTrainingMaster:
             }
             return new_params, new_us, new_ns, loss
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             step, mesh=mesh,
             in_specs=(repl, repl, repl, repl, data_seq, data_seq, repl),
             out_specs=(repl, repl, repl, repl),

@@ -39,6 +39,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.backend import device as backend
+from deeplearning4j_tpu.helpers import auto_partitioned
 from deeplearning4j_tpu.observability import (
     PhaseTimers, WorkerTelemetry, crash_dump, instrument, step_guard,
 )
@@ -308,8 +309,10 @@ class SyncTrainingMaster(TrainingMaster):
         out_shardings = (players, ulayers, repl, repl)
         if policy is not None:
             out_shardings = out_shardings + (repl,)
+        # XLA partitions this program from the shardings; the Pallas
+        # helpers inside need to be told (helpers.auto_partitioned)
         self._step = instrument(jax.jit(
-            step,
+            auto_partitioned(mesh)(step),
             in_shardings=in_shardings,
             out_shardings=out_shardings,
             donate_argnums=(0, 1, 2),
@@ -338,7 +341,6 @@ class SyncTrainingMaster(TrainingMaster):
         correctly across per-shard activation views (a pmean of
         per-shard maxes is not the global max), so harvest reports the
         last non-ZeRO refresh (docs/observability.md "Numerics")."""
-        from deeplearning4j_tpu.backend.compat import shard_map
         from deeplearning4j_tpu.observability import introspection, numerics
         from deeplearning4j_tpu.resilience import stability
 
@@ -428,7 +430,7 @@ class SyncTrainingMaster(TrainingMaster):
                 + ((P(),) if kw else ())
             args = (params, net_state, x, y, rng, lm, scale) \
                 + ((fm,) if has_fm else ())
-            out = shard_map(local, mesh=mesh, in_specs=in_specs,
+            out = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs, check_vma=False)(*args)
             if kw:
                 g_sh, gloss, new_ns, act_stats = out
@@ -884,9 +886,9 @@ class DistributedNetwork:
                 # genuinely sharded params, and pinning them replicated
                 # here would reject them — GSPMD gathers what the
                 # forward needs either way
-                self._eval_fn = jax.jit(self.net._output_fn(),
-                                        in_shardings=(None, None, data,
-                                                      data))
+                self._eval_fn = jax.jit(
+                    auto_partitioned(mesh)(self.net._output_fn()),
+                    in_shardings=(None, None, data, data))
                 self._eval_mesh = mesh
             sharded = self._eval_fn
 

@@ -1,11 +1,10 @@
 """Capture XLA profiler traces of the three benchmark models on the TPU.
 
-Produces ``profiles/<model>/`` XPlane traces (TensorBoard 'Profile' tab) and
-prints a JSON summary of measured step time vs the compiled step's XLA cost
-analysis (FLOPs + bytes accessed), the evidence behind PROFILE.md's
-conclusions on the XLA-conv thesis (≙ deeplearning4j-cuda's claim that the
-helper kernels beat the builtin path — here the question is whether stock
-XLA fusion suffices; see VERDICT round 2 item 6).
+Produces ``profiles/<model>/`` XPlane traces (TensorBoard 'Profile' tab,
+git-ignored) and prints a JSON summary of measured step time vs the compiled
+step's XLA cost analysis (FLOPs + bytes accessed) — evidence on the XLA-conv
+thesis (≙ deeplearning4j-cuda's claim that the helper kernels beat the
+builtin path — here the question is whether stock XLA fusion suffices).
 
 Run: ``python profile_tpu.py`` (real chip; ~2 min).
 """

@@ -1,6 +1,6 @@
-"""LSTM throughput-ceiling experiment (VERDICT r3 task 9 / r4 task 6).
+"""LSTM throughput-ceiling experiment.
 
-PROFILE.md asserts the GravesLSTM bench's low MFU is "intrinsic to the
+The claim under test: the GravesLSTM bench's low MFU is "intrinsic to the
 architecture" (T sequential [B,H]x[H,4H] matmuls cannot fill the MXU);
 this script MEASURES that claim instead of asserting it (reference analog:
 ``LSTMHelpers.java:144-181`` — the cuDNN path has the same shape problem).
@@ -24,9 +24,9 @@ bench config 2x200 H, T=50, fp32), each timed at batch 128 / 512 / 1024:
 Interpretation: if scan/no_recur >> 1 while scan/matmul_only ~ 1, the
 ceiling is the recurrence (wider batch is the only lever, until the
 [B,H]x[H,4H] step matmul saturates the unit) and a hand-written cell
-kernel cannot move it — the PROFILE.md claim, now with numbers.
+kernel cannot move it.
 
-Run on any platform; writes profiles/lstm_ceiling.json.
+Run on any platform; writes profiles/lstm_ceiling.json (git-ignored).
 """
 
 import json
@@ -131,6 +131,7 @@ def main():
     }
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "profiles", "lstm_ceiling.json")
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps({k: v for k, v in out.items() if k != "by_batch"}))

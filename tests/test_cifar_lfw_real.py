@@ -1,6 +1,6 @@
 """Real-format parse branches for CIFAR-10 and LFW, exercised hermetically
-(VERDICT r4 task 8 — the ``write_*`` inverse-format trick from
-tests/test_mnist_idx.py, applied to the two remaining image datasets).
+(the ``write_*`` inverse-format trick from tests/test_mnist_idx.py, applied
+to the two remaining image datasets).
 
 Reference formats: CIFAR binary batches (1 label byte + 3072 CHW RGB bytes
 per record, ``CifarDataSetIterator.java``/``CifarLoader``) and the LFW

@@ -125,7 +125,7 @@ def _cg_attention_char_lm(vocab=13, d=16, heads=2, cache=64):
 
 
 def test_cg_lstm_greedy_matches_host_loop():
-    """VERDICT r4 task 10: the compiled decode scan now covers
+    """The compiled decode scan covers
     ComputationGraph (reference ComputationGraph.rnnTimeStep:1674)."""
     net = _cg_lstm_char_lm()
     rs = np.random.RandomState(3)

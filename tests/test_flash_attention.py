@@ -524,7 +524,6 @@ def test_sliding_window_ring_matches_exact():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from deeplearning4j_tpu.backend.compat import shard_map
 
     from deeplearning4j_tpu.backend import device as backend
     from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
@@ -535,7 +534,7 @@ def test_sliding_window_ring_matches_exact():
     devs = np.array(jax.devices()[:4]).reshape(1, 1, 4)
     mesh = Mesh(devs, (backend.AXIS_DATA, backend.AXIS_MODEL, backend.AXIS_SEQ))
     spec = P(None, backend.AXIS_SEQ)
-    got = shard_map(
+    got = jax.shard_map(
         functools.partial(ring_attention, axis_name=backend.AXIS_SEQ,
                           causal=True, window=5),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -555,7 +554,6 @@ def test_gqa_window_flash_and_ring_paths(interpret_helper):
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from deeplearning4j_tpu.backend.compat import shard_map
 
     from deeplearning4j_tpu.backend import device as backend
     from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
@@ -580,7 +578,7 @@ def test_gqa_window_flash_and_ring_paths(interpret_helper):
     devs = np.array(jax.devices()[:4]).reshape(1, 1, 4)
     mesh = Mesh(devs, (backend.AXIS_DATA, backend.AXIS_MODEL, backend.AXIS_SEQ))
     spec = P(None, backend.AXIS_SEQ)
-    got = shard_map(
+    got = jax.shard_map(
         functools.partial(ring_attention, axis_name=backend.AXIS_SEQ,
                           causal=True, window=6),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -689,3 +687,46 @@ def test_sampling_filter_edge_cases():
     # top_p = 1.0 keeps everything
     p1 = np.asarray(_filter_logits(logits, None, 1.0))
     assert (p1 > -1e29).all()
+
+
+def test_sync_master_step_lowers_for_tpu_on_a_mesh(monkeypatch):
+    """A program XLA partitions by itself (jit + shardings, the DP/TP
+    masters' step) cannot hold a bare Pallas kernel: the TPU lowering
+    raises "Mosaic kernels cannot be automatically partitioned".  That is
+    checked when the step LOWERS, which needs no chip — cross-lower the
+    real SyncTrainingMaster step for tpu over a 4-device mesh with the
+    helpers forced on: flash attention must arrive inside shard_map, and
+    helpers that cannot shard themselves must have given way."""
+    import re
+
+    from deeplearning4j_tpu import backend, helpers
+    from deeplearning4j_tpu.helpers import fused_epilogue as fe
+    from deeplearning4j_tpu.models.zoo import transformer_char_lm
+    from deeplearning4j_tpu.parallel import SyncTrainingMaster
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(fe, "_interpret", lambda: False)
+    monkeypatch.setitem(helpers._registry, "attention",
+                        fa.FlashAttentionHelper(allow_interpret=True))
+    monkeypatch.setitem(helpers._registry, "epilogue",
+                        fe.FusedEpilogueHelper(allow_interpret=True))
+    mesh = backend.default_mesh(4)
+    with helpers.auto_partitioned(mesh):
+        assert helpers.get_helper("epilogue") is None
+        assert helpers.get_helper("attention") is not None
+    assert helpers.get_helper("epilogue") is not None
+
+    net = transformer_char_lm(vocab_size=32, d_model=256, n_heads=2,
+                              layers=1, compute_dtype="bfloat16")
+    master = SyncTrainingMaster(mesh=mesh)
+    master._build(net)
+    with jax.enable_x64(False):     # as on the chip
+        text = master._step.trace(
+            net.params, net.updater_state, net.net_state,
+            jnp.zeros((), jnp.float32), jnp.zeros((8, 256), jnp.int32),
+            jnp.zeros((8, 256, 32), jnp.float32), jax.random.key(0),
+            None, None).lower(lowering_platforms=("tpu",)).as_text()
+    kernels = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert sorted(set(kernels)) == ["flash_attention_dkv",
+                                    "flash_attention_dq",
+                                    "flash_attention_fwd"]

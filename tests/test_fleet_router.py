@@ -362,6 +362,11 @@ def subprocess_fleet():
     from deeplearning4j_tpu.observability.fleet import FleetAggregator
     from deeplearning4j_tpu.streaming.pubsub import MessageBroker
 
+    # replicas are processes of their own and take their platform from the
+    # environment the supervisor passes through: pin them to the CPU like
+    # the rest of this tier (restarts during the drills included)
+    env = pytest.MonkeyPatch()
+    env.setenv("JAX_PLATFORMS", "cpu")
     broker = MessageBroker()
     burl = f"http://127.0.0.1:{broker.serve(port=0)}"
     agg = FleetAggregator(url=burl, expire_after_s=3.0,
@@ -391,6 +396,7 @@ def subprocess_fleet():
     sup.stop_all()
     agg.stop()
     broker.stop()
+    env.undo()
 
 
 def _wait_live(router, wid, timeout=90):

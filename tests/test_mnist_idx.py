@@ -1,4 +1,4 @@
-"""Real-IDX parse path, exercised hermetically (VERDICT r3 missing #1).
+"""Real-IDX parse path, exercised hermetically.
 
 The reference downloads and parses real MNIST IDX binaries
 (``deeplearning4j-core/.../base/MnistFetcher.java:35``, readers

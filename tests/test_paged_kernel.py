@@ -3,7 +3,7 @@
 epilogue (`helpers/fused_epilogue.py`).
 
 The decode kernel's contract: computing per-row causal attention straight
-off the flattened page pool + int32 block tables must match the legacy
+off the page pool + int32 block tables must match the legacy
 gather+softmax oracle (``gather_pages`` + ``paged_attention``) on every
 impl (lax fallback, interpreted Pallas) and at every integration level —
 raw function, layer-level streaming across a page boundary, and the full
@@ -46,9 +46,9 @@ def _scenario(seed, *, pages, page_size, maxp, b, t, hq, hkv, d,
     (``trash_row``) row 0 is an all-padding fresh slot at position 0."""
     rng = np.random.default_rng(seed)
     pool_k = jnp.asarray(
-        rng.standard_normal((pages * page_size, hkv, d)), dtype)
+        rng.standard_normal((pages, hkv, page_size, d)), dtype)
     pool_v = jnp.asarray(
-        rng.standard_normal((pages * page_size, hkv, d)), dtype)
+        rng.standard_normal((pages, hkv, page_size, d)), dtype)
     q = jnp.asarray(rng.standard_normal((b, t, hq, d)), dtype)
     block = rng.integers(1, pages, size=(b, maxp))
     qlast = rng.integers(t - 1, maxp * page_size, size=(b,))
@@ -63,9 +63,9 @@ def _scenario(seed, *, pages, page_size, maxp, b, t, hq, hkv, d,
             jnp.asarray(qpos, jnp.int32))
 
 
-def _oracle(q, pk, pv, block, qpos, page_size):
-    gk = gather_pages(pk, block, page_size).astype(q.dtype)
-    gv = gather_pages(pv, block, page_size).astype(q.dtype)
+def _oracle(q, pk, pv, block, qpos):
+    gk = gather_pages(pk, block).astype(q.dtype)
+    gv = gather_pages(pv, block).astype(q.dtype)
     return paged_attention(q, gk, gv, qpos)
 
 
@@ -84,9 +84,8 @@ CONFIGS = {
 def test_fused_matches_gather_oracle(impl, name):
     cfg = CONFIGS[name]
     q, pk, pv, block, qpos = _scenario(7, **cfg)
-    ref = _oracle(q, pk, pv, block, qpos, cfg["page_size"])
-    out = paged_decode_attention(q, pk, pv, block, qpos,
-                                 page_size=cfg["page_size"], impl=impl,
+    ref = _oracle(q, pk, pv, block, qpos)
+    out = paged_decode_attention(q, pk, pv, block, qpos, impl=impl,
                                  interpret=True)
     assert bool(jnp.isfinite(out).all())
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -101,13 +100,40 @@ def test_all_padding_trash_row(impl):
     cfg = CONFIGS["gqa"]
     q, pk, pv, block, qpos = _scenario(11, **cfg, trash_row=True)
     assert int(block[0].max()) == 0 and int(qpos[0, 0]) == 0
-    out = paged_decode_attention(q, pk, pv, block, qpos,
-                                 page_size=cfg["page_size"], impl=impl,
+    out = paged_decode_attention(q, pk, pv, block, qpos, impl=impl,
                                  interpret=True)
-    ref = _oracle(q, pk, pv, block, qpos, cfg["page_size"])
+    ref = _oracle(q, pk, pv, block, qpos)
     assert bool(jnp.isfinite(out).all())
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("b,t,hq,hkv", [(16, 1, 8, 8), (16, 1, 8, 2),
+                                        (1, 128, 8, 8)])
+def test_pallas_kernel_lowers_for_tpu_at_serving_shapes(b, t, hq, hkv):
+    """The TPU block-shape rules are checked when the kernel LOWERS, which
+    needs no chip: cross-lower for the tpu platform at the serving shapes
+    (bf16, head_dim 128, page 16).  The token-major pool layout failed
+    exactly here ("last two dimensions of your block shape ...")."""
+    ps, maxp, d = 16, 8, 128
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, impl="pallas", interpret=False))
+    with jax.enable_x64(False):   # as on the chip (the TPU tier has no x64)
+        q = jnp.zeros((b, t, hq, d), jnp.bfloat16)
+        pool = jnp.zeros((b * maxp + 1, hkv, ps, d), jnp.bfloat16)
+        text = fn.trace(q, pool, pool, jnp.zeros((b, maxp), jnp.int32),
+                        jnp.zeros((b, t), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count('kernel_name = "fused_paged_attention"') == 1
+
+
+def test_compiled_kernel_rejects_page_size_the_dtype_cannot_tile():
+    pool = jnp.zeros((5, 2, 8, 128), jnp.bfloat16)   # bf16 tiles 16 rows
+    with pytest.raises(ValueError, match="page_size=8"):
+        paged_decode_attention(
+            jnp.zeros((1, 1, 2, 128), jnp.bfloat16), pool, pool,
+            jnp.zeros((1, 4), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+            impl="pallas", interpret=False)
 
 
 def test_mode_toggle_and_helper_gating():
@@ -132,8 +158,7 @@ def test_lax_fallback_zero_recompiles_across_fill_levels():
     on it."""
     cfg = CONFIGS["gqa"]
     ps = cfg["page_size"]
-    fn = jax.jit(lambda *a: paged_decode_attention(
-        *a, page_size=ps, impl="lax"))
+    fn = jax.jit(lambda *a: paged_decode_attention(*a, impl="lax"))
     q, pk, pv, block, qpos = _scenario(13, **cfg)
     fn(q, pk, pv, block, qpos).block_until_ready()
     traces = 0

@@ -281,7 +281,7 @@ def test_hetero_sharded_params_with_adam_matches_serial():
 
 
 def test_hetero_params_actually_partitioned_per_device():
-    """The memory point of pipeline parallelism (VERDICT r4 weak #4): with
+    """The memory point of pipeline parallelism: with
     the sharded layout, each device holds ~1/S of the param bytes, not a
     full replica."""
     x, y = data(32)

@@ -65,8 +65,8 @@ class NumpyTransport:
 
     def __init__(self, num_pages, page_size, feat=4):
         self.pools = {"att": {
-            "pk": np.zeros((num_pages, page_size, 1, feat), np.float32),
-            "pv": np.zeros((num_pages, page_size, 1, feat), np.float32)}}
+            "pk": np.zeros((num_pages, 1, page_size, feat), np.float32),
+            "pv": np.zeros((num_pages, 1, page_size, feat), np.float32)}}
 
     def page_bytes(self):
         c = self.pools["att"]

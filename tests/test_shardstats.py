@@ -147,7 +147,14 @@ def test_link_bandwidth_sources():
         platform = "tpu"
 
     bw, src = link_bandwidth_for(FakeTPU())
-    assert (bw, src) == (shardstats.LINK_BANDWIDTH["TPU v5"], "table")
+    assert (bw, src) == (shardstats.LINK_BANDWIDTH["TPU v5 lite"], "table")
+
+    class UnknownTPU:
+        device_kind = "TPU v5 something"   # a prefix match is not a match
+        platform = "tpu"
+
+    with pytest.raises(ValueError, match="no link bandwidth on record"):
+        link_bandwidth_for(UnknownTPU())
 
 
 # -------------------------------------------------------- program analysis

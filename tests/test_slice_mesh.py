@@ -107,6 +107,16 @@ def test_slice_index_unequal_groups_rejected():
         _group_by_slice(lopsided, 2)
 
 
+def test_mixed_slice_index_rejected():
+    """Some devices with a slice_index and some without is not a layout
+    to guess at (they used to be bucketed into a pseudo-slice -1)."""
+    from deeplearning4j_tpu.backend.device import _group_by_slice
+
+    mixed = [_StubDev(i, 0 if i < 4 else None) for i in range(8)]
+    with pytest.raises(ValueError, match="slice_index"):
+        _group_by_slice(mixed, 2)
+
+
 def test_virtual_split_error_names_the_real_cause():
     from deeplearning4j_tpu.backend.device import _group_by_slice
 
