@@ -94,7 +94,10 @@ class GenerationEngine:
                               engine_id=self.metrics.engine_id,
                               **(slo_targets or {}))
         # per-iteration phase breakdown of the decode loop (schedule /
-        # page_gather / jitted_step / sample_harvest / stream_write)
+        # page_gather / jitted_step / sample_harvest / stream_write), each
+        # under the stage it ran for: loop / admit / decode.  The same spans
+        # are in a profiler trace as generation_decode.<stage>.<phase>
+        # (docs/observability.md, "Span tracing")
         self.phases = PhaseTimers("generation_decode",
                                   registry=self.metrics.registry)
         self.busy_wall_s = 0.0          # decode-loop wall time, non-wait
@@ -368,7 +371,7 @@ class GenerationEngine:
                              or not self.scheduler.has_work):
                 break
             t_iter = time.perf_counter()
-            with self.phases.phase("schedule"):
+            with self.phases.phase("schedule", stage="loop"):
                 self.scheduler.purge_pending()
             try:
                 with self.models.lease(self.default_model) as mv:
@@ -408,11 +411,13 @@ class GenerationEngine:
                     return
             self.busy_wall_s += time.perf_counter() - t_iter
             if not stopping and not self.scheduler.has_work:
-                self.scheduler.wait_for_work(0.05)
+                # not busy time, so in the trace and not in "phases"
+                with self.phases.phase("wait", stage="loop", child=True):
+                    self.scheduler.wait_for_work(0.05)
 
     def _admit(self, progs: GenerationPrograms, mv: ModelVersion) -> None:
         while True:
-            with self.phases.phase("schedule"):
+            with self.phases.phase("schedule", stage="admit"):
                 req = self.scheduler.next_admittable()
             if req is None:
                 return
@@ -429,17 +434,19 @@ class GenerationEngine:
 
     def _prefill(self, progs: GenerationPrograms, mv: ModelVersion,
                  req: GenerationRequest) -> None:
-        with self.phases.phase("page_gather"):
+        phase = self.phases.phase
+        with phase("page_gather", stage="admit"):
             suffix = req.prompt[req.shared_len:]
             bucket = progs.bucket_for(len(suffix))
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :len(suffix)] = suffix
             shared_pages = req.shared_len // self.cache.page_size
-            base_key = _base_key(req.seed)
+            with phase("base_key", stage="admit", child=True):
+                base_key = _base_key(req.seed)
             block = self.cache.block_row(req.pages)[None]
         with step_guard("decode_prefill", engine=self.metrics.engine_id,
                         bucket=bucket, shared_pages=shared_pages):
-            with self.phases.phase("jitted_step"):
+            with phase("jitted_step", stage="admit"):
                 self._pools, tok = progs.prefill(
                     bucket, mv.model.params, mv.model.net_state,
                     self._pools, block,
@@ -449,9 +456,9 @@ class GenerationEngine:
                     np.asarray([req.temperature], np.float32),
                     np.asarray([req.top_k], np.int32),
                     np.asarray([req.top_p], np.float32))
-        with self.phases.phase("sample_harvest"):
+        with phase("sample_harvest", stage="admit"):
             first = int(jax.device_get(tok)[0])
-        with self.phases.phase("stream_write"):
+        with phase("stream_write", stage="admit"):
             self.scheduler.install(req, first, base_key)
             self.metrics.ttft.observe(req.ttft_s)
             self.metrics.prefix_pages.inc(shared_pages, outcome="shared")
@@ -462,24 +469,27 @@ class GenerationEngine:
 
     def _step(self, progs: GenerationPrograms, mv: ModelVersion) -> None:
         s = self.scheduler
+        phase = self.phases.phase
         active = len(s.active_slots())
         t_step0 = time.perf_counter()
         with step_guard("decode_step", engine=self.metrics.engine_id,
                         active=active):
-            with self.phases.phase("jitted_step"):
+            with phase("jitted_step", stage="decode"):
                 self._pools, sampled = progs.decode(
                     mv.model.params, mv.model.net_state, self._pools,
                     s.block, s.pos, s.last_tok, s.keys, s.tok_idx,
                     s.temps, s.top_ks, s.top_ps)
-        with self.phases.phase("sample_harvest"):
+        with phase("sample_harvest", stage="decode"):
             sampled_host = jax.device_get(sampled)
-        with self.phases.phase("stream_write"):
-            delivered = s.after_step(sampled_host)
+        with phase("stream_write", stage="decode"):
+            with phase("deliver", stage="decode", child=True):
+                delivered = s.after_step(sampled_host)
             self.steady_deliveries += delivered
-            self.metrics.steps.inc()
-            self.metrics.tokens.inc(delivered, model=mv.name)
-            self.metrics.batch_occupancy.observe(active / s.num_slots)
-            self._refresh_gauges()
+            with phase("gauges", stage="decode", child=True):
+                self.metrics.steps.inc()
+                self.metrics.tokens.inc(delivered, model=mv.name)
+                self.metrics.batch_occupancy.observe(active / s.num_slots)
+                self._refresh_gauges()
         if self.decode_step_floor_s > 0.0:
             # sleep (not spin) to the floor: the yielded core is exactly
             # what lets sibling replica processes decode concurrently
@@ -514,11 +524,14 @@ class GenerationEngine:
             completed=status in _OK_REASONS)
         end_ns = time.perf_counter_ns()
         start_ns = int(req.submitted * 1e9)
+        # a first token implies an admission, so queue_wait_s is set
+        prefill_s = (req.ttft_s - req.queue_wait_s
+                     if req.ttft_s is not None else None)
         get_tracer().record_span(
             "generation_request", start_ns, end_ns,
             trace_id=req.trace_id, tokens=len(req.tokens), status=status,
-            ttft_ms=(round(req.ttft_s * 1e3, 3)
-                     if req.ttft_s is not None else None),
+            ttft_ms=_ms(req.ttft_s), queue_wait_ms=_ms(req.queue_wait_s),
+            prefill_ms=_ms(prefill_s),
             itl_p50_ms=req.itl_p50_ms(), slo_ok=req.slo_ok)
 
     def kv_numerics(self, allocated_only: bool = True) -> dict:
@@ -578,6 +591,10 @@ class GenerationEngine:
             "prefix_cache": (self.prefix_cache.stats()
                              if self.prefix_cache is not None else None),
         }
+
+
+def _ms(seconds: Optional[float]) -> Optional[float]:
+    return None if seconds is None else round(seconds * 1e3, 3)
 
 
 def _base_key(seed: int) -> np.ndarray:

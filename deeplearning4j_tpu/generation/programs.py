@@ -164,9 +164,10 @@ class GenerationPrograms:
             """One token for every slot: [S] in, [S] out."""
             x = encode(tokens[:, None])
             pre, nc = fwd(params, net_state, x, _attach(pools, block, pos))
-            logits = pre[:, -1].astype(jnp.float32)
-            nxt = sample_tokens(logits, keys, token_idx, temps, top_ks,
-                                top_ps)
+            with jax.named_scope("sample"):
+                logits = pre[:, -1].astype(jnp.float32)
+                nxt = sample_tokens(logits, keys, token_idx, temps, top_ks,
+                                    top_ps)
             return _strip(nc), nxt.astype(jnp.int32)
 
         return decode_step
@@ -185,9 +186,10 @@ class GenerationPrograms:
             x = encode(tokens)
             pre, nc = fwd(params, net_state, x,
                           _attach(pools, block, start))
-            logits = jnp.take(pre[0], last_idx, axis=0)[None]
-            tok = sample_tokens(logits.astype(jnp.float32), keys,
-                                token_idx, temps, top_ks, top_ps)
+            with jax.named_scope("sample"):
+                logits = jnp.take(pre[0], last_idx, axis=0)[None]
+                tok = sample_tokens(logits.astype(jnp.float32), keys,
+                                    token_idx, temps, top_ks, top_ps)
             return _strip(nc), tok.astype(jnp.int32)
 
         return prefill

@@ -73,6 +73,8 @@ class GenerationRequest:
         self.trace_id = trace_id or new_trace_id()
         self.submitted = time.perf_counter()
         self.ttft_s: Optional[float] = None
+        # submit -> picked by next_admittable; the rest of TTFT is prefill
+        self.queue_wait_s: Optional[float] = None
         self.itl_s: List[float] = []    # gaps between delivered tokens
         self._last_token_t: Optional[float] = None
         self.slo_ok: Optional[bool] = None   # set by the engine's SLOTracker
@@ -343,6 +345,7 @@ class DecodeScheduler:
             except PageExhaustedError:
                 return None     # keep queued; pages free as slots retire
             self._pending.popleft()
+        req.queue_wait_s = time.perf_counter() - req.submitted
         req.slot = free
         req.pages = pages
         req.shared_len = shared_len

@@ -147,37 +147,43 @@ class MultiLayerNetwork(LazyScoreMixin):
                         if hasattr(a, "dtype")
                         and jnp.issubdtype(a.dtype, jnp.floating) else a)
 
-            params = jax.tree_util.tree_map(_cast, params)
-            h = _cast(jnp.asarray(h))
+            with jax.named_scope("param_cast"):
+                params = jax.tree_util.tree_map(_cast, params)
+                h = _cast(jnp.asarray(h))
         n = len(self.layers)
         rngs = jax.random.split(rng, n) if rng is not None else [None] * n
         for i, layer in enumerate(self.layers):
             if i in self.conf.preprocessors:
                 h = self.conf.preprocessors[i](h)
             lstate = net_state.get(layer.name, {})
-            if _is_recurrent(layer):
-                carry = (carries or {}).get(layer.name)
-                h, lst, new_carry = layer.apply_with_carry(
-                    params[layer.name], lstate, h, carry,
-                    train=train, rng=rngs[i], mask=fmask,
-                )
-                new_carries[layer.name] = new_carry
-            elif isinstance(layer, (OutputLayer,)):
-                # output head: stop at preoutput; activation applied on demand
-                h = self.maybe_flatten_time(layer, h)
-                h = layer.maybe_dropout(h, train=train, rng=rngs[i])
-                h = layer.pre_output(params[layer.name], h)
-            else:
-                from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
-                from deeplearning4j_tpu.nn.layers.composite import ResidualBlock
-                from deeplearning4j_tpu.nn.layers.convolution import GlobalPoolingLayer
+            # the layer's name on its device operations (metadata only)
+            with jax.named_scope(layer.name):
+                if _is_recurrent(layer):
+                    carry = (carries or {}).get(layer.name)
+                    h, lst, new_carry = layer.apply_with_carry(
+                        params[layer.name], lstate, h, carry,
+                        train=train, rng=rngs[i], mask=fmask,
+                    )
+                    new_carries[layer.name] = new_carry
+                elif isinstance(layer, (OutputLayer,)):
+                    # output head: stop at preoutput; activation applied
+                    # on demand
+                    h = self.maybe_flatten_time(layer, h)
+                    h = layer.maybe_dropout(h, train=train, rng=rngs[i])
+                    h = layer.pre_output(params[layer.name], h)
+                else:
+                    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
+                    from deeplearning4j_tpu.nn.layers.composite import ResidualBlock
+                    from deeplearning4j_tpu.nn.layers.convolution import GlobalPoolingLayer
 
-                mask_aware = (GlobalPoolingLayer, SelfAttentionLayer, ResidualBlock)
-                kw = {"mask": fmask} if isinstance(layer, mask_aware) else {}
-                h, lst = layer.apply(params[layer.name], lstate, h,
-                                     train=train, rng=rngs[i], **kw)
-                if lst:
-                    new_state[layer.name] = lst
+                    mask_aware = (GlobalPoolingLayer, SelfAttentionLayer,
+                                  ResidualBlock)
+                    kw = ({"mask": fmask} if isinstance(layer, mask_aware)
+                          else {})
+                    h, lst = layer.apply(params[layer.name], lstate, h,
+                                         train=train, rng=rngs[i], **kw)
+                    if lst:
+                        new_state[layer.name] = lst
             if collect:
                 acts.append(h)
         return h, acts, new_state, new_carries
@@ -197,9 +203,11 @@ class MultiLayerNetwork(LazyScoreMixin):
             params, net_state, x, train=train, rng=rng, fmask=fmask,
             carries=carries, collect=collect_acts
         )
-        if self.conf.compute_dtype is not None:
-            pre = pre.astype(jnp.float32)  # loss in full precision
-        data_loss = losses_mod.score(out_layer.loss, y, pre, out_layer.activation, lmask)
+        with jax.named_scope("loss"):
+            if self.conf.compute_dtype is not None:
+                pre = pre.astype(jnp.float32)  # loss in full precision
+            data_loss = losses_mod.score(out_layer.loss, y, pre,
+                                         out_layer.activation, lmask)
         reg = jnp.zeros(())
         for layer in self.layers:
             if layer.has_params():
@@ -266,13 +274,15 @@ class MultiLayerNetwork(LazyScoreMixin):
                 new_net_state, new_carries, act_stats = (
                     numerics.unpack_aux(plan, nplan, aux))
                 grads = {k: v for k, v in grads.items() if v}
-                updates, new_upd_state = upd.update(
-                    updater_cfg, grads, upd_state, iteration, lr_overrides,
-                    params=params,
-                )
-                new_params = dict(params)
-                for lname, u in updates.items():
-                    new_params[lname] = upd.apply_updates(params[lname], u)
+                with jax.named_scope("updater"):
+                    updates, new_upd_state = upd.update(
+                        updater_cfg, grads, upd_state, iteration,
+                        lr_overrides, params=params,
+                    )
+                    new_params = dict(params)
+                    for lname, u in updates.items():
+                        new_params[lname] = upd.apply_updates(
+                            params[lname], u)
                 introspection.attach(
                     new_upd_state, plan, grads=grads, params=params,
                     new_params=new_params, iteration=iteration,

@@ -3,12 +3,9 @@ parent/child nesting, JSON-lines export.
 
 Spans are host-side wall-time markers around *dispatch* (on TPU the device
 work is async — a span brackets what the host did, which is exactly the
-phase-attribution SparkNet/DeepSpark-style throughput tuning needs).  For
-*device* time, enable the optional jax-profiler passthrough: with
-``use_jax_profiler=True`` every span also enters a
-``jax.profiler.TraceAnnotation`` so spans line up with XLA ops in the
-TensorBoard profile, and ``SpanTracer.profile(log_dir)`` brackets a whole
-region with ``jax.profiler.start_trace``/``stop_trace``.
+phase-attribution SparkNet/DeepSpark-style throughput tuning needs).  These
+spans are not in a profiler trace; the program's spans on the device's clock
+are ``observability.phases.PhaseTimers``' (docs/observability.md).
 
 Request tracing: serving mints (or accepts via ``X-Request-Id``) a
 ``trace_id`` per request and stamps it on the per-stage spans
@@ -93,10 +90,8 @@ class SpanTracer:
     ``perf_counter_ns`` clocks, O(1) memory via a ``deque(maxlen=...)``.
     """
 
-    def __init__(self, max_spans: int = 4096,
-                 use_jax_profiler: bool = False):
+    def __init__(self, max_spans: int = 4096):
         self.max_spans = max_spans
-        self.use_jax_profiler = use_jax_profiler
         self._ids = itertools.count(1)
         self._tls = threading.local()
         self._lock = threading.Lock()
@@ -153,46 +148,15 @@ class SpanTracer:
         parent = stack[-1].span_id if stack else None
         s = Span(name, next(self._ids), parent, time.perf_counter_ns(), attrs)
         stack.append(s)
-        annot = None
-        if self.use_jax_profiler:
-            try:
-                import jax
-
-                annot = jax.profiler.TraceAnnotation(name)
-                annot.__enter__()
-            except Exception:
-                annot = None
         try:
             yield s
         finally:
-            if annot is not None:
-                annot.__exit__(None, None, None)
             s.end_ns = time.perf_counter_ns()
             stack.pop()
             with self._lock:
                 if len(self._finished) == self._finished.maxlen:
                     self.dropped += 1
                 self._finished.append(s)
-
-    @contextmanager
-    def profile(self, log_dir: str) -> Iterator[None]:
-        """Bracket a region with a jax profiler trace (XPlane/TensorBoard);
-        no-ops if the profiler is unavailable."""
-        started = False
-        try:
-            import jax
-
-            jax.profiler.start_trace(str(log_dir))
-            started = True
-        except Exception:
-            pass
-        try:
-            yield
-        finally:
-            if started:
-                import jax
-
-                jax.profiler.stop_trace()
 
     def record_span(self, name: str, start_ns: int, end_ns: int,
                     **attrs) -> Span:
