@@ -359,9 +359,22 @@ class GenerationEngine:
                     f"cannot deploy {mv.key}: its paged-cache geometry "
                     "differs from the live pools (layer names / kv heads "
                     "/ head dims must match the serving architecture)")
+        # the version's one cast, counted, before its programs compile
+        # against the snapshot
+        self._serving_params(progs, mv)
         progs.warm()
         self._programs[mv.key] = progs
         return progs
+
+    def _serving_params(self, progs: GenerationPrograms, mv: ModelVersion):
+        """``mv``'s parameters as its programs take them (the snapshot
+        ``progs`` casts once and casts again when ``mv.model.params`` has
+        changed); every cast lands in ``dl4j_decode_param_casts_total``."""
+        casts = progs.param_casts
+        params = progs.serving_params()
+        if progs.param_casts != casts:
+            self.metrics.param_casts.inc(model=mv.name)
+        return params
 
     # ------------------------------------------------------------ decode loop
     def _run(self) -> None:
@@ -448,7 +461,8 @@ class GenerationEngine:
                         bucket=bucket, shared_pages=shared_pages):
             with phase("jitted_step", stage="admit"):
                 self._pools, tok = progs.prefill(
-                    bucket, mv.model.params, mv.model.net_state,
+                    bucket, self._serving_params(progs, mv),
+                    mv.model.net_state,
                     self._pools, block,
                     np.asarray([req.shared_len], np.int32),
                     np.int32(len(suffix) - 1), tokens, base_key[None],
@@ -476,7 +490,8 @@ class GenerationEngine:
                         active=active):
             with phase("jitted_step", stage="decode"):
                 self._pools, sampled = progs.decode(
-                    mv.model.params, mv.model.net_state, self._pools,
+                    self._serving_params(progs, mv), mv.model.net_state,
+                    self._pools,
                     s.block, s.pos, s.last_tok, s.keys, s.tok_idx,
                     s.temps, s.top_ks, s.top_ps)
         with phase("sample_harvest", stage="decode"):

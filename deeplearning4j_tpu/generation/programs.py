@@ -21,20 +21,32 @@ state compiles exactly nothing — proven through the version's
 
 KV pools are donated on every call: XLA writes the new K/V in place
 instead of copying pool-sized buffers per token.
+
+Parameters reach ``prefill_<bucket>`` and ``decode`` as the version's
+SERVING SNAPSHOT (``serving_params``): the net's tree with every floating
+leaf cast to ``conf.compute_dtype`` once, by one jitted program, instead
+of inside each execution.  The programs are compiled for that tree.
 """
 
 from __future__ import annotations
 
+import operator
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.models.common import cast_to_compute
 from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
+
+
+# the snapshot's one program; versions of one architecture share its
+# compiled form, so a deploy or a re-cast compiles nothing
+_cast = jax.jit(cast_to_compute, static_argnums=(1,))
 
 
 def named_layers_of(net) -> List[Tuple[str, object]]:
@@ -136,6 +148,46 @@ class GenerationPrograms:
         self._read_page = jax.jit(self._make_read_page())
         self._write_page = jax.jit(self._make_write_page(),
                                    donate_argnums=(0,))
+        # the serving snapshot: (leaves it was cast from, the cast tree),
+        # replaced whole so a reader never sees a mix; None until the
+        # first serving_params()
+        self._serving = (None, None)
+        self.param_casts = 0            # snapshots cast so far
+
+    # ------------------------------------------------------ serving snapshot
+    def serving_params(self):
+        """The net's parameters as the compute programs take them: every
+        floating leaf in ``conf.compute_dtype``, cast ONCE by a single
+        jitted program and kept until the net's tree changes.  With
+        ``compute_dtype`` None, and for a leaf already in that dtype, the
+        snapshot holds the net's own buffer (no copy).
+
+        Never stale: the snapshot remembers the leaves it was cast from,
+        and a tree whose leaves are no longer those (``fit`` or
+        ``set_params_vector`` rebound ``net.params``, a pretrain loop
+        replaced a subtree in place) is cast again here, before the
+        dispatch, at the same shapes and dtypes, so nothing recompiles.
+        The check is one flatten and an identity comparison per leaf: host
+        work only, no device access."""
+        leaves, treedef = jax.tree_util.tree_flatten(self.net.params)
+        src, snapshot = self._serving
+        if (src is not None and len(leaves) == len(src)
+                and all(map(operator.is_, leaves, src))):
+            return snapshot
+        dt = self.net.conf.compute_dtype
+        # only the leaves the rule changes go through the program: a jit
+        # output is a new buffer even where the function is the identity
+        want = jax.eval_shape(lambda t: cast_to_compute(t, dt), leaves)
+        todo = [i for i, (a, w) in enumerate(zip(leaves, want))
+                if hasattr(a, "dtype") and a.dtype != w.dtype]
+        out = list(leaves)
+        if todo:
+            for i, c in zip(todo, _cast([leaves[i] for i in todo], dt)):
+                out[i] = c
+        snapshot = jax.tree_util.tree_unflatten(treedef, out)
+        self._serving = (leaves, snapshot)
+        self.param_casts += 1
+        return snapshot
 
     # ---------------------------------------------------------------- build
     def fresh_pools(self):
@@ -303,7 +355,7 @@ class GenerationPrograms:
         pools; nothing executes, nothing is donated) — how a caller reads
         which kernels a program was built from: ``.as_text()`` for the
         kernel names, ``.compile().as_text()`` for the final HLO."""
-        head = (self.net.params, self.net.net_state,
+        head = (self.serving_params(), self.net.net_state,
                 jax.eval_shape(self.fresh_pools))
         return {name: jitted.lower(*head, *tail)
                 for name, (jitted, tail) in self._compute_programs().items()}
@@ -326,12 +378,16 @@ class GenerationPrograms:
         ever while the opt-in collector is installed."""
         from deeplearning4j_tpu.observability import shardstats
 
+        params, net_state = self.serving_params(), self.net.net_state
         pools = self.fresh_pools()
         shardstats.record_ledger(
             "generation",
-            {"params": self.net.params, "net_state": self.net.net_state,
-             "kv_pools": pools})
-        params, net_state = self.net.params, self.net.net_state
+            {"params": self.net.params,
+             # what the snapshot costs: its copies, not the shared leaves
+             "serving_params": jax.tree_util.tree_map(
+                 lambda s, p: None if s is p else s, params,
+                 self.net.params),
+             "net_state": net_state, "kv_pools": pools})
         progs = self._compute_programs()
         coll = shardstats.active_collector()
         if coll is not None:

@@ -36,6 +36,32 @@ class LazyScoreMixin:
         self._score = value
 
 
+def cast_to_compute(tree, compute_dtype):
+    """``tree`` with every floating leaf in ``compute_dtype`` and everything
+    else untouched: the one mixed-precision rule of both facades.
+
+    Inside a traced forward the cast is part of the graph, so gradients
+    flow back to the f32 parameters (loss and updater math stay f32).  A
+    leaf already in the compute dtype passes through (``astype`` to the
+    same dtype emits nothing), which is how a generation program takes
+    the serving snapshot ``GenerationPrograms`` casts once per version.
+    With ``compute_dtype`` None the tree is returned as it came."""
+    if compute_dtype is None:
+        return tree
+    import jax
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(compute_dtype)
+
+    def _cast(a):
+        return (a.astype(dt)
+                if hasattr(a, "dtype")
+                and jnp.issubdtype(a.dtype, jnp.floating) else a)
+
+    with jax.named_scope("param_cast"):
+        return jax.tree_util.tree_map(_cast, tree)
+
+
 def notify_listeners(model, batch_size=None) -> None:
     """Fire ``iteration_done`` on the model's listeners, first wiring the
     actual minibatch size into any listener that wants it (fixes

@@ -23,7 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.backend.rng import KeyStream
-from deeplearning4j_tpu.models.common import LazyScoreMixin, notify_listeners
+from deeplearning4j_tpu.models.common import (
+    LazyScoreMixin, cast_to_compute, notify_listeners,
+)
 from deeplearning4j_tpu.observability import (
     crash_dump, fit_telemetry, instrument, step_guard,
 )
@@ -401,17 +403,10 @@ class ComputationGraph(LazyScoreMixin):
         new_state = dict(net_state)
         cd = self.conf.compute_dtype
         if cd is not None:
-            # mixed precision: cast float leaves into the compute dtype inside
-            # the graph so grads flow back to fp32 params (MLN._forward policy)
-            dt = jnp.dtype(cd)
-
-            def _cast(a):
-                return (a.astype(dt)
-                        if hasattr(a, "dtype")
-                        and jnp.issubdtype(a.dtype, jnp.floating) else a)
-
-            params = jax.tree_util.tree_map(_cast, params)
-            acts = {k: _cast(jnp.asarray(v)) for k, v in acts.items()}
+            # mixed precision: parameters and inputs in the compute dtype
+            params, vals = cast_to_compute(
+                (params, [jnp.asarray(v) for v in acts.values()]), cd)
+            acts = dict(zip(acts, vals))
         n_nodes = len(self.topo)
         rngs = jax.random.split(rng, n_nodes) if rng is not None else [None] * n_nodes
         out_names = set(self.conf.outputs)

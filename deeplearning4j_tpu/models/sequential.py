@@ -23,7 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.backend.rng import KeyStream
-from deeplearning4j_tpu.models.common import LazyScoreMixin, notify_listeners
+from deeplearning4j_tpu.models.common import (
+    LazyScoreMixin, cast_to_compute, notify_listeners,
+)
 from deeplearning4j_tpu.observability import (
     crash_dump, fit_telemetry, instrument, step_guard,
 )
@@ -137,19 +139,8 @@ class MultiLayerNetwork(LazyScoreMixin):
         h = x
         cd = self.conf.compute_dtype
         if cd is not None:
-            # mixed precision: cast float leaves to the compute dtype; the
-            # cast sits inside the graph, so grads flow back to fp32 params
-            # (loss and updater math stay fp32)
-            dt = jnp.dtype(cd)
-
-            def _cast(a):
-                return (a.astype(dt)
-                        if hasattr(a, "dtype")
-                        and jnp.issubdtype(a.dtype, jnp.floating) else a)
-
-            with jax.named_scope("param_cast"):
-                params = jax.tree_util.tree_map(_cast, params)
-                h = _cast(jnp.asarray(h))
+            # mixed precision: parameters and input in the compute dtype
+            params, h = cast_to_compute((params, jnp.asarray(h)), cd)
         n = len(self.layers)
         rngs = jax.random.split(rng, n) if rng is not None else [None] * n
         for i, layer in enumerate(self.layers):

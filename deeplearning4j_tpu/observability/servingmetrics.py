@@ -174,6 +174,12 @@ class GenerationMetrics:
         self.swaps = reg.counter(
             "dl4j_decode_model_swaps_total",
             "Completed generation-model hot-swaps", labels=("model",))
+        self.param_casts = reg.counter(
+            "dl4j_decode_param_casts_total",
+            "Serving snapshots cast: a version's parameters copied into "
+            "the compute dtype for its generation programs (one per "
+            "loaded version, one more each time its net's parameter "
+            "tree is found changed at a dispatch)", labels=("model",))
         # per-instance children
         self.active_slots = reg.gauge(
             "dl4j_decode_active_slots",
