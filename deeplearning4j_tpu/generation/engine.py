@@ -63,6 +63,9 @@ from deeplearning4j_tpu.serving.registry import ModelRegistry, ModelVersion
 logger = logging.getLogger("deeplearning4j_tpu.generation")
 
 DEFAULT_MODEL = "default"
+# harvests of an expert net's routing counts summed on the host between
+# two increments of the registry's counters (_take_moe_counts)
+MOE_FLUSH_EVERY = 32
 
 # finish reasons that count as a successful completion
 _OK_REASONS = ("length", "stop")
@@ -134,6 +137,8 @@ class GenerationEngine:
         self._drain = True
         self._thread: Optional[threading.Thread] = None
         self.steady_deliveries = 0      # tokens delivered since start
+        self._moe_pending = None        # expert-layer counts not yet in
+        self._moe_harvests = 0          # the registry (_take_moe_counts)
         # device-simulation pacing: enforce a minimum wall time per
         # decode step.  On real accelerators the host thread mostly
         # WAITS on the device, so N replica processes scale across N
@@ -424,9 +429,11 @@ class GenerationEngine:
                     return
             self.busy_wall_s += time.perf_counter() - t_iter
             if not stopping and not self.scheduler.has_work:
+                self._flush_moe_counts()
                 # not busy time, so in the trace and not in "phases"
                 with self.phases.phase("wait", stage="loop", child=True):
                     self.scheduler.wait_for_work(0.05)
+        self._flush_moe_counts()
 
     def _admit(self, progs: GenerationPrograms, mv: ModelVersion) -> None:
         while True:
@@ -471,9 +478,10 @@ class GenerationEngine:
                     np.asarray([req.top_k], np.int32),
                     np.asarray([req.top_p], np.float32))
         with phase("sample_harvest", stage="admit"):
-            first = int(jax.device_get(tok)[0])
+            tok = jax.device_get(tok)
         with phase("stream_write", stage="admit"):
-            self.scheduler.install(req, first, base_key)
+            tok = self._take_moe_counts(tok, "admit")
+            self.scheduler.install(req, int(tok[0]), base_key)
             self.metrics.ttft.observe(req.ttft_s)
             self.metrics.prefix_pages.inc(shared_pages, outcome="shared")
             self.metrics.prefix_pages.inc(len(req.pages) - shared_pages,
@@ -497,6 +505,7 @@ class GenerationEngine:
         with phase("sample_harvest", stage="decode"):
             sampled_host = jax.device_get(sampled)
         with phase("stream_write", stage="decode"):
+            sampled_host = self._take_moe_counts(sampled_host, "decode")
             with phase("deliver", stage="decode", child=True):
                 delivered = s.after_step(sampled_host)
             self.steady_deliveries += delivered
@@ -512,6 +521,37 @@ class GenerationEngine:
                                                  - t_step0)
             if remain > 0:
                 time.sleep(remain)
+
+    def _take_moe_counts(self, harvested, stage: str):
+        """The sampled ids out of what a compute program returned.  A net
+        with expert layers returns ``(ids, counts)`` (``programs.
+        _with_counts``), fetched in the one ``device_get``: the counts are
+        summed on the host under the child phase ``<stage>.moe_counters``
+        and reach the registry every ``MOE_FLUSH_EVERY`` harvests, when
+        the loop runs out of work and when it exits.  Any other net's
+        harvest passes through untouched."""
+        if not isinstance(harvested, tuple):
+            return harvested
+        ids, counts = harvested
+        with self.phases.phase("moe_counters", stage=stage, child=True):
+            self._moe_pending = (counts if self._moe_pending is None
+                                 else self._moe_pending + counts)
+            self._moe_harvests += 1
+            if self._moe_harvests % MOE_FLUSH_EVERY == 0:
+                self._flush_moe_counts()
+        return ids
+
+    def _flush_moe_counts(self) -> None:
+        """The pending counts into ``dl4j_moe_tokens_total`` and
+        ``dl4j_moe_held_assignments_total{expert}``."""
+        pending, self._moe_pending = self._moe_pending, None
+        if pending is None:
+            return
+        self.metrics.moe_tokens.inc(int(pending[0]))
+        for expert, n in enumerate(pending[1:]):
+            if n:
+                self.metrics.moe_held_assignments.inc(int(n),
+                                                      expert=str(expert))
 
     def _refresh_gauges(self) -> None:
         self.metrics.active_slots.set(len(self.scheduler.active_slots()))

@@ -7,9 +7,10 @@ where requests of wildly different lengths join and leave a running
 decode batch every step.  The paged design (vLLM's PagedAttention)
 decouples them:
 
-- **Device side** (``pools``): per attention layer, K/V pools of
-  ``num_pages`` pages of ``page_size`` positions each
-  (``init_paged_cache``).  Pool shapes are the only shapes XLA ever
+- **Device side** (``pools``): per attention layer, the pools its
+  ``init_paged_cache`` returns — K and V for ``SelfAttentionLayer``, one
+  latent pool for ``LatentAttentionLayer`` — each of ``num_pages`` pages
+  of ``page_size`` positions.  Pool shapes are the only shapes XLA ever
   sees — slot count, page count, and page size close the decode shape
   set, so steady-state serving compiles exactly nothing.
 - **Host side** (this class): a page allocator with per-page refcounts,

@@ -5,21 +5,27 @@ programs per model version, all AOT-warmed before the version serves:
 
 - ``prefill_<bucket>``: one request's (non-shared) prompt suffix, padded
   up to the bucket length, forwarded through the paged carries in a
-  single [1, bucket] call — writes its K/V into the request's pages and
-  samples the first token from the last REAL prompt position's logits.
+  single [1, bucket] call — writes its cache rows (K/V, or latent rows)
+  into the request's pages and samples the first token from the last
+  REAL prompt position's logits.
 - ``decode``: one token for EVERY slot in a single [slots, 1] call —
   the iteration-level batch.  Idle slots ride along pointed at the
   trash page with temperature 0; their lanes are pure garbage-in/
   garbage-out and the scheduler ignores their outputs.
-- ``read_page`` / ``write_page``: one page's K/V slice out of / into
-  every pool — the prefix cache's host-tier transport.
+- ``read_page`` / ``write_page``: one page's slice out of / into every
+  pool — the prefix cache's host-tier transport.
+
+A POOL is whatever page-major arrays a layer's ``init_paged_cache``
+returns: ``pk``/``pv`` [P, Hkv, page, D] for ``SelfAttentionLayer``, one
+latent ``pc`` [P, page, W] for ``LatentAttentionLayer``.
+Every function here reaches them through one walker, ``map_pools``.
 
 Shapes are closed by construction (slot count, pool size, block-table
 width, bucket lengths are all fixed at engine construction), so steady
 state compiles exactly nothing — proven through the version's
 ``RecompileDetector`` the same way the PR-2 serving warmup proves it.
 
-KV pools are donated on every call: XLA writes the new K/V in place
+Pools are donated on every call: XLA writes the new rows in place
 instead of copying pool-sized buffers per token.
 
 Parameters reach ``prefill_<bucket>`` and ``decode`` as the version's
@@ -37,10 +43,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.generation.paged_cache import TRASH_PAGE
 from deeplearning4j_tpu.models.common import cast_to_compute
 from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
+from deeplearning4j_tpu.nn.layers.moe import counting
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
 
@@ -63,7 +71,7 @@ def named_layers_of(net) -> List[Tuple[str, object]]:
 
 def seed_paged_pools(net, num_pages: int, page_size: int,
                      dtype=None) -> Dict:
-    """Paged KV pools for every pageable layer of ``net`` (the paged
+    """Paged pools for every pageable layer of ``net`` (the paged
     analog of ``models.common.seed_stream_caches``).  Raises when the
     net carries state that cannot be paged (recurrent hidden state) —
     the engine must fail at setup, not serve wrong tokens."""
@@ -82,39 +90,50 @@ def seed_paged_pools(net, num_pages: int, page_size: int,
     if not pools:
         raise ValueError(
             "no pageable attention layers found — the generation engine "
-            "needs at least one causal SelfAttentionLayer KV cache")
+            "needs at least one causal attention layer with a paged cache")
     return pools
 
 
+def map_pools(fn, pools, *others):
+    """The one walker over a pool tree: ``fn(pool, *other)`` at every POOL
+    — the dict of page-major arrays ``[num_pages, ...]`` that one layer's
+    ``init_paged_cache`` returned (``pk``/``pv`` for ``SelfAttentionLayer``,
+    one latent ``pc`` for ``LatentAttentionLayer``; a dict none of whose
+    values is a dict) — with the entries at the same path of every tree in
+    ``others``; returns the tree of the results.  ``others`` may hold
+    more than ``pools`` does (the forward's carries); only ``pools``' paths
+    are visited."""
+    def walk(c, *o):
+        if not any(isinstance(v, dict) for v in c.values()):
+            return fn(c, *o)
+        return {k: walk(v, *(x[k] for x in o)) for k, v in c.items()}
+    return {k: walk(v, *(x[k] for x in others)) for k, v in pools.items()}
+
+
 def _attach(pools, block, pos):
-    """Insert the dispatch's block table / positions into every paged
-    leaf (the pool pytree stays pk/pv-only between dispatches)."""
-    def walk(c):
-        if isinstance(c, dict) and "pk" in c:
-            return {**c, "block": block, "pos": pos}
-        if isinstance(c, dict):
-            return {k: walk(v) for k, v in c.items()}
-        return c
-    return {k: walk(v) for k, v in pools.items()}
+    """Insert the dispatch's block table / positions beside every pool's
+    arrays (the pool pytree holds the arrays alone between dispatches)."""
+    return map_pools(lambda c: {**c, "block": block, "pos": pos}, pools)
 
 
-def _strip(carries):
+def _strip(carries, pools):
     """Keep only the updated pools out of the forward's new carries.
     The forward returns a carry entry for EVERY carry-capable layer —
     ``None`` for the ones that ran carry-less (MLP residual blocks) —
-    and those must be dropped, or the output pytree's structure would
-    differ from the input pools' and every warmed program would retrace
-    on its first live call."""
-    def walk(c):
-        if isinstance(c, dict) and "pk" in c:
-            return {"pk": c["pk"], "pv": c["pv"]}
-        if isinstance(c, dict):
-            out = {k: w for k, v in c.items()
-                   if (w := walk(v)) is not None}
-            return out or None
-        return None
-    return {k: w for k, v in (carries or {}).items()
-            if (w := walk(v)) is not None}
+    and beside each pool's arrays the block table and positions; what
+    goes back has exactly ``pools``' structure, or every warmed program
+    would retrace on its first live call."""
+    return map_pools(lambda c, new: {k: new[k] for k in c}, pools, carries)
+
+
+def _with_counts(tokens, counts):
+    """What a compute program returns beside the pools: the sampled ids,
+    and for a net with expert layers ``(ids, counts)``, the layers'
+    ``nn.layers.moe.counting`` vectors summed (one more small array in the
+    same fetch)."""
+    if not counts:
+        return tokens
+    return tokens, sum(counts[1:], counts[0])
 
 
 class GenerationPrograms:
@@ -142,7 +161,7 @@ class GenerationPrograms:
         self._prefill = {
             b: jax.jit(self._make_prefill(b), donate_argnums=(2,))
             for b in self.prefill_buckets}
-        # page transport (prefix-cache host tier): one page's K/V slice
+        # page transport (prefix-cache host tier): one page's slice
         # out of / into every pool.  Fixed shapes — two more members of
         # the closed program set, warmed with the rest.
         self._read_page = jax.jit(self._make_read_page())
@@ -186,7 +205,7 @@ class GenerationPrograms:
                 out[i] = c
         snapshot = jax.tree_util.tree_unflatten(treedef, out)
         self._serving = (leaves, snapshot)
-        self.param_casts += 1
+        self.param_casts += bool(todo)    # leaves all in the dtype: no cast
         return snapshot
 
     # ---------------------------------------------------------------- build
@@ -215,12 +234,16 @@ class GenerationPrograms:
                         keys, token_idx, temps, top_ks, top_ps):
             """One token for every slot: [S] in, [S] out."""
             x = encode(tokens[:, None])
-            pre, nc = fwd(params, net_state, x, _attach(pools, block, pos))
+            # real rows: an idle slot's table points at the trash page
+            with counting(lambda: block[:, :1] != TRASH_PAGE) as counts:
+                pre, nc = fwd(params, net_state, x,
+                              _attach(pools, block, pos))
             with jax.named_scope("sample"):
                 logits = pre[:, -1].astype(jnp.float32)
                 nxt = sample_tokens(logits, keys, token_idx, temps, top_ks,
                                     top_ps)
-            return _strip(nc), nxt.astype(jnp.int32)
+            return _strip(nc, pools), _with_counts(nxt.astype(jnp.int32),
+                                                   counts)
 
         return decode_step
 
@@ -233,52 +256,43 @@ class GenerationPrograms:
             ``start`` [1] is the suffix's global start position (0, or
             the shared-prefix length); ``last_idx`` () indexes the last
             REAL token inside the bucket — bucket padding beyond it
-            writes scratch K/V that the causal mask hides until decode
+            writes scratch rows that the causal mask hides until decode
             overwrites it position by position."""
             x = encode(tokens)
-            pre, nc = fwd(params, net_state, x,
-                          _attach(pools, block, start))
+            # real rows: the prompt's own tokens, not the bucket's padding
+            with counting(lambda: jnp.arange(bucket)[None] <= last_idx
+                          ) as counts:
+                pre, nc = fwd(params, net_state, x,
+                              _attach(pools, block, start))
             with jax.named_scope("sample"):
                 logits = jnp.take(pre[0], last_idx, axis=0)[None]
                 tok = sample_tokens(logits.astype(jnp.float32), keys,
                                     token_idx, temps, top_ks, top_ps)
-            return _strip(nc), tok.astype(jnp.int32)
+            return _strip(nc, pools), _with_counts(tok.astype(jnp.int32),
+                                                   counts)
 
         return prefill
 
     def _make_read_page(self):
         def read_page(pools, page):
-            """One page's [Hkv, page_size, D] K/V slice from every pool
-            (the offload side of the host tier)."""
-            def walk(c):
-                if isinstance(c, dict) and "pk" in c:
-                    return {"pk": jax.lax.dynamic_index_in_dim(
-                                c["pk"], page, 0, keepdims=False),
-                            "pv": jax.lax.dynamic_index_in_dim(
-                                c["pv"], page, 0, keepdims=False)}
-                if isinstance(c, dict):
-                    return {k: walk(v) for k, v in c.items()}
-                return c
-            return {k: walk(v) for k, v in pools.items()}
+            """One page's slice of every pool's arrays (the offload side
+            of the host tier)."""
+            return map_pools(
+                lambda c: {k: jax.lax.dynamic_index_in_dim(
+                    a, page, 0, keepdims=False) for k, a in c.items()},
+                pools)
 
         return read_page
 
     def _make_write_page(self):
         def write_page(pools, page, payload):
-            """One page's K/V slice back into every pool (the restore
-            side); pools are donated, so the write is in place."""
-            def walk(c, p):
-                if isinstance(c, dict) and "pk" in c:
-                    return {"pk": jax.lax.dynamic_update_index_in_dim(
-                                c["pk"], p["pk"].astype(c["pk"].dtype),
-                                page, 0),
-                            "pv": jax.lax.dynamic_update_index_in_dim(
-                                c["pv"], p["pv"].astype(c["pv"].dtype),
-                                page, 0)}
-                if isinstance(c, dict):
-                    return {k: walk(v, p[k]) for k, v in c.items()}
-                return c
-            return {k: walk(v, payload[k]) for k, v in pools.items()}
+            """One page's slices back into every pool (the restore side);
+            pools are donated, so the write is in place."""
+            return map_pools(
+                lambda c, p: {k: jax.lax.dynamic_update_index_in_dim(
+                    a, p[k].astype(a.dtype), page, 0)
+                    for k, a in c.items()},
+                pools, payload)
 
         return write_page
 
@@ -302,7 +316,7 @@ class GenerationPrograms:
             keys, token_idx, temps, top_ks, top_ps)
 
     def read_page(self, pools, page: int, expected: bool = False):
-        """Device → host: one page's K/V slices as a numpy payload."""
+        """Device → host: one page of every pool as a numpy payload."""
         if self.detector is not None:
             self.detector.check(("read_page",), {}, expected=expected)
         return jax.device_get(self._read_page(pools, np.int32(page)))
@@ -316,19 +330,10 @@ class GenerationPrograms:
         return self._write_page(pools, np.int32(page), payload)
 
     def page_nbytes(self, pools) -> int:
-        """Host bytes one offloaded page costs (every pool's K+V slice)
-        — the unit of the prefix cache's host-tier budget."""
-        total = 0
-        def walk(c):
-            nonlocal total
-            if isinstance(c, dict) and "pk" in c:
-                total += ((c["pk"].nbytes + c["pv"].nbytes)
-                          // c["pk"].shape[0])
-            elif isinstance(c, dict):
-                for v in c.values():
-                    walk(v)
-        walk(pools)
-        return total
+        """Host bytes one offloaded page costs (one page of every pool
+        array) — the unit of the prefix cache's host-tier budget."""
+        return sum(a.nbytes // a.shape[0]
+                   for a in jax.tree_util.tree_leaves(pools))
 
     # --------------------------------------------------------------- warmup
     def _compute_programs(self) -> Dict[str, Tuple]:
@@ -366,7 +371,7 @@ class GenerationPrograms:
         detector as planned compiles.  Returns the number of programs
         warmed — after this, steady-state serving compiles nothing.
 
-        Warmup is also the memory-observability hook: the KV pool /
+        Warmup is also the memory-observability hook: the pool /
         params ledger is recorded here (metadata walk), and when a
         ``ShardStatsCollector`` is installed each program additionally
         gets its HLO memory + collective census (abstract lowering on

@@ -190,8 +190,10 @@ def _q_index(causal, block_q, block_k, window=None):
 
 def _fwd_call(q, k, v, *, scale, causal, window, block_q, block_k,
               interpret):
-    """q,k,v: [BH, T, D] (D already lane-padded). Returns (o, lse[BH,T,128])."""
+    """q,k: [BH, T, D], v: [BH, T, Dv] (D and Dv already lane-padded).
+    Returns (o [BH, T, Dv], lse [BH, T, 128])."""
     bh, t, d = q.shape
+    dv = v.shape[-1]
     nq, nk = t // block_q, t // block_k
     grid = (bh, nq, nk)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -204,20 +206,20 @@ def _fwd_call(q, k, v, *, scale, causal, window, block_q, block_k,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((None, block_k, d), kv_idx),
-            pl.BlockSpec((None, block_k, d), kv_idx),
+            pl.BlockSpec((None, block_k, dv), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((None, block_q, LANES), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
-            _sds((bh, t, d), q.dtype, q),
+            _sds((bh, t, dv), q.dtype, q),
             _sds((bh, t, LANES), jnp.float32, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -362,6 +364,10 @@ def _flash_fwd(q, k, v, scale, causal, window, block_q, block_k,
 
 def _flash_bwd(scale, causal, window, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "flash attention's backward kernels take one head width; "
+            f"got q.k {q.shape[-1]} and v {v.shape[-1]} (forward only)")
     dq, dk, dv = _bwd_call(q, k, v, o, lse, g, scale=scale,
                            causal=causal, window=window, block_q=block_q,
                            block_k=block_k, interpret=interpret)
@@ -376,8 +382,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     window: Optional[int] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Fused attention on ``[B, T, H, D]`` tensors (layer layout).
+                    interpret: Optional[bool] = None,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Fused attention on ``[B, T, H, D]`` tensors (layer layout).  ``v``
+    (and so the output) may have another width than ``q`` and ``k``
+    (latent attention's expanded path: 192 against 128), forward only;
+    ``scale`` replaces the ``1/sqrt(D)`` on the scores.
 
     Requires T to be a multiple of the block sizes (see :func:`supports`);
     when blocks are not given the largest exact tiling up to 512/1024 is
@@ -401,19 +411,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_q, block_k = picked
     if interpret is None:
         interpret = _interpret()
-    scale = 1.0 / (d ** 0.5)  # softmax scale uses the TRUE head dim
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)  # softmax scale uses the TRUE head dim
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
 
-    dp = (-d) % LANES
-    if dp:
-        pad = ((0, 0), (0, 0), (0, 0), (0, dp))
-        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+    def lane_pad(x, n):
+        return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, n))) if n else x
+
+    dv = v.shape[-1]
+    dp, dvp = (-d) % LANES, (-dv) % LANES
+    q, k, v = lane_pad(q, dp), lane_pad(k, dp), lane_pad(v, dvp)
     o = _flash(to_bh(q), to_bh(k), to_bh(v), scale, causal, window,
                block_q, block_k, interpret)
-    o = o.reshape(b, h, t, d + dp).transpose(0, 2, 1, 3)
-    return o[..., :d] if dp else o
+    o = o.reshape(b, h, t, dv + dvp).transpose(0, 2, 1, 3)
+    return o[..., :dv] if dvp else o
 
 
 class FlashAttentionHelper:
@@ -446,14 +459,16 @@ class FlashAttentionHelper:
         return supports(t, d)
 
     def attend(self, q, k, v, *, causal: bool = False,
-               window: Optional[int] = None) -> jax.Array:
+               window: Optional[int] = None,
+               scale: Optional[float] = None) -> jax.Array:
         from jax.sharding import PartitionSpec as P
 
         from deeplearning4j_tpu import helpers
         from deeplearning4j_tpu.backend.device import AXIS_DATA, AXIS_MODEL
 
         def attn(q, k, v):
-            return flash_attention(q, k, v, causal=causal, window=window)
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   scale=scale)
 
         mesh = helpers.partition_mesh()
         if mesh is None:

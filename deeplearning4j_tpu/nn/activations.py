@@ -93,6 +93,7 @@ _REGISTRY: Dict[str, Callable] = {
     "softmax": softmax,
     "gelu": gelu,
     "swish": swish,
+    "silu": swish,
 }
 
 

@@ -5,6 +5,7 @@ from deeplearning4j_tpu.nn.layers.dense import (
     ActivationLayer,
     DropoutLayer,
     EmbeddingLayer,
+    GatedMLP,
 )
 from deeplearning4j_tpu.nn.layers.convolution import (
     ConvolutionLayer,
@@ -15,8 +16,10 @@ from deeplearning4j_tpu.nn.layers.normalization import (
     BatchNormalization,
     LayerNorm,
     LocalResponseNormalization,
+    RMSNorm,
 )
 from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
+from deeplearning4j_tpu.nn.layers.latent_attention import LatentAttentionLayer
 from deeplearning4j_tpu.nn.layers.composite import ResidualBlock
 from deeplearning4j_tpu.nn.layers.recurrent import (
     GravesLSTM,
@@ -25,4 +28,4 @@ from deeplearning4j_tpu.nn.layers.recurrent import (
     RnnOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.autoencoder import AutoEncoder, RBM
-from deeplearning4j_tpu.nn.layers.moe import MoELayer
+from deeplearning4j_tpu.nn.layers.moe import MoELayer, RoutedMoELayer

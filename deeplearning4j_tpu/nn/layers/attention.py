@@ -20,10 +20,12 @@ Design notes (TPU):
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn import activations, initializers
 from deeplearning4j_tpu.nn.inputs import InputType
@@ -50,10 +52,44 @@ def check_window(causal: bool, window: Optional[int]) -> None:
             f"window={window} requires causal=True and window >= 1")
 
 
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's rotary frequencies (Peng et al. 2023, as DeepSeek-V2/V3
+    apply them) for ``dim`` rotated columns: each pair's frequency is
+    blended between the extrapolated one (``theta^(-2i/dim)``) and the
+    interpolated one (the same over ``factor``) by a linear ramp over the
+    pair index, from the pair that turns ``beta_fast`` times within
+    ``original_max`` positions down to the one that turns ``beta_slow``
+    times.  Static numbers, computed on the host in float64."""
+    half = dim // 2
+    extra = theta ** (-np.arange(half, dtype=np.float64) / half)
+    inter = extra / factor
+
+    def pair_at(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_at(beta_fast)), 0)
+    high = min(math.ceil(pair_at(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def rope(x: jax.Array, positions: jax.Array,
-         theta: float = 10000.0) -> jax.Array:
+         theta: float = 10000.0, inv_freq=None) -> jax.Array:
     """Rotary position embedding on ``[B, T, H, D]`` (RoFormer; public
-    standard).  ``positions`` is the [T] vector of GLOBAL positions —
+    standard).  ``inv_freq`` ([D // 2], e.g. ``yarn_inv_freq``) replaces
+    the frequencies ``theta`` gives.  ``positions`` is the [T] vector of
+    GLOBAL positions —
     or, for the paged continuous-batching decode path where every batch
     row sits at a different stream position, a per-row [B, T] matrix —
     which is what makes the same function serve the full-sequence path,
@@ -64,8 +100,11 @@ def rope(x: jax.Array, positions: jax.Array,
     d = x.shape[-1]
     half = d // 2
     acc = jnp.promote_types(x.dtype, jnp.float32)
-    freqs = jnp.power(jnp.asarray(theta, acc),
-                      -jnp.arange(0, half, dtype=acc) / max(half, 1))
+    if inv_freq is not None:
+        freqs = jnp.asarray(inv_freq, acc)
+    else:
+        freqs = jnp.power(jnp.asarray(theta, acc),
+                          -jnp.arange(0, half, dtype=acc) / max(half, 1))
     ang = positions.astype(acc)[..., :, None] * freqs  # [(B,) T, half]
     if positions.ndim == 1:
         cos = jnp.cos(ang)[None, :, None, :]
@@ -93,8 +132,11 @@ def dot_product_attention(
     k_offset: int | jax.Array = 0,
     q_positions: Optional[jax.Array] = None,
     k_positions: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Scaled dot-product attention on ``[B, T, H, D]`` tensors.
+    """Scaled dot-product attention on ``[B, T, H, D]`` tensors; ``scale``
+    replaces the ``1/sqrt(D)`` on the scores, and ``v`` may be narrower or
+    wider than ``q`` and ``k`` (ungrouped heads only).
 
     ``q_offset``/``k_offset`` give the global time positions of the local
     q/k blocks — this is what lets the same function serve as the per-block
@@ -119,7 +161,10 @@ def dot_product_attention(
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(acc)
     else:
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(acc)
-    scores = scores / jnp.sqrt(jnp.asarray(d, acc))
+    if scale is None:
+        scores = scores / jnp.sqrt(jnp.asarray(d, acc))
+    else:
+        scores = scores * jnp.asarray(scale, acc)
     neg = jnp.asarray(-1e30, acc)
     head_dims = (None,) * (scores.ndim - 3)   # axes between batch and [q,k]
     if causal:

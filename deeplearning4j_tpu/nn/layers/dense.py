@@ -60,6 +60,52 @@ class DenseLayer(Layer):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class GatedMLP(Layer):
+    """Bias-free gated feed-forward block (SwiGLU with the default
+    activation): ``(act(x W_gate) * (x W_up)) W_down``.  ``hidden`` is the
+    width of the gate and up projections."""
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None
+    hidden: int = 0
+    activation: str = "silu"
+
+    def setup(self, input_type: InputType) -> "GatedMLP":
+        n_in = self.n_in if self.n_in is not None else input_type.flat_size()
+        n_out = self.n_out if self.n_out is not None else n_in
+        return dataclasses.replace(self, n_in=n_in, n_out=n_out)
+
+    def output_type(self, input_type: InputType) -> InputType:
+        if input_type.kind == "rnn":
+            return InputType.recurrent(self.n_out, input_type.timesteps)
+        return InputType.feed_forward(self.n_out)
+
+    def init(self, key, dtype=jnp.float32):
+        if self.hidden < 1:
+            raise ValueError("GatedMLP needs hidden >= 1")
+        kg, ku, kd = jax.random.split(key, 3)
+
+        def w(k, shape):
+            return initializers.init(self.weight_init, k, shape, dtype)
+
+        return {"W_gate": w(kg, (self.n_in, self.hidden)),
+                "W_up": w(ku, (self.n_in, self.hidden)),
+                "W_down": w(kd, (self.hidden, self.n_out))}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        x = self.maybe_dropout(x, train=train, rng=rng)
+        return gated_mlp(x, params["W_gate"], params["W_up"],
+                         params["W_down"], self.activation), state
+
+
+def gated_mlp(x, w_gate, w_up, w_down, activation="silu"):
+    """One gated feed-forward product, shared with the expert layer's
+    shared expert."""
+    return (activations.get(activation)(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class OutputLayer(DenseLayer):
     """Dense + loss head (reference ``nn/layers/OutputLayer.java``).
     ``loss`` names a function in :mod:`deeplearning4j_tpu.nn.losses`."""

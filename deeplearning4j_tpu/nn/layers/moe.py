@@ -11,12 +11,21 @@ expert's FFN on its own chips with all-to-all dispatch inserted by GSPMD.
 Tokens over a full expert's capacity are dropped (contribute the residual
 path only) — standard Switch-Transformer semantics that keeps the program
 shape-static under jit.
+
+``RoutedMoELayer`` is the dropless top-k layer of the DeepSeek-V3 family:
+sigmoid scores, a selection bias, normalised weights, a shared expert, and
+a layer that is TOLD WHICH EXPERTS IT HOLDS — one chip's share of an
+expert-parallel deployment.  It routes over all ``n_experts``, computes
+the part of the result its own experts give, and adds nothing for the
+others; on one chip it runs without its exchange.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +33,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn import activations, initializers
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.nn.layers.dense import gated_mlp
 
 
 @register_layer
@@ -112,3 +122,202 @@ class MoELayer(Layer):
         if self.residual:
             combined = combined + tokens
         return combined.reshape(orig_shape[:-1] + (self.n_out,)), state
+
+
+# ---------------------------------------------------------------------------
+# trace-time counting scope (the generation programs' counters)
+# ---------------------------------------------------------------------------
+
+_counting = contextvars.ContextVar("dl4j_tpu_moe_counting", default=None)
+
+
+@contextlib.contextmanager
+def counting(valid):
+    """Trace-time scope, like ``helpers.auto_partitioned``: every
+    ``RoutedMoELayer`` traced inside appends to the yielded list one int32
+    vector ``[real tokens, assignments to held expert 0, 1, ...]``.
+    ``valid`` is a thunk that gives the boolean mask of real rows, shaped
+    like the layer input's leading axes; it is called only if an expert
+    layer is there, so a net without one traces nothing more."""
+    sink = []
+    token = _counting.set((valid, sink))
+    try:
+        yield sink
+    finally:
+        _counting.reset(token)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RoutedMoELayer(Layer):
+    """Dropless top-k expert FFN with a shared expert (DeepSeek-V3's
+    ``MoE`` with ``noaux_tc`` routing in one group), as one share of it.
+
+    In float32 whatever the compute dtype: ``s = sigmoid(x W_router)``
+    over all ``n_experts``; the ``top_k`` largest of ``s + b_router`` are
+    chosen; their weights are the chosen ``s`` (without the bias), divided
+    by their sum when ``norm_topk_prob``, times ``routed_scaling_factor``.
+    ``y = sum_i w_i E_i(x) + E_shared(x)``, every expert a bias-free gated
+    MLP of width ``hidden`` (the shared one of width ``shared``; 0 = none).
+    No capacity: no token is dropped.
+
+    ``experts_held = (first, count)`` names the experts whose weights this
+    layer has (``W_gate/W_up/W_down`` are ``[count, ...]``); None holds
+    all.  Tokens are sorted by held expert and multiplied group by group
+    (``jax.lax.ragged_dot``); assignments to experts held elsewhere sort
+    last and add nothing."""
+
+    n_in: Optional[int] = None
+    n_out: Optional[int] = None
+    n_experts: int = 8
+    top_k: int = 2
+    hidden: int = 0
+    shared: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    activation: str = "silu"
+
+    def setup(self, input_type: InputType) -> "RoutedMoELayer":
+        n_in = self.n_in if self.n_in is not None else input_type.flat_size()
+        n_out = self.n_out if self.n_out is not None else n_in
+        return dataclasses.replace(self, n_in=n_in, n_out=n_out)
+
+    def output_type(self, input_type: InputType) -> InputType:
+        if input_type.kind == "rnn":
+            return InputType.recurrent(self.n_out, input_type.timesteps)
+        return InputType.feed_forward(self.n_out)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        if self.experts_held is None:
+            return 0, self.n_experts
+        return int(self.experts_held[0]), int(self.experts_held[1])
+
+    def validate(self) -> None:
+        super().validate()
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} lies outside the "
+                f"{self.n_experts} experts")
+        if not 1 <= self.top_k <= self.n_experts or self.hidden < 1:
+            raise ValueError("RoutedMoELayer needs 1 <= top_k <= n_experts "
+                             "and hidden >= 1")
+
+    def init(self, key, dtype=jnp.float32) -> Dict[str, jax.Array]:
+        count = self.held[1]
+        d, h = self.n_in, self.hidden
+        ks = jax.random.split(key, 7)
+
+        def w(k, shape, fan_in, fan_out):
+            return initializers.init(self.weight_init, k, shape, dtype,
+                                     fan_in=fan_in, fan_out=fan_out)
+
+        p = {"W_router": w(ks[0], (d, self.n_experts), d, self.n_experts),
+             "b_router": jnp.zeros((self.n_experts,), dtype),
+             "W_gate": w(ks[1], (count, d, h), d, h),
+             "W_up": w(ks[2], (count, d, h), d, h),
+             "W_down": w(ks[3], (count, h, self.n_out), h, self.n_out)}
+        if self.shared:
+            p["Ws_gate"] = w(ks[4], (d, self.shared), d, self.shared)
+            p["Ws_up"] = w(ks[5], (d, self.shared), d, self.shared)
+            p["Ws_down"] = w(ks[6], (self.shared, self.n_out), self.shared,
+                             self.n_out)
+        return p
+
+    def route(self, params, tokens):
+        """tokens [T, d] -> (expert ids [T, k] int32, weights [T, k]
+        float32), over all ``n_experts``."""
+        with jax.named_scope("moe_router"):
+            f32 = jnp.float32
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens, params["W_router"], preferred_element_type=f32
+            ).astype(f32))
+            _, ids = jax.lax.top_k(scores + params["b_router"].astype(f32),
+                                   self.top_k)
+            w = jnp.take_along_axis(scores, ids, axis=1)
+            if self.norm_topk_prob:
+                w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+            return ids.astype(jnp.int32), w * self.routed_scaling_factor
+
+    def _held_experts(self, params, tokens, ids, w):
+        """The held experts' part of the result, [T, n_out] float32.
+
+        Assignments are sorted by held expert (those held elsewhere last)
+        and multiplied in blocks of ``rows`` sorted rows, as many blocks as
+        the held assignments fill: a block is four times the share
+        ``count / n_experts`` of all assignments, so uniform routing takes
+        one block and a skewed batch takes more (dropless either way), and
+        the rows gathered at once stay a fraction of ``T * top_k``."""
+        first, count = self.held
+        t, k = ids.shape
+        a = t * k
+        local = ids - first
+        mine = (local >= 0) & (local < count)
+        key = jnp.where(mine, local, count).reshape(a)    # elsewhere: last
+        rows = min(a, -(-4 * a * count // self.n_experts))
+        blocks = -(-a // rows)
+        order = jnp.pad(jnp.argsort(key, stable=True),
+                        (0, blocks * rows - a))
+        ends = jnp.cumsum(jnp.sum(
+            key[:, None] == jnp.arange(count)[None, :], axis=0,
+            dtype=jnp.int32))        # the sorted row where e's group ends
+        n_mine = ends[-1]
+        weight = jnp.where(mine, w, 0.0).reshape(a)
+        act = activations.get(self.activation)
+
+        def block(j, acc):
+            lo = j * rows
+            at = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            cut = jnp.clip(ends, lo, lo + rows) - lo
+            sizes = jnp.diff(cut, prepend=0)   # each group's rows in here
+            tok = at // k
+            x = tokens[tok]
+            hid = (act(jax.lax.ragged_dot(x, params["W_gate"], sizes))
+                   * jax.lax.ragged_dot(x, params["W_up"], sizes))
+            y = jax.lax.ragged_dot(hid, params["W_down"], sizes,
+                                   preferred_element_type=jnp.float32)
+            # rows past the held ones are no group's: whatever they hold
+            y = jnp.where((lo + jnp.arange(rows) < n_mine)[:, None],
+                          y * weight[at][:, None], 0.0)
+            return acc.at[tok].add(y)
+
+        return jax.lax.fori_loop(
+            0, (n_mine + rows - 1) // rows, block,
+            jnp.zeros((t, self.n_out), jnp.float32))
+
+    def _count(self, ids):
+        scope = _counting.get()
+        if scope is None:
+            return
+        valid, sink = scope
+        first, count = self.held
+        real = valid().reshape(-1)
+        local = jnp.where(real[:, None], ids - first, -1)
+        per = jnp.sum(local[..., None] == jnp.arange(count), axis=(0, 1),
+                      dtype=jnp.int32)
+        sink.append(jnp.concatenate(
+            [jnp.sum(real, dtype=jnp.int32)[None], per]))
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        x = self.maybe_dropout(x, train=train, rng=rng)
+        tokens = x.reshape(-1, x.shape[-1])
+        ids, w = self.route(params, tokens)
+        self._count(ids)
+        with jax.named_scope("moe_experts"):
+            y = self._held_experts(params, tokens, ids, w)
+        if self.shared:
+            with jax.named_scope("moe_shared_expert"):
+                y = y + gated_mlp(tokens, params["Ws_gate"],
+                                  params["Ws_up"], params["Ws_down"],
+                                  self.activation)
+        return y.astype(x.dtype).reshape(x.shape[:-1] + (self.n_out,)), state
+
+    @classmethod
+    def from_dict(cls, d):
+        d = dict(d)
+        if d.get("experts_held") is not None:
+            d["experts_held"] = tuple(d["experts_held"])
+        return super().from_dict(d)

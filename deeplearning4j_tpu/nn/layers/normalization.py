@@ -184,3 +184,40 @@ class LayerNorm(Layer):
         y = (x - mu) * lax.rsqrt(var + self.eps)
         y = params["gamma"] * y + params["beta"]
         return activations.get(self.activation)(y), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the trailing feature axis
+    (Zhang & Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * gamma`` — no
+    mean subtraction, no bias.  The statistics are taken in float32
+    whatever the compute dtype and the result is cast back."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-5
+    activation: str = "identity"
+
+    def setup(self, input_type: InputType) -> "RMSNorm":
+        if self.n_in is None:
+            return dataclasses.replace(self, n_in=input_type.size)
+        return self
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def init(self, key, dtype=jnp.float32):
+        return {"gamma": jnp.ones((self.n_in,), dtype)}
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        y = rms_norm(x, params["gamma"], self.eps)
+        return activations.get(self.activation)(y), state
+
+
+def rms_norm(x, gamma, eps):
+    """The RMS norm itself, shared with the layers that norm inside
+    (latent attention's two compressed streams)."""
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(acc)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(acc)).astype(x.dtype)

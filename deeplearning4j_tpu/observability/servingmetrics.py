@@ -180,6 +180,18 @@ class GenerationMetrics:
             "the compute dtype for its generation programs (one per "
             "loaded version, one more each time its net's parameter "
             "tree is found changed at a dispatch)", labels=("model",))
+        self.moe_tokens = reg.counter(
+            "dl4j_moe_tokens_total",
+            "Real tokens routed by the served net's expert layers, summed "
+            "over those layers (bucket padding and idle slots excluded); "
+            "counted on the device, harvested with the sampled ids")
+        self.moe_held_assignments = reg.counter(
+            "dl4j_moe_held_assignments_total",
+            "Token-to-expert assignments that fell on an expert this "
+            "engine's net holds, by the expert's index among the held "
+            "ones, summed over expert layers; over "
+            "dl4j_moe_tokens_total: top_k * held / n_experts when routing "
+            "is uniform", labels=("expert",))
         # per-instance children
         self.active_slots = reg.gauge(
             "dl4j_decode_active_slots",

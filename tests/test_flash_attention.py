@@ -730,3 +730,21 @@ def test_sync_master_step_lowers_for_tpu_on_a_mesh(monkeypatch):
     assert sorted(set(kernels)) == ["flash_attention_dkv",
                                     "flash_attention_dq",
                                     "flash_attention_fwd"]
+
+
+@pytest.mark.parametrize("d,dv", [(48, 32), (192, 128)])
+def test_forward_parity_with_a_v_width_and_a_scale_of_its_own(d, dv):
+    """Latent attention's expanded path: q.k width != v width (192 against
+    128 as published) and the YaRN temperature on the scores."""
+    q, k = (_rand((1, 256, 2, d), s) for s in (0, 1))
+    v = _rand((1, 256, 2, dv), 2)
+    scale = 1.4159 ** 2 / d ** 0.5
+    ref = dot_product_attention(q, k, v, causal=True, scale=scale)
+    out = fa.flash_attention(q, k, v, causal=True, scale=scale)
+    assert out.shape == (1, 256, 2, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    if d == 192:    # 256 lanes against 128: the backward kernels take one
+        with pytest.raises(NotImplementedError, match="forward only"):
+            jax.grad(lambda q: jnp.sum(fa.flash_attention(
+                q, k, v, causal=True, scale=scale)))(q)

@@ -26,17 +26,23 @@ _KWARGS = {
     "DenseLayer": dict(n_in=4, n_out=3),
     "DropoutLayer": dict(dropout=0.5),
     "EmbeddingLayer": dict(n_in=7, n_out=4),
+    "GatedMLP": dict(n_in=4, n_out=4, hidden=6),
     "GlobalPoolingLayer": dict(),
     "GravesBidirectionalLSTM": dict(n_in=3, n_out=4),
     "GravesLSTM": dict(n_in=3, n_out=4),
     "LSTM": dict(n_in=3, n_out=4),
+    "LatentAttentionLayer": dict(n_in=8, n_out=8, n_heads=2, q_rank=6,
+                                 kv_rank=4, nope_dim=4, rope_dim=2, v_dim=4),
     "LayerNorm": dict(n_in=5),
     "LocalResponseNormalization": dict(),
     "MoELayer": dict(n_in=4, n_out=4, num_experts=2),
     "OutputLayer": dict(n_in=4, n_out=3),
     "RBM": dict(n_in=6, n_out=4),
+    "RMSNorm": dict(n_in=5),
     "ResidualBlock": None,  # composite: exercised in test_mixed/test_graph
     "RnnOutputLayer": dict(n_in=4, n_out=3),
+    "RoutedMoELayer": dict(n_in=4, n_out=4, n_experts=4, top_k=2, hidden=6,
+                           shared=6, experts_held=(1, 2)),
     "SelfAttentionLayer": dict(n_in=4, n_out=4, n_heads=2),
     "SubsamplingLayer": dict(kernel_size=(2, 2), stride=(2, 2)),
 }
@@ -50,16 +56,20 @@ _INPUTS = {
     "DenseLayer": (2, 4),
     "DropoutLayer": (2, 5),
     "EmbeddingLayer": (2, 3),          # integer ids
+    "GatedMLP": (2, 4),
     "GlobalPoolingLayer": (2, 4, 4, 3),
     "GravesBidirectionalLSTM": (2, 5, 3),
     "GravesLSTM": (2, 5, 3),
     "LSTM": (2, 5, 3),
+    "LatentAttentionLayer": (2, 5, 8),
     "LayerNorm": (2, 5),
     "LocalResponseNormalization": (2, 4, 4, 3),
     "MoELayer": (2, 4),
     "OutputLayer": (2, 4),
     "RBM": (2, 6),
+    "RMSNorm": (2, 5),
     "RnnOutputLayer": (2, 5, 4),
+    "RoutedMoELayer": (2, 4),
     "SelfAttentionLayer": (2, 5, 4),
     "SubsamplingLayer": (2, 6, 6, 2),
 }
