@@ -59,6 +59,7 @@ from deeplearning4j_tpu.observability.tracing import get_tracer
 from deeplearning4j_tpu.serving.admission import ModelNotFoundError
 from deeplearning4j_tpu.serving.buckets import _pow2_buckets
 from deeplearning4j_tpu.serving.registry import ModelRegistry, ModelVersion
+from deeplearning4j_tpu.utils.sampling import SAMPLING_PATHS, sampling_path
 
 logger = logging.getLogger("deeplearning4j_tpu.generation")
 
@@ -464,6 +465,9 @@ class GenerationEngine:
             with phase("base_key", stage="admit", child=True):
                 base_key = _base_key(req.seed)
             block = self.cache.block_row(req.pages)[None]
+            policy = (np.asarray([req.temperature], np.float32),
+                      np.asarray([req.top_k], np.int32),
+                      np.asarray([req.top_p], np.float32))
         with step_guard("decode_prefill", engine=self.metrics.engine_id,
                         bucket=bucket, shared_pages=shared_pages):
             with phase("jitted_step", stage="admit"):
@@ -473,10 +477,7 @@ class GenerationEngine:
                     self._pools, block,
                     np.asarray([req.shared_len], np.int32),
                     np.int32(len(suffix) - 1), tokens, base_key[None],
-                    np.zeros(1, np.int32),
-                    np.asarray([req.temperature], np.float32),
-                    np.asarray([req.top_k], np.int32),
-                    np.asarray([req.top_p], np.float32))
+                    np.zeros(1, np.int32), *policy)
         with phase("sample_harvest", stage="admit"):
             tok = jax.device_get(tok)
         with phase("stream_write", stage="admit"):
@@ -487,6 +488,8 @@ class GenerationEngine:
             self.metrics.prefix_pages.inc(len(req.pages) - shared_pages,
                                           outcome="fresh")
             self.metrics.tokens.inc(model=mv.name)
+            self.metrics.sampling_steps.inc(
+                stage="admit", path=SAMPLING_PATHS[sampling_path(*policy)])
             self._refresh_gauges()
 
     def _step(self, progs: GenerationPrograms, mv: ModelVersion) -> None:
@@ -506,11 +509,14 @@ class GenerationEngine:
             sampled_host = jax.device_get(sampled)
         with phase("stream_write", stage="decode"):
             sampled_host = self._take_moe_counts(sampled_host, "decode")
+            # of the rows as dispatched: after_step resets a finished slot
+            path = SAMPLING_PATHS[sampling_path(s.temps, s.top_ks, s.top_ps)]
             with phase("deliver", stage="decode", child=True):
                 delivered = s.after_step(sampled_host)
             self.steady_deliveries += delivered
             with phase("gauges", stage="decode", child=True):
                 self.metrics.steps.inc()
+                self.metrics.sampling_steps.inc(stage="decode", path=path)
                 self.metrics.tokens.inc(delivered, model=mv.name)
                 self.metrics.batch_occupancy.observe(active / s.num_slots)
                 self._refresh_gauges()

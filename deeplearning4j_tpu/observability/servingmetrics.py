@@ -180,6 +180,14 @@ class GenerationMetrics:
             "the compute dtype for its generation programs (one per "
             "loaded version, one more each time its net's parameter "
             "tree is found changed at a dispatch)", labels=("model",))
+        self.sampling_steps = reg.counter(
+            "dl4j_sampling_steps_total",
+            "Dispatched decode steps (stage=decode) and prefills "
+            "(stage=admit) by what their sampling epilogue ran, from the "
+            "rows' policy (utils.sampling.sampling_path): greedy = an "
+            "argmax, draw = temperature and the categorical draw, filter "
+            "= the top-k / top-p sorts for the whole batch",
+            labels=("stage", "path"))
         self.moe_tokens = reg.counter(
             "dl4j_moe_tokens_total",
             "Real tokens routed by the served net's expert layers, summed "

@@ -15,6 +15,20 @@ continuous-batching generation engine (per-slot RUNTIME policy arrays via
 ``sample_tokens`` — one compiled decode step serves requests with mixed
 sampling configs).  All three route through ``_filter_logits`` so the
 kept-set semantics can never diverge between paths.
+
+What a ``sample_tokens`` step costs follows the batch's policy arrays
+(``sampling_path``, decided inside the program, one compiled program):
+
+- ``greedy`` — no row has ``temperature > 0``: one argmax over the
+  vocabulary, nothing else;
+- ``draw`` — some row draws and no drawing row sets ``top_k >= 1`` or
+  ``top_p < 1``: the division by the temperature and the categorical
+  draw (Gumbel noise over ``[B, V]``), no sort;
+- ``filter`` — a drawing row asks for top-k or top-p: two sorts of
+  ``[B, V]``, a softmax and a cumulative sum, for the WHOLE batch.
+
+A greedy row's ``top_k`` / ``top_p`` never count: the kept set does not
+change an argmax.
 """
 
 from __future__ import annotations
@@ -96,6 +110,22 @@ def _sampler(temperature: float, top_k: Optional[int],
     return sample
 
 
+# what a batch's sampling epilogue does, cheapest first (sampling_path)
+SAMPLING_PATHS = ("greedy", "draw", "filter")
+
+
+def sampling_path(temperature, top_k, top_p):
+    """Index into ``SAMPLING_PATHS`` of the least work that serves every
+    row of a batch with these ``[B]`` policy arrays.  Pure and written
+    against the array methods numpy and ``jnp`` share: ``sample_tokens``
+    calls it on traced arrays to pick its branch, the engine on the
+    scheduler's numpy arrays to count ``dl4j_sampling_steps_total``."""
+    draws = temperature > 0
+    filters = draws & ((top_k >= 1) | (top_p < 1))
+    return (draws.any().astype(np.int32)
+            + filters.any().astype(np.int32))
+
+
 def sample_tokens(logits: jax.Array, keys: jax.Array, token_idx: jax.Array,
                   temperature: jax.Array, top_k: jax.Array,
                   top_p: jax.Array) -> jax.Array:
@@ -108,14 +138,36 @@ def sample_tokens(logits: jax.Array, keys: jax.Array, token_idx: jax.Array,
     who else is in the batch); ``temperature`` [B] (<= 0 -> greedy);
     ``top_k`` [B] int32 (< 1 disables); ``top_p`` [B] (>= 1 disables).
     Same policy math as ``_sampler`` row-for-row (shared
-    ``_filter_logits``)."""
-    step_keys = jax.vmap(jax.random.fold_in)(keys, token_idx)
+    ``_filter_logits``).
+
+    One ``lax.switch`` on ``sampling_path`` of the three arrays runs only
+    what the batch asks for; every branch returns, row for row, what the
+    ``filter`` branch returns: ``greedy`` and ``draw`` leave out only work
+    whose result the last ``where`` discards or a filter that keeps
+    everything.  (Up to rounding: a disabled top-p's f32 cumulative sum
+    can reach 1 before the last entries and drop a tail of total mass
+    under 1e-6 of a 49,152-wide row; ``draw`` keeps that tail.)"""
     temp = jnp.asarray(temperature, logits.dtype)
-    safe_t = jnp.where(temp > 0, temp, jnp.ones_like(temp))
-    filtered = _filter_logits(logits / safe_t[:, None], top_k, top_p)
-    drawn = jax.vmap(lambda k, l: jax.random.categorical(k, l, axis=-1))(
-        step_keys, filtered)
-    return jnp.where(temp > 0, drawn, jnp.argmax(logits, axis=-1))
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, logits.dtype)
+
+    def greedy():
+        return jnp.argmax(logits, axis=-1)
+
+    def draw(filtered: bool):
+        step_keys = jax.vmap(jax.random.fold_in)(keys, token_idx)
+        safe_t = jnp.where(temp > 0, temp, jnp.ones_like(temp))
+        scaled = logits / safe_t[:, None]
+        if filtered:
+            scaled = _filter_logits(scaled, top_k, top_p)
+        drawn = jax.vmap(
+            lambda k, l: jax.random.categorical(k, l, axis=-1))(
+            step_keys, scaled)
+        return jnp.where(temp > 0, drawn, greedy())
+
+    return jax.lax.switch(
+        sampling_path(temp, top_k, top_p),
+        (greedy, lambda: draw(False), lambda: draw(True)))
 
 
 def _resolve_encoding(net, prompt_ids, one_hot: Optional[bool],
