@@ -2,7 +2,7 @@
 
 A d1024 train step takes the better part of a minute to compile on the
 chip and every process starts cold, so the entry points (``chip_smoke.py``,
-``bench.py``, ``fleet/replica_main.py``, the TPU test tier) share JAX's
+``fleet/replica_main.py``, the TPU test tier) share JAX's
 persistent compilation cache.  The directory is part of the cache key, so
 it must not move between runs: it is either wherever the operator put it
 (``JAX_COMPILATION_CACHE_DIR``, which JAX reads by itself) or one fixed

@@ -2,10 +2,9 @@
 
 STDLIB ONLY on purpose — no package imports at all.  The router's
 placement decision must be simulatable without jax, numpy, or even the
-rest of this package: ``scripts/ci_checks.py`` gate 6 loads THIS FILE by
-path (the same pattern ``check_bench_regression.py`` uses for
-``observability/regression.py``) and runs ``placement_selftest()`` as a
-millisecond-fast pre-test gate.  ``fleet/router.py`` builds the live
+rest of this package: ``scripts/ci_checks.py`` gate 5 loads THIS FILE by
+path and runs ``placement_selftest()`` as a millisecond-fast pre-test
+gate.  ``fleet/router.py`` builds the live
 router (handles, retries, metrics, spans) on top of these primitives.
 
 The policy, in order:
@@ -294,7 +293,7 @@ def _sim_run(policy: str, seed: int, page_size: int = 4
 
 
 def placement_selftest(verbose: bool = False) -> int:
-    """CI gate 6: the placement policy's behavioral contract, simulated
+    """CI gate 5: the placement policy's behavioral contract, simulated
     with zero processes and zero jax.  Returns 0 on pass, 1 on fail."""
     failures: List[str] = []
 

@@ -43,8 +43,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill-buckets", default="16",
                     help="comma-separated prompt buckets")
     ap.add_argument("--step-floor-ms", type=float, default=0.0,
-                    help="decode_step_floor_s pacing in ms (device-sim; "
-                    "0 = off)")
+                    help="least wall time of a decode step, in ms: the "
+                    "fleet kill drill paces its replicas with it (0 = off)")
     args = ap.parse_args(argv)
 
     # imports AFTER argparse: --help must not pay the jax tax

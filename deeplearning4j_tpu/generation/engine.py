@@ -140,13 +140,10 @@ class GenerationEngine:
         self.steady_deliveries = 0      # tokens delivered since start
         self._moe_pending = None        # expert-layer counts not yet in
         self._moe_harvests = 0          # the registry (_take_moe_counts)
-        # device-simulation pacing: enforce a minimum wall time per
-        # decode step.  On real accelerators the host thread mostly
-        # WAITS on the device, so N replica processes scale across N
-        # chips even on one host core; on the CPU tier the "device" IS
-        # the host core and replicas contend instead.  The fleet bench
-        # sets this to model the device-bound regime honestly (labeled
-        # "paced" in its output); 0 disables and changes nothing.
+        # pacing: a minimum wall time per decode step.  Its one user is
+        # the fleet kill drill (tests/test_fleet_router.py), which slows
+        # its CPU replicas so that a kill lands while a stream is still
+        # being written; 0 disables and changes nothing.
         self.decode_step_floor_s = float(decode_step_floor_s)
 
     # ------------------------------------------------------------- lifecycle
@@ -521,8 +518,8 @@ class GenerationEngine:
                 self.metrics.batch_occupancy.observe(active / s.num_slots)
                 self._refresh_gauges()
         if self.decode_step_floor_s > 0.0:
-            # sleep (not spin) to the floor: the yielded core is exactly
-            # what lets sibling replica processes decode concurrently
+            # sleep (not spin) to the floor: sibling replica processes
+            # share the host's cores
             remain = self.decode_step_floor_s - (time.perf_counter()
                                                  - t_step0)
             if remain > 0:

@@ -1,4 +1,5 @@
-"""Model zoo — the benchmark configs (BASELINE.json) built on the DSL.
+"""Model zoo — the reference's target configurations (BASELINE.json) built
+on the DSL.
 
 - LeNet-5 / MNIST  (reference baseline config 1: MultiLayerNetwork)
 - ResNet-50        (reference baseline config 2: ComputationGraph; residual

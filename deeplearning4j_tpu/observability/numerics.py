@@ -112,7 +112,7 @@ ENTRY = 1 + 2 * len(FORMATS) + HIST_BINS
 # past the format's max finite, and the absorption cutoff derived from
 # the max exponent) never depend on the sample; only the fraction
 # magnitudes carry the ~1/sqrt(n) sampling error.  This is what keeps
-# the ledger's step overhead under the 5% bench sentinel.  Policy knob:
+# the ledger's step overhead small.  Policy knob:
 # ``TrainingNumerics(sample=0)`` forces exact full-pass fractions.
 DEFAULT_SAMPLE = 1024
 

@@ -61,8 +61,9 @@ _PEAK = "dl4j_backend_peak_flops"
 _CAPTURES = "dl4j_profile_captures_total"
 _STEP_PEAK_MEM = "dl4j_step_peak_memory_bytes"
 
-# peak dense matmul throughput per chip, bf16 FLOP/s — the one owner of
-# the table (bench.py imports it from here).  Keyed by the EXACT
+# peak dense matmul throughput per chip, bf16 FLOP/s — the program's own
+# table behind the MFU gauges (tests/test_profiling.py pins its v5e row
+# to the benchmark's peaks.json).  Keyed by the EXACT
 # ``device_kind`` string the runtime reports, so a chip nobody measured
 # against can never inherit a neighbour's number.
 PEAK_FLOPS = {

@@ -239,8 +239,7 @@ class RecompileDetector:
 class _InstrumentedJit:
     """Transparent wrapper: ``__call__`` runs the detector then the jitted
     fn; everything else (``lower``, ``trace``, ``clear_cache``…) delegates,
-    so AOT-compile workflows (bench.py) keep working on the wrapped
-    object.
+    so AOT-compile workflows keep working on the wrapped object.
 
     ``argnums`` restricts the fingerprint to those positional args — the
     fit loops pass only the DATA argument positions (batch, labels, masks,

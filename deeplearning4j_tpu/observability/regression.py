@@ -1,35 +1,30 @@
-"""Bench regression sentinel: compare a fresh ``bench_full.json`` against a
-committed baseline with per-metric direction + tolerance rules.
+"""The comparison behind ``kernel_trust.json``: per-metric direction and
+tolerance rules over two JSON documents.
 
-Until now the perf trajectory was advisory: ``bench.py`` wrote numbers, a
-human eyeballed them.  This module gives it teeth — a rule says which
-field of which bench entry matters, which DIRECTION is good, and how much
-relative slack the (noisy, CPU-jittered) measurement gets before a change
-counts as a regression.  ``scripts/check_bench_regression.py`` wraps it as
-a CI gate: exit 0 clean, exit 1 on any regression.
+``python -m deeplearning4j_tpu.observability.kerneldiff --baseline
+kernel_trust.json`` sweeps every kernel against its reference and holds
+the fresh report to the committed one with ``KERNEL_TRUST_RULES``: a rule
+says which field of which entry matters, which DIRECTION is good, and how
+much relative slack the value gets before a change counts as a
+regression.  The values are error bounds and counts from a deterministic
+sweep, never timings: speed is measured by ``python3 -m benchmark.run``
+on the chip and judged by the driver (``PERF.md``).
 
-STDLIB ONLY on purpose: the checker script must run in milliseconds with
-no jax import, and the module is imported by file path from ``scripts/``
-(same pattern as ``check_metrics_docs.py``).
-
-Rule addressing: bench entries live in ``doc["all"]``, each with a
-``metric`` name like ``"Decode tokens/sec (d256 L4, b4, ...)"`` — the
-part after `` (`` encodes the config and changes across platforms, so
-rules match on the PREFIX before it.  ``field`` is a dotted path inside
-the entry (``"value"``, ``"variants.gqa2_rolling.tokens_per_sec"``).
-With ``scope="doc"`` the rule skips the entry lookup and resolves
-``field`` from the DOCUMENT root instead — how the memory sentinels
-address ``observability.memory.sentinels.*`` (the ``metric`` string is
-then only the display name).
+Rule addressing: entries live in ``doc["all"]``, each with a ``metric``
+name like ``"Kernel max rel error (flash_attention)"``; rules match on
+the PREFIX.  ``field`` is a dotted path inside the entry (``"value"``,
+``"variants.fast.tps"``).  With ``scope="doc"`` the rule skips the entry
+lookup and resolves ``field`` from the DOCUMENT root instead — how
+``summary.failing_configs`` is addressed (the ``metric`` string is then
+only the display name).
 """
 
 from __future__ import annotations
 
-import json
 from typing import Any, Dict, List, Optional
 
-HIGHER = "higher"   # bigger is better (throughput)
-LOWER = "lower"     # smaller is better (latency, step time)
+HIGHER = "higher"   # bigger is better
+LOWER = "lower"     # smaller is better (an error bound, a failure count)
 
 
 class Rule:
@@ -77,217 +72,6 @@ class Rule:
                     d.get("required", True), d.get("scope", "all"))
 
 
-# The committed policy over bench_full.json.  Tolerances are wide (0.4)
-# because the CPU bench's run-to-run spread reaches ~25% (bench.py
-# SPREAD_THRESHOLD discussion); the sentinel is for collapses, not jitter.
-DEFAULT_RULES: List[Rule] = [
-    Rule("ResNet-50 images/sec/chip", tolerance=0.4),
-    Rule("LeNet-MNIST train step time", direction=LOWER, tolerance=0.4),
-    Rule("GravesLSTM char-LM throughput", tolerance=0.4),
-    Rule("Transformer char-LM tokens/sec", tolerance=0.4),
-    Rule("Decode tokens/sec", tolerance=0.4),
-    Rule("Decode tokens/sec", field="variants.gqa2_rolling.tokens_per_sec",
-         tolerance=0.4, required=False),
-    # continuous-batching generation (bench_generation): the aggregate
-    # 16-client decode throughput is the headline the paged-KV engine
-    # exists for; the speedup-vs-single-stream ratio guards the batching
-    # win itself (an aggregate that only tracks single-stream drift
-    # would let the scheduler silently serialize); the exact zero rule
-    # pins the decode-side AOT-warmup contract.
-    Rule("Generation tokens/sec", tolerance=0.4),
-    Rule("Generation tokens/sec", field="speedup_vs_single_stream",
-         tolerance=0.4, required=False),
-    Rule("Generation tokens/sec", field="p99_ttft_ms", direction=LOWER,
-         tolerance=1.0, required=False),
-    Rule("Generation tokens/sec", field="steady_state_compiles",
-         direction=LOWER, tolerance=0.0, required=False),
-    # persistent prefix cache (ISSUE 17): ttft_collapse_ok pins "a hit's
-    # p99 TTFT is <= 0.3x a cold miss's" (1 = collapse held; direction=
-    # higher + tolerance=0 means any drop to 0 regresses), and
-    # hit_rate_nonzero pins "the steady state actually hits the cache" —
-    # a change that silently stops matching (version-tag bug, tree never
-    # populated) fails immediately rather than showing up as a slow
-    # TTFT drift
-    Rule("Generation tokens/sec", field="prefix_cache.ttft_collapse_ok",
-         tolerance=0.0, required=False),
-    Rule("Generation tokens/sec", field="prefix_cache.hit_rate_nonzero",
-         tolerance=0.0, required=False),
-    Rule("Generation tokens/sec",
-         field="prefix_cache.steady_state_compiles",
-         direction=LOWER, tolerance=0.0, required=False),
-    # decode SLO attribution (ISSUE 18): the ITL histogram must stay
-    # populated under the 16-client window, the per-phase breakdown must
-    # keep reconciling with the decode loop's busy wall (within 10% —
-    # phase_sum_ok pins it), and serializing a federated snapshot must
-    # stay host-side only (publisher_host_sync_free: any new device
-    # sync drops the sentinel to 0 and fails immediately)
-    Rule("Generation tokens/sec", field="slo.itl_populated",
-         tolerance=0.0, required=False),
-    Rule("Generation tokens/sec", field="slo.phase_sum_ok",
-         tolerance=0.0, required=False),
-    Rule("Generation tokens/sec", field="slo.publisher_host_sync_free",
-         tolerance=0.0, required=False),
-    # fused paged decode (ISSUE 19): speedup_vs_gather pins the measured
-    # fused-vs-gather-oracle throughput ratio on this container;
-    # fused_no_slower (1 = the fused default is at least as fast) and
-    # gather_share_collapsed (1 = the per-token decode-step cost the
-    # gather used to pay has collapsed) are exact sentinels — a change
-    # that silently routes decode back through the materialized gather
-    # drops them to 0 and fails immediately; the exact-zero compile rule
-    # pins the fused program set's AOT-warmup contract
-    Rule("Generation tokens/sec", field="fused_decode.speedup_vs_gather",
-         tolerance=0.4, required=False),
-    Rule("Generation tokens/sec", field="fused_decode.fused_no_slower",
-         tolerance=0.0, required=False),
-    Rule("Generation tokens/sec",
-         field="fused_decode.gather_share_collapsed",
-         tolerance=0.0, required=False),
-    Rule("Generation tokens/sec",
-         field="fused_decode.steady_state_compiles",
-         direction=LOWER, tolerance=0.0, required=False),
-    Rule("Long-context train tokens/sec", tolerance=0.4),
-    Rule("Serving rows/sec", tolerance=0.4),
-    Rule("Serving rows/sec", field="p99_ms", direction=LOWER, tolerance=1.0,
-         required=False),
-    # zero-compile contract: the baseline is 0, so ANY steady-state
-    # compile regresses regardless of tolerance (0 * (1+tol) == 0)
-    Rule("Serving rows/sec", field="steady_state_compiles", direction=LOWER,
-         tolerance=0.0, required=False),
-    Rule("Checkpoint save throughput", tolerance=0.4),
-    Rule("Elastic DP samples/sec", tolerance=0.4),
-    Rule("Elastic DP samples/sec", field="degraded_vs_lockstep_speedup",
-         tolerance=0.5, required=False),
-    # stream-to-serving model freshness: seconds from a published event to
-    # a swapped-in model serving it, under concurrent load (bench_online).
-    # Smaller is better; tolerance is wide because the window includes
-    # eval + canary + watch phases whose sleeps jitter on a loaded CPU.
-    Rule("Online stream-to-serving freshness", direction=LOWER,
-         tolerance=1.0),
-    Rule("Online stream-to-serving freshness", field="promoted",
-         tolerance=0.0, required=False),
-    # stability engine (bench_stability): the guarded train step must not
-    # drift slower — the device-side non-finite mask + loss scaling ride
-    # inside the XLA program, so a step-time collapse here means the
-    # guard fell off the fused path.  Recovery = poison onset -> guard
-    # skips -> sentinel verdict -> checkpoint rewind -> training resumed;
-    # wide tolerance because the drill includes checkpoint I/O.
-    Rule("Stability guarded step", direction=LOWER, tolerance=0.4),
-    Rule("Stability guarded step", field="recovery_ms", direction=LOWER,
-         tolerance=1.0, required=False),
-    # training introspection (bench_introspection): the stats-on fit step
-    # must not drift slower — the per-layer reductions are fused into the
-    # XLA step and the harvest is one batched transfer per 10th step, so
-    # a collapse here means the collection fell off the fused path (or a
-    # per-report host-sync storm came back).
-    Rule("Introspected train step", direction=LOWER, tolerance=0.4),
-    # precision ledger (bench_numerics): the numerics-on fit step must
-    # not drift slower (the range stats ride inside the XLA step like
-    # the introspection reductions); ledger_overhead_ok pins the <5%
-    # overhead contract itself (1 = within budget, direction=higher +
-    # tolerance=0 means any drop to 0 regresses), and the exact-zero
-    # rule pins "enabling the ledger adds NO steady-state recompiles"
-    Rule("Numerics-ledger train step", direction=LOWER, tolerance=0.4),
-    Rule("Numerics-ledger train step", field="ledger_overhead_ok",
-         tolerance=0.0, required=False),
-    Rule("Numerics-ledger train step", field="steady_state_compiles",
-         direction=LOWER, tolerance=0.0, required=False),
-    # fleet telemetry plane (bench_fleet, ISSUE 18): publish->ingest lag
-    # across the two-process federation must stay bounded (lower; wide
-    # tolerance — the HTTP long-poll handoff jitters on a loaded CPU),
-    # publisher_overhead_ok pins the <2%-on-the-train-step contract, and
-    # the kill/restart drill's verdicts must stay 1: the dead worker is
-    # detected AND named, and the restarted epoch merges with no
-    # double-count and no reset-to-zero
-    Rule("Fleet telemetry ingest lag", direction=LOWER, tolerance=3.0),
-    Rule("Fleet telemetry ingest lag", field="publisher_overhead_ok",
-         tolerance=0.0, required=False),
-    Rule("Fleet telemetry ingest lag", field="federation.stale_detected",
-         tolerance=0.0, required=False),
-    Rule("Fleet telemetry ingest lag",
-         field="federation.stale_worker_named",
-         tolerance=0.0, required=False),
-    Rule("Fleet telemetry ingest lag",
-         field="federation.restart_merge_ok",
-         tolerance=0.0, required=False),
-    # serving fleet (bench_fleet_serving, ISSUE 20): the 4-replica
-    # aggregate is the headline; scaling_4x_ok pins the >=3.0x floor of
-    # the 4-vs-1 aggregate (1 = floor held; direction=higher +
-    # tolerance=0 means any drop to 0 regresses) with the raw speedup
-    # tracked alongside; affinity_beats_random pins "cache-aware
-    # placement finds more resident prefixes than the seeded-random
-    # control"; zero_queued_errors pins the failover contract (a
-    # SIGKILLed replica's queued requests land on survivors with no
-    # client-visible error) and rejoin/rollback verdicts pin the
-    # lifecycle halves; the exact-zero compile rule pins steady-state
-    # traffic across the scaling+affinity arms (captured before the
-    # kill drill — a restart legitimately re-runs its AOT warmup)
-    Rule("Fleet serving tokens/sec", tolerance=0.4),
-    Rule("Fleet serving tokens/sec", field="scaling.speedup_4x_vs_1",
-         tolerance=0.4, required=False),
-    Rule("Fleet serving tokens/sec", field="scaling.scaling_4x_ok",
-         tolerance=0.0, required=False),
-    Rule("Fleet serving tokens/sec", field="p99_ttft_ms",
-         direction=LOWER, tolerance=1.0, required=False),
-    Rule("Fleet serving tokens/sec",
-         field="affinity.affinity_beats_random",
-         tolerance=0.0, required=False),
-    Rule("Fleet serving tokens/sec",
-         field="failover.zero_queued_errors",
-         tolerance=0.0, required=False),
-    Rule("Fleet serving tokens/sec", field="failover.recovery_ms",
-         direction=LOWER, tolerance=3.0, required=False),
-    Rule("Fleet serving tokens/sec", field="failover.restart_rejoined",
-         tolerance=0.0, required=False),
-    Rule("Fleet serving tokens/sec", field="rollout.promoted",
-         tolerance=0.0, required=False),
-    Rule("Fleet serving tokens/sec", field="rollout.rolled_back_all",
-         tolerance=0.0, required=False),
-    Rule("Fleet serving tokens/sec", field="steady_state_compiles",
-         direction=LOWER, tolerance=0.0, required=False),
-    # memory & collective-communication sentinels (bench _memory_measure
-    # -> observability.memory.sentinels): FLIPPED to the ZeRO baselines
-    # by the update-sharding PR (ROADMAP item 2, arXiv 2004.13336) — the
-    # sentinels now pin the SHARDED numbers: updater-state replication
-    # ~1 (was K), params ~1, the window's collective/wire bytes in the
-    # all-to-all + all-gather decomposition (at or below the old
-    # all-reduce wire bytes), and per-device train-state bytes at the
-    # sharded level.  direction=lower + tolerance=0 means "any increase
-    # regresses" — a change that silently knocks the wrapper back to
-    # replicated updater state fails the replication rule immediately.
-    # Optional because the section needs the virtual mesh (subprocess,
-    # like the elastic bench).
-    Rule("Memory: updater replication (4-replica DP, ZeRO)", scope="doc",
-         field="observability.memory.sentinels.updater_replication_factor",
-         direction=LOWER, tolerance=0.0, required=False),
-    Rule("Memory: param replication (4-replica DP, ZeRO)", scope="doc",
-         field="observability.memory.sentinels.param_replication_factor",
-         direction=LOWER, tolerance=0.0, required=False),
-    Rule("Memory: collective bytes/step (4-replica DP, ZeRO)", scope="doc",
-         field="observability.memory.sentinels.collective_bytes_per_step",
-         direction=LOWER, tolerance=0.25, required=False),
-    Rule("Memory: wire bytes/step (4-replica DP, ZeRO)", scope="doc",
-         field="observability.memory.sentinels.wire_bytes_per_step",
-         direction=LOWER, tolerance=0.25, required=False),
-    Rule("Memory: per-device train bytes (4-replica DP, ZeRO)", scope="doc",
-         field="observability.memory.sentinels.per_device_bytes",
-         direction=LOWER, tolerance=0.25, required=False),
-    # the ZeRO window's zero-steady-state-recompile contract: the
-    # baseline is EXACTLY 0, so any steady-state compile of the sharded
-    # window regresses regardless of tolerance (0 * (1+tol) == 0)
-    Rule("Memory: ZeRO window steady-state recompiles", scope="doc",
-         field=("observability.memory.sentinels"
-                ".zero_steady_state_recompiles"),
-         direction=LOWER, tolerance=0.0, required=False),
-    # bench_zero: ZeRO step time must stay in the replicated band (the
-    # sharded update + gather must not fall off the fused path), and the
-    # per-device-bytes ratio guards the memory win itself (~(2+K)/(3K)
-    # for adam; a ratio drifting toward 1 means the sharding fell off)
-    Rule("ZeRO DP step time", direction=LOWER, tolerance=0.4),
-    Rule("ZeRO DP step time", field="per_device_bytes_ratio",
-         direction=LOWER, tolerance=0.1, required=False),
-]
-
-
 # The committed policy over kernel_trust.json (observability.kerneldiff
 # sweeps; ``python -m ...kerneldiff --baseline kernel_trust.json``).
 # Worst-config max-rel-error per kernel: direction=lower with a 1.0
@@ -321,15 +105,6 @@ KERNEL_TRUST_RULES: List[Rule] = [
     Rule("Kernel trust failing configs", scope="doc",
          field="summary.failing_configs", direction=LOWER, tolerance=0.0),
 ]
-
-
-def load_rules(path: str) -> List[Rule]:
-    """Rules from a JSON file: a list of rule dicts (see Rule.from_dict)."""
-    with open(path) as f:
-        data = json.load(f)
-    if not isinstance(data, list):
-        raise ValueError(f"{path}: rules file must be a JSON list")
-    return [Rule.from_dict(d) for d in data]
 
 
 # ------------------------------------------------------------- extraction
@@ -415,16 +190,16 @@ class Report:
 
 
 def compare(baseline: Dict[str, Any], fresh: Dict[str, Any],
-            rules: Optional[List[Rule]] = None) -> Report:
+            rules: List[Rule]) -> Report:
     """Evaluate every rule: a fresh value past ``baseline * (1 ± tol)``
     in the BAD direction regresses; a missing fresh value regresses when
     the rule is ``required``; a MISSING baseline skips the rule
     (``no_baseline`` — there is nothing to hold the line against).  A
     zero baseline is enforced, not skipped: with ``direction=lower`` and
     ``tolerance=0`` it means "any increase regresses" — the
-    steady-state-compiles contract depends on exactly that."""
+    ``summary.failing_configs`` rule depends on exactly that."""
     verdicts: List[Verdict] = []
-    for rule in (rules if rules is not None else DEFAULT_RULES):
+    for rule in rules:
         base = extract(baseline, rule)
         new = extract(fresh, rule)
         if base is None:
@@ -453,12 +228,3 @@ def compare(baseline: Dict[str, Any], fresh: Dict[str, Any],
                   f"(fails when {arrow} {limit:g})")
         verdicts.append(Verdict(rule, status, base, new, limit, detail))
     return Report(verdicts)
-
-
-def check_files(baseline_path: str, fresh_path: str,
-                rules: Optional[List[Rule]] = None) -> Report:
-    with open(baseline_path) as f:
-        baseline = json.load(f)
-    with open(fresh_path) as f:
-        fresh = json.load(f)
-    return compare(baseline, fresh, rules)

@@ -68,10 +68,9 @@ _REPLICATION = "dl4j_replication_factor"
 
 # ---------------------------------------------------------------- bandwidth
 # Per-chip interconnect (ICI) bandwidth, bytes/s, all links combined.
-# The ONE owner of the table: the comm roofline, the grad-sync CLI and
-# bench all import it from here (same single-owner discipline as
-# ``profiling.PEAK_FLOPS``, and like it keyed by the EXACT ``device_kind``
-# string the runtime reports).  Every consumer labels the derived seconds
+# The ONE owner of the table: the comm roofline reads it from here (same
+# single-owner discipline as ``profiling.PEAK_FLOPS``, and like it keyed
+# by the EXACT ``device_kind`` string the runtime reports).  Every consumer labels the derived seconds
 # as estimates.
 LINK_BANDWIDTH = {
     # TPU v5e: 1,600 Gbit/s per chip (Google Cloud documentation, "TPU v5e")
@@ -108,7 +107,7 @@ def link_bandwidth_for(device=None) -> Tuple[float, str]:
 def ring_wire_bytes(op: str, payload_bytes: float,
                     group_size: Optional[int]) -> float:
     """Bytes through each device's link for one collective, ring
-    algorithm (the scaling-book recipe ``measure_grad_sync`` uses):
+    algorithm (the scaling-book recipe):
     all-reduce moves ``2(g-1)/g * payload``; all-gather/reduce-scatter
     half that; a permute moves the payload once.  Unknown group size
     falls back to the payload (a lower bound, labeled as such)."""

@@ -31,7 +31,7 @@ the single-program mesh world of ``ParallelWrapper`` /
   virtual-device test tier: with a ``FaultInjector`` active, each window
   stalls for the slowest ACTIVE worker's injected delay (lockstep
   semantics — what a real mesh pays in ICI wait).  Degraded mode's win is
-  exactly the stall it no longer pays; ``bench_elastic`` measures it.
+  exactly the stall it no longer pays.
 
 Every transition lands in the flight recorder (``elastic_eviction`` /
 ``elastic_readmission`` events naming the replica) and the
@@ -61,7 +61,7 @@ class ElasticConfig:
 
     ``degraded_mode`` — master switch: off keeps full lockstep semantics
     (no evictions ever; the barrier simulation still stalls on every
-    worker — this is the "today's behavior" arm of ``bench_elastic``).
+    worker).
     ``evict_after_flags`` — straggler verdicts (detector flags since
     admission) that trigger eviction; ``None`` disables straggler-based
     eviction (hang/death still evict).  ``min_healthy`` — never evict

@@ -8,28 +8,25 @@ Runs, in order:
 2. ``check_metrics_docs`` — the standalone shim, proving the
    backwards-compatible entry point still answers (it shares the
    metrics-docs rule with dl4jlint, so this is a wiring check);
-3. ``check_bench_regression --self-test`` — the bench sentinel's
-   rule-engine unit checks plus a self-compare of the committed
-   ``bench_full.json``;
-4. ``fleet schema self-test`` — the fleet telemetry snapshot's
+3. ``fleet schema self-test`` — the fleet telemetry snapshot's
    serialize → merge → re-export round trip must be bit-stable
    (``observability.fleet.schema_roundtrip_selftest``);
-5. ``kernel-trust registry`` — the committed ``kernel_trust.json`` and
+4. ``kernel-trust registry`` — the committed ``kernel_trust.json`` and
    the kerneldiff sweep registry must list the same kernels in both
    directions, so no fused kernel can merge without sweep evidence and
    no stale trust entry can outlive its kernel
    (``kerneldiff --check-registry``);
-6. ``fleet placement self-test`` — the router's placement policy
+5. ``fleet placement self-test`` — the router's placement policy
    simulated end to end with no jax and no package imports
-   (``fleet/placement.py`` is loaded BY FILE PATH, same pattern as the
-   bench sentinel): deterministic seeded ties, affinity beating the
-   seeded-random control on hit rate, version-tag shadow invalidation,
-   drain/stale/dead exclusion, canary-split fractions, session pins.
+   (``fleet/placement.py`` is loaded BY FILE PATH): deterministic seeded
+   ties, affinity beating the seeded-random control on hit rate,
+   version-tag shadow invalidation, drain/stale/dead exclusion,
+   canary-split fractions, session pins.
 
-All six run in a few seconds with no device work — this is the
+All five run in a few seconds with no device work — this is the
 pre-test gate: run it before the pytest tiers and fail fast on lint
-debt, a broken sentinel, a fleet wire-schema drift, or a placement
-policy regression.
+debt, a fleet wire-schema drift, stale kernel-trust evidence, or a
+placement policy regression.
 
 Usage::
 
@@ -56,10 +53,6 @@ CHECKS: List[Tuple[str, List[str]]] = [
     ("metrics-docs shim",
      [sys.executable, os.path.join(REPO, "scripts",
                                    "check_metrics_docs.py")]),
-    ("bench sentinel self-test",
-     [sys.executable, os.path.join(REPO, "scripts",
-                                   "check_bench_regression.py"),
-      "--self-test"]),
     ("fleet schema self-test",
      [sys.executable, "-c",
       "import sys; "

@@ -9,8 +9,8 @@ Usage::
     python -m scripts.dl4jlint --list-rules
     python -m scripts.dl4jlint --json
 
-Exit codes (same contract as the bench sentinel): 0 clean against the
-baseline, 1 new findings (or a refused ratchet), 2 usage/IO error.
+Exit codes: 0 clean against the baseline, 1 new findings (or a refused
+ratchet), 2 usage/IO error.
 Stdlib-only, never imports jax; a full-repo run is sub-second.
 """
 
@@ -45,7 +45,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         prog="dl4jlint", description=__doc__.split("\n")[0])
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to scan (default: the "
-                         "deeplearning4j_tpu package + bench.py)")
+                         "deeplearning4j_tpu package)")
     ap.add_argument("--rules", default=None,
                     help="comma-separated rule names (default: all)")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE,

@@ -3,9 +3,8 @@
 Everything here is stdlib-only and never imports the package under
 analysis (and therefore never imports jax) — the whole suite is pure
 ``ast`` source analysis, same discipline as ``check_metrics_docs.py``
-and ``check_bench_regression.py`` before it, so a full-repo run stays
-well under the 5-second budget and works on a machine with no
-accelerator stack installed.
+before it, so a full-repo run stays well under the 5-second budget and
+works on a machine with no accelerator stack installed.
 
 Vocabulary:
 
@@ -36,7 +35,6 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 PACKAGE_DIR = os.path.join(REPO, "deeplearning4j_tpu")
-EXTRA_FILES = (os.path.join(REPO, "bench.py"),)
 
 ERROR = "error"
 WARNING = "warning"
@@ -192,8 +190,7 @@ class FileContext:
 
 # ------------------------------------------------------------------ running
 def iter_source_files(paths: Optional[Sequence[str]] = None) -> List[str]:
-    """Default scan scope: the whole ``deeplearning4j_tpu`` package plus
-    ``bench.py`` (the same corpus the metrics-docs lint always walked).
+    """Default scan scope: the whole ``deeplearning4j_tpu`` package.
     Explicit ``paths`` (files or directories) override it."""
     if paths:
         out: List[str] = []
@@ -210,7 +207,6 @@ def iter_source_files(paths: Optional[Sequence[str]] = None) -> List[str]:
     for root, _dirs, files in os.walk(PACKAGE_DIR):
         out.extend(os.path.join(root, f) for f in sorted(files)
                    if f.endswith(".py"))
-    out.extend(f for f in EXTRA_FILES if os.path.exists(f))
     return sorted(out)
 
 

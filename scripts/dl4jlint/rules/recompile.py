@@ -1,8 +1,8 @@
 """recompile-hazard: patterns that defeat XLA's compile cache.
 
-The repo's serving and bench contracts assume a CLOSED shape set and
-zero steady-state compiles (PR-2's bucket policy; the
-``steady_state_compiles`` bench rule).  Three statically detectable ways
+The repo's serving contract assumes a CLOSED shape set and zero
+steady-state compiles (PR-2's bucket policy; the benchmark's
+``window_compiles`` reads 0 in every cell).  Three statically detectable ways
 code breaks that:
 
 1. **fresh-jit-invoked-immediately** — ``jax.jit(f)(x)``: the jitted

@@ -58,8 +58,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "profiling: performance-attribution tests — step profiler "
-        "captures, XLA cost analysis / MFU gauges, request tracing, bench "
-        "regression sentinel (python -m pytest -m profiling)")
+        "captures, XLA cost analysis / MFU gauges, request tracing, the "
+        "kernel-trust rule engine (python -m pytest -m profiling)")
     config.addinivalue_line(
         "markers",
         "online: continuous-learning pipeline tests — stream consumption "
@@ -69,8 +69,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "lint: source-level static-analysis gates — the dl4jlint rule "
-        "suite, its ratcheting baseline, and the metrics-docs/"
-        "bench-sentinel shims (python -m pytest -m lint)")
+        "suite, its ratcheting baseline, and the metrics-docs "
+        "shim (python -m pytest -m lint)")
     config.addinivalue_line(
         "markers",
         "stability: training-stability engine tests — device-side "

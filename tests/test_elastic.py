@@ -499,8 +499,7 @@ def test_degraded_mode_stops_paying_the_straggler_stall():
     slow worker's injected delay every window; degraded mode stops paying
     the moment the worker is evicted.  Eviction is driven by a
     deterministic kill at step 2 (not detector timing), so the two arms
-    differ by exactly (n_win - 2) barrier stalls.  This is the
-    bench_elastic claim in miniature."""
+    differ by exactly (n_win - 2) barrier stalls."""
     import time as _time
 
     K = 4

