@@ -36,6 +36,7 @@ of inside each execution.  The programs are compiled for that tree.
 
 from __future__ import annotations
 
+import logging
 import operator
 from typing import Dict, List, Optional, Tuple
 
@@ -52,6 +53,8 @@ from deeplearning4j_tpu.nn.layers.moe import counting
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
 
+logger = logging.getLogger("deeplearning4j_tpu.generation")
+
 # the snapshot's one program; versions of one architecture share its
 # compiled form, so a deploy or a re-cast compiles nothing
 _cast = jax.jit(cast_to_compute, static_argnums=(1,))
@@ -67,6 +70,21 @@ def named_layers_of(net) -> List[Tuple[str, object]]:
     _cg_single_io(net)   # generation feeds back ONE token stream
     return [(n, net.nodes[n].layer) for n in net.topo
             if net.nodes[n].layer is not None]
+
+
+def _paged_attention_shapes(net) -> List[Tuple[int, int, int]]:
+    """``(q heads, kv heads, head_dim)`` of every ``SelfAttentionLayer`` of
+    ``net``, those inside composite layers too, each shape once."""
+    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
+
+    def walk(layer):
+        if isinstance(layer, SelfAttentionLayer):
+            yield (layer.n_heads, layer._kv_heads,
+                   layer.n_out // layer.n_heads)
+        for sub in getattr(layer, "layers", ()):
+            yield from walk(sub)
+
+    return sorted({s for _, l in named_layers_of(net) for s in walk(l)})
 
 
 def seed_paged_pools(net, num_pages: int, page_size: int,
@@ -355,6 +373,31 @@ class GenerationPrograms:
             z((s,), np.int32), np.ones((s,), np.float32)))
         return progs
 
+    def _log_paged_tiling(self) -> None:
+        """How ``fused_paged_attention`` tiles each compute program, once a
+        program.  The choice is static per program, a function of its
+        shapes, so this is the whole account of how the kernel engaged."""
+        from deeplearning4j_tpu.helpers import helpers_enabled
+        from deeplearning4j_tpu.helpers import paged_attention as pa
+
+        if not (helpers_enabled() and pa.paged_attention_mode() == "fused"
+                and pa.default_impl() == "pallas"):
+            return
+        dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
+        programs = [(f"prefill_{b}", 1, b) for b in self.prefill_buckets]
+        programs.append(("decode", self.slots, 1))
+        for hq, hkv, d in _paged_attention_shapes(self.net):
+            for name, b, t in programs:
+                ppb, tq, vmem = pa.paged_tiling(
+                    b, t, hq, hkv, d, self.page_size, self.pages_per_slot,
+                    dtype)
+                logger.info(
+                    "generation.%s: fused_paged_attention q [%d, %d, %d, "
+                    "%d] over %d pages of %d: %d pages a block, %d query "
+                    "positions a tile, grid (%d, %d), %.2f MB of VMEM",
+                    name, b, t, hq, d, self.pages_per_slot, self.page_size,
+                    ppb, tq, b, -(-t // tq), vmem / 2 ** 20)
+
     def lowered(self) -> Dict[str, "jax.stages.Lowered"]:
         """Each compute program lowered at its serving signature (abstract
         pools; nothing executes, nothing is donated) — how a caller reads
@@ -394,6 +437,7 @@ class GenerationPrograms:
                  self.net.params),
              "net_state": net_state, "kv_pools": pools})
         progs = self._compute_programs()
+        self._log_paged_tiling()
         coll = shardstats.active_collector()
         if coll is not None:
             # census at the exact warmup signatures; lower-only, so the

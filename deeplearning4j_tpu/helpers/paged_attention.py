@@ -1,29 +1,42 @@
-"""Fused paged decode attention (the ``gather_pages`` seam, fused).
+"""Fused paged attention (the ``gather_pages`` seam, fused).
 
-ROADMAP item 1's decode half: the continuous-batching engine's hot loop
-used to materialize every row's logical KV view from the page pool
-(``gather_pages`` -> ``paged_attention`` in ``nn/layers/attention.py``)
-— a ``[B, MAXP*page_size, Hkv, D]`` round trip through HBM per layer
-per decode step, just to immediately reduce it through a softmax.  This
-module computes the same per-row causal attention DIRECTLY from the
-page pool + int32 block tables, streaming pages block-by-block
-with the online-softmax recurrence (running row-max ``m``, normaliser
-``l`` — the flash-attention scheme, see ``helpers/flash_attention.py``),
-so the gathered view is never built.
+The continuous-batching engine's hot loop used to materialize every
+row's logical KV view from the page pool (``gather_pages`` ->
+``paged_attention`` in ``nn/layers/attention.py``) — a
+``[B, MAXP*page_size, Hkv, D]`` round trip through HBM per layer per
+step, just to immediately reduce it through a softmax.  This module
+computes the same per-row causal attention DIRECTLY from the page pool +
+int32 block tables, streaming pages with the online-softmax recurrence
+(running row-max ``m``, normaliser ``l`` — the flash-attention scheme,
+see ``helpers/flash_attention.py``), so the gathered view is never
+built.  The op serves every paged call of ``SelfAttentionLayer``: the
+decode step (``[slots, 1]``) and each prefill chunk (``[1, bucket]``,
+behind a shared prefix or not).
 
 Two implementations behind one public op:
 
 - ``impl="pallas"`` (default on TPU): a Pallas kernel on the grid
-  ``(B, Hkv, MAXP)`` whose sequential page axis carries the softmax
-  scratch.  The block table and each row's highest query position ride
-  scalar prefetch (``PrefetchScalarGridSpec``), so each page's HBM->VMEM
-  DMA — one contiguous ``(page_size, D)`` tile of the
-  ``[P, Hkv, page_size, D]`` pool — is issued straight off
-  ``block[b, p]``: the kernel IS the gather.  Pages that
-  lie wholly above every live position of a row batch are skipped:
-  their compute is predicated off and their DMA index clamps to the
-  last live page (the Pallas pipeline elides copies whose index did
-  not change), so a 3-page row in a 32-page table pays for 3 pages.
+  ``(B, row tiles)``.  A ROW TILE is ``row_tile`` consecutive query
+  positions of one batch row with all their heads: per kv head the
+  ``G = Hq // Hkv`` query heads stack into ``G * row_tile`` rows, so GQA
+  multiplies the unexpanded K once for the whole group.  A BLOCK is
+  ``pages_per_block`` pages of the row, about 128 key positions (8 pages
+  of 16, 2 of 64, never more than the table has), so scores are
+  ``[rows, 128]``: whole lanes.  The pools stay in HBM
+  (``memory_space=pl.ANY``); the block table and each tile's highest
+  query position ride scalar prefetch; a page of every kv head —
+  ``[Hkv, page_size, D]`` is one contiguous slab of the
+  ``[P, Hkv, page_size, D]`` pool — comes in by one
+  ``pltpu.make_async_copy`` into a two-slot VMEM buffer, the next block
+  in flight while the current one is multiplied.  Dead-block rule: a
+  tile's loop over blocks ends at the block that holds its highest
+  position, so a 3-block row in a 36-page table pays for 3 blocks, and a
+  tile of a prefill chunk skips the blocks wholly above it (causal
+  skip); inside the last live block the per-row position mask does the
+  rest.  ``paged_tiling`` picks both parameters from the shapes alone,
+  ``row_tile`` the largest that keeps one grid step's buffers within
+  ``VMEM_BUDGET`` (8 MB of the chip's 16 MB scoped limit) at any group
+  size and bucket; no argument or environment variable tunes it.
 - ``impl="lax"`` (default elsewhere): a compiled ``lax.fori_loop`` over
   pages with the same online-softmax accumulator, gathering only one
   ``[B, Hkv, page_size, D]`` page slab per iteration.  The loop bound
@@ -45,15 +58,15 @@ for the seam contract, including the plan to dequantize int8/fp8 pages
 Mode toggle (trace-time, like ``enable_helpers``):
 ``set_paged_attention_mode("gather")`` or env DL4J_TPU_PAGED_GATHER=1
 routes ``SelfAttentionLayer._apply_paged`` back through the legacy
-gather+softmax path — the bit-compatible oracle the parity tests and
-the bench's before/after arm compare against.
+gather+softmax path — the bit-compatible oracle the parity tests compare
+against, and the other side of the in-cell comparison (PERF.md §6, PR 32).
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -165,48 +178,11 @@ def _lax_paged(q, pk, pv, block, q_positions):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (B, Hkv, MAXP), scalar-prefetched block table
+# Pallas kernel: grid (B, row tiles), a block of pages a loop step
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(blk_ref, qmax_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, scale, page_size):
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    npages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # pages wholly above every row position contribute nothing; their
-    # DMA already clamped to the last live page (kv_idx in _pallas_paged)
-    run = p * page_size <= qmax_ref[b]
-
-    @pl.when(run)
-    def _step():
-        s = _dot_f32(q_ref[:], k_ref[:], trans_b=True) * scale  # [R, ps]
-        kpos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # per-row global query positions, lane-broadcast like m/l
-        s = jnp.where(qp_ref[:, :1] >= kpos, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p_exp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p_exp, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + _dot_f32(
-            p_exp.astype(v_ref.dtype), v_ref[:])
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(p == npages - 1)
-    def _finish():
-        l = l_scr[:, :1]
-        safe = jnp.where(l > 0, l, 1.0)               # idle / trash rows
-        o_ref[:] = (acc_scr[:] / safe).astype(o_ref.dtype)
+BLOCK_KEYS = 128             # key positions a block of pages aims at
+VMEM_BUDGET = 8 * 2 ** 20    # bytes one grid step's buffers may take
 
 
 def _sublanes(dtype) -> int:
@@ -215,80 +191,209 @@ def _sublanes(dtype) -> int:
     return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
+                 maxp: int, dtype) -> Tuple[int, int, int]:
+    """How the Pallas kernel tiles ``q`` [b, t, hq, d] over pools
+    [P, hkv, page_size, d] of ``dtype`` behind a block table [b, maxp]:
+    ``(pages_per_block, row_tile, vmem_bytes)``.
+
+    ``pages_per_block`` pages (about ``BLOCK_KEYS`` key positions, never
+    more than the table has) are copied and multiplied per loop step.
+    ``row_tile`` query positions of the ``t`` form one grid step — with
+    the ``hq // hkv`` query heads of a kv head stacked, ``G * row_tile``
+    rows a kv head — and it is the largest power-of-two multiple of the
+    dtype's sublane tile (or ``t`` itself when that is smaller) whose
+    ``vmem_bytes`` stay within ``VMEM_BUDGET``: the q and output tiles
+    (double-buffered by the pipeline), the per-row positions, the f32
+    softmax state (m, l, accumulator) of every kv head, the two-slot K
+    and V page buffers, and one head's scores and probabilities.  The
+    grid is ``(b, cdiv(t, row_tile))``.  A pure function of the shapes:
+    the kernel calls it, and so can whoever wants to know how it engaged.
+    """
+    item = jnp.dtype(dtype).itemsize
+    sub = _sublanes(dtype)
+    g = hq // hkv
+    dpad = _round_up(d, LANES)
+    ppb = max(1, min(BLOCK_KEYS // page_size, maxp))
+    bk = ppb * page_size
+    pages = 2 * 2 * ppb * hkv * page_size * dpad * item
+
+    def vmem(tq):
+        rows = _round_up(g * tq, sub)
+        state = dpad * 4 + 2 * LANES * 4               # acc, m, l
+        tiles = 2 * 2 * dpad * item                    # q and o, 2 buffers
+        return (pages + hkv * rows * (state + tiles)
+                + 2 * rows * LANES * 4                 # positions
+                + rows * bk * (4 + 4 + item))          # s, p, p cast
+
+    tq = min(t, sub)
+    while tq * 2 <= t and vmem(tq * 2) <= VMEM_BUDGET:
+        tq *= 2
+    return ppb, tq, vmem(tq)
+
+
+def _paged_kernel(blk_ref, qmax_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
+                  scale, page_size, maxp):
+    b, ti = pl.program_id(0), pl.program_id(1)
+    _, ppb, hkv, _, dpad = kbuf.shape
+    rows = q_ref.shape[1]
+    bk = ppb * page_size
+    # live blocks of this row tile: up to the one holding its highest
+    # query position (a dead block costs neither a copy nor a step)
+    nblk = jnp.minimum(qmax_ref[b * pl.num_programs(1) + ti] // bk + 1,
+                       pl.cdiv(maxp, ppb))
+
+    def copies(j, slot):
+        out = []
+        for i in range(ppb):
+            p = j * ppb + i
+            if maxp % ppb:
+                # the table is not whole blocks: slots past its end repeat
+                # the last page, under key positions no query reaches
+                p = jnp.minimum(p, maxp - 1)
+            page = blk_ref[b * maxp + p]
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], kbuf.at[slot, i], sem.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], vbuf.at[slot, i], sem.at[1, slot]))
+        return out
+
+    for c in copies(0, 0):
+        c.start()
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block_step(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < nblk)
+        def _prefetch():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+        # per-row global query positions, lane-broadcast like m/l
+        keep = qp_ref[:, :1] >= kpos
+        for h in range(hkv):
+            k = kbuf[slot, :, h].reshape(bk, dpad)
+            v = vbuf[slot, :, h].reshape(bk, dpad)
+            s = _dot_f32(q_ref[h], k, trans_b=True) * scale   # [rows, bk]
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p_exp = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = (alpha * l_scr[h, :, :1]
+                     + jnp.sum(p_exp, axis=1, keepdims=True))
+            acc_scr[h] = acc_scr[h] * alpha + _dot_f32(
+                p_exp.astype(v.dtype), v)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    jax.lax.fori_loop(0, nblk, block_step, None)
+    l = l_scr[:, :, :1]
+    safe = jnp.where(l > 0, l, 1.0)                   # idle / trash rows
+    o_ref[...] = (acc_scr[...] / safe).astype(o_ref.dtype)
+
+
+# jitted so that the layers of a program that call it at one shape share
+# one trace and one lowering: the kernel's unrolled copies and heads make
+# those the dear part (six layers x three programs took 3 s of the serve
+# cell's set-up without it; XLA inlines the calls, the program is the same)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_paged(q, pk, pv, block, q_positions, interpret):
     b, t, hq, d = q.shape
     hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
-    gt = g * t
     maxp = block.shape[1]
     scale = 1.0 / (d ** 0.5)
-    if not interpret and page_size % _sublanes(pk.dtype):
+    sub = _sublanes(pk.dtype)
+    if not interpret and page_size % sub:
         raise ValueError(
             f"page_size={page_size} cannot tile a {pk.dtype} KV pool on "
             f"TPU: one page is one (page_size, D) tile per kv head, so "
-            f"page_size must be a multiple of {_sublanes(pk.dtype)} for "
-            "this dtype")
+            f"page_size must be a multiple of {sub} for this dtype")
     dp = (-d) % LANES
     if dp:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dp)))
         pk = jnp.pad(pk, ((0, 0), (0, 0), (0, 0), (0, dp)))
         pv = jnp.pad(pv, ((0, 0), (0, 0), (0, 0), (0, dp)))
     dpad = d + dp
-    # [B, Hkv, G*T, D]: one grid step owns one (batch row, kv head); its
-    # q rows are laid out [G, T] flattened (t = row % T).  Rows pad up to
-    # whole tiles (MHA decode has G*T = 1); padded rows sit at position 0
-    # and are sliced off below.
-    rows = -(-gt // _sublanes(q.dtype)) * _sublanes(q.dtype)
-    qb = (q.reshape(b, t, hkv, g, dpad).transpose(0, 2, 3, 1, 4)
-          .reshape(b, hkv, gt, dpad))
-    qb = jnp.pad(qb, ((0, 0), (0, 0), (0, rows - gt), (0, 0)))
-    qpos = q_positions.astype(jnp.int32)
-    qrows = jnp.pad(jnp.tile(qpos, (1, g)), ((0, 0), (0, rows - gt)))
-    qrows = jnp.broadcast_to(qrows[:, :, None], (b, rows, LANES))
-    qmax = jnp.max(qpos, axis=1)
+    ppb, tq, _ = paged_tiling(b, t, hq, hkv, d, page_size, maxp, pk.dtype)
+    nt = -(-t // tq)
+    tpad = nt * tq - t
+    # [B, tiles, Hkv, G*tq, D]: one grid step owns one (batch row, tile of
+    # tq query positions) with every kv head; a head's rows are laid out
+    # [G, tq] flattened (position = row % tq).  Rows pad up to whole
+    # sublane tiles (MHA decode has G*tq = 1); padded rows and padded
+    # positions sit at position 0 and are sliced off below.
+    rows = _round_up(g * tq, _sublanes(q.dtype))
+    rpad = rows - g * tq
+    qb = jnp.pad(q, ((0, 0), (0, tpad), (0, 0), (0, 0)))
+    qb = (qb.reshape(b, nt, tq, hkv, g, dpad).transpose(0, 1, 3, 4, 2, 5)
+          .reshape(b, nt, hkv, g * tq, dpad))
+    qb = jnp.pad(qb, ((0, 0), (0, 0), (0, 0), (0, rpad), (0, 0)))
+    qpos = jnp.pad(q_positions.astype(jnp.int32), ((0, 0), (0, tpad)))
+    qpos = qpos.reshape(b, nt, tq)
+    qrows = jnp.pad(jnp.tile(qpos, (1, 1, g)), ((0, 0), (0, 0), (0, rpad)))
+    qrows = jnp.broadcast_to(qrows[..., None], (b, nt, rows, LANES))
+    qmax = jnp.max(qpos, axis=2).reshape(-1)
 
-    def kv_idx(bi, h, p, blk, qmax):
-        # dead pages clamp to the last live one so their copies are elided
-        return (blk[bi, jnp.minimum(p, qmax[bi] // page_size)], h, 0, 0)
+    def tile_idx(bi, ti, blk, qmax):
+        return (bi, ti, 0, 0, 0)
 
-    def row_idx(bi, h, p, blk, qmax):
-        return (bi, h, 0, 0)
-
-    kern = functools.partial(_decode_kernel, scale=scale,
-                             page_size=page_size)
+    kern = functools.partial(_paged_kernel, scale=scale,
+                             page_size=page_size, maxp=maxp)
     o = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, maxp),
+            grid=(b, nt),
             in_specs=[
-                pl.BlockSpec((None, rows, LANES),
-                             lambda bi, h, p, blk, qmax: (bi, 0, 0)),
-                pl.BlockSpec((None, None, rows, dpad), row_idx),
-                pl.BlockSpec((None, None, page_size, dpad), kv_idx),
-                pl.BlockSpec((None, None, page_size, dpad), kv_idx),
+                pl.BlockSpec((None, None, rows, LANES),
+                             lambda bi, ti, blk, qmax: (bi, ti, 0, 0)),
+                pl.BlockSpec((None, None, hkv, rows, dpad), tile_idx),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, None, rows, dpad), row_idx),
+            out_specs=pl.BlockSpec((None, None, hkv, rows, dpad), tile_idx),
             scratch_shapes=[
-                pltpu.VMEM((rows, LANES), jnp.float32),
-                pltpu.VMEM((rows, LANES), jnp.float32),
-                pltpu.VMEM((rows, dpad), jnp.float32),
+                pltpu.VMEM((2, ppb, hkv, page_size, dpad), pk.dtype),
+                pltpu.VMEM((2, ppb, hkv, page_size, dpad), pv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hkv, rows, LANES), jnp.float32),
+                pltpu.VMEM((hkv, rows, LANES), jnp.float32),
+                pltpu.VMEM((hkv, rows, dpad), jnp.float32),
             ],
         ),
-        out_shape=_sds((b, hkv, rows, dpad), q.dtype, q),
+        out_shape=_sds((b, nt, hkv, rows, dpad), q.dtype, q),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="fused_paged_attention",
-    )(block.astype(jnp.int32), qmax, qrows, qb, pk, pv)
-    o = (o[:, :, :gt].reshape(b, hkv, g, t, dpad).transpose(0, 3, 1, 2, 4)
-         .reshape(b, t, hq, dpad))
-    return o[..., :d] if dp else o
+    )(block.astype(jnp.int32).reshape(-1), qmax, qrows, qb, pk, pv)
+    o = (o[:, :, :, :g * tq].reshape(b, nt, hkv, g, tq, dpad)
+         .transpose(0, 1, 4, 2, 3, 5).reshape(b, nt * tq, hq, dpad))
+    return o[:, :t, :, :d]
 
 
 # ---------------------------------------------------------------------------
 # public op
 # ---------------------------------------------------------------------------
+
+def default_impl() -> str:
+    """What ``impl=None`` means here: the Pallas kernel on a TPU, the
+    compiled lax page loop elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "lax"
+
 
 def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
                            block: jax.Array, q_positions: jax.Array, *,
@@ -312,7 +417,7 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
     """
     _check_shapes(q, pk, pv, block, q_positions)
     if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "lax"
+        impl = default_impl()
     if impl == "gather":
         from deeplearning4j_tpu.nn.layers.attention import (
             gather_pages, paged_attention)

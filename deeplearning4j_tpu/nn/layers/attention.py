@@ -366,11 +366,13 @@ class SelfAttentionLayer(Layer):
         sees, so slot count and pool size close the decode shape set.
         Like the linear cache, GQA pools store the UNEXPANDED kv heads.
 
-        Layout ``[num_pages, Hkv, page_size, D]``: one (page, kv head) is
-        a contiguous ``(page_size, D)`` tile — the block the fused Pallas
-        kernel DMAs per grid step.  (Token-major ``[.., page_size, Hkv,
-        D]`` would make that block take 1 of Hkv rows in the tiled
-        second-minor dimension, which the TPU lowering rejects.)"""
+        Layout ``[num_pages, Hkv, page_size, D]``: one page is a
+        contiguous ``[Hkv, page_size, D]`` slab — what the fused Pallas
+        kernel copies per page, every kv head at once — and one (page,
+        kv head) a whole ``(page_size, D)`` tile to multiply.
+        (Token-major ``[.., page_size, Hkv, D]`` would leave a kv head 1
+        of Hkv rows in the tiled second-minor dimension, which the TPU
+        lowering rejects.)"""
         if self.window is not None:
             raise ValueError(
                 "paged KV caching does not support sliding-window "

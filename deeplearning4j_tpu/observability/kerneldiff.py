@@ -341,6 +341,12 @@ def _fused_paged_configs(full: bool):
             # non-lane-multiple head dim exercises the Pallas lane padding
             {"pages": 8, "page_size": 16, "maxp": 3, "hq": 8, "hkv": 2,
              "d": 48, "b": 4, "t": 1},
+            # a served width (group of 9, head 128) behind a table that is
+            # not whole blocks of 8 pages, and a chunk over two row tiles
+            {"pages": 50, "page_size": 16, "maxp": 20, "hq": 18, "hkv": 2,
+             "d": 128, "b": 4, "t": 1},
+            {"pages": 50, "page_size": 16, "maxp": 20, "hq": 18, "hkv": 2,
+             "d": 128, "b": 2, "t": 40},
         ]
     for g in grids:
         for dtype in ("float32", "bfloat16"):

@@ -26,8 +26,8 @@ from deeplearning4j_tpu.helpers.fused_epilogue import (
     FusedEpilogueHelper, dropout_residual_norm,
 )
 from deeplearning4j_tpu.helpers.paged_attention import (
-    PagedAttentionHelper, paged_attention_mode, paged_decode_attention,
-    set_paged_attention_mode,
+    VMEM_BUDGET, PagedAttentionHelper, paged_attention_mode,
+    paged_decode_attention, paged_tiling, set_paged_attention_mode,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     SelfAttentionLayer, gather_pages, paged_attention,
@@ -40,10 +40,11 @@ VOCAB = 29
 
 # --------------------------------------------------------------- scenarios
 def _scenario(seed, *, pages, page_size, maxp, b, t, hq, hkv, d,
-              dtype=jnp.float32, trash_row=True):
+              dtype=jnp.float32, trash_row=True, qlast=None):
     """Engine-shaped inputs: page 0 is the trash page, unassigned
-    block-table slots point at it, per-row positions are mixed, and
-    (``trash_row``) row 0 is an all-padding fresh slot at position 0."""
+    block-table slots point at it, per-row positions are mixed (or end
+    at ``qlast``, one per row), and (``trash_row``) row 0 is an
+    all-padding fresh slot at position 0."""
     rng = np.random.default_rng(seed)
     pool_k = jnp.asarray(
         rng.standard_normal((pages, hkv, page_size, d)), dtype)
@@ -51,7 +52,8 @@ def _scenario(seed, *, pages, page_size, maxp, b, t, hq, hkv, d,
         rng.standard_normal((pages, hkv, page_size, d)), dtype)
     q = jnp.asarray(rng.standard_normal((b, t, hq, d)), dtype)
     block = rng.integers(1, pages, size=(b, maxp))
-    qlast = rng.integers(t - 1, maxp * page_size, size=(b,))
+    drawn = rng.integers(t - 1, maxp * page_size, size=(b,))
+    qlast = drawn if qlast is None else np.asarray(qlast)
     if trash_row:
         qlast[0] = t - 1
         block[0] = 0
@@ -75,7 +77,47 @@ CONFIGS = {
                       hkv=4, d=64),
     "odd_head_dim": dict(pages=8, page_size=16, maxp=3, b=4, t=1, hq=8,
                          hkv=2, d=48),
+    # what the tiling can get wrong.  At page 16 a block is 8 pages (128
+    # keys), so a 20-page table ends in a block of 4: rows whose last live
+    # page is the first (8), a middle (12) and the last (15) page of block
+    # 1, the last page of the table (19), and page 0
+    "ragged_blocks": dict(pages=70, page_size=16, maxp=20, b=6, t=1, hq=4,
+                          hkv=2, d=32,
+                          qlast=[0, 8 * 16, 12 * 16 + 5, 16 * 16 - 1,
+                                 20 * 16 - 1, 7]),
+    # a chunk behind a prefix: positions start past 0 and the 40 rows
+    # split over two row tiles (f32: tiles of 32 positions), the second
+    # one part padding
+    "chunk_behind_prefix": dict(pages=40, page_size=16, maxp=12, b=2, t=40,
+                                hq=4, hkv=2, d=32, trash_row=False,
+                                qlast=[37 + 39, 12 * 16 - 1]),
+    "page64": dict(pages=20, page_size=64, maxp=5, b=3, t=1, hq=4, hkv=2,
+                   d=32),
+    "page64_chunk": dict(pages=20, page_size=64, maxp=5, b=2, t=24, hq=8,
+                         hkv=2, d=32, trash_row=False,
+                         qlast=[100 + 23, 5 * 64 - 1]),
+    # the served widths: groups of 9 (starcoder2-7b) and 12 (-3b), head
+    # 128, page 16, bf16
+    "g9_bf16": dict(pages=40, page_size=16, maxp=12, b=3, t=1, hq=18,
+                    hkv=2, d=128, dtype=jnp.bfloat16),
+    "g12_bf16_chunk": dict(pages=40, page_size=16, maxp=12, b=1, t=48,
+                           hq=24, hkv=2, d=128, dtype=jnp.bfloat16,
+                           trash_row=False, qlast=[130 + 47]),
 }
+
+
+def _tolerance(ref):
+    """f32: the two orders of summation.  bf16: both sides take bf16
+    inputs, accumulate in f32 and round the result to bf16 in a different
+    order of operations (online softmax over blocks of pages against one
+    softmax over the gathered view; the kernel also rounds the
+    probabilities to bf16 ahead of the second matmul), so they may land
+    on neighbouring bf16 values: 2 units in the last place at the
+    reference's largest magnitude, the TPU tier's rule."""
+    if ref.dtype != jnp.bfloat16:
+        return dict(rtol=2e-5, atol=2e-6)
+    top = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
+    return dict(rtol=0, atol=2 * 2.0 ** (np.floor(np.log2(top)) - 7))
 
 
 # ----------------------------------------------------- raw kernel parity
@@ -88,8 +130,9 @@ def test_fused_matches_gather_oracle(impl, name):
     out = paged_decode_attention(q, pk, pv, block, qpos, impl=impl,
                                  interpret=True)
     assert bool(jnp.isfinite(out).all())
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               **_tolerance(ref))
 
 
 @pytest.mark.parametrize("impl", ["lax", "pallas"])
@@ -108,23 +151,91 @@ def test_all_padding_trash_row(impl):
                                rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("b,t,hq,hkv", [(16, 1, 8, 8), (16, 1, 8, 2),
-                                        (1, 128, 8, 8)])
-def test_pallas_kernel_lowers_for_tpu_at_serving_shapes(b, t, hq, hkv):
+SERVING_SHAPES = [        # b, t, hq, hkv, maxp: bf16, head_dim 128, page 16
+    (16, 1, 8, 8, 8), (16, 1, 8, 2, 8), (1, 128, 8, 8, 8),
+    # starcoder2-7b (G = 9): the decode step, prefill_512, and the
+    # prefill_1024 that stacking all G x T rows in VMEM could not compile
+    (32, 1, 36, 4, 36), (1, 512, 36, 4, 36), (1, 1024, 36, 4, 72),
+    # starcoder2-3b (G = 12)
+    (1, 512, 24, 2, 36), (1, 512, 24, 2, 72),
+]
+
+
+def _serving_args(b, t, hq, hkv, maxp, sharding=None):
+    ps, d = 16, 128
+    pool = jax.ShapeDtypeStruct((b * maxp + 1, hkv, ps, d), jnp.bfloat16,
+                                sharding=sharding)
+    return (jax.ShapeDtypeStruct((b, t, hq, d), jnp.bfloat16,
+                                 sharding=sharding), pool, pool,
+            jax.ShapeDtypeStruct((b, maxp), jnp.int32, sharding=sharding),
+            jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=sharding))
+
+
+@pytest.mark.parametrize("b,t,hq,hkv,maxp", SERVING_SHAPES)
+def test_pallas_kernel_lowers_for_tpu_at_serving_shapes(b, t, hq, hkv,
+                                                        maxp):
     """The TPU block-shape rules are checked when the kernel LOWERS, which
     needs no chip: cross-lower for the tpu platform at the serving shapes
     (bf16, head_dim 128, page 16).  The token-major pool layout failed
     exactly here ("last two dimensions of your block shape ...")."""
-    ps, maxp, d = 16, 8, 128
     fn = jax.jit(lambda *a: paged_decode_attention(
         *a, impl="pallas", interpret=False))
     with jax.enable_x64(False):   # as on the chip (the TPU tier has no x64)
-        q = jnp.zeros((b, t, hq, d), jnp.bfloat16)
-        pool = jnp.zeros((b * maxp + 1, hkv, ps, d), jnp.bfloat16)
-        text = fn.trace(q, pool, pool, jnp.zeros((b, maxp), jnp.int32),
-                        jnp.zeros((b, t), jnp.int32)).lower(
+        text = fn.trace(*_serving_args(b, t, hq, hkv, maxp)).lower(
             lowering_platforms=("tpu",)).as_text()
     assert text.count('kernel_name = "fused_paged_attention"') == 1
+
+
+@pytest.mark.parametrize("b,t,hq,hkv,maxp", SERVING_SHAPES)
+def test_tiling_at_serving_shapes(b, t, hq, hkv, maxp):
+    """``paged_tiling`` is how the kernel says how it engaged: a block of
+    8 pages of 16 (128 key positions), a row tile that is a whole number
+    of bf16 sublane tiles (or all of a short ``t``), and buffers inside
+    the budget whatever the group and the bucket."""
+    ppb, tq, vmem = paged_tiling(b, t, hq, hkv, 128, 16, maxp,
+                                 jnp.bfloat16)
+    assert ppb == 8
+    assert tq == t or (tq % 16 == 0 and t % tq == 0)
+    assert vmem <= VMEM_BUDGET
+    # the bound is what tiles the rows: one more doubling would pass it
+    assert tq == t or paged_tiling(
+        b, tq * 2, hq, hkv, 128, 16, maxp, jnp.bfloat16)[1] == tq
+
+
+def test_tiling_never_takes_more_pages_than_the_table_has():
+    assert paged_tiling(4, 1, 8, 2, 128, 16, 3, jnp.bfloat16)[0] == 3
+    assert paged_tiling(4, 1, 8, 2, 128, 64, 72, jnp.bfloat16)[0] == 2
+    assert paged_tiling(4, 1, 8, 2, 128, 256, 8, jnp.bfloat16)[0] == 1
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e: the TPU's compiler is installed here
+    and compiles for a chip that is not attached.  Made inside a fixture
+    so that only the worker that runs this file loads the library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,t,hq,hkv,maxp", [
+    (32, 1, 36, 4, 36), (1, 1024, 36, 4, 72), (1, 512, 24, 2, 72)])
+def test_pallas_kernel_compiles_for_v5e(v5e_chip, b, t, hq, hkv, maxp):
+    """Lowering does not see the VMEM a kernel takes; the chip's compiler
+    does (``prefill_1024`` at G = 9 lowered fine and was refused there:
+    31.02 MB against 16).  Compile for the described chip."""
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, impl="pallas", interpret=False))
+    with jax.enable_x64(False):
+        compiled = fn.lower(
+            *_serving_args(b, t, hq, hkv, maxp, v5e_chip)).compile()
+    assert "fused_paged_attention" in compiled.as_text()
 
 
 def test_compiled_kernel_rejects_page_size_the_dtype_cannot_tile():
@@ -228,6 +339,37 @@ def _in_mode(mode, fn):
         return fn()
     finally:
         set_paged_attention_mode("fused")
+
+
+def test_programs_log_their_tiling_once_a_program(monkeypatch, caplog):
+    """``GenerationPrograms.warm`` says how the kernel engaged in each
+    compute program (static per program, so one line a program is the
+    whole account) — and says nothing where the lax loop runs."""
+    import logging
+
+    from deeplearning4j_tpu.generation.programs import GenerationPrograms
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+
+    progs = GenerationPrograms(_small_lm(), slots=4, pages_per_slot=8,
+                               page_size=4, num_pages=33,
+                               prefill_buckets=(8, 16))
+
+    def lines():
+        caplog.clear()
+        with caplog.at_level(logging.INFO,
+                             logger="deeplearning4j_tpu.generation"):
+            progs._log_paged_tiling()
+        return [r.getMessage() for r in caplog.records
+                if "fused_paged_attention" in r.getMessage()]
+
+    assert lines() == []                      # CPU: impl "lax"
+    monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
+    said = lines()
+    assert [m.split(":")[0] for m in said] == [
+        "generation.prefill_8", "generation.prefill_16",
+        "generation.decode"]
+    ppb, tq, _ = paged_tiling(4, 1, 4, 4, 8, 4, 8, jnp.float32)
+    assert f"{ppb} pages a block, {tq} query positions a tile" in said[-1]
 
 
 def test_engine_join_leave_parity_fused_vs_gather(rng):
