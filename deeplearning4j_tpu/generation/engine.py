@@ -45,7 +45,9 @@ from deeplearning4j_tpu.generation.paged_cache import PagedKVCache
 from deeplearning4j_tpu.generation.prefix_cache import (
     PrefixCache, PrefixCacheConfig,
 )
-from deeplearning4j_tpu.generation.programs import GenerationPrograms
+from deeplearning4j_tpu.generation.programs import (
+    GenerationPrograms, window_pool_pages, window_ring_pages,
+)
 from deeplearning4j_tpu.generation.scheduler import (
     DecodeScheduler, GenerationRequest,
 )
@@ -108,7 +110,19 @@ class GenerationEngine:
         self.models = models or ModelRegistry(
             metrics_registry=self.metrics.registry)
         self.default_model = default_model
-        self.cache = PagedKVCache(num_pages, page_size, pages_per_slot)
+        # pages by layer kind, learnt from the layers as the pools are: a
+        # net with sliding-window attention layers holds a ring of
+        # ceil(window / page) + 1 pages a slot in pools of their own kind
+        ring = self._window_ring(model, page_size)
+        if ring and prefix_cache:
+            raise ValueError(
+                "prefix_cache cannot serve a net with sliding-window "
+                "attention layers: a cached page skips its prefill, which "
+                "would leave the window layers' rings unfilled (in-flight "
+                "prefix sharing is off for such a net for the same reason)")
+        self.cache = PagedKVCache(
+            num_pages, page_size, pages_per_slot, window_pages_per_slot=ring,
+            num_window_pages=window_pool_pages(slots, ring))
         # persistent radix-tree prefix cache (opt-in retention policy):
         # prefix_cache=True for defaults, a PrefixCacheConfig for knobs,
         # None/False keeps PR-13 free-on-release behavior bit-identical
@@ -145,6 +159,17 @@ class GenerationEngine:
         # its CPU replicas so that a kill lands while a stream is still
         # being written; 0 disables and changes nothing.
         self.decode_step_floor_s = float(decode_step_floor_s)
+
+    def _window_ring(self, model, page_size: int) -> int:
+        """The ring of the net this engine is built around (the one
+        given, or the registry's active default): 0 without window
+        layers, or when no model is registered yet."""
+        if model is None:
+            try:
+                model = self.models.active(self.default_model).model
+            except ModelNotFoundError:
+                return 0
+        return window_ring_pages(model, page_size)
 
     # ------------------------------------------------------------- lifecycle
     def start(self) -> "GenerationEngine":
@@ -351,6 +376,12 @@ class GenerationEngine:
             pages_per_slot=self.cache.pages_per_slot,
             page_size=self.cache.page_size, num_pages=self.cache.num_pages,
             prefill_buckets=self.prefill_buckets, detector=mv.detector)
+        if progs.ring != self.cache.window_pages_per_slot:
+            raise ValueError(
+                f"cannot serve {mv.key}: its window layers ring through "
+                f"{progs.ring} pages a slot, the page manager was built "
+                f"for {self.cache.window_pages_per_slot} (the engine "
+                "learns the ring from the model it is constructed with)")
         if self._pools is not None:
             live = jax.tree_util.tree_map(
                 lambda a: (a.shape, str(a.dtype)), self._pools)
@@ -453,6 +484,10 @@ class GenerationEngine:
     def _prefill(self, progs: GenerationPrograms, mv: ModelVersion,
                  req: GenerationRequest) -> None:
         phase = self.phases.phase
+        # a window layer's chunk is written into its ring as a prompt
+        # prefilled whole from position 0 (_apply_window_paged)
+        assert not (progs.ring and req.shared_len), (
+            "a prefix was shared under window layers")
         with phase("page_gather", stage="admit"):
             suffix = req.prompt[req.shared_len:]
             bucket = progs.bucket_for(len(suffix))
@@ -559,6 +594,11 @@ class GenerationEngine:
     def _refresh_gauges(self) -> None:
         self.metrics.active_slots.set(len(self.scheduler.active_slots()))
         self.metrics.page_util.set(self.cache.utilization())
+        for kind in self.cache.KINDS:
+            if self.cache.pages_total(kind):
+                self.metrics.set_kv_pages(kind,
+                                          self.cache.pages_in_use(kind),
+                                          self.cache.pages_total(kind))
         if self.prefix_cache is not None:
             # one locked snapshot — three separate reads could tear
             # across a concurrent eviction/offload (resident dropping
@@ -605,8 +645,9 @@ class GenerationEngine:
             return {}
         allocated = None
         if allocated_only:
-            allocated = [p for p in range(1, self.cache.num_pages)
-                         if self.cache.refcount(p) > 0]
+            # each kind of pool has page ids of its own
+            allocated = {kind: self.cache.allocated_pages(kind)
+                         for kind in self.cache.KINDS}
         return numerics.kv_page_ledger(pools, allocated=allocated)
 
     # ----------------------------------------------------------------- stats

@@ -19,6 +19,21 @@ decouples them:
   map to the SAME read-only pages (refcounted — freed only when the
   last sharer leaves).
 
+**Pages by layer kind.**  A net with sliding-window attention layers has a
+second kind of pool (``SelfAttentionLayer.init_paged_cache``: ``wk``/``wv``):
+a window layer needs the last ``window`` positions only, so every request
+holds a RING of at most ``window_pages_per_slot = ceil(window / page_size)
++ 1`` pages of that kind, written modulo, however long its context grows.
+The one manager keeps both budgets: admission takes a request's global pages
+(by its context) and its ring (``min(those, window_pages_per_slot)``) or
+neither; release returns both; ``utilization()`` and ``pages_in_use`` count
+both.  The two kinds have page ids of their own (each pool's page 0 is its
+trash page), and a block-table row is the global table and the ring table
+side by side (``table_width`` columns).  Prefix sharing is off under window
+layers: a shared page skips its prefill, which would leave the sharer's
+ring unfilled (the persistent ``PrefixCache`` is refused at engine set-up
+for the same reason).
+
 Page 0 is reserved as the TRASH page: unallocated block-table entries
 point at it, so bucket-padding positions and idle decode slots scatter
 their garbage somewhere harmless that no causal mask ever lets a real
@@ -60,9 +75,15 @@ class PagedKVCache:
 
     ``num_pages`` counts the usable pool INCLUDING the reserved trash
     page; ``pages_per_slot`` is the block-table width (the per-request
-    context ceiling is ``pages_per_slot * page_size``)."""
+    context ceiling is ``pages_per_slot * page_size``).
+    ``window_pages_per_slot`` (0: the net has no window layer) is the ring
+    a request holds of the window kind, ``num_window_pages`` that kind's
+    pool, its trash page included."""
 
-    def __init__(self, num_pages: int, page_size: int, pages_per_slot: int):
+    KINDS = ("global", "window")
+
+    def __init__(self, num_pages: int, page_size: int, pages_per_slot: int,
+                 window_pages_per_slot: int = 0, num_window_pages: int = 0):
         if num_pages < 2:
             raise ValueError(f"num_pages={num_pages} must be >= 2 "
                              "(page 0 is the reserved trash page)")
@@ -71,7 +92,17 @@ class PagedKVCache:
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.pages_per_slot = int(pages_per_slot)
+        self.window_pages_per_slot = int(window_pages_per_slot)
+        self.num_window_pages = int(num_window_pages)
+        if self.window_pages_per_slot and self.num_window_pages < 2:
+            raise ValueError(
+                f"num_window_pages={num_window_pages} must be >= 2 with "
+                "window layers (page 0 of that kind is its trash page)")
         self._free: List[int] = list(range(1, self.num_pages))
+        # the window kind: no refcounts (never shared); a request's ring is
+        # kept under its first global page, which is its alone
+        self._window_free: List[int] = list(range(1, self.num_window_pages))
+        self._ring_of: Dict[int, List[int]] = {}
         self._refs = np.zeros(self.num_pages, np.int64)
         self._refs[TRASH_PAGE] = 1   # never allocatable
         # chained prefix hash -> page id, and the reverse for cleanup
@@ -100,9 +131,31 @@ class PagedKVCache:
     def used_pages(self) -> int:
         return (self.num_pages - 1) - len(self._free)
 
+    @property
+    def table_width(self) -> int:
+        """Columns of a block-table row: the global table, then the ring."""
+        return self.pages_per_slot + self.window_pages_per_slot
+
+    def pages_total(self, kind: str) -> int:
+        """Usable pages of ``kind`` ("global" | "window")."""
+        return max(0, (self.num_window_pages if kind == "window"
+                       else self.num_pages) - 1)
+
+    def pages_in_use(self, kind: str) -> int:
+        if kind == "window":
+            return self.pages_total(kind) - len(self._window_free)
+        return self.used_pages
+
+    def allocated_pages(self, kind: str) -> List[int]:
+        """Page ids of ``kind`` that a request (or the prefix cache) holds."""
+        if kind == "window":
+            return sorted(p for ring in self._ring_of.values() for p in ring)
+        return [p for p in range(1, self.num_pages) if self._refs[p] > 0]
+
     def utilization(self) -> float:
-        usable = self.num_pages - 1
-        return (self.used_pages / usable) if usable else 0.0
+        usable = sum(self.pages_total(k) for k in self.KINDS)
+        used = sum(self.pages_in_use(k) for k in self.KINDS)
+        return (used / usable) if usable else 0.0
 
     def pages_needed(self, occupancy: int) -> int:
         """Pages covering ``occupancy`` written positions."""
@@ -138,8 +191,10 @@ class PagedKVCache:
         # the first sample and are not cached with the pages)
         shared: List[int] = []
         key: Optional[bytes] = None
+        # under window layers nothing is shared or indexed (module docstring)
+        sharing = not self.window_pages_per_slot
         max_share = min(len(self._full_prompt_pages(prompt)),
-                        (len(prompt) - 1) // self.page_size)
+                        (len(prompt) - 1) // self.page_size) * sharing
         for i in range(max_share):
             key = _chain(key, prompt[i * self.page_size:
                                      (i + 1) * self.page_size])
@@ -152,6 +207,11 @@ class PagedKVCache:
             raise PageExhaustedError(
                 f"need {fresh_count} pages, {len(self._free)} free "
                 f"(pool {self.num_pages - 1})")
+        ring_count = min(total, self.window_pages_per_slot)
+        if ring_count > len(self._window_free):
+            raise PageExhaustedError(
+                f"need {ring_count} window pages, {len(self._window_free)} "
+                f"free (pool {self.num_window_pages - 1})")
         for p in shared:
             self._refs[p] += 1
         fresh = [self._free.pop() for _ in range(fresh_count)]
@@ -160,10 +220,13 @@ class PagedKVCache:
         self.shared_pages += len(shared)
         self.fresh_pages += fresh_count
         pages = shared + fresh
+        if ring_count:
+            self._ring_of[pages[0]] = [self._window_free.pop()
+                                       for _ in range(ring_count)]
         # register THIS request's freshly prefilled full prompt pages so
         # later identical prompts can share them
         chain_key: Optional[bytes] = None
-        for i in self._full_prompt_pages(prompt):
+        for i in self._full_prompt_pages(prompt) if sharing else ():
             chain_key = _chain(chain_key,
                                prompt[i * self.page_size:
                                       (i + 1) * self.page_size])
@@ -199,7 +262,10 @@ class PagedKVCache:
 
     def free(self, pages: Sequence[int]) -> None:
         """Drop one request's references; pages return to the free list
-        (and leave the prefix index) when their last sharer leaves."""
+        (and leave the prefix index) when their last sharer leaves.  The
+        request's ring of window pages, if it holds one, goes back too."""
+        if len(pages):
+            self._window_free.extend(self._ring_of.pop(int(pages[0]), ()))
         for p in pages:
             if p == TRASH_PAGE:
                 continue
@@ -217,9 +283,13 @@ class PagedKVCache:
 
     def block_row(self, pages: Sequence[int]) -> np.ndarray:
         """A full block-table row: the request's pages in logical order,
-        trash-padded to ``pages_per_slot``."""
-        row = np.full(self.pages_per_slot, TRASH_PAGE, np.int32)
+        trash-padded to ``pages_per_slot``; under window layers its ring
+        after them, trash-padded to ``window_pages_per_slot``."""
+        row = np.full(self.table_width, TRASH_PAGE, np.int32)
         row[:len(pages)] = np.asarray(pages, np.int32)
+        if len(pages):
+            ring = self._ring_of.get(int(pages[0]), ())
+            row[self.pages_per_slot:self.pages_per_slot + len(ring)] = ring
         return row
 
     def as_dict(self) -> dict:
@@ -231,6 +301,12 @@ class PagedKVCache:
                "prefix_index_size": len(self._prefix),
                "shared_pages_total": self.shared_pages,
                "fresh_pages_total": self.fresh_pages}
+        if self.window_pages_per_slot:
+            out.update(
+                window_pages_per_slot=self.window_pages_per_slot,
+                num_window_pages=self.num_window_pages,
+                free_window_pages=len(self._window_free),
+                used_window_pages=self.pages_in_use("window"))
         if self.retention is not None:
             out["prefix_cache"] = self.retention.stats()
         return out

@@ -16,9 +16,13 @@ programs per model version, all AOT-warmed before the version serves:
   pool — the prefix cache's host-tier transport.
 
 A POOL is whatever page-major arrays a layer's ``init_paged_cache``
-returns: ``pk``/``pv`` [P, Hkv, page, D] for ``SelfAttentionLayer``, one
-latent ``pc`` [P, page, W] for ``LatentAttentionLayer``.
+returns: ``pk``/``pv`` [P, Hkv, page, D] for ``SelfAttentionLayer``
+(``wk``/``wv`` [Pw, Hkv, page, D], the WINDOW kind, where it has a
+``window``), one latent ``pc`` [P, page, W] for ``LatentAttentionLayer``.
 Every function here reaches them through one walker, ``map_pools``.
+Under window layers the dispatch's block table is two tables side by side
+(``PagedKVCache.table_width``): ``_attach`` hands a window pool the ring
+columns, every other pool the global ones.
 
 Shapes are closed by construction (slot count, pool size, block-table
 width, bucket lengths are all fixed at engine construction), so steady
@@ -45,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.generation.paged_cache import TRASH_PAGE
+from deeplearning4j_tpu.helpers.paged_attention import pool_kind
 from deeplearning4j_tpu.models.common import cast_to_compute
 from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
@@ -72,32 +77,54 @@ def named_layers_of(net) -> List[Tuple[str, object]]:
             if net.nodes[n].layer is not None]
 
 
-def _paged_attention_shapes(net) -> List[Tuple[int, int, int]]:
-    """``(q heads, kv heads, head_dim)`` of every ``SelfAttentionLayer`` of
-    ``net``, those inside composite layers too, each shape once."""
+def _self_attention_layers(net):
+    """Every ``SelfAttentionLayer`` of ``net``, those inside composite
+    layers too."""
     from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
 
     def walk(layer):
         if isinstance(layer, SelfAttentionLayer):
-            yield (layer.n_heads, layer._kv_heads,
-                   layer.n_out // layer.n_heads)
+            yield layer
         for sub in getattr(layer, "layers", ()):
             yield from walk(sub)
 
-    return sorted({s for _, l in named_layers_of(net) for s in walk(l)})
+    return [a for _, l in named_layers_of(net) for a in walk(l)]
+
+
+def _paged_attention_shapes(net) -> List[Tuple[int, int, int, int]]:
+    """``(q heads, kv heads, head_dim, window or 0)`` of every
+    ``SelfAttentionLayer`` of ``net``, each shape once."""
+    return sorted({(a.n_heads, a._kv_heads, a._d_head, a.window or 0)
+                   for a in _self_attention_layers(net)})
+
+
+def window_ring_pages(net, page_size: int) -> int:
+    """Pages a slot that ``net``'s window layers ring through (their
+    ``paged_ring``; the widest, since one manager's page ids address every
+    window pool), 0 for a net with none."""
+    return max((a.paged_ring(page_size) or 0
+                for a in _self_attention_layers(net)), default=0)
+
+
+def window_pool_pages(slots: int, ring: int) -> int:
+    """Pages of the window kind's pools: every slot's ring and the trash
+    page; 0 for a net without window layers."""
+    return slots * ring + 1 if ring else 0
 
 
 def seed_paged_pools(net, num_pages: int, page_size: int,
-                     dtype=None) -> Dict:
+                     dtype=None, window_pages: Optional[int] = None) -> Dict:
     """Paged pools for every pageable layer of ``net`` (the paged
     analog of ``models.common.seed_stream_caches``).  Raises when the
     net carries state that cannot be paged (recurrent hidden state) —
-    the engine must fail at setup, not serve wrong tokens."""
+    the engine must fail at setup, not serve wrong tokens.
+    ``window_pages`` sizes the pools of window layers."""
     cache_dtype = (jnp.dtype(dtype) if dtype else jnp.float32)
     pools = {}
     for name, layer in named_layers_of(net):
         if hasattr(layer, "init_paged_cache"):
-            c = layer.init_paged_cache(num_pages, page_size, cache_dtype)
+            c = layer.init_paged_cache(num_pages, page_size, cache_dtype,
+                                       window_pages=window_pages)
             if c is not None:
                 pools[name] = c
         elif hasattr(layer, "apply_with_carry"):
@@ -128,10 +155,25 @@ def map_pools(fn, pools, *others):
     return {k: walk(v, *(x[k] for x in others)) for k, v in pools.items()}
 
 
-def _attach(pools, block, pos):
+def _attach(pools, block, pos, maxp=None, live=None):
     """Insert the dispatch's block table / positions beside every pool's
-    arrays (the pool pytree holds the arrays alone between dispatches)."""
-    return map_pools(lambda c: {**c, "block": block, "pos": pos}, pools)
+    arrays (the pool pytree holds the arrays alone between dispatches).
+    ``maxp`` (a net with window layers): ``block`` holds the global table in
+    its first ``maxp`` columns and the ring table after them, and each pool
+    gets the one that addresses it; a window pool (``pool_kind``) also gets
+    ``live`` [B], the chunk's real tokens, where the dispatch has padding."""
+    if maxp is None:
+        return map_pools(lambda c: {**c, "block": block, "pos": pos}, pools)
+    tables = block[:, :maxp], block[:, maxp:]
+
+    def attach(c):
+        ring = pool_kind(c) == "window"
+        out = {**c, "block": tables[ring], "pos": pos}
+        if ring and live is not None:
+            out["live"] = live
+        return out
+
+    return map_pools(attach, pools)
 
 
 def _strip(carries, pools):
@@ -173,8 +215,13 @@ class GenerationPrograms:
             net, probe, None, None)
         self.expand_ids = _ids_need_time_axis(net, self.one_hot)
         self._fwd = _last_logits_fwd(net)
+        # pages by layer kind: the ring a slot holds of window pages, and
+        # that kind's pool; 0 and 0 for a net without window layers
+        self.ring = window_ring_pages(net, self.page_size)
+        self.num_window_pages = window_pool_pages(self.slots, self.ring)
         # validate pageability eagerly (raises on recurrent stacks)
-        seed_paged_pools(net, 2, page_size, net.conf.compute_dtype)
+        seed_paged_pools(net, 2, page_size, net.conf.compute_dtype,
+                         window_pages=2)
         self._decode = jax.jit(self._make_decode(), donate_argnums=(2,))
         self._prefill = {
             b: jax.jit(self._make_prefill(b), donate_argnums=(2,))
@@ -229,7 +276,8 @@ class GenerationPrograms:
     # ---------------------------------------------------------------- build
     def fresh_pools(self):
         return seed_paged_pools(self.net, self.num_pages, self.page_size,
-                                self.net.conf.compute_dtype)
+                                self.net.conf.compute_dtype,
+                                window_pages=self.num_window_pages)
 
     def bucket_for(self, length: int) -> int:
         for b in self.prefill_buckets:
@@ -247,6 +295,7 @@ class GenerationPrograms:
 
     def _make_decode(self):
         fwd, encode = self._fwd, self._encode
+        maxp = self.pages_per_slot if self.ring else None
 
         def decode_step(params, net_state, pools, block, pos, tokens,
                         keys, token_idx, temps, top_ks, top_ps):
@@ -255,7 +304,7 @@ class GenerationPrograms:
             # real rows: an idle slot's table points at the trash page
             with counting(lambda: block[:, :1] != TRASH_PAGE) as counts:
                 pre, nc = fwd(params, net_state, x,
-                              _attach(pools, block, pos))
+                              _attach(pools, block, pos, maxp))
             with jax.named_scope("sample"):
                 logits = pre[:, -1].astype(jnp.float32)
                 nxt = sample_tokens(logits, keys, token_idx, temps, top_ks,
@@ -267,6 +316,7 @@ class GenerationPrograms:
 
     def _make_prefill(self, bucket: int):
         fwd, encode = self._fwd, self._encode
+        maxp = self.pages_per_slot if self.ring else None
 
         def prefill(params, net_state, pools, block, start, last_idx,
                     tokens, keys, token_idx, temps, top_ks, top_ps):
@@ -281,7 +331,8 @@ class GenerationPrograms:
             with counting(lambda: jnp.arange(bucket)[None] <= last_idx
                           ) as counts:
                 pre, nc = fwd(params, net_state, x,
-                              _attach(pools, block, start))
+                              _attach(pools, block, start, maxp,
+                                      (last_idx + 1)[None]))
             with jax.named_scope("sample"):
                 logits = jnp.take(pre[0], last_idx, axis=0)[None]
                 tok = sample_tokens(logits.astype(jnp.float32), keys,
@@ -358,7 +409,7 @@ class GenerationPrograms:
         """``{name: (jitted, args after the pools)}`` for the programs that
         run the model — each ``prefill_<bucket>`` and ``decode`` — with the
         exact arguments they are warmed, and therefore served, with."""
-        s, maxp = self.slots, self.pages_per_slot
+        s, maxp = self.slots, self.pages_per_slot + self.ring
         z = np.zeros
         progs = {
             f"prefill_{b}": (self._prefill[b], (
@@ -386,16 +437,19 @@ class GenerationPrograms:
         dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
         programs = [(f"prefill_{b}", 1, b) for b in self.prefill_buckets]
         programs.append(("decode", self.slots, 1))
-        for hq, hkv, d in _paged_attention_shapes(self.net):
-            for name, b, t in programs:
+        for hq, hkv, d, window in _paged_attention_shapes(self.net):
+            # a window layer's kernel runs over its ring, and in the
+            # decode step alone (its prefill chunk attends over itself)
+            pages = self.ring if window else self.pages_per_slot
+            for name, b, t in programs[-1:] if window else programs:
                 ppb, tq, vmem = pa.paged_tiling(
-                    b, t, hq, hkv, d, self.page_size, self.pages_per_slot,
-                    dtype)
+                    b, t, hq, hkv, d, self.page_size, pages, dtype)
                 logger.info(
                     "generation.%s: fused_paged_attention q [%d, %d, %d, "
-                    "%d] over %d pages of %d: %d pages a block, %d query "
+                    "%d] over %d pages of %d%s: %d pages a block, %d query "
                     "positions a tile, grid (%d, %d), %.2f MB of VMEM",
-                    name, b, t, hq, d, self.pages_per_slot, self.page_size,
+                    name, b, t, hq, d, pages, self.page_size,
+                    f" (a ring, window {window})" if window else "",
                     ppb, tq, b, -(-t // tq), vmem / 2 ** 20)
 
     def lowered(self) -> Dict[str, "jax.stages.Lowered"]:

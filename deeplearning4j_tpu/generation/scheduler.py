@@ -195,10 +195,9 @@ class DecodeScheduler:
         self._pending: "deque[GenerationRequest]" = deque()
         self._stopping = False
         self.slots: List[Optional[_Slot]] = [None] * self.num_slots
-        maxp = cache.pages_per_slot
         # the decode step's host-side mirror arrays, updated in place on
         # admit/retire and handed to the jitted step every iteration
-        self.block = np.zeros((self.num_slots, maxp), np.int32)
+        self.block = np.zeros((self.num_slots, cache.table_width), np.int32)
         self.pos = np.zeros(self.num_slots, np.int32)
         self.last_tok = np.zeros(self.num_slots, np.int32)
         self.keys = np.zeros((self.num_slots, 2), np.uint32)

@@ -46,6 +46,17 @@ Two implementations behind one public op:
   measured CPU decode win comes from: the legacy gather always pays
   all MAXP pages.
 
+A WINDOW layer's pool is addressed through a RING table instead
+(``window=``; ``SelfAttentionLayer.init_paged_cache``): ``R = ceil(window
+/ page_size) + 1`` pages a row, position ``p`` in column ``(p //
+page_size) % R``.  The three implementations then recover each column's
+logical page from the row's highest query position (``ring_pages``; for
+the kernel a third scalar prefetch), give every key its absolute
+position, and mask to ``q - window < k <= q``; a row's live blocks are the
+columns it has reached, all of the ring once it has wrapped.  The engine
+calls it so for decoded tokens only (a window layer's prefill chunk
+attends over its own keys).
+
 Semantics match the legacy pair exactly (the flag-selectable oracle):
 GQA contracts the UNEXPANDED kv heads, masking is per-row
 ``q_positions >= key_position`` where a key's global position is its
@@ -131,14 +142,69 @@ def _check_shapes(q, pk, pv, block, q_positions):
 
 
 # ---------------------------------------------------------------------------
+# the pool's layout: what writes a page and what reads it agree on here
+# ---------------------------------------------------------------------------
+
+# leaves of a WINDOW layer's pool (``SelfAttentionLayer.init_paged_cache``);
+# every other pool is of the global kind
+WINDOW_POOL_LEAVES = ("wk", "wv")
+
+
+def pool_kind(pool) -> str:
+    """The page manager's kind (``PagedKVCache.KINDS``) of a layer's pool
+    dict: ``"window"`` where a request holds a ring of pages, ``"global"``
+    where it holds its whole context."""
+    return ("window" if any(n in pool for n in WINDOW_POOL_LEAVES)
+            else "global")
+
+
+def write_token_rows(pool: jax.Array, page: jax.Array, off: jax.Array,
+                     rows: jax.Array) -> jax.Array:
+    """``pool`` [P, Hkv, page_size, D] with ``rows`` [N, Hkv, D] written at
+    ``(page[n], :, off[n], :)``, as a scatter of ``N * Hkv`` whole rows into
+    the pool seen as a table ``[P * Hkv * page_size, D]``.  The 4-D scatter
+    ``pool.at[page, :, off].set(rows)`` says the same, but the TPU compiler
+    re-lays the WHOLE pool out token-major ahead of it and back after it
+    (two pool-sized copies a pool: 0.57 GB each at 4,353 pages of 8 x 64 x
+    128 bf16; compiled for a described v5e, PERF.md PR 33); rows of a table
+    scatter in place."""
+    p_, hkv, ps, d = pool.shape
+    idx = ((page[:, None] * hkv + jnp.arange(hkv, dtype=page.dtype)[None, :])
+           * ps + off[:, None]).reshape(-1)
+    flat = pool.reshape(p_ * hkv * ps, d).at[idx].set(
+        rows.reshape(-1, d).astype(pool.dtype))
+    return flat.reshape(pool.shape)
+
+
+def ring_column(positions: jax.Array, page_size: int, ring: int) -> jax.Array:
+    """The column of a row's RING table that holds ``positions``: a window
+    layer's pool keeps ``ring`` pages a slot, written modulo."""
+    return (positions // page_size) % ring
+
+
+def ring_pages(q_positions: jax.Array, page_size: int,
+               ring: int) -> jax.Array:
+    """[B, R] int32: the logical page each column of a row's ring table
+    holds (``ring_column`` read backwards).  Once a row has been written up
+    to its highest query position, column ``r`` holds the newest logical
+    page ``L <= that position // page_size`` with ``L % R == r`` --
+    negative where the row has not reached column ``r`` yet (never written;
+    the ``kpos >= 0`` mask hides it)."""
+    top = jnp.max(q_positions, axis=1).astype(jnp.int32) // page_size
+    r = jnp.arange(ring, dtype=jnp.int32)
+    return top[:, None] - (top[:, None] - r[None, :]) % ring
+
+
+# ---------------------------------------------------------------------------
 # lax fallback: fori_loop over live pages, online softmax
 # ---------------------------------------------------------------------------
 
-def _lax_paged(q, pk, pv, block, q_positions):
+def _lax_paged(q, pk, pv, block, q_positions, window=None):
     """Compiled page-streaming fallback for non-TPU backends.  One
     ``[B, Hkv, page_size, D]`` slab in flight at a time; loop bound is
     the dynamic live-page watermark (traced -> while_loop -> zero
-    steady-state recompiles)."""
+    steady-state recompiles).  With ``window`` the table is a ring
+    (``ring_pages`` gives each column's logical page)."""
     b, t, hq, d = q.shape
     hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
@@ -151,6 +217,8 @@ def _lax_paged(q, pk, pv, block, q_positions):
     m0 = jnp.full((b, hkv, g, t), NEG_INF, acc_dt)
     l0 = jnp.zeros((b, hkv, g, t), acc_dt)
     a0 = jnp.zeros((b, t, hkv, g, d), acc_dt)
+    if window is not None:
+        lpage = ring_pages(q_positions, page_size, maxp)      # [B, R]
 
     def body(p, carry):
         m, l, acc = carry
@@ -158,8 +226,13 @@ def _lax_paged(q, pk, pv, block, q_positions):
         v = pv[block[:, p]].astype(acc_dt)
         kpos = p * page_size + offs
         s = jnp.einsum("bthgd,bhkd->bhgtk", qg, k) * scale
-        keep = (q_positions[:, None, None, :, None]
-                >= kpos[None, None, None, None, :])
+        qp = q_positions[:, None, None, :, None]
+        if window is None:
+            keep = qp >= kpos[None, None, None, None, :]
+        else:
+            kpos = (jax.lax.dynamic_index_in_dim(lpage, p, 1) * page_size
+                    + offs[None, :])[:, None, None, None, :]
+            keep = (qp >= kpos) & (kpos >= 0) & (kpos > qp - window)
         s = jnp.where(keep, s, NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_cur)
@@ -236,17 +309,27 @@ def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
     return ppb, tq, vmem(tq)
 
 
-def _paged_kernel(blk_ref, qmax_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
-                  scale, page_size, maxp):
+def _paged_kernel(blk_ref, qmax_ref, *refs, scale, page_size, maxp,
+                  window=None):
+    if window is not None:
+        # a ring table: the logical page of each of its columns rides a
+        # third scalar prefetch (``ring_pages``)
+        lp_ref, *refs = refs
+    (qp_ref, q_ref, k_hbm, v_hbm, o_ref,
+     kbuf, vbuf, sem, m_scr, l_scr, acc_scr) = refs
     b, ti = pl.program_id(0), pl.program_id(1)
     _, ppb, hkv, _, dpad = kbuf.shape
     rows = q_ref.shape[1]
     bk = ppb * page_size
-    # live blocks of this row tile: up to the one holding its highest
-    # query position (a dead block costs neither a copy nor a step)
-    nblk = jnp.minimum(qmax_ref[b * pl.num_programs(1) + ti] // bk + 1,
-                       pl.cdiv(maxp, ppb))
+    qmax = qmax_ref[b * pl.num_programs(1) + ti]
+    if window is None:
+        # live blocks of this row tile: up to the one holding its highest
+        # query position (a dead block costs neither a copy nor a step)
+        nblk = jnp.minimum(qmax // bk + 1, pl.cdiv(maxp, ppb))
+    else:
+        # the columns the row has reached (qmax is the ROW's highest
+        # position here): all of the ring once it has wrapped
+        nblk = (jnp.minimum(qmax // page_size + 1, maxp) + ppb - 1) // ppb
 
     def copies(j, slot):
         out = []
@@ -279,9 +362,29 @@ def _paged_kernel(blk_ref, qmax_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         for c in copies(j, slot):
             c.wait()
-        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
-        # per-row global query positions, lane-broadcast like m/l
-        keep = qp_ref[:, :1] >= kpos
+        if window is None:
+            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+            # per-row global query positions, lane-broadcast like m/l
+            keep = qp_ref[:, :1] >= kpos
+        else:
+            col = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+            qp = qp_ref[:, :1]
+            # a column past the table's end repeats the last page (see
+            # copies): its keys get no position
+            kpos = jnp.full((rows, bk), -1, jnp.int32)
+            for i in range(ppb):
+                p = j * ppb + i
+                if maxp % ppb:
+                    first = jnp.where(
+                        p < maxp,
+                        lp_ref[b * maxp + jnp.minimum(p, maxp - 1)]
+                        * page_size, -(2 ** 30))
+                else:
+                    first = lp_ref[b * maxp + p] * page_size
+                kpos = jnp.where(
+                    (col >= i * page_size) & (col < (i + 1) * page_size),
+                    first + col - i * page_size, kpos)
+            keep = (qp >= kpos) & (kpos >= 0) & (kpos > qp - window)
         for h in range(hkv):
             k = kbuf[slot, :, h].reshape(bk, dpad)
             v = vbuf[slot, :, h].reshape(bk, dpad)
@@ -308,8 +411,8 @@ def _paged_kernel(blk_ref, qmax_ref, qp_ref, q_ref, k_hbm, v_hbm, o_ref,
 # one trace and one lowering: the kernel's unrolled copies and heads make
 # those the dear part (six layers x three programs took 3 s of the serve
 # cell's set-up without it; XLA inlines the calls, the program is the same)
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_paged(q, pk, pv, block, q_positions, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def _pallas_paged(q, pk, pv, block, q_positions, interpret, window=None):
     b, t, hq, d = q.shape
     hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
@@ -346,20 +449,29 @@ def _pallas_paged(q, pk, pv, block, q_positions, interpret):
     qrows = jnp.pad(jnp.tile(qpos, (1, 1, g)), ((0, 0), (0, 0), (0, rpad)))
     qrows = jnp.broadcast_to(qrows[..., None], (b, nt, rows, LANES))
     qmax = jnp.max(qpos, axis=2).reshape(-1)
-
-    def tile_idx(bi, ti, blk, qmax):
-        return (bi, ti, 0, 0, 0)
-
+    prefetch = (block.astype(jnp.int32).reshape(-1), qmax)
     kern = functools.partial(_paged_kernel, scale=scale,
                              page_size=page_size, maxp=maxp)
+    if window is not None:
+        # the ring is written up to the ROW's highest position, whatever
+        # tile a query sits in
+        prefetch = (prefetch[0],
+                    jnp.repeat(jnp.max(q_positions.astype(jnp.int32),
+                                       axis=1), nt),
+                    ring_pages(q_positions, page_size, maxp).reshape(-1))
+        kern = functools.partial(kern, window=window)
+
+    def tile_idx(bi, ti, *prefetched):
+        return (bi, ti, 0, 0, 0)
+
     o = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=(b, nt),
             in_specs=[
                 pl.BlockSpec((None, None, rows, LANES),
-                             lambda bi, ti, blk, qmax: (bi, ti, 0, 0)),
+                             lambda bi, ti, *prefetched: (bi, ti, 0, 0)),
                 pl.BlockSpec((None, None, hkv, rows, dpad), tile_idx),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -379,7 +491,7 @@ def _pallas_paged(q, pk, pv, block, q_positions, interpret):
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
         name="fused_paged_attention",
-    )(block.astype(jnp.int32).reshape(-1), qmax, qrows, qb, pk, pv)
+    )(*prefetch, qrows, qb, pk, pv)
     o = (o[:, :, :, :g * tq].reshape(b, nt, hkv, g, tq, dpad)
          .transpose(0, 1, 4, 2, 3, 5).reshape(b, nt * tq, hq, dpad))
     return o[:, :t, :, :d]
@@ -397,6 +509,7 @@ def default_impl() -> str:
 
 def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
                            block: jax.Array, q_positions: jax.Array, *,
+                           window: Optional[int] = None,
                            impl: Optional[str] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Per-row causal attention of ``q`` [B, T, Hq, D] directly over the
@@ -409,6 +522,14 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
     per row, which (exactly as the legacy ``paged_attention`` documents)
     also hides unwritten pages and trash-page-0 padding entries.  GQA
     contracts the unexpanded kv heads.
+
+    ``window`` makes ``block`` a RING table [B, R] over a window layer's
+    pool (``SelfAttentionLayer.init_paged_cache``): position ``p`` lives in
+    column ``(p // page_size) % R``, every row written up to its highest
+    query position; a key's position is recovered from that
+    (``ring_pages``) and a query sees the keys at
+    ``q - window < k <= q``.  The caller keeps ``R * page_size`` at least
+    ``window`` plus the positions written in the call.
 
     ``impl``: None picks ``"pallas"`` on TPU and ``"lax"`` elsewhere;
     ``"gather"`` routes through the legacy gather+softmax pair (the
@@ -424,14 +545,20 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
 
         gk = gather_pages(pk, block).astype(q.dtype)
         gv = gather_pages(pv, block).astype(q.dtype)
-        return paged_attention(q, gk, gv, q_positions)
+        if window is None:
+            return paged_attention(q, gk, gv, q_positions)
+        ps = pk.shape[2]
+        kpos = (ring_pages(q_positions, ps, block.shape[1])[:, :, None] * ps
+                + jnp.arange(ps)).reshape(block.shape[0], -1)
+        return paged_attention(q, gk, gv, q_positions, k_positions=kpos,
+                               window=window)
     if impl == "lax":
-        return _lax_paged(q, pk, pv, block, q_positions)
+        return _lax_paged(q, pk, pv, block, q_positions, window)
     if impl != "pallas":
         raise ValueError(f"impl={impl!r} not one of pallas/lax/gather")
     if interpret is None:
         interpret = _interpret()
-    return _pallas_paged(q, pk, pv, block, q_positions, interpret)
+    return _pallas_paged(q, pk, pv, block, q_positions, interpret, window)
 
 
 class PagedAttentionHelper:
@@ -449,5 +576,7 @@ class PagedAttentionHelper:
     def supports(self, q, page_size: int) -> bool:
         return paged_attention_mode() == "fused"
 
-    def attend(self, q, pk, pv, block, q_positions) -> jax.Array:
-        return paged_decode_attention(q, pk, pv, block, q_positions)
+    def attend(self, q, pk, pv, block, q_positions,
+               window: Optional[int] = None) -> jax.Array:
+        return paged_decode_attention(q, pk, pv, block, q_positions,
+                                      window=window)

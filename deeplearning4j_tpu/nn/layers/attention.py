@@ -85,10 +85,13 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
 
 
 def rope(x: jax.Array, positions: jax.Array,
-         theta: float = 10000.0, inv_freq=None) -> jax.Array:
+         theta: float = 10000.0, inv_freq=None,
+         mscale: float = 1.0) -> jax.Array:
     """Rotary position embedding on ``[B, T, H, D]`` (RoFormer; public
     standard).  ``inv_freq`` ([D // 2], e.g. ``yarn_inv_freq``) replaces
-    the frequencies ``theta`` gives.  ``positions`` is the [T] vector of
+    the frequencies ``theta`` gives; ``mscale`` multiplies cos and sin
+    (YaRN's ``attention_factor`` as ``rope_type: yarn`` applies it).
+    ``positions`` is the [T] vector of
     GLOBAL positions —
     or, for the paged continuous-batching decode path where every batch
     row sits at a different stream position, a per-row [B, T] matrix —
@@ -112,6 +115,8 @@ def rope(x: jax.Array, positions: jax.Array,
     else:
         cos = jnp.cos(ang)[:, :, None, :]
         sin = jnp.sin(ang)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1 = x[..., :half].astype(acc)
     x2 = x[..., half:2 * half].astype(acc)
     out = jnp.concatenate(
@@ -209,10 +214,15 @@ def gather_pages(pages: jax.Array, block: jax.Array) -> jax.Array:
 
 
 def paged_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    q_positions: jax.Array) -> jax.Array:
+                    q_positions: jax.Array,
+                    k_positions: Optional[jax.Array] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Causal attention of ``q`` [B, T, H, D] over a gathered paged view
     ``k``/``v`` [B, L, Hkv, D] whose flat index IS the global position
-    (see ``gather_pages``).  ``q_positions`` [B, T] are per-row global
+    (see ``gather_pages``) -- or, over a ring table's view, whose positions
+    are ``k_positions`` [B, L] (``helpers.paged_attention.ring_pages``;
+    negative = never written),
+    banded to the last ``window`` positions of each query.  ``q_positions`` [B, T] are per-row global
     query positions — every batch row sits at a different point of its
     own stream, which is the whole point of continuous batching, so the
     causal mask is per-row (``dot_product_attention`` masks by a single
@@ -226,8 +236,14 @@ def paged_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     grouped = hq != hkv
-    kpos = jnp.arange(k.shape[1])
-    cm = q_positions[:, :, None] >= kpos[None, None, :]   # [B, T, L]
+    if k_positions is None:
+        kpos = jnp.arange(k.shape[1])
+        cm = q_positions[:, :, None] >= kpos[None, None, :]   # [B, T, L]
+    else:
+        kpos = k_positions[:, None, :]
+        cm = (q_positions[:, :, None] >= kpos) & (kpos >= 0)
+    if window is not None:
+        cm &= kpos > q_positions[:, :, None] - window
     neg = jnp.asarray(-1e30, acc)
     if grouped:
         qg = q.reshape(q.shape[0], q.shape[1], hkv, hq // hkv, d)
@@ -250,9 +266,10 @@ class SelfAttentionLayer(Layer):
     """Multi-head self-attention over ``[B, T, F]``.
 
     Params follow the framework's reference-style short names:
-    ``Wq/Wk/Wv/Wo`` + ``bq/bk/bv/bo``.  ``causal=True`` gives decoder
-    (language-model) masking.  ``seq_axis`` switches the inner product to
-    ring attention over that mesh axis (requires shard_map execution).
+    ``Wq/Wk/Wv/Wo`` + ``bq/bk/bv/bo`` (``bias=False``: no bias vectors),
+    ``Wg`` with ``gate``.  ``causal=True`` gives decoder (language-model)
+    masking.  ``seq_axis`` switches the inner product to ring attention
+    over that mesh axis (requires shard_map execution).
     """
 
     n_in: Optional[int] = None
@@ -285,8 +302,29 @@ class SelfAttentionLayer(Layer):
     # compute AND HBM fetches; the einsum/ring paths apply the band as
     # masking (full score matrices); streaming decode uses a window-length
     # ROLLING cache (position-tracked ring buffer) — O(window) memory for
-    # unbounded decode
+    # unbounded decode; the paged engine gives a window layer a RING of
+    # ceil(window / page) + 1 pages a slot (init_paged_cache)
     window: Optional[int] = None
+    # width of one head; None = n_out // n_heads.  Set, the heads' total
+    # width n_heads * head_dim is free of n_out: Wq [n_in, H * D],
+    # Wo [H * D, n_out]
+    head_dim: Optional[int] = None
+    # False: no bq/bk/bv/bo
+    bias: bool = True
+    # partial rotary: rotate the first rotary_dim columns of a head
+    # (rotate-half pairing inside them), pass the rest; None = all of them
+    rotary_dim: Optional[int] = None
+    # YaRN (rope_factor > 1), named as LatentAttentionLayer names them:
+    # frequencies blended by yarn_inv_freq, cos and sin times
+    # yarn_mscale(rope_factor, rope_mscale) (HF's attention_factor)
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    # "per_head": head h's output times sigmoid(x Wg)[h] ahead of Wo
+    # (a head-wise output gate computed from the layer's input)
+    gate: Optional[str] = None
 
     def setup(self, input_type: InputType) -> "SelfAttentionLayer":
         upd = {}
@@ -299,9 +337,27 @@ class SelfAttentionLayer(Layer):
     def output_type(self, input_type: InputType) -> InputType:
         return InputType.recurrent(self.n_out, input_type.timesteps)
 
+    def validate(self) -> None:
+        super().validate()
+        if self.gate not in (None, "per_head"):
+            raise ValueError(f"gate={self.gate!r} not one of None, "
+                             "'per_head'")
+        if self.rotary_dim is not None and (
+                self.rotary_dim % 2 or self.rotary_dim < 2
+                or (self.n_out is not None
+                    and self.rotary_dim > self._d_head)):
+            raise ValueError(
+                f"rotary_dim={self.rotary_dim} must be even and within the "
+                f"head width")
+
     @property
     def _kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
+
+    @property
+    def _d_head(self) -> int:
+        return (self.n_out // self.n_heads if self.head_dim is None
+                else self.head_dim)
 
     def _expand_kv(self, x: jax.Array) -> jax.Array:
         """[B, T, Hkv, D] -> [B, T, H, D]: share each KV head across its
@@ -310,7 +366,7 @@ class SelfAttentionLayer(Layer):
         return x if groups == 1 else jnp.repeat(x, groups, axis=2)
 
     def init(self, key, dtype=jnp.float32):
-        if self.n_out % self.n_heads:
+        if self.head_dim is None and self.n_out % self.n_heads:
             raise ValueError(
                 f"n_out={self.n_out} not divisible by n_heads={self.n_heads}")
         if self._kv_heads < 1 or self.n_heads % self._kv_heads:
@@ -318,18 +374,88 @@ class SelfAttentionLayer(Layer):
                 f"n_kv_heads={self.n_kv_heads} must be a positive divisor "
                 f"of n_heads={self.n_heads}")
         check_window(self.causal, self.window)
-        kv_out = self._kv_heads * (self.n_out // self.n_heads)
+        q_out = self.n_heads * self._d_head
+        kv_out = self._kv_heads * self._d_head
         ks = jax.random.split(key, 4)
         p: Dict[str, jax.Array] = {}
         for name, k, (fi, fo) in (
-            ("Wq", ks[0], (self.n_in, self.n_out)),
+            ("Wq", ks[0], (self.n_in, q_out)),
             ("Wk", ks[1], (self.n_in, kv_out)),
             ("Wv", ks[2], (self.n_in, kv_out)),
-            ("Wo", ks[3], (self.n_out, self.n_out)),
+            ("Wo", ks[3], (q_out, self.n_out)),
         ):
             p[name] = initializers.init(self.weight_init, k, (fi, fo), dtype)
-            p["b" + name[1].lower()] = jnp.zeros((fo,), dtype)
+            if self.bias:
+                p["b" + name[1].lower()] = jnp.zeros((fo,), dtype)
+        if self.gate is not None:
+            # a fifth key derived from the fourth, so that the four above
+            # stay what a layer without a gate draws
+            p["Wg"] = initializers.init(
+                self.weight_init, jax.random.fold_in(ks[3], 1),
+                (self.n_in, self.n_heads), dtype)
         return p
+
+    # ------------------------------------------------------------ the parts
+    def _project(self, params, x):
+        """x [B, T, F] -> q [B, T, H, D], k and v [B, T, Hkv, D]."""
+        def lin(w, b):
+            y = x @ params[w]
+            return y + params[b] if self.bias else y
+
+        return (split_heads(lin("Wq", "bq"), self.n_heads),
+                split_heads(lin("Wk", "bk"), self._kv_heads),
+                split_heads(lin("Wv", "bv"), self._kv_heads))
+
+    def _rotate(self, x, positions):
+        """RoPE of q or k [B, T, H, D] at ``positions`` ([T] or [B, T]):
+        plain, or YaRN's frequencies and cos / sin scale, over the first
+        ``rotary_dim`` columns."""
+        if not self.rope:
+            return x
+        if self.rotary_dim is None and self.rope_factor <= 1:
+            return rope(x, positions, self.rope_theta)
+        rd = x.shape[-1] if self.rotary_dim is None else self.rotary_dim
+        inv_freq, mscale = None, 1.0
+        if self.rope_factor > 1:
+            inv_freq = yarn_inv_freq(
+                rd, self.rope_theta, self.rope_factor,
+                self.rope_original_max, self.rope_beta_fast,
+                self.rope_beta_slow)
+            mscale = yarn_mscale(self.rope_factor, self.rope_mscale)
+        y = rope(x[..., :rd], positions, self.rope_theta, inv_freq=inv_freq,
+                 mscale=mscale)
+        return jnp.concatenate([y, x[..., rd:]], axis=-1)
+
+    def _out(self, params, o, x):
+        """Heads [B, T, H, D] -> [B, T, n_out]: the per-head gate (from the
+        layer's input ``x``), ``Wo``, the activation."""
+        if self.gate is not None:
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid((x @ params["Wg"]).astype(jnp.float32))
+                o = (o * g[..., None]).astype(o.dtype)
+        y = merge_heads(o) @ params["Wo"]
+        if self.bias:
+            y = y + params["bo"]
+        return activations.get(self.activation)(y)
+
+    def _attend_sequence(self, q, k, v, mask=None):
+        """Attention of a whole sequence over its own keys (``apply``, and a
+        window layer's paged chunk): the flash kernel where it engages, the
+        grouped einsum elsewhere."""
+        with jax.named_scope("attention_core"):
+            if self.flash and mask is None and q.dtype != jnp.float64:
+                from deeplearning4j_tpu.helpers import get_helper
+
+                helper = get_helper("attention")
+                if helper is not None and helper.supports(q.shape[1],
+                                                          q.shape[3]):
+                    return helper.attend(q, self._expand_kv(k),
+                                         self._expand_kv(v),
+                                         causal=self.causal,
+                                         window=self.window)
+            # grouped contraction: no KV expansion materialized
+            return dot_product_attention(q, k, v, causal=self.causal,
+                                         window=self.window, mask=mask)
 
     def init_cache(self, batch: int, dtype=jnp.float32) -> Dict[str, jax.Array]:
         """KV cache for streaming inference (``rnn_time_step`` on
@@ -342,10 +468,9 @@ class SelfAttentionLayer(Layer):
         GLOBAL position tracked in ``kpos`` — unbounded decode length in
         O(window) memory (out-of-band keys are overwritten exactly when
         they leave the band)."""
-        d_head = self.n_out // self.n_heads
         # GQA caches store the UNEXPANDED kv heads — the decode-memory win
         length = self.window if self.window is not None else self.max_cache
-        shape = (batch, length, self._kv_heads, d_head)
+        shape = (batch, length, self._kv_heads, self._d_head)
         cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
                  "pos": jnp.zeros((), jnp.int32)}
         if self.window is not None:
@@ -354,8 +479,18 @@ class SelfAttentionLayer(Layer):
                                      jnp.int32)
         return cache
 
+    def paged_ring(self, page_size: int) -> Optional[int]:
+        """Pages a slot of this layer's paged pool when it is a WINDOW
+        layer: ``ceil(window / page_size)`` to hold the band and one more,
+        the page being written; None for a layer that keeps every
+        position."""
+        if self.window is None:
+            return None
+        return -(-self.window // page_size) + 1
+
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         dtype=jnp.float32) -> Dict[str, jax.Array]:
+                         dtype=jnp.float32, window_pages: Optional[int] = None
+                         ) -> Dict[str, jax.Array]:
         """KV pool for PAGED streaming inference (the continuous-batching
         generation engine, ``deeplearning4j_tpu/generation/``): instead of
         one contiguous [B, max_cache] cache per stream, K/V live in a
@@ -372,23 +507,35 @@ class SelfAttentionLayer(Layer):
         kv head) a whole ``(page_size, D)`` tile to multiply.
         (Token-major ``[.., page_size, Hkv, D]`` would leave a kv head 1
         of Hkv rows in the tiled second-minor dimension, which the TPU
-        lowering rejects.)"""
-        if self.window is not None:
-            raise ValueError(
-                "paged KV caching does not support sliding-window "
-                f"attention (window={self.window}): pages are addressed "
-                "by absolute position; use the rolling cache for "
-                "windowed streaming")
+        lowering rejects.)
+
+        A WINDOW layer's pools are another kind, ``wk``/``wv``
+        ``[window_pages, Hkv, page_size, D]``: the engine's page manager
+        gives every request a RING of at most ``paged_ring(page_size)``
+        of these pages, addressed through a ring table of its own; position
+        ``p`` lives in column ``(p // page_size) % ring``, so the pages a
+        request holds here never grow with its context.  ``window_pages``
+        is the count the manager keeps for this kind (``num_pages`` is the
+        other kind's)."""
         if not self.causal or self.seq_axis is not None:
             raise ValueError(
                 "paged KV caching requires causal=True attention without "
                 f"seq_axis (got causal={self.causal}, "
                 f"seq_axis={self.seq_axis})")
-        d_head = self.n_out // self.n_heads
-        shape = (num_pages, self._kv_heads, page_size, d_head)
+        if self.window is not None:
+            if window_pages is None:
+                raise ValueError(
+                    f"a window layer (window={self.window}) pages through "
+                    "a ring of its own kind of pages: init_paged_cache "
+                    "needs window_pages, the count the page manager keeps "
+                    "for window layers")
+            shape = (window_pages, self._kv_heads, page_size, self._d_head)
+            return {"wk": jnp.zeros(shape, dtype),
+                    "wv": jnp.zeros(shape, dtype)}
+        shape = (num_pages, self._kv_heads, page_size, self._d_head)
         return {"pk": jnp.zeros(shape, dtype), "pv": jnp.zeros(shape, dtype)}
 
-    def _apply_paged(self, params, state, q, k, v, carry):
+    def _apply_paged(self, params, state, x, q, k, v, carry):
         """The paged-gather decode path (sibling of the rolling/linear
         branches below): write this chunk's K/V into the pool at the
         rows' global positions through the block table, gather each
@@ -400,21 +547,21 @@ class SelfAttentionLayer(Layer):
         ps = carry["pk"].shape[2]
         t_new = q.shape[1]
         new_pos = pos[:, None] + jnp.arange(t_new, dtype=pos.dtype)
-        if self.rope:
-            # rotate by each ROW's global positions (rows sit at
-            # different points of their own streams)
-            q = rope(q, new_pos, self.rope_theta)
-            k = rope(k, new_pos, self.rope_theta)
+        # rotate by each ROW's global positions (rows sit at different
+        # points of their own streams)
+        q = self._rotate(q, new_pos)
+        k = self._rotate(k, new_pos)
         page = jnp.take_along_axis(block, new_pos // ps, axis=1).reshape(-1)
         off = (new_pos % ps).reshape(-1)
         hkv, dh = k.shape[2], k.shape[3]
-        # one [Hkv, D] slab per new token at (page, :, offset, :)
-        pk = carry["pk"].at[page, :, off].set(
-            k.reshape(-1, hkv, dh).astype(carry["pk"].dtype))
-        pv = carry["pv"].at[page, :, off].set(
-            v.reshape(-1, hkv, dh).astype(carry["pv"].dtype))
         from deeplearning4j_tpu.helpers import get_helper
+        from deeplearning4j_tpu.helpers.paged_attention import (
+            write_token_rows)
 
+        # one [Hkv, D] slab per new token at (page, :, offset, :), as Hkv
+        # rows of the pool seen as a table
+        pk = write_token_rows(carry["pk"], page, off, k.reshape(-1, hkv, dh))
+        pv = write_token_rows(carry["pv"], page, off, v.reshape(-1, hkv, dh))
         helper = get_helper("paged_attention")
         # one scope whichever path does the work, so a trace reader can
         # find attention by its scope and not by a kernel's name
@@ -430,8 +577,74 @@ class SelfAttentionLayer(Layer):
                 gv = gather_pages(pv, block).astype(q.dtype)
                 o = paged_attention(q, gk, gv, new_pos)
         new_carry = {"pk": pk, "pv": pv, "block": block, "pos": pos + t_new}
-        y = merge_heads(o) @ params["Wo"] + params["bo"]
-        return activations.get(self.activation)(y), state, new_carry
+        return self._out(params, o, x), state, new_carry
+
+    def _apply_window_paged(self, params, state, x, q, k, v, carry):
+        """A window layer's paged path.  ``carry["block"]`` [B, R] is the
+        rows' RING table into the ``wk``/``wv`` pools: position ``p`` lives
+        in column ``ring_column(p)``, ``R * page >= window + page`` positions
+        a row.
+
+        One token a row (decode): write it, then attend over the ring, the
+        keys' positions recovered from the row's own (``ring_pages``), the
+        band as a mask.  A longer chunk is a prompt prefilled whole FROM
+        POSITION 0 (the engine shares no prefix under window layers, so no
+        chunk starts behind one): it attends over its own keys, banded
+        (flash where it engages), and only the last ``R * page`` of its
+        ``carry["live"]`` real tokens are written -- bucket padding and
+        what has already left the band go to the trash page, 0."""
+        from deeplearning4j_tpu.helpers.paged_attention import (
+            paged_decode_attention, ring_column, write_token_rows)
+
+        ring_tbl, pos = carry["block"], carry["pos"]   # [B, R], [B]
+        ps, ring = carry["wk"].shape[2], ring_tbl.shape[1]
+        b, t_new = q.shape[:2]
+        new_pos = pos[:, None] + jnp.arange(t_new, dtype=pos.dtype)
+        q = self._rotate(q, new_pos)
+        k = self._rotate(k, new_pos)
+        if t_new > 1:
+            o = self._attend_sequence(q, k, v)
+        # the chunk's real tokens; what is written is the last ring's worth
+        # of them at most
+        last = (carry["live"].astype(pos.dtype) if "live" in carry
+                else jnp.full((b,), t_new, pos.dtype))
+        cap = ring * ps
+        if t_new > cap:
+            idx = (jnp.maximum(last - cap, 0)[:, None]
+                   + jnp.arange(cap, dtype=pos.dtype))
+            k_w = jnp.take_along_axis(k, idx[:, :, None, None], axis=1)
+            v_w = jnp.take_along_axis(v, idx[:, :, None, None], axis=1)
+        else:
+            idx = jnp.broadcast_to(jnp.arange(t_new, dtype=pos.dtype),
+                                   (b, t_new))
+            k_w, v_w = k, v
+        w_pos = pos[:, None] + idx
+        page = jnp.where(
+            idx < last[:, None],
+            jnp.take_along_axis(ring_tbl, ring_column(w_pos, ps, ring),
+                                axis=1), 0)
+        page, off = page.reshape(-1), (w_pos % ps).reshape(-1)
+        hkv, dh = k.shape[2], k.shape[3]
+        wk = write_token_rows(carry["wk"], page, off,
+                              k_w.reshape(-1, hkv, dh))
+        wv = write_token_rows(carry["wv"], page, off,
+                              v_w.reshape(-1, hkv, dh))
+        if t_new == 1:
+            from deeplearning4j_tpu.helpers import get_helper
+
+            helper = get_helper("paged_attention")
+            with jax.named_scope("attention_core"):
+                if helper is not None and helper.supports(q, ps):
+                    o = helper.attend(q, wk, wv, ring_tbl, new_pos,
+                                      window=self.window)
+                else:
+                    # legacy gather+softmax oracle (DL4J_TPU_PAGED_GATHER=1)
+                    o = paged_decode_attention(
+                        q, wk, wv, ring_tbl, new_pos, window=self.window,
+                        impl="gather")
+        new_carry = {"wk": wk, "wv": wv, "block": ring_tbl,
+                     "pos": pos + t_new}
+        return self._out(params, o, x), state, new_carry
 
     @staticmethod
     def cache_overflow(carry, t_new: int, pos: Optional[int] = None) -> bool:
@@ -468,20 +681,20 @@ class SelfAttentionLayer(Layer):
                 f"causal={self.causal}, seq_axis={self.seq_axis}, "
                 f"mask={'set' if mask is not None else None}")
         x = self.maybe_dropout(x, train=train, rng=rng)
-        q = split_heads(x @ params["Wq"] + params["bq"], self.n_heads)
-        k = split_heads(x @ params["Wk"] + params["bk"], self._kv_heads)
-        v = split_heads(x @ params["Wv"] + params["bv"], self._kv_heads)
+        q, k, v = self._project(params, x)
         if "pk" in carry:
             # paged mode (continuous batching): per-ROW positions and a
             # block-table-addressed pool; see _apply_paged
-            return self._apply_paged(params, state, q, k, v, carry)
+            return self._apply_paged(params, state, x, q, k, v, carry)
+        if "wk" in carry:
+            # paged mode, window kind: a ring of pages a row
+            return self._apply_window_paged(params, state, x, q, k, v, carry)
         t_new = q.shape[1]
         pos = carry["pos"]
         new_pos = pos + jnp.arange(t_new, dtype=pos.dtype)
-        if self.rope:
-            # rotate by GLOBAL position; cached keys are stored rotated
-            q = rope(q, new_pos, self.rope_theta)
-            k = rope(k, new_pos, self.rope_theta)
+        # rotate by GLOBAL position; cached keys are stored rotated
+        q = self._rotate(q, new_pos)
+        k = self._rotate(k, new_pos)
         if "kpos" in carry:
             # rolling mode: attend over [old ring buffer || this chunk]
             # (writing first would clobber keys still in-band for the
@@ -523,14 +736,11 @@ class SelfAttentionLayer(Layer):
                 q, kc.astype(q.dtype), vc.astype(q.dtype),
                 causal=True, window=self.window, q_offset=pos, k_offset=0)
             new_carry = {"k": kc, "v": vc, "pos": pos + t_new}
-        y = merge_heads(o) @ params["Wo"] + params["bo"]
-        return activations.get(self.activation)(y), state, new_carry
+        return self._out(params, o, x), state, new_carry
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout(x, train=train, rng=rng)
-        q = split_heads(x @ params["Wq"] + params["bq"], self.n_heads)
-        k = split_heads(x @ params["Wk"] + params["bk"], self._kv_heads)
-        v = split_heads(x @ params["Wv"] + params["bv"], self._kv_heads)
+        q, k, v = self._project(params, x)
         if self.rope:
             if self.seq_axis is not None:
                 # inside shard_map each chip holds global timesteps
@@ -539,8 +749,8 @@ class SelfAttentionLayer(Layer):
             else:
                 off = 0
             positions = off + jnp.arange(q.shape[1])
-            q = rope(q, positions, self.rope_theta)
-            k = rope(k, positions, self.rope_theta)
+            q = self._rotate(q, positions)
+            k = self._rotate(k, positions)
         if self.seq_axis is not None:
             from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
 
@@ -549,21 +759,5 @@ class SelfAttentionLayer(Layer):
             o = ring_attention(q, k, v, mask, axis_name=self.seq_axis,
                                causal=self.causal, window=self.window)
         else:
-            o = None
-            with jax.named_scope("attention_core"):
-                if self.flash and mask is None and q.dtype != jnp.float64:
-                    from deeplearning4j_tpu.helpers import get_helper
-
-                    helper = get_helper("attention")
-                    if helper is not None and helper.supports(q.shape[1],
-                                                              q.shape[3]):
-                        o = helper.attend(q, self._expand_kv(k),
-                                          self._expand_kv(v),
-                                          causal=self.causal,
-                                          window=self.window)
-                if o is None:
-                    # grouped contraction: no KV expansion materialized
-                    o = dot_product_attention(q, k, v, causal=self.causal,
-                                              window=self.window, mask=mask)
-        y = merge_heads(o) @ params["Wo"] + params["bo"]
-        return activations.get(self.activation)(y), state
+            o = self._attend_sequence(q, k, v, mask)
+        return self._out(params, o, x), state

@@ -144,19 +144,21 @@ class ResidualBlock(Layer):
         return carry if carryable else None
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         dtype=jnp.float32):
+                         dtype=jnp.float32, window_pages=None):
         """Paged-pool carries for pageable sublayers (attention KV pools —
         see ``SelfAttentionLayer.init_paged_cache``).  A sublayer that is
         carryable but NOT pageable (recurrent state) makes the whole block
         unpageable: the continuous-batching engine needs every carry to be
         slot-addressable through the block table, and recurrent hidden
-        state is not — it raises so the engine fails loudly at setup."""
+        state is not — it raises so the engine fails loudly at setup.
+        ``window_pages`` sizes the pools of window sublayers."""
         carry = {}
         pageable = False
         for i, sub in enumerate(self.layers):
             if hasattr(sub, "init_paged_cache"):
                 pageable = True
-                c = sub.init_paged_cache(num_pages, page_size, dtype)
+                c = sub.init_paged_cache(num_pages, page_size, dtype,
+                                         window_pages=window_pages)
                 if c is not None:
                     carry[f"sub{i}"] = c
             elif hasattr(sub, "apply_with_carry"):

@@ -157,6 +157,9 @@ class RoutedMoELayer(Layer):
     over all ``n_experts``; the ``top_k`` largest of ``s + b_router`` are
     chosen; their weights are the chosen ``s`` (without the bias), divided
     by their sum when ``norm_topk_prob``, times ``routed_scaling_factor``.
+    ``scoring="softmax"`` (the Qwen-MoE family's gate): ``s = softmax(x
+    W_router)`` over all ``n_experts``, the ``top_k`` largest chosen, no
+    selection bias (no ``b_router`` leaf), the rest alike.
     ``y = sum_i w_i E_i(x) + E_shared(x)``, every expert a bias-free gated
     MLP of width ``hidden`` (the shared one of width ``shared``; 0 = none).
     No capacity: no token is dropped.
@@ -177,6 +180,7 @@ class RoutedMoELayer(Layer):
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     activation: str = "silu"
+    scoring: str = "sigmoid"
 
     def setup(self, input_type: InputType) -> "RoutedMoELayer":
         n_in = self.n_in if self.n_in is not None else input_type.flat_size()
@@ -205,6 +209,9 @@ class RoutedMoELayer(Layer):
         if not 1 <= self.top_k <= self.n_experts or self.hidden < 1:
             raise ValueError("RoutedMoELayer needs 1 <= top_k <= n_experts "
                              "and hidden >= 1")
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring={self.scoring!r} not one of "
+                             "'sigmoid', 'softmax'")
 
     def init(self, key, dtype=jnp.float32) -> Dict[str, jax.Array]:
         count = self.held[1]
@@ -216,10 +223,11 @@ class RoutedMoELayer(Layer):
                                      fan_in=fan_in, fan_out=fan_out)
 
         p = {"W_router": w(ks[0], (d, self.n_experts), d, self.n_experts),
-             "b_router": jnp.zeros((self.n_experts,), dtype),
              "W_gate": w(ks[1], (count, d, h), d, h),
              "W_up": w(ks[2], (count, d, h), d, h),
              "W_down": w(ks[3], (count, h, self.n_out), h, self.n_out)}
+        if self.scoring == "sigmoid":
+            p["b_router"] = jnp.zeros((self.n_experts,), dtype)
         if self.shared:
             p["Ws_gate"] = w(ks[4], (d, self.shared), d, self.shared)
             p["Ws_up"] = w(ks[5], (d, self.shared), d, self.shared)
@@ -232,11 +240,15 @@ class RoutedMoELayer(Layer):
         float32), over all ``n_experts``."""
         with jax.named_scope("moe_router"):
             f32 = jnp.float32
-            scores = jax.nn.sigmoid(jnp.dot(
-                tokens, params["W_router"], preferred_element_type=f32
-            ).astype(f32))
-            _, ids = jax.lax.top_k(scores + params["b_router"].astype(f32),
-                                   self.top_k)
+            logits = jnp.dot(tokens, params["W_router"],
+                             preferred_element_type=f32).astype(f32)
+            if self.scoring == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+                _, ids = jax.lax.top_k(scores, self.top_k)
+            else:
+                scores = jax.nn.sigmoid(logits)
+                _, ids = jax.lax.top_k(
+                    scores + params["b_router"].astype(f32), self.top_k)
             w = jnp.take_along_axis(scores, ids, axis=1)
             if self.norm_topk_prob:
                 w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)

@@ -58,7 +58,7 @@ import logging
 import math
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -663,7 +663,8 @@ class NumericsMonitor:
 # ---------------------------------------------------------------------------
 
 def kv_page_ledger(pools: Dict[str, Any],
-                   allocated: Optional[Sequence[int]] = None
+                   allocated: Union[Sequence[int], Dict[str, Sequence[int]],
+                                    None] = None
                    ) -> Dict[str, Any]:
     """Per-page dynamic-range stats over the generation engine's paged
     KV pools — the int8-quantization-readiness evidence for ROADMAP
@@ -673,7 +674,9 @@ def kv_page_ledger(pools: Dict[str, Any],
     ``pools``: ``{layer: {"pk": [P, Hkv, page_size, D], "pv": ...}}``
     (the engine's live pools; nested sub-layer dicts are walked and
     joined with ``/``).  ``allocated``: page ids to report (defaults to every
-    non-trash page).  ONE device_get per pool leaf; host-side numpy
+    non-trash page), or ``{"global": ids, "window": ids}`` where window
+    layers' ``wk``/``wv`` pools have page ids of their own
+    (``PagedKVCache.allocated_pages``).  ONE device_get per pool leaf; host-side numpy
     reductions after that — this is an operator/report surface, never
     called inside the decode loop."""
     def _leaf_pools(tree, prefix=""):
@@ -686,13 +689,17 @@ def kv_page_ledger(pools: Dict[str, Any],
             name = f"{prefix}/{key}" if prefix else str(key)
             yield from _leaf_pools(sub, name)
 
+    from deeplearning4j_tpu.helpers.paged_attention import pool_kind
+
     out: Dict[str, Any] = {}
     for layer, pool in _leaf_pools(pools):
         layer_entry: Dict[str, Any] = {}
+        ids = (allocated[pool_kind(pool)] if isinstance(allocated, dict)
+               else allocated)
         for leaf_name, arr in pool.items():
             a = np.abs(np.asarray(jax.device_get(arr), np.float32))
             total = a.shape[0]
-            pages = (list(allocated) if allocated is not None
+            pages = (list(ids) if ids is not None
                      else list(range(1, total)))   # page 0 = TRASH
             per = a.reshape(total, -1)
             max_abs, under, nonzero = [], [], []

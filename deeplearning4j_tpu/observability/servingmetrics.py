@@ -209,6 +209,19 @@ class GenerationMetrics:
             "dl4j_decode_page_utilization",
             "Allocated fraction of the paged KV pool (trash page "
             "excluded)", labels=("engine",)).labels(engine=self.engine_id)
+        self._kv_pages = {
+            "in_use": reg.gauge(
+                "dl4j_kv_pages_in_use",
+                "Pages of the paged KV pools held by requests (or by the "
+                "prefix cache), by layer kind: global = layers that keep "
+                "every position, window = sliding-window layers, whose "
+                "requests hold a ring that does not grow with the context",
+                labels=("engine", "kind")),
+            "total": reg.gauge(
+                "dl4j_kv_pages_total",
+                "Usable pages of the paged KV pools (trash page excluded), "
+                "by layer kind", labels=("engine", "kind"))}
+        self._kv_pages_children = {}
         self.fused_attention = reg.gauge(
             "dl4j_decode_fused_attention",
             "1 when decode attention runs the fused paged kernel "
@@ -233,3 +246,15 @@ class GenerationMetrics:
             "Active slots per dispatched decode step / total slots (1.0 = "
             "every lane did useful work)",
             buckets=_UTIL_BUCKETS)
+
+    def set_kv_pages(self, kind: str, in_use: int, total: int) -> None:
+        """``dl4j_kv_pages_in_use{kind}`` and ``dl4j_kv_pages_total{kind}``
+        of this engine; a kind's children exist from its first set, so a
+        net without window layers reports ``kind="global"`` alone."""
+        children = self._kv_pages_children.get(kind)
+        if children is None:
+            children = self._kv_pages_children[kind] = tuple(
+                self._kv_pages[k].labels(engine=self.engine_id, kind=kind)
+                for k in ("in_use", "total"))
+        children[0].set(in_use)
+        children[1].set(total)

@@ -28,6 +28,7 @@ from deeplearning4j_tpu.helpers.fused_epilogue import (
 from deeplearning4j_tpu.helpers.paged_attention import (
     VMEM_BUDGET, PagedAttentionHelper, paged_attention_mode,
     paged_decode_attention, paged_tiling, set_paged_attention_mode,
+    write_token_rows,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     SelfAttentionLayer, gather_pages, paged_attention,
@@ -236,6 +237,41 @@ def test_pallas_kernel_compiles_for_v5e(v5e_chip, b, t, hq, hkv, maxp):
         compiled = fn.lower(
             *_serving_args(b, t, hq, hkv, maxp, v5e_chip)).compile()
     assert "fused_paged_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b,t,hq,maxp,window", [
+    (32, 1, 72, 9, 512),       # a sliding layer's decode over its ring
+    (32, 1, 48, 136, None),    # a full layer's decode, group of 6
+    (1, 8192, 48, 136, None)])  # its largest prefill bucket
+def test_laguna_shapes_compile_for_v5e(v5e_chip, b, t, hq, maxp, window):
+    """``laguna-s-2.1-ep8``'s calls (8 kv heads of 128, pages of 64): the
+    ring kernel's position recovery from scalar prefetch, and row tiles of
+    a group of 6 at 8,192 positions, through the chip's compiler."""
+    ps, hkv, d = 64, 8, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    pool = sds((b * maxp + 1, hkv, ps, d), jnp.bfloat16)
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, window=window, impl="pallas", interpret=False))
+    with jax.enable_x64(False):
+        compiled = fn.lower(sds((b, t, hq, d), jnp.bfloat16), pool, pool,
+                            sds((b, maxp), jnp.int32),
+                            sds((b, t), jnp.int32)).compile()
+    assert "fused_paged_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n", [4, 40])   # a decode step's rows, a chunk
+def test_rows_of_a_table_write_what_the_slab_scatter_writes(n):
+    """``write_token_rows`` against ``pool.at[page, :, off].set``, trash
+    page hit twice and a cast to the pool's dtype among the rows."""
+    rng = np.random.default_rng(n)
+    pool = jnp.asarray(rng.standard_normal((7, 4, 16, 8)), jnp.bfloat16)
+    flat = rng.permutation(6 * 16)[:n - 2] + 16     # distinct (page, off)
+    page = jnp.asarray(np.r_[flat // 16, 0, 0], jnp.int32)
+    off = jnp.asarray(np.r_[flat % 16, 3, 9], jnp.int32)
+    rows = jnp.asarray(rng.standard_normal((n, 4, 8)), jnp.float32)
+    got = write_token_rows(pool, page, off, rows)
+    want = pool.at[page, :, off].set(rows.astype(pool.dtype))
+    assert got.dtype == pool.dtype and (got == want).all()
 
 
 def test_compiled_kernel_rejects_page_size_the_dtype_cannot_tile():
