@@ -20,6 +20,33 @@ One background decode thread owns the device pools, the slot arrays,
 and the page allocator; clients only touch the admission queue and
 their own request handles, so ``submit``/``stream`` are thread-safe.
 
+THE LOOP RUNS ONE STEP AHEAD OF THE DEVICE.  It never waits for a
+program's result before it issues the next program: the sampled ids stay
+on the device (``_ids`` [slots]: a decode step's output is the next
+step's ``tokens``, a prefill writes its first token into its lane), and
+everything else a dispatch needs the host already knows (pages are all
+allocated at admission; a finish by length is a count).  One iteration
+(``_iterate``): dispatch the prefill of every admittable request, dispatch
+decode step N+1, and only then harvest step N — ``device_get`` its ids,
+deliver them, retire what ended — and the first tokens of the requests
+just admitted, all while the device runs what was dispatched.  The depth
+is one step, fixed.  What follows from it:
+
+- a stop token, a cancel or a deadline is seen at a harvest, when the next
+  step is already in flight: the row runs AT MOST ONE STEP MORE, whose id
+  is dropped at its harvest (``dl4j_decode_discarded_rows_total``); nothing
+  past a stop token is delivered;
+- pages are freed at the harvest that ends a request and may be reused at
+  once: the device executes programs in the order they were dispatched,
+  so whatever reuses a page, or a lane of ``_ids``, runs after the last
+  program that read or wrote it;
+- the lease is per iteration: the step in flight finishes under the
+  weights it was dispatched with, the next dispatch takes the new ones;
+- an error surfaces where the host next waits: the step in flight is
+  dropped, the batch evicted, pools and ids reseeded;
+- ``stop(drain=True)`` harvests what is in flight before the thread exits,
+  ``stop(drain=False)`` drops it and evicts its rows.
+
 Minimal use::
 
     engine = GenerationEngine(net, slots=8, page_size=16,
@@ -46,7 +73,7 @@ from deeplearning4j_tpu.generation.prefix_cache import (
     PrefixCache, PrefixCacheConfig,
 )
 from deeplearning4j_tpu.generation.programs import (
-    GenerationPrograms, window_pool_pages, window_ring_pages,
+    GenerationPrograms, sampled_ids, window_pool_pages, window_ring_pages,
 )
 from deeplearning4j_tpu.generation.scheduler import (
     DecodeScheduler, GenerationRequest,
@@ -72,6 +99,18 @@ MOE_FLUSH_EVERY = 32
 
 # finish reasons that count as a successful completion
 _OK_REASONS = ("length", "stop")
+
+
+class _Step:
+    """A decode step in flight: dispatched, its ids not yet harvested."""
+
+    __slots__ = ("sampled", "rows", "model", "path")
+
+    def __init__(self, sampled, rows, model: str, path: str):
+        self.sampled = sampled      # device: ids, or (ids, counts)
+        self.rows = rows            # (lane, slot) as dispatched
+        self.model = model
+        self.path = path            # sampling path of the rows as dispatched
 
 
 class GenerationEngine:
@@ -147,6 +186,9 @@ class GenerationEngine:
             self.models.register(default_model, model)
         self._programs: "dict[str, GenerationPrograms]" = {}
         self._pools = None              # decode-thread-owned device state
+        self._ids = None                # [slots] last sampled id a lane
+        self._in_flight: Optional[_Step] = None   # dispatched, unharvested
+        self._firsts: list = []         # prefills dispatched this iteration
         self._swap_lock = threading.Lock()
         self._stop_event = threading.Event()
         self._drain = True
@@ -181,7 +223,8 @@ class GenerationEngine:
             raise RuntimeError("engine already started")
         mv = self.models.active(self.default_model)
         progs = self._build_programs(mv)
-        self._pools = progs.fresh_pools()
+        self._reseed(progs)
+        self._in_flight, self._firsts = None, []
         if self.prefix_cache is not None:
             # fresh pools mean every cached node points at garbage:
             # drop the tree, stamp the serving version, wire the page
@@ -414,8 +457,7 @@ class GenerationEngine:
     def _run(self) -> None:
         while True:
             stopping = self._stop_event.is_set()
-            if stopping and (not self._drain
-                             or not self.scheduler.has_work):
+            if stopping and (not self._drain or not self._has_work()):
                 break
             t_iter = time.perf_counter()
             with self.phases.phase("schedule", stage="loop"):
@@ -434,9 +476,7 @@ class GenerationEngine:
                         logger.info("prefix cache invalidated on swap "
                                     "to %s (%d nodes dropped)",
                                     mv.key, n)
-                    self._admit(progs, mv)
-                    if self.scheduler.active_slots():
-                        self._step(progs, mv)
+                    if self._iterate(progs, mv):
                         self.busy_wall_s += time.perf_counter() - t_iter
                         continue
             except Exception as e:
@@ -444,11 +484,12 @@ class GenerationEngine:
                                  "running batch and reseeding the pools")
                 get_flight_recorder().record("generation_error",
                                              error=str(e)[:200])
+                # what is in flight read or wrote the pools that failed
+                self._in_flight, self._firsts = None, []
                 self.scheduler.evict_all("error", e)
                 try:
-                    self._pools = self._programs[
-                        self.models.active(self.default_model).key
-                    ].fresh_pools()
+                    self._reseed(self._programs[
+                        self.models.active(self.default_model).key])
                     if self.prefix_cache is not None:
                         # the reseed just zeroed every cached page
                         self.prefix_cache.invalidate("pool_reset")
@@ -457,12 +498,45 @@ class GenerationEngine:
                                      "exiting")
                     return
             self.busy_wall_s += time.perf_counter() - t_iter
-            if not stopping and not self.scheduler.has_work:
+            if not stopping and not self._has_work():
                 self._flush_moe_counts()
                 # not busy time, so in the trace and not in "phases"
                 with self.phases.phase("wait", stage="loop", child=True):
                     self.scheduler.wait_for_work(0.05)
+        # stop(drain=False) leaves a step behind: stop() evicts its rows
+        self._in_flight, self._firsts = None, []
         self._flush_moe_counts()
+
+    def _has_work(self) -> bool:
+        return self._in_flight is not None or self.scheduler.has_work
+
+    def _reseed(self, progs: GenerationPrograms) -> None:
+        self._pools, self._ids = progs.fresh_pools(), progs.fresh_ids()
+
+    def _iterate(self, progs: GenerationPrograms, mv: ModelVersion) -> bool:
+        """One turn of the loop, one step ahead of the device: dispatch
+        the prefills of what can be admitted and decode step N+1, all from
+        ids still on the device, and only then wait for step N's ids and
+        deliver them, and after them the first tokens of the requests
+        just admitted, while the device runs what was dispatched.  True
+        if anything was dispatched or harvested."""
+        t0 = time.perf_counter()
+        self._admit(progs, mv)
+        rows = self.scheduler.running_rows()
+        step = self._dispatch(progs, mv, rows) if rows else None
+        harvest, self._in_flight = self._in_flight, step
+        if harvest is not None:
+            self._harvest(harvest)
+        firsts, self._firsts = self._firsts, []
+        for first in firsts:
+            self._harvest_first(*first)
+        if step is not None and self.decode_step_floor_s > 0.0:
+            # sleep (not spin) to the floor: sibling replica processes
+            # share the host's cores
+            remain = self.decode_step_floor_s - (time.perf_counter() - t0)
+            if remain > 0:
+                time.sleep(remain)
+        return bool(step or harvest or firsts)
 
     def _admit(self, progs: GenerationPrograms, mv: ModelVersion) -> None:
         while True:
@@ -483,6 +557,10 @@ class GenerationEngine:
 
     def _prefill(self, progs: GenerationPrograms, mv: ModelVersion,
                  req: GenerationRequest) -> None:
+        """Dispatch ``req``'s prefill and bind its slot; nothing here
+        waits for the device.  The first token goes into ``self._ids`` at
+        the request's lane on the device and reaches the client at this
+        iteration's harvest (``_harvest_first``)."""
         phase = self.phases.phase
         # a window layer's chunk is written into its ring as a prompt
         # prefilled whole from position 0 (_apply_window_paged)
@@ -503,62 +581,81 @@ class GenerationEngine:
         with step_guard("decode_prefill", engine=self.metrics.engine_id,
                         bucket=bucket, shared_pages=shared_pages):
             with phase("jitted_step", stage="admit"):
-                self._pools, tok = progs.prefill(
+                self._pools, tok, self._ids = progs.prefill(
                     bucket, self._serving_params(progs, mv),
                     mv.model.net_state,
                     self._pools, block,
                     np.asarray([req.shared_len], np.int32),
                     np.int32(len(suffix) - 1), tokens, base_key[None],
-                    np.zeros(1, np.int32), *policy)
+                    np.zeros(1, np.int32), *policy, self._ids,
+                    np.int32(req.slot))
+                _copy_to_host_async(tok)
+        with phase("page_gather", stage="admit"):
+            self.scheduler.install(req, base_key)
+        self._firsts.append((req, tok, mv.name,
+                             SAMPLING_PATHS[sampling_path(*policy)]))
+
+    def _harvest_first(self, req: GenerationRequest, tok, model: str,
+                       path: str) -> None:
+        """Wait for one prefill's sample and deliver it.  The prefill is
+        ahead of the decode step just dispatched in the device's queue, so
+        the device is busy with that step while the host is here."""
+        phase = self.phases.phase
         with phase("sample_harvest", stage="admit"):
             tok = jax.device_get(tok)
         with phase("stream_write", stage="admit"):
             tok = self._take_moe_counts(tok, "admit")
-            self.scheduler.install(req, int(tok[0]), base_key)
+            self.scheduler.first_token(req, int(tok[0]))
             self.metrics.ttft.observe(req.ttft_s)
+            shared_pages = req.shared_len // self.cache.page_size
             self.metrics.prefix_pages.inc(shared_pages, outcome="shared")
             self.metrics.prefix_pages.inc(len(req.pages) - shared_pages,
                                           outcome="fresh")
-            self.metrics.tokens.inc(model=mv.name)
-            self.metrics.sampling_steps.inc(
-                stage="admit", path=SAMPLING_PATHS[sampling_path(*policy)])
+            self.metrics.tokens.inc(model=model)
+            self.metrics.sampling_steps.inc(stage="admit", path=path)
             self._refresh_gauges()
 
-    def _step(self, progs: GenerationPrograms, mv: ModelVersion) -> None:
+    def _dispatch(self, progs: GenerationPrograms, mv: ModelVersion,
+                  rows) -> _Step:
+        """Dispatch one decode step over ``rows`` from the ids on the
+        device, start their copy to the host, and move the scheduler's
+        mirrors to where the NEXT dispatch finds them."""
         s = self.scheduler
-        phase = self.phases.phase
-        active = len(s.active_slots())
-        t_step0 = time.perf_counter()
         with step_guard("decode_step", engine=self.metrics.engine_id,
-                        active=active):
-            with phase("jitted_step", stage="decode"):
+                        active=len(rows)):
+            with self.phases.phase("jitted_step", stage="decode"):
+                where, policy = s.step_inputs()
                 self._pools, sampled = progs.decode(
                     self._serving_params(progs, mv), mv.model.net_state,
-                    self._pools,
-                    s.block, s.pos, s.last_tok, s.keys, s.tok_idx,
-                    s.temps, s.top_ks, s.top_ps)
+                    self._pools, *where, self._ids, *policy)
+                self._ids = sampled_ids(sampled)
+                _copy_to_host_async(sampled)
+                s.advance(rows)
+        self.metrics.decode_dispatch.inc(
+            mode="sync" if self._in_flight is None else "ahead")
+        return _Step(sampled, rows, mv.name,
+                     SAMPLING_PATHS[sampling_path(*policy[2:])])
+
+    def _harvest(self, step: _Step) -> None:
+        """Wait for a dispatched step's ids and deliver them; the device
+        is meanwhile running the step dispatched after it."""
+        s = self.scheduler
+        phase = self.phases.phase
         with phase("sample_harvest", stage="decode"):
-            sampled_host = jax.device_get(sampled)
+            sampled_host = jax.device_get(step.sampled)
         with phase("stream_write", stage="decode"):
             sampled_host = self._take_moe_counts(sampled_host, "decode")
-            # of the rows as dispatched: after_step resets a finished slot
-            path = SAMPLING_PATHS[sampling_path(s.temps, s.top_ks, s.top_ps)]
             with phase("deliver", stage="decode", child=True):
-                delivered = s.after_step(sampled_host)
+                delivered = s.harvest_step(step.rows, sampled_host)
             self.steady_deliveries += delivered
             with phase("gauges", stage="decode", child=True):
                 self.metrics.steps.inc()
-                self.metrics.sampling_steps.inc(stage="decode", path=path)
-                self.metrics.tokens.inc(delivered, model=mv.name)
-                self.metrics.batch_occupancy.observe(active / s.num_slots)
+                self.metrics.sampling_steps.inc(stage="decode",
+                                                path=step.path)
+                self.metrics.tokens.inc(delivered, model=step.model)
+                self.metrics.batch_occupancy.observe(
+                    len(step.rows) / s.num_slots)
                 self._refresh_gauges()
-        if self.decode_step_floor_s > 0.0:
-            # sleep (not spin) to the floor: sibling replica processes
-            # share the host's cores
-            remain = self.decode_step_floor_s - (time.perf_counter()
-                                                 - t_step0)
-            if remain > 0:
-                time.sleep(remain)
 
     def _take_moe_counts(self, harvested, stage: str):
         """The sampled ids out of what a compute program returned.  A net
@@ -692,11 +789,23 @@ class GenerationEngine:
         }
 
 
+def _copy_to_host_async(sampled) -> None:
+    """Start the transfer of what a program sampled as soon as the program
+    is dispatched, so the harvest finds it on the host."""
+    for a in jax.tree_util.tree_leaves(sampled):
+        a.copy_to_host_async()
+
+
 def _ms(seconds: Optional[float]) -> Optional[float]:
     return None if seconds is None else round(seconds * 1e3, 3)
 
 
 def _base_key(seed: int) -> np.ndarray:
-    """A request's raw uint32 base PRNG key (host copy; folded per token
-    index on device — see ``utils.sampling.sample_tokens``)."""
-    return np.asarray(jax.device_get(jax.random.PRNGKey(seed)), np.uint32)
+    """A request's raw uint32 base PRNG key (folded per token index on
+    device — see ``utils.sampling.sample_tokens``), made on the host: what
+    ``jax.random.PRNGKey(seed)`` holds for the default threefry key, with
+    no program and no transfer in the way of admission.  The seed wraps
+    to the integer width of the process (``jax_enable_x64``); its high
+    word is 0 in 32-bit mode (tests/test_decode_loop.py proves both)."""
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)

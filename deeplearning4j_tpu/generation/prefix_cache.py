@@ -226,8 +226,12 @@ class PrefixCache:
                                 # admission pins have done their job
             fresh = self.cache.alloc(fresh_count)
             pages = [n.page for n in matched] + fresh
-            # register this request's freshly prefilled full prompt
-            # pages as new tree nodes (tree takes its own ref on each)
+            # register this request's full prompt pages as new tree
+            # nodes (tree takes its own ref on each).  Its prefill is
+            # dispatched right after this and nothing waits for it: a
+            # later request that matches these nodes reads pages that the
+            # device writes first, because it runs programs in the order
+            # they were dispatched
             created: List[_Node] = []
             parent = matched[-1] if matched else self._root
             for i in range(len(matched), len(prompt) // self.page_size):

@@ -6,14 +6,21 @@ programs per model version, all AOT-warmed before the version serves:
 - ``prefill_<bucket>``: one request's (non-shared) prompt suffix, padded
   up to the bucket length, forwarded through the paged carries in a
   single [1, bucket] call — writes its cache rows (K/V, or latent rows)
-  into the request's pages and samples the first token from the last
-  REAL prompt position's logits.
+  into the request's pages, samples the first token from the last
+  REAL prompt position's logits, and puts it at the request's lane of
+  the ids vector it was handed (below).
 - ``decode``: one token for EVERY slot in a single [slots, 1] call —
   the iteration-level batch.  Idle slots ride along pointed at the
-  trash page with temperature 0; their lanes are pure garbage-in/
-  garbage-out and the scheduler ignores their outputs.
+  trash page with temperature 0 (and whatever id their last request
+  left); their lanes are pure garbage-in/garbage-out and the scheduler
+  ignores their outputs.
 - ``read_page`` / ``write_page``: one page's slice out of / into every
   pool — the prefix cache's host-tier transport.
+
+THE IDS VECTOR ``[slots]`` lives on the device: a decode step's sampled
+ids are the next step's ``tokens`` as they are, and a prefill writes its
+first token into the vector at its lane, so the engine dispatches the
+next program without having seen a value (``generation/engine.py``).
 
 A POOL is whatever page-major arrays a layer's ``init_paged_cache``
 returns: ``pk``/``pv`` [P, Hkv, page, D] for ``SelfAttentionLayer``
@@ -196,6 +203,12 @@ def _with_counts(tokens, counts):
     return tokens, sum(counts[1:], counts[0])
 
 
+def sampled_ids(sampled):
+    """The ids out of what a compute program sampled (``_with_counts``),
+    on the device or harvested."""
+    return sampled[0] if isinstance(sampled, tuple) else sampled
+
+
 class GenerationPrograms:
     """The jitted program set for ONE model version (the engine builds a
     fresh set per deploy and AOT-warms it before the version serves)."""
@@ -274,6 +287,11 @@ class GenerationPrograms:
         return snapshot
 
     # ---------------------------------------------------------------- build
+    def fresh_ids(self):
+        """The ids vector [slots] before any program has written it, on
+        the device like every one after it."""
+        return jax.device_put(np.zeros(self.slots, np.int32))
+
     def fresh_pools(self):
         return seed_paged_pools(self.net, self.num_pages, self.page_size,
                                 self.net.conf.compute_dtype,
@@ -299,7 +317,8 @@ class GenerationPrograms:
 
         def decode_step(params, net_state, pools, block, pos, tokens,
                         keys, token_idx, temps, top_ks, top_ps):
-            """One token for every slot: [S] in, [S] out."""
+            """One token for every slot: [S] in, [S] out.  ``tokens`` is
+            what the last step or prefill left in each lane."""
             x = encode(tokens[:, None])
             # real rows: an idle slot's table points at the trash page
             with counting(lambda: block[:, :1] != TRASH_PAGE) as counts:
@@ -319,13 +338,15 @@ class GenerationPrograms:
         maxp = self.pages_per_slot if self.ring else None
 
         def prefill(params, net_state, pools, block, start, last_idx,
-                    tokens, keys, token_idx, temps, top_ks, top_ps):
+                    tokens, keys, token_idx, temps, top_ks, top_ps, ids,
+                    slot):
             """One request's prompt suffix ([1, bucket]) + first sample.
             ``start`` [1] is the suffix's global start position (0, or
             the shared-prefix length); ``last_idx`` () indexes the last
             REAL token inside the bucket — bucket padding beyond it
             writes scratch rows that the causal mask hides until decode
-            overwrites it position by position."""
+            overwrites it position by position.  ``ids`` [S] comes back
+            with the sample at lane ``slot``: the decode step's tokens."""
             x = encode(tokens)
             # real rows: the prompt's own tokens, not the bucket's padding
             with counting(lambda: jnp.arange(bucket)[None] <= last_idx
@@ -337,8 +358,9 @@ class GenerationPrograms:
                 logits = jnp.take(pre[0], last_idx, axis=0)[None]
                 tok = sample_tokens(logits.astype(jnp.float32), keys,
                                     token_idx, temps, top_ks, top_ps)
-            return _strip(nc, pools), _with_counts(tok.astype(jnp.int32),
-                                                   counts)
+            tok = tok.astype(jnp.int32)
+            return (_strip(nc, pools), _with_counts(tok, counts),
+                    ids.at[slot].set(tok[0]))
 
         return prefill
 
@@ -376,13 +398,15 @@ class GenerationPrograms:
 
     def prefill(self, bucket, params, net_state, pools, block, start,
                 last_idx, tokens, keys, token_idx, temps, top_ks, top_ps,
-                expected: bool = False):
+                ids, slot, expected: bool = False):
+        """``(pools, sampled, ids)``: ``sampled`` the first token [1] (or
+        ``(token, counts)``), ``ids`` with it at lane ``slot``."""
         if self.detector is not None:
-            self.detector.check((f"prefill_{bucket}", tokens, start), {},
-                                expected=expected)
+            self.detector.check((f"prefill_{bucket}", tokens, start, ids),
+                                {}, expected=expected)
         return self._prefill[bucket](
             params, net_state, pools, block, start, last_idx, tokens,
-            keys, token_idx, temps, top_ks, top_ps)
+            keys, token_idx, temps, top_ks, top_ps, ids, slot)
 
     def read_page(self, pools, page: int, expected: bool = False):
         """Device → host: one page of every pool as a numpy payload."""
@@ -408,18 +432,19 @@ class GenerationPrograms:
     def _compute_programs(self) -> Dict[str, Tuple]:
         """``{name: (jitted, args after the pools)}`` for the programs that
         run the model — each ``prefill_<bucket>`` and ``decode`` — with the
-        exact arguments they are warmed, and therefore served, with."""
+        exact arguments they are warmed, and therefore served, with: NumPy
+        mirrors, and the ids vector a device array."""
         s, maxp = self.slots, self.pages_per_slot + self.ring
-        z = np.zeros
+        z, ids = np.zeros, self.fresh_ids()
         progs = {
             f"prefill_{b}": (self._prefill[b], (
                 z((1, maxp), np.int32), z((1,), np.int32), np.int32(0),
                 z((1, b), np.int32), z((1, 2), np.uint32), z((1,), np.int32),
                 z((1,), np.float32), z((1,), np.int32),
-                np.ones((1,), np.float32)))
+                np.ones((1,), np.float32), ids, np.int32(0)))
             for b in self.prefill_buckets}
         progs["decode"] = (self._decode, (
-            z((s, maxp), np.int32), z((s,), np.int32), z((s,), np.int32),
+            z((s, maxp), np.int32), z((s,), np.int32), ids,
             z((s, 2), np.uint32), z((s,), np.int32), z((s,), np.float32),
             z((s,), np.int32), np.ones((s,), np.float32)))
         return progs
@@ -499,11 +524,19 @@ class GenerationPrograms:
             for name, (jitted, tail) in progs.items():
                 coll.analyze_program(jitted, f"generation.{name}",
                                      (params, net_state, pools) + tail)
+        # the ids vector goes from program to program as it does served:
+        # fresh into the first, then each program's own output
+        ids = self.fresh_ids()
         for b in self.prefill_buckets:
-            pools, _ = self.prefill(b, params, net_state, pools,
-                                    *progs[f"prefill_{b}"][1], expected=True)
-        pools, tok = self.decode(params, net_state, pools,
-                                 *progs["decode"][1], expected=True)
+            tail = progs[f"prefill_{b}"][1]
+            pools, _, ids = self.prefill(b, params, net_state, pools,
+                                         *tail[:-2], ids, tail[-1],
+                                         expected=True)
+        block, pos, _, *policy = progs["decode"][1]
+        for _ in range(2):      # after a prefill, then after a decode step
+            pools, tok = self.decode(params, net_state, pools, block, pos,
+                                     ids, *policy, expected=True)
+            ids = sampled_ids(tok)
         payload = self.read_page(pools, 1, expected=True)
         pools = self.write_page(pools, 1, payload, expected=True)
         jax.block_until_ready(tok)

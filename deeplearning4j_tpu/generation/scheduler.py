@@ -16,6 +16,17 @@ pages.  The bounded pending queue sheds with the serving-stack errors
 (429 ``QueueFullError`` / 503 ``ShuttingDownError`` / 504
 ``DeadlineExceededError``) instead of ever hanging a caller.
 
+The engine runs its loop ONE STEP AHEAD (``generation/engine.py``): a
+step is dispatched from ids still on the device while the previous one's
+are on their way to the host.  So what a slot needs is split by when it
+is known.  ``install`` and ``advance`` move the mirrors at DISPATCH (the
+position, the token index, the generated count; a row whose last token by
+``max_new_tokens`` was just dispatched leaves the next step there and
+then).  ``first_token`` and ``harvest_step`` deliver at HARVEST, where
+the token's value is known: a stop token, a cancel or a passed deadline
+is found one step late, and the row's part of the step then in flight is
+dropped when that step is harvested.
+
 Threading: ``submit``/``cancel`` run on client threads and only touch
 the pending deque + per-request flags (lock-guarded); everything else
 (slots, block tables, the page allocator) is owned by the engine's
@@ -28,7 +39,7 @@ import queue
 import threading
 import time
 from collections import deque
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -163,12 +174,17 @@ class GenerationRequest:
 class _Slot:
     """Decode-thread-side state of one running request."""
 
-    __slots__ = ("req", "pos", "generated")
+    __slots__ = ("req", "generated", "running")
 
-    def __init__(self, req: GenerationRequest, pos: int):
+    def __init__(self, req: GenerationRequest):
         self.req = req
-        self.pos = pos            # stream position the NEXT write lands at
-        self.generated = 1        # prefill already sampled token 0
+        # tokens sampled by the programs DISPATCHED so far (the prefill
+        # samples token 0); the delivered ones are req.tokens
+        self.generated = 1
+        # in the next decode step: False once the program that samples its
+        # last token by length is dispatched (the slot is held until that
+        # token is harvested and delivered)
+        self.running = req.max_new_tokens > 1
 
 
 class DecodeScheduler:
@@ -196,10 +212,10 @@ class DecodeScheduler:
         self._stopping = False
         self.slots: List[Optional[_Slot]] = [None] * self.num_slots
         # the decode step's host-side mirror arrays, updated in place on
-        # admit/retire and handed to the jitted step every iteration
+        # admit/dispatch/retire; a dispatch takes copies (step_inputs).
+        # The last sampled ids have no mirror: they stay on the device
         self.block = np.zeros((self.num_slots, cache.table_width), np.int32)
         self.pos = np.zeros(self.num_slots, np.int32)
-        self.last_tok = np.zeros(self.num_slots, np.int32)
         self.keys = np.zeros((self.num_slots, 2), np.uint32)
         self.tok_idx = np.zeros(self.num_slots, np.int32)
         self.temps = np.zeros(self.num_slots, np.float32)
@@ -372,55 +388,90 @@ class DecodeScheduler:
         if self.metrics is not None:
             self.metrics.evictions.inc(reason="error")
 
-    def install(self, req: GenerationRequest, first_token: int,
-                base_key: np.ndarray) -> None:
-        """Bind an admitted+prefilled request to its slot: mirror arrays
-        pick it up from the next decode step on."""
+    def install(self, req: GenerationRequest, base_key: np.ndarray) -> None:
+        """Bind an admitted request to its slot once its prefill is
+        DISPATCHED: the mirror arrays carry it from the next decode step
+        on.  Its first token is still on the device (``first_token``
+        delivers it); a request of one token never runs a decode step, so
+        its lane stays parked."""
         i = req.slot
-        self.slots[i] = _Slot(req, pos=len(req.prompt))
+        slot = self.slots[i] = _Slot(req)
+        if not slot.running:
+            return
         self.block[i] = self.cache.block_row(req.pages)
-        self.pos[i] = len(req.prompt)
-        self.last_tok[i] = int(first_token)
+        self.pos[i] = len(req.prompt)    # where the NEXT write lands
         self.keys[i] = base_key
         self.tok_idx[i] = 1
         self.temps[i] = req.temperature
         self.top_ks[i] = req.top_k
         self.top_ps[i] = req.top_p
-        req._deliver(first_token)
-        self._maybe_finish(i, int(first_token))
 
-    def after_step(self, sampled: np.ndarray) -> int:
-        """Deliver one decode step's tokens and advance/retire slots;
-        returns the number of tokens delivered."""
+    def running_rows(self) -> "List[Tuple[int, _Slot]]":
+        """``(lane, slot)`` of the rows the next decode step serves."""
+        return [(i, s) for i, s in enumerate(self.slots)
+                if s is not None and s.running]
+
+    def step_inputs(self) -> Tuple[np.ndarray, ...]:
+        """The mirrors as one dispatch takes them, in the decode program's
+        order around the ids: ``(block, pos), (keys, tok_idx, temps,
+        top_ks, top_ps)``.  Copies: the mirrors move on while the step is
+        in flight, and a backend may read a NumPy argument in place (the
+        CPU's does, without a copy)."""
+        return ((self.block.copy(), self.pos.copy()),
+                (self.keys.copy(), self.tok_idx.copy(), self.temps.copy(),
+                 self.top_ks.copy(), self.top_ps.copy()))
+
+    def advance(self, rows: "List[Tuple[int, _Slot]]") -> None:
+        """A decode step over ``rows`` was dispatched: move what the next
+        dispatch needs and the host already knows.  A row whose last token
+        by length this step samples is not in the next one."""
+        for i, slot in rows:
+            slot.generated += 1
+            if slot.generated >= slot.req.max_new_tokens:
+                slot.running = False
+                self._park(i)
+            else:
+                self.pos[i] += 1
+                self.tok_idx[i] += 1
+
+    def first_token(self, req: GenerationRequest, tok: int) -> None:
+        """Deliver the token a request's prefill sampled."""
+        req._deliver(tok)
+        self._finish_if_ended(req.slot, tok)
+
+    def harvest_step(self, rows: "List[Tuple[int, _Slot]]",
+                     sampled: np.ndarray) -> int:
+        """Deliver one decode step's tokens to the rows it was dispatched
+        with and retire what ended; returns the number delivered.  A row
+        that an earlier harvest ended (stop token, cancel, deadline) while
+        this step was already in flight has run one step too many: its id
+        is dropped.  Its K/V row went to a page the request still held
+        when the step was dispatched, or to one whose next owner's
+        programs run after this step."""
         delivered = 0
         now = time.monotonic()
-        for i in self.active_slots():
-            slot = self.slots[i]
+        for i, slot in rows:
             req = slot.req
+            if self.slots[i] is not slot:
+                if self.metrics is not None:
+                    self.metrics.discarded_rows.inc(reason=req.finish_reason)
+                continue
             tok = int(sampled[i])
-            slot.pos += 1
-            self.pos[i] = slot.pos
-            self.last_tok[i] = tok
-            self.tok_idx[i] += 1
-            slot.generated += 1
             req._deliver(tok)
             if self.metrics is not None and req.itl_s:
                 self.metrics.inter_token.observe(req.itl_s[-1])
             delivered += 1
-            if not self._maybe_finish(i, tok) and (
+            if not self._finish_if_ended(i, tok) and (
                     req.cancelled or now > req.deadline):
                 self._evict(i, "cancelled" if req.cancelled else "deadline")
         return delivered
 
-    def _maybe_finish(self, i: int, tok: int) -> bool:
-        slot = self.slots[i] if self.slots[i] is not None else None
-        if slot is None:   # install() path before the slot exists
-            return False
-        req = slot.req
+    def _finish_if_ended(self, i: int, tok: int) -> bool:
+        req = self.slots[i].req
         if req.stop_token is not None and tok == req.stop_token:
             self._retire(i, "stop")
             return True
-        if slot.generated >= req.max_new_tokens:
+        if len(req.tokens) >= req.max_new_tokens:
             self._retire(i, "length")
             return True
         return False
@@ -457,13 +508,19 @@ class DecodeScheduler:
             self.metrics.evictions.inc(reason=reason)
 
     def _release(self, i: int) -> None:
+        """Free the slot and its pages NOW, whatever is in flight: the
+        device runs programs in the order they were dispatched, so a
+        program that reuses these pages runs after the last that read or
+        wrote them."""
         slot = self.slots[i]
         self.cache.free(slot.req.pages)
         self.slots[i] = None
-        # park the lane on the trash page with greedy sampling
+        self._park(i)
+
+    def _park(self, i: int) -> None:
+        """Point the lane at the trash page with greedy sampling."""
         self.block[i] = self.cache.block_row([])
         self.pos[i] = 0
-        self.last_tok[i] = 0
         self.keys[i] = 0
         self.tok_idx[i] = 0
         self.temps[i] = 0.0

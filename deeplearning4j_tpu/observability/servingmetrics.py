@@ -121,7 +121,21 @@ class GenerationMetrics:
             labels=("model",))
         self.steps = reg.counter(
             "dl4j_decode_steps_total",
-            "Decode-step dispatches (one per running-batch iteration)")
+            "Decode steps harvested (one per running-batch iteration)")
+        self.decode_dispatch = reg.counter(
+            "dl4j_decode_dispatch_total",
+            "Decode-step dispatches by how far ahead of the host the loop "
+            "ran: ahead = dispatched from ids on the device while the "
+            "previous step's were not yet harvested (the steady state), "
+            "sync = nothing was in flight (the first step after an idle "
+            "loop or after an error)", labels=("mode",))
+        self.discarded_rows = reg.counter(
+            "dl4j_decode_discarded_rows_total",
+            "Rows of a decode step whose id was dropped at its harvest: "
+            "the request had ended at the harvest before (stop = its stop "
+            "token, cancelled, deadline) while the step was in flight, so "
+            "it ran one step more than it was served",
+            labels=("reason",))
         self.prefix_pages = reg.counter(
             "dl4j_decode_prefix_pages_total",
             "Prompt pages at admission by outcome: shared counts pages an "

@@ -463,13 +463,17 @@ def test_a_model_whose_ring_the_manager_was_not_built_for_is_refused():
 # these programs replaces the hashes with its own parent's.  StarCoder2's
 # differ from the parent's in ONE thing, how a chunk's K/V reach the pool
 # (``write_token_rows``, the one write since this PR; the parent's was the
-# 4-D scatter): with the parent's write put back, the text is the parent's
+# 4-D scatter): with the parent's write put back, the text is the parent's.
+# PR 34 (the loop one step ahead): ``decode`` is still e499f6f's text, hash
+# for hash; a ``prefill_<bucket>`` takes the ids vector and a lane and
+# returns the vector with its sample there (one dynamic-update-slice more),
+# so its hashes are that PR's own
 PARENT_PROGRAMS = {
-    "starcoder2": {"prefill_16": "271f3bc54a31410b",
-                   "prefill_32": "0ba5397a5a5cd47c",
+    "starcoder2": {"prefill_16": "487447505f7cdceb",
+                   "prefill_32": "b7803529ed6ac46d",
                    "decode": "0def4fd672d19a09"},
-    "kimi": {"prefill_16": "7ae4954b629f0761",
-             "prefill_32": "abcf2493ca5c9865",
+    "kimi": {"prefill_16": "6fdc1d06e6124d0d",
+             "prefill_32": "5b986d6eeb6cebf8",
              "decode": "9efb82807bff073f"},
 }
 

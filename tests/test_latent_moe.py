@@ -14,6 +14,7 @@ from deeplearning4j_tpu.generation.engine import GenerationEngine
 from deeplearning4j_tpu.generation.programs import GenerationPrograms
 from deeplearning4j_tpu.nn.layers import GatedMLP, RMSNorm
 from deeplearning4j_tpu.nn.layers.moe import counting
+from deeplearning4j_tpu.observability.metrics import MetricsRegistry
 
 # original_max_position_embeddings 16: the sequences below run past it, so
 # YaRN's blended frequencies and its softmax temperature are in every test
@@ -231,6 +232,9 @@ def test_new_layers_round_trip_through_the_config_json():
 
 # ---------------------------------------- (d), (f) through the engine
 def run_engine(net, requests, **kw):
+    # a registry of its own: the counters below are read as totals, and the
+    # process-wide one holds what other files' engines counted before
+    kw.setdefault("registry", MetricsRegistry())
     eng = GenerationEngine(net, slots=4, page_size=8, max_context=48,
                            prefill_buckets=(16, 32), **kw).start()
     try:
