@@ -663,12 +663,16 @@ class GenerationEngine:
         _with_counts``), fetched in the one ``device_get``: the counts are
         summed on the host under the child phase ``<stage>.moe_counters``
         and reach the registry every ``MOE_FLUSH_EVERY`` harvests, when
-        the loop runs out of work and when it exits.  Any other net's
-        harvest passes through untouched."""
+        the loop runs out of work and when it exits.  A net with
+        hyper-connection blocks returns a third entry, what its step left
+        ``H_res`` short of doubly stochastic: ``dl4j_mhc_row_sum_error``.
+        Any other net's harvest passes through untouched."""
         if not isinstance(harvested, tuple):
             return harvested
-        ids, counts = harvested
+        ids, counts, *gauge = harvested
         with self.phases.phase("moe_counters", stage=stage, child=True):
+            if gauge:
+                self.metrics.mhc_row_sum_error.set(float(gauge[0]))
             self._moe_pending = (counts if self._moe_pending is None
                                  else self._moe_pending + counts)
             self._moe_harvests += 1

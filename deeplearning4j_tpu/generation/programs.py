@@ -61,6 +61,7 @@ from deeplearning4j_tpu.models.common import cast_to_compute
 from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
+from deeplearning4j_tpu.nn.layers.composite import gauging
 from deeplearning4j_tpu.nn.layers.moe import counting
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
@@ -193,14 +194,20 @@ def _strip(carries, pools):
     return map_pools(lambda c, new: {k: new[k] for k in c}, pools, carries)
 
 
-def _with_counts(tokens, counts):
+def _with_counts(tokens, counts, gauges=()):
     """What a compute program returns beside the pools: the sampled ids,
     and for a net with expert layers ``(ids, counts)``, the layers'
     ``nn.layers.moe.counting`` vectors summed (one more small array in the
-    same fetch)."""
-    if not counts:
+    same fetch).  A net with hyper-connection blocks returns ``(ids, counts,
+    gauge)``: the largest of the blocks' ``nn.layers.composite.gauging``
+    scalars (``counts`` one zero where it has no expert layer)."""
+    if not counts and not gauges:
         return tokens
-    return tokens, sum(counts[1:], counts[0])
+    total = (sum(counts[1:], counts[0]) if counts
+             else jnp.zeros((1,), jnp.int32))
+    if not gauges:
+        return tokens, total
+    return tokens, total, jnp.max(jnp.stack(gauges))
 
 
 def sampled_ids(sampled):
@@ -320,8 +327,11 @@ class GenerationPrograms:
             """One token for every slot: [S] in, [S] out.  ``tokens`` is
             what the last step or prefill left in each lane."""
             x = encode(tokens[:, None])
-            # real rows: an idle slot's table points at the trash page
-            with counting(lambda: block[:, :1] != TRASH_PAGE) as counts:
+
+            def real():      # an idle slot's table points at the trash page
+                return block[:, :1] != TRASH_PAGE
+
+            with counting(real) as counts, gauging(real) as gauges:
                 pre, nc = fwd(params, net_state, x,
                               _attach(pools, block, pos, maxp))
             with jax.named_scope("sample"):
@@ -329,7 +339,7 @@ class GenerationPrograms:
                 nxt = sample_tokens(logits, keys, token_idx, temps, top_ks,
                                     top_ps)
             return _strip(nc, pools), _with_counts(nxt.astype(jnp.int32),
-                                                   counts)
+                                                   counts, gauges)
 
         return decode_step
 
@@ -348,9 +358,11 @@ class GenerationPrograms:
             overwrites it position by position.  ``ids`` [S] comes back
             with the sample at lane ``slot``: the decode step's tokens."""
             x = encode(tokens)
-            # real rows: the prompt's own tokens, not the bucket's padding
-            with counting(lambda: jnp.arange(bucket)[None] <= last_idx
-                          ) as counts:
+
+            def real():      # the prompt's own tokens, not the padding
+                return jnp.arange(bucket)[None] <= last_idx
+
+            with counting(real) as counts, gauging(real) as gauges:
                 pre, nc = fwd(params, net_state, x,
                               _attach(pools, block, start, maxp,
                                       (last_idx + 1)[None]))
@@ -359,7 +371,7 @@ class GenerationPrograms:
                 tok = sample_tokens(logits.astype(jnp.float32), keys,
                                     token_idx, temps, top_ks, top_ps)
             tok = tok.astype(jnp.int32)
-            return (_strip(nc, pools), _with_counts(tok, counts),
+            return (_strip(nc, pools), _with_counts(tok, counts, gauges),
                     ids.at[slot].set(tok[0]))
 
         return prefill

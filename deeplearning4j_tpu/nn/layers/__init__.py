@@ -20,7 +20,12 @@ from deeplearning4j_tpu.nn.layers.normalization import (
 )
 from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
 from deeplearning4j_tpu.nn.layers.latent_attention import LatentAttentionLayer
-from deeplearning4j_tpu.nn.layers.composite import ResidualBlock
+from deeplearning4j_tpu.nn.layers.composite import (
+    HyperConnectionBlock,
+    HyperStreamExpand,
+    HyperStreamReduce,
+    ResidualBlock,
+)
 from deeplearning4j_tpu.nn.layers.recurrent import (
     GravesLSTM,
     GravesBidirectionalLSTM,

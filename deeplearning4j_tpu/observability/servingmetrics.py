@@ -214,6 +214,15 @@ class GenerationMetrics:
             "ones, summed over expert layers; over "
             "dl4j_moe_tokens_total: top_k * held / n_experts when routing "
             "is uniform", labels=("expert",))
+        self.mhc_row_sum_error = reg.gauge(
+            "dl4j_mhc_row_sum_error",
+            "Largest distance from 1 of a row sum or a column sum of H_res "
+            "(the Sinkhorn-normalised mixing matrix of a hyper-connection "
+            "block) over the real rows and the blocks of the last harvested "
+            "decode step or prefill; the loop's last division is over rows, "
+            "so the columns carry what it left undone: ~1e-3 after 20 "
+            "iterations, tenths after one", labels=("engine",)
+        ).labels(engine=self.engine_id)
         # per-instance children
         self.active_slots = reg.gauge(
             "dl4j_decode_active_slots",

@@ -30,6 +30,12 @@ _KWARGS = {
     "GlobalPoolingLayer": dict(),
     "GravesBidirectionalLSTM": dict(n_in=3, n_out=4),
     "GravesLSTM": dict(n_in=3, n_out=4),
+    "HyperConnectionBlock": dict(
+        n_in=8, streams=2,
+        layers=(base._LAYER_REGISTRY["RMSNorm"](n_in=4),
+                base._LAYER_REGISTRY["GatedMLP"](n_in=4, n_out=4, hidden=6))),
+    "HyperStreamExpand": dict(n_in=4, streams=2),
+    "HyperStreamReduce": dict(n_in=8, streams=2),
     "LSTM": dict(n_in=3, n_out=4),
     "LatentAttentionLayer": dict(n_in=8, n_out=8, n_heads=2, q_rank=6,
                                  kv_rank=4, nope_dim=4, rope_dim=2, v_dim=4),
@@ -60,6 +66,9 @@ _INPUTS = {
     "GlobalPoolingLayer": (2, 4, 4, 3),
     "GravesBidirectionalLSTM": (2, 5, 3),
     "GravesLSTM": (2, 5, 3),
+    "HyperConnectionBlock": (2, 5, 8),
+    "HyperStreamExpand": (2, 5, 4),
+    "HyperStreamReduce": (2, 5, 8),
     "LSTM": (2, 5, 3),
     "LatentAttentionLayer": (2, 5, 8),
     "LayerNorm": (2, 5),
