@@ -592,6 +592,8 @@ class GenerationEngine:
                 _copy_to_host_async(tok)
         with phase("page_gather", stage="admit"):
             self.scheduler.install(req, base_key)
+        for path in progs.expert_paths.get(bucket, ()):
+            self.metrics.moe_expert_steps.inc(stage="prefill", path=path)
         self._firsts.append((req, tok, mv.name,
                              SAMPLING_PATHS[sampling_path(*policy)]))
 
@@ -633,6 +635,8 @@ class GenerationEngine:
                 s.advance(rows)
         self.metrics.decode_dispatch.inc(
             mode="sync" if self._in_flight is None else "ahead")
+        for path in progs.expert_paths.get("decode", ()):
+            self.metrics.moe_expert_steps.inc(stage="decode", path=path)
         return _Step(sampled, rows, mv.name,
                      SAMPLING_PATHS[sampling_path(*policy[2:])])
 

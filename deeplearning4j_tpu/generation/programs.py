@@ -62,7 +62,7 @@ from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
 from deeplearning4j_tpu.nn.layers.composite import gauging
-from deeplearning4j_tpu.nn.layers.moe import counting
+from deeplearning4j_tpu.nn.layers.moe import RoutedMoELayer, counting
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
 
@@ -85,18 +85,23 @@ def named_layers_of(net) -> List[Tuple[str, object]]:
             if net.nodes[n].layer is not None]
 
 
-def _self_attention_layers(net):
-    """Every ``SelfAttentionLayer`` of ``net``, those inside composite
+def _layers_of_kind(net, kind):
+    """Every layer of class ``kind`` in ``net``, those inside composite
     layers too."""
-    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
 
     def walk(layer):
-        if isinstance(layer, SelfAttentionLayer):
+        if isinstance(layer, kind):
             yield layer
         for sub in getattr(layer, "layers", ()):
             yield from walk(sub)
 
     return [a for _, l in named_layers_of(net) for a in walk(l)]
+
+
+def _self_attention_layers(net):
+    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
+
+    return _layers_of_kind(net, SelfAttentionLayer)
 
 
 def _paged_attention_shapes(net) -> List[Tuple[int, int, int, int]]:
@@ -238,6 +243,15 @@ class GenerationPrograms:
         # pages by layer kind: the ring a slot holds of window pages, and
         # that kind's pool; 0 and 0 for a net without window layers
         self.ring = window_ring_pages(net, self.page_size)
+        # how each compute program multiplies its expert layers' held
+        # experts (``RoutedMoELayer.path`` of its rows, the rule the layer
+        # branches on when the program is traced): the paths taken, by
+        # ``"decode"`` / bucket; nothing for a net without expert layers
+        self.expert_layers = _layers_of_kind(net, RoutedMoELayer)
+        rows = {"decode": self.slots, **{b: b for b in self.prefill_buckets}}
+        self.expert_paths = {
+            name: tuple(sorted({l.path(t) for l in self.expert_layers}))
+            for name, t in rows.items()} if self.expert_layers else {}
         self.num_window_pages = window_pool_pages(self.slots, self.ring)
         # validate pageability eagerly (raises on recurrent stacks)
         seed_paged_pools(net, 2, page_size, net.conf.compute_dtype,
@@ -489,6 +503,28 @@ class GenerationPrograms:
                     f" (a ring, window {window})" if window else "",
                     ppb, tq, b, -(-t // tq), vmem / 2 ** 20)
 
+    def _log_expert_tiling(self) -> None:
+        """How ``grouped_experts`` tiles each compute program whose expert
+        layers take the ``streamed`` path, once a program and shape."""
+        from deeplearning4j_tpu.helpers import grouped_experts as ge
+
+        dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
+        shapes = sorted({(l.held[1], l.n_in, l.hidden, l.n_out)
+                         for l in self.expert_layers})
+        for name, paths in self.expert_paths.items():
+            if "streamed" not in paths:
+                continue
+            t = self.slots if name == "decode" else name
+            for count, d, hidden, n_out in shapes:
+                rows, tf, vmem = ge.expert_tiling(t, d, hidden, n_out, dtype)
+                logger.info(
+                    "generation.%s: grouped_experts tokens [%d, %d] over %d "
+                    "held experts of width %d: %d rows, hidden tiles of %d, "
+                    "grid (%d, %d), %.2f MB of VMEM",
+                    name if name == "decode" else f"prefill_{name}", t, d,
+                    count, hidden, rows, tf, count, hidden // tf,
+                    vmem / 2 ** 20)
+
     def lowered(self) -> Dict[str, "jax.stages.Lowered"]:
         """Each compute program lowered at its serving signature (abstract
         pools; nothing executes, nothing is donated) — how a caller reads
@@ -529,6 +565,7 @@ class GenerationPrograms:
              "net_state": net_state, "kv_pools": pools})
         progs = self._compute_programs()
         self._log_paged_tiling()
+        self._log_expert_tiling()
         coll = shardstats.active_collector()
         if coll is not None:
             # census at the exact warmup signatures; lower-only, so the

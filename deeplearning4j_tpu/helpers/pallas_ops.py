@@ -345,6 +345,11 @@ def register_default_helpers() -> None:
         from deeplearning4j_tpu.helpers.paged_attention import PagedAttentionHelper
 
         _helpers.register_helper("paged_attention", PagedAttentionHelper())
+    if "grouped_experts" not in _helpers._registry:
+        from deeplearning4j_tpu.helpers.grouped_experts import (
+            GroupedExpertsHelper)
+
+        _helpers.register_helper("grouped_experts", GroupedExpertsHelper())
     if "epilogue" not in _helpers._registry:
         from deeplearning4j_tpu.helpers.fused_epilogue import FusedEpilogueHelper
 

@@ -25,11 +25,14 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from deeplearning4j_tpu import helpers
 from deeplearning4j_tpu.nn import activations, initializers
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
@@ -147,6 +150,42 @@ def counting(valid):
         _counting.reset(token)
 
 
+# ---------------------------------------------------------------------------
+# which way the held experts are multiplied (RoutedMoELayer._held_experts)
+# ---------------------------------------------------------------------------
+
+EXPERT_PATHS = ("streamed", "ragged")
+
+# Rows up to which a call streams its experts (helpers/grouped_experts.py).
+# The kernel multiplies EVERY row by every touched expert: 6 * d * hidden
+# operations a row against 6 * d * hidden bytes of bf16 weights an expert,
+# whatever the widths, so its arithmetic stays under the bytes while
+# rows / peak FLOP/s < 1 / peak B/s: 240 rows at a v5e's peaks.  Measured
+# alone on the chip (PERF.md PR 36) an expert layer at Xing's widths costs
+# 1.86 / 1.90 / 2.03 ms at 64 / 128 / 256 rows (1.72 ms of bytes) and 3.02 /
+# 4.17 at 384 / 512, where the arithmetic has taken over; at 256 rows it
+# is ahead of the sorted groups at all three served widths (2.03 against
+# 4.74 ms, 1.66 against 2.38, 0.86 against 1.42).  Past the bound the
+# sorted groups' arithmetic (top_k * held / n_experts of the dense form's)
+# is the smaller cost to grow.
+STREAMED_ROWS = 256
+
+
+def expert_path(rows: int, train: bool = False, kernel: bool = True) -> str:
+    """Which of ``EXPERT_PATHS`` a call of ``rows`` rows (static: the
+    flattened leading axes of the layer's input) takes: ``"streamed"`` —
+    one fused kernel that reads each touched held expert's weights once,
+    dense over the rows — when the rows are few, no gradient is wanted
+    (``train``: a ``fit`` step keeps the sorted groups, whose backward it
+    needs) and the kernel is there (``kernel``: the helper seam offers it
+    and takes the widths); else ``"ragged"``, rows sorted by expert through
+    ``jax.lax.ragged_dot`` in blocks.  Pure: the layer calls it while it is
+    traced, the engine on the host to count
+    ``dl4j_moe_expert_steps_total``."""
+    streams = kernel and not train and rows <= STREAMED_ROWS
+    return "streamed" if streams else "ragged"
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class RoutedMoELayer(Layer):
@@ -166,9 +205,12 @@ class RoutedMoELayer(Layer):
 
     ``experts_held = (first, count)`` names the experts whose weights this
     layer has (``W_gate/W_up/W_down`` are ``[count, ...]``); None holds
-    all.  Tokens are sorted by held expert and multiplied group by group
-    (``jax.lax.ragged_dot``); assignments to experts held elsewhere sort
-    last and add nothing."""
+    all.  One algorithm under two schedules (``expert_path`` picks by the
+    call's row count): many rows are sorted by held expert and multiplied
+    group by group (``jax.lax.ragged_dot``); a few rows — a decode step —
+    are all multiplied by every expert one of them chose, whose weights one
+    kernel streams once (``helpers/grouped_experts.py``).  Assignments to
+    experts held elsewhere add nothing either way."""
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None
@@ -254,11 +296,27 @@ class RoutedMoELayer(Layer):
                 w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
             return ids.astype(jnp.int32), w * self.routed_scaling_factor
 
-    def _held_experts(self, params, tokens, ids, w):
-        """The held experts' part of the result, [T, n_out] float32.
+    def path(self, rows: int, train: bool = False) -> str:
+        """``expert_path`` of a call of ``rows`` rows on this layer as the
+        process stands: with the kernel only if the helper seam offers it
+        (not under ``helpers.auto_partitioned``, not with helpers
+        disabled) for these widths."""
+        kernel = helpers.get_helper("grouped_experts")
+        return expert_path(rows, train, kernel is not None and kernel.supports(
+            self.n_in, self.hidden, self.n_out))
 
-        Assignments are sorted by held expert (those held elsewhere last)
-        and multiplied in blocks of ``rows`` sorted rows, as many blocks as
+    def _held_experts(self, params, tokens, ids, w, train=False):
+        """The held experts' part of the result, [T, n_out] float32, by
+        the path ``expert_path`` names."""
+        args = (params["W_gate"], params["W_up"], params["W_down"], tokens,
+                ids, w)
+        if self.path(tokens.shape[0], train) == "streamed":
+            return _held_streamed(self, *args)
+        return self._held_ragged(*args)
+
+    def _held_ragged(self, w_gate, w_up, w_down, tokens, ids, w):
+        """The ``ragged`` path.  Assignments are sorted by held expert
+        (those held elsewhere last) and multiplied in blocks of ``rows`` sorted rows, as many blocks as
         the held assignments fill: a block is four times the share
         ``count / n_experts`` of all assignments, so uniform routing takes
         one block and a skewed batch takes more (dropless either way), and
@@ -287,9 +345,9 @@ class RoutedMoELayer(Layer):
             sizes = jnp.diff(cut, prepend=0)   # each group's rows in here
             tok = at // k
             x = tokens[tok]
-            hid = (act(jax.lax.ragged_dot(x, params["W_gate"], sizes))
-                   * jax.lax.ragged_dot(x, params["W_up"], sizes))
-            y = jax.lax.ragged_dot(hid, params["W_down"], sizes,
+            hid = (act(jax.lax.ragged_dot(x, w_gate, sizes))
+                   * jax.lax.ragged_dot(x, w_up, sizes))
+            y = jax.lax.ragged_dot(hid, w_down, sizes,
                                    preferred_element_type=jnp.float32)
             # rows past the held ones are no group's: whatever they hold
             y = jnp.where((lo + jnp.arange(rows) < n_mine)[:, None],
@@ -319,7 +377,7 @@ class RoutedMoELayer(Layer):
         ids, w = self.route(params, tokens)
         self._count(ids)
         with jax.named_scope("moe_experts"):
-            y = self._held_experts(params, tokens, ids, w)
+            y = self._held_experts(params, tokens, ids, w, train)
         if self.shared:
             with jax.named_scope("moe_shared_expert"):
                 y = y + gated_mlp(tokens, params["Ws_gate"],
@@ -333,3 +391,29 @@ class RoutedMoELayer(Layer):
         if d.get("experts_held") is not None:
             d["experts_held"] = tuple(d["experts_held"])
         return super().from_dict(d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_streamed(layer: RoutedMoELayer, w_gate, w_up, w_down, tokens, ids,
+                   w):
+    """The ``streamed`` path: ``layer``'s held experts through the helper's
+    kernel.  Differentiated (``jax.grad`` through an inference call), its
+    backward is the ``ragged`` path's: the two compute one function."""
+    return helpers.get_helper("grouped_experts").apply(
+        tokens, w_gate, w_up, w_down, ids, w, layer.held, layer.activation)
+
+
+def _held_streamed_fwd(layer, *args):
+    return _held_streamed(layer, *args), args
+
+
+def _held_streamed_bwd(layer, args, g):
+    w_gate, w_up, w_down, tokens, ids, w = args
+    _, vjp = jax.vjp(
+        lambda wg, wu, wd, x, ww: layer._held_ragged(wg, wu, wd, x, ids, ww),
+        w_gate, w_up, w_down, tokens, w)
+    *floats, dw = vjp(g)
+    return (*floats, np.zeros(ids.shape, jax.dtypes.float0), dw)
+
+
+_held_streamed.defvjp(_held_streamed_fwd, _held_streamed_bwd)

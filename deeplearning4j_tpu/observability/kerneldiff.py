@@ -1,7 +1,8 @@
 """Kernel-trust differential harness.
 
 The repo's fused kernels (Pallas flash attention, the fused LRN/BN
-passes, the paged-attention decode path) were validated by their unit
+passes, the paged-attention decode path, the streamed experts of a decode
+step) were validated by their unit
 tests — which is trust by sampling.  This module is trust by SWEEP: run
 every fused kernel against an independent float64 numpy reference over
 a shape × dtype × masking grid, record per-config max-abs / max-rel
@@ -168,6 +169,17 @@ def _np_dropout_residual_norm(h, res, gamma, beta, eps, mask,
          + np.asarray(beta, np.float64))
     if mask is not None:
         y = np.where(np.asarray(mask), y / keep, 0.0)
+    return y
+
+def _np_grouped_experts(x, wg, wu, wd, c) -> np.ndarray:
+    """float64 ``sum_e c[:, e] * (silu(x Wg[e]) * (x Wu[e])) Wd[e]``, one
+    expert at a time (``helpers/grouped_experts.py``)."""
+    x, c = np.asarray(x, np.float64), np.asarray(c, np.float64)
+    y = np.zeros((x.shape[0], wd.shape[2]))
+    for e in range(wg.shape[0]):
+        g = x @ np.asarray(wg[e], np.float64)
+        h = g / (1.0 + np.exp(-g)) * (x @ np.asarray(wu[e], np.float64))
+        y += c[:, e:e + 1] * (h @ np.asarray(wd[e], np.float64))
     return y
 
 def _np_lrn(x2d, k, n, alpha, beta) -> np.ndarray:
@@ -418,6 +430,50 @@ def _run_epilogue(cfg) -> Tuple[Any, np.ndarray]:
         np.asarray(mask) if mask is not None else None, keep)
     return out, ref
 
+def _grouped_experts_configs(full: bool):
+    # the three expert cells' decode ratios (rows, top-k, held of all) at toy
+    # widths: every expert held and ~4 rows on each (xing.serve-reason), a
+    # 1/16 share with ~1 row an expert (k2.serve-docqa), a 1/8 share under
+    # top-10 (laguna.serve-mixed-8k); in both an expert no row chose
+    grids = [{"shape": [8, 64, 128], "top_k": 4, "held": [0, 8], "experts": 8}]
+    if full:
+        grids += [
+            {"shape": [12, 128, 256], "top_k": 8, "held": [40, 6],
+             "experts": 96},
+            {"shape": [8, 96, 128], "top_k": 10, "held": [8, 4],
+             "experts": 32},
+            # one row; rows past a sublane tile
+            {"shape": [1, 64, 128], "top_k": 2, "held": [0, 4], "experts": 4},
+            {"shape": [37, 64, 128], "top_k": 2, "held": [2, 4],
+             "experts": 8},
+        ]
+    for g in grids:
+        for dtype in ("float32", "bfloat16"):
+            yield dict(g, dtype=dtype)
+
+def _run_grouped_experts(cfg) -> Tuple[Any, np.ndarray]:
+    """The interpreted Pallas kernel on ids drawn as a router draws them
+    (top-k of random scores over ALL experts, weights normalised) against
+    the f64 per-expert loop on the same rounded inputs."""
+    from deeplearning4j_tpu.helpers.grouped_experts import (
+        combine_matrix, grouped_experts)
+    t, d, hidden = cfg["shape"]
+    first, count = cfg["held"]
+    dt = jnp.dtype(cfg["dtype"])
+    x = _rng(t, d, dtype=dt, seed=40)
+    wg = _rng(count, d, hidden, dtype=jnp.float32, seed=41) * d ** -0.5
+    wu = _rng(count, d, hidden, dtype=jnp.float32, seed=42) * d ** -0.5
+    wd = _rng(count, hidden, d, dtype=jnp.float32, seed=43) * hidden ** -0.5
+    wg, wu, wd = (w.astype(dt) for w in (wg, wu, wd))
+    scores = np.random.default_rng(44).random((t, cfg["experts"]))
+    ids = np.argsort(-scores, axis=1)[:, :cfg["top_k"]]
+    w = np.take_along_axis(scores, ids, axis=1)
+    w = (w / w.sum(axis=1, keepdims=True)).astype(np.float32)
+    c, touched = combine_matrix(jnp.asarray(ids, jnp.int32), jnp.asarray(w),
+                                first, count)
+    out = grouped_experts(x, wg, wu, wd, c, touched, interpret=True)
+    return out, _np_grouped_experts(x, wg, wu, wd, c)
+
 def _pallas2d_configs(full: bool):
     shapes = [(32, 24)]
     if full:
@@ -464,6 +520,8 @@ KERNELS: Dict[str, Tuple[Callable, Callable, bool]] = {
     "paged_attention": (_paged_configs, _run_paged_attention, False),
     "fused_paged_attention": (_fused_paged_configs, _run_fused_paged, False),
     "fused_dropout_residual_norm": (_epilogue_configs, _run_epilogue, False),
+    "grouped_experts": (_grouped_experts_configs, _run_grouped_experts,
+                        False),
     "pallas_lrn": (_pallas2d_configs, _run_lrn, False),
     "pallas_bn_inference": (_pallas2d_configs, _run_bn_inference, False),
     "pallas_bn_training": (_pallas2d_configs, _run_bn_training, False),

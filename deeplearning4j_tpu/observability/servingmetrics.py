@@ -214,6 +214,16 @@ class GenerationMetrics:
             "ones, summed over expert layers; over "
             "dl4j_moe_tokens_total: top_k * held / n_experts when routing "
             "is uniform", labels=("expert",))
+        self.moe_expert_steps = reg.counter(
+            "dl4j_moe_expert_steps_total",
+            "Dispatched decode steps (stage=decode) and prefills "
+            "(stage=prefill) of a net with expert layers, by how the "
+            "program multiplies their held experts, from its row count "
+            "(nn.layers.moe.expert_path): streamed = one fused kernel "
+            "reads each touched expert's weights once, every row against "
+            "it (few rows: a decode step), ragged = rows sorted by expert "
+            "through ragged_dot in blocks (a prefill bucket; any program "
+            "where the kernel gives way)", labels=("stage", "path"))
         self.mhc_row_sum_error = reg.gauge(
             "dl4j_mhc_row_sum_error",
             "Largest distance from 1 of a row sum or a column sum of H_res "

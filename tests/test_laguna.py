@@ -23,6 +23,7 @@ from deeplearning4j_tpu.generation.programs import (
     GenerationPrograms, window_ring_pages,
 )
 from deeplearning4j_tpu.helpers import paged_attention as pa
+from deeplearning4j_tpu.helpers.grouped_experts import GroupedExpertsHelper
 from deeplearning4j_tpu.nn.layers import SelfAttentionLayer
 
 # a period of 4 with different head counts (groups of 6 and 9 over 2 kv
@@ -467,7 +468,10 @@ def test_a_model_whose_ring_the_manager_was_not_built_for_is_refused():
 # PR 34 (the loop one step ahead): ``decode`` is still e499f6f's text, hash
 # for hash; a ``prefill_<bucket>`` takes the ids vector and a lane and
 # returns the vector with its sample there (one dynamic-update-slice more),
-# so its hashes are that PR's own
+# so its hashes are that PR's own.  PR 36 (the streamed experts): kimi's
+# programs call ``grouped_experts`` wherever a call has few rows (all three
+# here); with the kernel withheld from the seam the text is still the
+# parent's, hash for hash, so the ``ragged`` path is the parent's own
 PARENT_PROGRAMS = {
     "starcoder2": {"prefill_16": "487447505f7cdceb",
                    "prefill_32": "b7803529ed6ac46d",
@@ -507,6 +511,8 @@ def test_accepted_nets_lower_to_the_programs_of_the_parent(family,
     monkeypatch.setattr(
         pa, "write_token_rows", lambda pool, page, off, rows:
         pool.at[page, :, off].set(rows.astype(pool.dtype)))
+    monkeypatch.setattr(GroupedExpertsHelper, "supports",
+                        lambda self, *widths: False)
     progs = GenerationPrograms(_accepted_toy_net(family), slots=4,
                                pages_per_slot=6, page_size=8, num_pages=25,
                                prefill_buckets=(16, 32))

@@ -259,6 +259,34 @@ def test_laguna_shapes_compile_for_v5e(v5e_chip, b, t, hq, maxp, window):
     assert "fused_paged_attention" in compiled.as_text()
 
 
+@pytest.mark.parametrize("t,d,hidden,count", [
+    (64, 3584, 1024, 64),      # xing.serve-reason's decode step
+    (48, 7168, 2048, 12),      # k2.serve-docqa's: the widest tiles
+    (32, 3072, 1024, 32),      # laguna.serve-mixed-8k's
+    (256, 7168, 2048, 12)])    # the most rows the layer streams
+def test_grouped_experts_compiles_for_v5e(v5e_chip, t, d, hidden, count):
+    """``helpers/grouped_experts.py`` at the expert cells' decode shapes
+    through the chip's compiler (it refuses what lowering cannot see: the
+    VMEM of a grid step's tiles), and the weight stacks reach the kernel as
+    they are stored: no copy the size of one."""
+    import re
+
+    from deeplearning4j_tpu.helpers.grouped_experts import grouped_experts
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    bf = jnp.bfloat16
+    fn = jax.jit(lambda *a: grouped_experts(*a, interpret=False))
+    with jax.enable_x64(False):
+        text = fn.lower(
+            sds((t, d), bf), sds((count, d, hidden), bf),
+            sds((count, d, hidden), bf), sds((count, hidden, d), bf),
+            sds((t, count), jnp.float32), sds((count,), jnp.bool_)
+        ).compile().as_text()
+    assert "grouped_experts" in text
+    copied = re.findall(r"= bf16\[([\d,]+)\][^ ]* copy\(", text)
+    assert not [c for c in copied if c.startswith(f"{count},")]
+
+
 @pytest.mark.parametrize("n", [4, 40])   # a decode step's rows, a chunk
 def test_rows_of_a_table_write_what_the_slab_scatter_writes(n):
     """``write_token_rows`` against ``pool.at[page, :, off].set``, trash
