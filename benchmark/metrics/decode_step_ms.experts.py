@@ -1,0 +1,11 @@
+"""Device milliseconds a decode execution spends in the expert layers (kind
+scope ``experts`` with ``moe_router``, ``moe_experts``,
+``moe_shared_expert``), the mean over the traced window's executions.  The
+five ``decode_step_ms.*`` add up to the mean ``XLA Modules`` duration of the
+decode program (``_layer_time``)."""
+
+from benchmark.metrics import _layer_time
+
+
+def read(ctx):
+    return _layer_time.decode_step_ms(ctx, "experts")

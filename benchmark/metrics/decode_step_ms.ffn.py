@@ -1,0 +1,10 @@
+"""Device milliseconds a decode execution spends in the dense feed-forward
+layers (kind scope ``ffn``: ``DenseLayer``, ``GatedMLP``), the mean over the
+traced window's executions.  The five ``decode_step_ms.*`` add up to the
+mean ``XLA Modules`` duration of the decode program (``_layer_time``)."""
+
+from benchmark.metrics import _layer_time
+
+
+def read(ctx):
+    return _layer_time.decode_step_ms(ctx, "ffn")

@@ -1,0 +1,12 @@
+"""Percent of the traced window the ``prefill_<bucket>`` programs spend in
+everything else: norms, the embedding, the hyper-connections, residual adds,
+the gaps between operations, and what no table or kind holds
+(``device_time_unattributed_share``), all buckets together.  The five
+``prefill_share.*`` add up to ``prefill_share_of_window``;
+``prefill_ms_by_bucket`` goes into the line's notes (``_layer_time``)."""
+
+from benchmark.metrics import _layer_time
+
+
+def read(ctx):
+    return _layer_time.prefill_share(ctx, "rest")

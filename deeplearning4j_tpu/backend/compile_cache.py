@@ -20,15 +20,24 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on and return its directory.
 
-    With ``JAX_COMPILATION_CACHE_DIR`` set nothing is configured in code —
-    JAX already honours the variable — and its value is returned.
+    With ``JAX_COMPILATION_CACHE_DIR`` set no directory is configured in
+    code — JAX already honours the variable — and its value is returned.
     Otherwise the cache lives at ``<checkout>/.jax_cache`` (git-ignored).
-    Call before the first compilation of the process."""
+    Call before the first compilation of the process.
+
+    Either way the programs' metadata (scope names, source lines) becomes
+    part of the cache's key.  JAX leaves it out by default, and a hit then
+    returns the executable with the ``op_name`` s of whatever code compiled
+    it first: ``observability.recompile.program_scopes`` and the profiler
+    would describe a program by scopes it no longer has.  The price is that
+    an edit which moves a line on a program's trace path compiles that
+    program cold once."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

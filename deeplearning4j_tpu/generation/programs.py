@@ -388,6 +388,9 @@ class GenerationPrograms:
             return (_strip(nc, pools), _with_counts(tok, counts, gauges),
                     ids.at[slot].set(tok[0]))
 
+        # the compiled module's name, so the profiler's ``XLA Modules`` line
+        # reads ``jit_prefill_512`` and tells the buckets apart
+        prefill.__name__ = prefill.__qualname__ = f"prefill_{bucket}"
         return prefill
 
     def _make_read_page(self):
@@ -528,8 +531,13 @@ class GenerationPrograms:
     def lowered(self) -> Dict[str, "jax.stages.Lowered"]:
         """Each compute program lowered at its serving signature (abstract
         pools; nothing executes, nothing is donated) — how a caller reads
-        which kernels a program was built from: ``.as_text()`` for the
-        kernel names, ``.compile().as_text()`` for the final HLO."""
+        which kernels a program was built from BEFORE it is compiled:
+        ``.as_text()`` for the kernel names.  It needs this object and its
+        net's real parameters.  For the compiled module — its instructions
+        and the scope each lies under — ask ``observability.recompile.
+        program_scopes("generation.<name>")``, which needs neither and
+        still answers after the engine has let the version go; these are the
+        two ways a program is described, and there is no third."""
         head = (self.serving_params(), self.net.net_state,
                 jax.eval_shape(self.fresh_pools))
         return {name: jitted.lower(*head, *tail)
@@ -540,6 +548,16 @@ class GenerationPrograms:
         it; the live pool is never touched) through the version's
         detector as planned compiles.  Returns the number of programs
         warmed — after this, steady-state serving compiles nothing.
+
+        Warmup is also where the compute programs learn to describe
+        themselves: ``decode`` and each ``prefill_<bucket>`` are handed to
+        ``observability.recompile.register_program`` as
+        ``generation.<name>`` with the exact arguments they are warmed with
+        (abstracted there: shapes, dtypes and shardings, no array), so that
+        ``program_scopes("generation.decode")`` can give the compiled
+        module's instructions by scope at any later time.  (``lowered()``
+        is the other, earlier view: the lowering's kernel names, from this
+        object and real parameters.)
 
         Warmup is also the memory-observability hook: the pool /
         params ledger is recorded here (metadata walk), and when a
@@ -552,6 +570,9 @@ class GenerationPrograms:
         ``StepProfiler`` cost-analysis seam pays (profiling.py), only
         ever while the opt-in collector is installed."""
         from deeplearning4j_tpu.observability import shardstats
+        from deeplearning4j_tpu.observability.recompile import (
+            register_program,
+        )
 
         params, net_state = self.serving_params(), self.net.net_state
         pools = self.fresh_pools()
@@ -564,6 +585,9 @@ class GenerationPrograms:
                  self.net.params),
              "net_state": net_state, "kv_pools": pools})
         progs = self._compute_programs()
+        for name, (jitted, tail) in progs.items():
+            register_program(f"generation.{name}", jitted,
+                             (params, net_state, pools) + tail)
         self._log_paged_tiling()
         self._log_expert_tiling()
         coll = shardstats.active_collector()

@@ -417,26 +417,29 @@ class ComputationGraph(LazyScoreMixin):
             if node.layer is not None:
                 layer = node.layer
                 lstate = net_state.get(name, {})
-                if isinstance(layer, OutputLayer) and name in out_names and stop_at_preoutput:
-                    h = layer.maybe_dropout(xs[0], train=train, rng=rngs[i])
-                    acts[name] = layer.pre_output(params[name], h)
-                elif hasattr(layer, "apply_with_carry"):
-                    carry = (carries or {}).get(name)
-                    y, lst, new_carry = layer.apply_with_carry(
-                        params[name], lstate, xs[0], carry,
-                        train=train, rng=rngs[i], mask=fmask,
-                    )
-                    new_carries[name] = new_carry
-                    acts[name] = y
-                else:
-                    from deeplearning4j_tpu.nn.layers.convolution import GlobalPoolingLayer
+                # the node's name and the layer's kind on its device
+                # operations (metadata only)
+                with jax.named_scope(name), layer.kind_scope():
+                    if isinstance(layer, OutputLayer) and name in out_names and stop_at_preoutput:
+                        h = layer.maybe_dropout(xs[0], train=train, rng=rngs[i])
+                        acts[name] = layer.pre_output(params[name], h)
+                    elif hasattr(layer, "apply_with_carry"):
+                        carry = (carries or {}).get(name)
+                        y, lst, new_carry = layer.apply_with_carry(
+                            params[name], lstate, xs[0], carry,
+                            train=train, rng=rngs[i], mask=fmask,
+                        )
+                        new_carries[name] = new_carry
+                        acts[name] = y
+                    else:
+                        from deeplearning4j_tpu.nn.layers.convolution import GlobalPoolingLayer
 
-                    kw = {"mask": fmask} if isinstance(layer, GlobalPoolingLayer) else {}
-                    y, lst = layer.apply(params[name], lstate, xs[0],
-                                         train=train, rng=rngs[i], **kw)
-                    if lst:
-                        new_state[name] = lst
-                    acts[name] = y
+                        kw = {"mask": fmask} if isinstance(layer, GlobalPoolingLayer) else {}
+                        y, lst = layer.apply(params[name], lstate, xs[0],
+                                             train=train, rng=rngs[i], **kw)
+                        if lst:
+                            new_state[name] = lst
+                        acts[name] = y
             else:
                 if isinstance(node.vertex, LastTimeStepVertex):
                     acts[name] = node.vertex.apply(xs, mask=fmask)
@@ -459,11 +462,12 @@ class ComputationGraph(LazyScoreMixin):
             layer = node.layer
             lm = lmask.get(node.name) if isinstance(lmask, dict) else lmask
             pre = acts[node.name]
-            if self.conf.compute_dtype is not None:
-                pre = pre.astype(jnp.float32)  # loss in full precision
-            total = total + losses_mod.score(
-                layer.loss, labels[node.name], pre, layer.activation, lm
-            )
+            with jax.named_scope("loss"):
+                if self.conf.compute_dtype is not None:
+                    pre = pre.astype(jnp.float32)  # loss in full precision
+                total = total + losses_mod.score(
+                    layer.loss, labels[node.name], pre, layer.activation, lm
+                )
         for n in self.conf.nodes:
             if n.layer is not None and n.layer.has_params():
                 total = total + n.layer.reg_score(params[n.name])

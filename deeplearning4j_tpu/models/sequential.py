@@ -147,8 +147,9 @@ class MultiLayerNetwork(LazyScoreMixin):
             if i in self.conf.preprocessors:
                 h = self.conf.preprocessors[i](h)
             lstate = net_state.get(layer.name, {})
-            # the layer's name on its device operations (metadata only)
-            with jax.named_scope(layer.name):
+            # the layer's name and kind on its device operations
+            # (metadata only)
+            with jax.named_scope(layer.name), layer.kind_scope():
                 if _is_recurrent(layer):
                     carry = (carries or {}).get(layer.name)
                     h, lst, new_carry = layer.apply_with_carry(
@@ -265,15 +266,13 @@ class MultiLayerNetwork(LazyScoreMixin):
                 new_net_state, new_carries, act_stats = (
                     numerics.unpack_aux(plan, nplan, aux))
                 grads = {k: v for k, v in grads.items() if v}
-                with jax.named_scope("updater"):
-                    updates, new_upd_state = upd.update(
-                        updater_cfg, grads, upd_state, iteration,
-                        lr_overrides, params=params,
-                    )
-                    new_params = dict(params)
-                    for lname, u in updates.items():
-                        new_params[lname] = upd.apply_updates(
-                            params[lname], u)
+                updates, new_upd_state = upd.update(
+                    updater_cfg, grads, upd_state, iteration,
+                    lr_overrides, params=params,
+                )
+                new_params = dict(params)
+                for lname, u in updates.items():
+                    new_params[lname] = upd.apply_updates(params[lname], u)
                 introspection.attach(
                     new_upd_state, plan, grads=grads, params=params,
                     new_params=new_params, iteration=iteration,

@@ -272,6 +272,8 @@ class SelfAttentionLayer(Layer):
     over that mesh axis (requires shard_map execution).
     """
 
+    kind = "attention"
+
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     n_heads: int = 4

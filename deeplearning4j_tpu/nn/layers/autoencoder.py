@@ -28,6 +28,8 @@ from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class AutoEncoder(Layer):
+    kind = "ffn"
+
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     corruption_level: float = 0.3
@@ -83,6 +85,8 @@ class RBM(Layer):
     VisibleUnit enums; RECTIFIED/SOFTMAX variants are gated behind the same
     field and can be added without API change).
     """
+
+    kind = "ffn"
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None

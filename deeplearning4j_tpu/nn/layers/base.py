@@ -22,6 +22,7 @@ Serialization: each class registers under its reference-style type name;
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Dict, Optional, Tuple, Type
 
@@ -31,6 +32,17 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.inputs import InputType
 
 _LAYER_REGISTRY: Dict[str, Type["Layer"]] = {}
+
+# What a layer IS, whatever it is called: the closed vocabulary of
+# ``Layer.kind``.  The walkers (``MultiLayerNetwork._forward``, the composite
+# blocks' sublayer chain, ``ComputationGraph._forward``) put it on the layer's
+# device operations as a ``jax.named_scope`` beside the layer's name, which is
+# how ``observability.recompile.program_scopes`` and a trace reader tell an
+# attention matmul from an FFN's (docs/observability.md, "Device time by
+# layer").  The ``mhc_mix`` of the two ends of a hyper-connection stack is the
+# scope their blocks already use.
+KINDS = ("embed", "norm", "attention", "ffn", "experts", "head", "conv",
+         "recurrent", "mhc_mix")
 
 
 def register_layer(cls: Type["Layer"]) -> Type["Layer"]:
@@ -63,6 +75,9 @@ class Layer:
     dropout: float = 0.0               # input dropout probability (reference dropOut)
     drop_connect: bool = False         # dropOut masks WEIGHTS instead of inputs
     _SUPPORTS_DROP_CONNECT = False     # overridden by layers that mask W
+    # one of ``KINDS``, declared once a class; None for a composite block
+    # (its sublayers carry theirs) and for a custom layer that declares none
+    kind = None
     l1: float = 0.0
     l2: float = 0.0
     learning_rate: Optional[float] = None   # per-layer lr override
@@ -103,6 +118,12 @@ class Layer:
         return True
 
     # ---- forward --------------------------------------------------------
+    def kind_scope(self):
+        """The ``jax.named_scope`` of the layer's kind, for the walker that
+        applies it (metadata on the device operations; nothing is added)."""
+        return (jax.named_scope(self.kind) if self.kind
+                else contextlib.nullcontext())
+
     def apply(
         self,
         params: Dict[str, jax.Array],

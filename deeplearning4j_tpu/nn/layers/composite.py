@@ -80,8 +80,9 @@ class _SublayerChain(Layer):
         """Sublayer ``i`` (or ``sub`` in its place) without a carry."""
         sub = self.layers[i] if sub is None else sub
         kw = {"mask": mask} if mask is not None and self._forms[i][1] else {}
-        h, _ = sub.apply(params.get(f"sub{i}", {}), {}, h, train=train,
-                         rng=rng, **kw)
+        with sub.kind_scope():
+            h, _ = sub.apply(params.get(f"sub{i}", {}), {}, h, train=train,
+                             rng=rng, **kw)
         return h
 
     def _chain_with_carry(self, params, h, carry, *, train, rngs, mask):
@@ -93,9 +94,11 @@ class _SublayerChain(Layer):
                 # sublayers initialize their own state and return it — they
                 # must NOT be applied statelessly here, or their hidden
                 # state would reset every streamed chunk)
-                h, _, nc = sub.apply_with_carry(
-                    params.get(f"sub{i}", {}), {}, h, carry.get(f"sub{i}"),
-                    train=train, rng=rngs[i], mask=mask)
+                with sub.kind_scope():
+                    h, _, nc = sub.apply_with_carry(
+                        params.get(f"sub{i}", {}), {}, h,
+                        carry.get(f"sub{i}"), train=train, rng=rngs[i],
+                        mask=mask)
                 if nc is not None:
                     new_carry[f"sub{i}"] = nc
             else:
@@ -216,9 +219,10 @@ class ResidualBlock(_SublayerChain):
                 # mask is bit-identical to the unfused path's
                 rate = (sub1.dropout if train and sub1.dropout > 0.0
                         and not sub1.drop_connect else 0.0)
-                h = fused.prologue(
-                    h, params["sub0"]["gamma"], params["sub0"]["beta"],
-                    eps=ln.eps, rate=rate, rng=rngs[1], train=train)
+                with ln.kind_scope():
+                    h = fused.prologue(
+                        h, params["sub0"]["gamma"], params["sub0"]["beta"],
+                        eps=ln.eps, rate=rate, rng=rngs[1], train=train)
                 sub1r = (dataclasses.replace(sub1, dropout=0.0)
                          if rate > 0.0 else sub1)
                 h = self._apply_sub(1, params, h, train=train, rng=rngs[1],
@@ -496,6 +500,8 @@ class HyperStreamExpand(Layer):
     of ``HyperConnectionBlock`` s start as ``streams`` copies of the
     embedding, side by side."""
 
+    kind = "mhc_mix"
+
     n_in: Optional[int] = None
     streams: int = 4
     activation: str = "identity"
@@ -523,6 +529,8 @@ class HyperStreamExpand(Layer):
 class HyperStreamReduce(Layer):
     """``[B, T, streams * C] -> [B, T, C]``: the streams summed (in
     float32), ahead of the final norm."""
+
+    kind = "mhc_mix"
 
     n_in: Optional[int] = None
     streams: int = 4

@@ -39,6 +39,8 @@ def _out_size(size, k, s, p):
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class ConvolutionLayer(Layer):
+    kind = "conv"
+
     n_in: Optional[int] = None    # input channels (inferred)
     n_out: Optional[int] = None   # output channels
     kernel_size: Tuple[int, int] = (5, 5)
@@ -106,6 +108,8 @@ class SubsamplingLayer(Layer):
     helper). TPU-native: ``lax.reduce_window`` — XLA fuses and the backward
     pass (scatter for max, uniform spread for avg) comes from autodiff."""
 
+    kind = "conv"
+
     pooling_type: str = "max"  # max | avg | sum
     kernel_size: Tuple[int, int] = (2, 2)
     stride: Tuple[int, int] = (2, 2)
@@ -156,6 +160,8 @@ class SubsamplingLayer(Layer):
 class GlobalPoolingLayer(Layer):
     """Global spatial (or temporal) pooling: [B,H,W,C]->[B,C] or
     [B,T,F]->[B,F].  TPU-native reduction; used by ResNet-style heads."""
+
+    kind = "conv"
 
     pooling_type: str = "avg"  # avg | max | sum
 

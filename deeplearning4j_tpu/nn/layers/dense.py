@@ -22,6 +22,8 @@ from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class DenseLayer(Layer):
+    kind = "ffn"
+
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     _SUPPORTS_DROP_CONNECT = True  # apply() masks W via maybe_drop_connect
@@ -64,6 +66,8 @@ class GatedMLP(Layer):
     """Bias-free gated feed-forward block (SwiGLU with the default
     activation): ``(act(x W_gate) * (x W_up)) W_down``.  ``hidden`` is the
     width of the gate and up projections."""
+
+    kind = "ffn"
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None
@@ -110,6 +114,8 @@ class OutputLayer(DenseLayer):
     """Dense + loss head (reference ``nn/layers/OutputLayer.java``).
     ``loss`` names a function in :mod:`deeplearning4j_tpu.nn.losses`."""
 
+    kind = "head"
+
     loss: str = "mcxent"
     # default differs from the base "sigmoid": with the default mcxent loss
     # sigmoid degenerates (see validate); softmax is the classification
@@ -142,6 +148,8 @@ class OutputLayer(DenseLayer):
 class ActivationLayer(Layer):
     """Pure activation layer (reference ``nn/conf/layers/ActivationLayer``)."""
 
+    kind = "ffn"
+
     def output_type(self, input_type: InputType) -> InputType:
         return input_type
 
@@ -159,6 +167,8 @@ class ActivationLayer(Layer):
 @dataclasses.dataclass(frozen=True)
 class DropoutLayer(Layer):
     """Standalone dropout (reference DropoutLayer)."""
+
+    kind = "ffn"
 
     def output_type(self, input_type: InputType) -> InputType:
         return input_type
@@ -179,6 +189,8 @@ class EmbeddingLayer(Layer):
     """Index lookup layer (reference ``EmbeddingLayer.java``: input is a
     column of indices; forward = row gather, a TPU-native one-hot-free
     ``jnp.take``)."""
+
+    kind = "embed"
 
     n_in: Optional[int] = None   # vocab size
     n_out: Optional[int] = None

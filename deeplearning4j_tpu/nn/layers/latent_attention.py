@@ -56,6 +56,8 @@ class LatentAttentionLayer(Layer):
     (frequencies blended by ``yarn_inv_freq``; the softmax scale times
     ``yarn_mscale(rope_factor, rope_mscale_all_dim)`` squared)."""
 
+    kind = "attention"
+
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     n_heads: int = 4

@@ -48,6 +48,8 @@ class MoELayer(Layer):
     residual add, transformer-style).  hidden: per-expert FFN width.
     """
 
+    kind = "experts"
+
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     num_experts: int = 4
@@ -211,6 +213,8 @@ class RoutedMoELayer(Layer):
     are all multiplied by every expert one of them chose, whose weights one
     kernel streams once (``helpers/grouped_experts.py``).  Assignments to
     experts held elsewhere add nothing either way."""
+
+    kind = "experts"
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None

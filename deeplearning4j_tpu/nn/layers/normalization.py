@@ -25,6 +25,8 @@ from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class BatchNormalization(Layer):
+    kind = "norm"
+
     n_out: Optional[int] = None   # feature/channel count (inferred)
     decay: float = 0.9            # running-average decay (reference default)
     eps: float = 1e-5
@@ -117,6 +119,8 @@ class LocalResponseNormalization(Layer):
     Reference defaults k=2, n=5, alpha=1e-4, beta=0.75
     (``nn/conf/layers/LocalResponseNormalization``)."""
 
+    kind = "norm"
+
     k: float = 2.0
     n: int = 5
     alpha: float = 1e-4
@@ -160,6 +164,8 @@ class LayerNorm(Layer):
     sequence-shard-safe: under sequence parallelism every timestep
     normalizes locally with no collective."""
 
+    kind = "norm"
+
     n_in: Optional[int] = None
     eps: float = 1e-5
     activation: str = "identity"
@@ -193,6 +199,8 @@ class RMSNorm(Layer):
     (Zhang & Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * gamma`` — no
     mean subtraction, no bias.  The statistics are taken in float32
     whatever the compute dtype and the result is cast back."""
+
+    kind = "norm"
 
     n_in: Optional[int] = None
     eps: float = 1e-5

@@ -118,6 +118,8 @@ class GravesLSTM(Layer):
     """Graves-style LSTM with peephole connections
     (reference ``nn/layers/recurrent/GravesLSTM.java:38``)."""
 
+    kind = "recurrent"
+
     n_in: Optional[int] = None
     n_out: Optional[int] = None
     activation: str = "tanh"
@@ -183,6 +185,8 @@ class LSTM(GravesLSTM):
 class GravesBidirectionalLSTM(Layer):
     """Bidirectional Graves LSTM; directions are summed
     (reference ``GravesBidirectionalLSTM.java:218`` ``fwdOutput.addi(backOutput)``)."""
+
+    kind = "recurrent"
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None

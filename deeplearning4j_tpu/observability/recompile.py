@@ -17,13 +17,22 @@ warning per *new* signature after the first with the old→new delta, e.g.::
 The fingerprint is a few microseconds of host work per call (tuple of
 shape/dtype ids per leaf); the paths needed for a readable delta are only
 computed on a miss.
+
+The same miss path is where a program remembers how to describe itself:
+``register_program`` keeps the program traced at the abstract signature it
+was called with, and ``program_scopes(name)`` compiles it again from that on
+demand and returns, per instruction of the compiled module, the
+``jax.named_scope`` path its device operation lies under (docs/
+observability.md, "Device time by layer").
 """
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import re
 import threading
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from deeplearning4j_tpu.observability import profiling, shardstats
 
@@ -276,12 +285,16 @@ class _InstrumentedJit:
         elif prof is not None and prof.cost_analysis:
             cost_fn = lambda: profiling.jit_cost_analysis(fn, args, kwargs)
         if self._argnums is None:
-            self.detector.check(args, kwargs, cost_fn=cost_fn)
+            new = self.detector.check(args, kwargs, cost_fn=cost_fn)
         else:
             # dict keyed by the ORIGINAL position so delta paths stay
             # meaningful ("args[4]: f32[32,8] -> f32[20,8]")
             sel = {i: args[i] for i in self._argnums if i < len(args)}
-            self.detector.check(sel, kwargs, cost_fn=cost_fn)
+            new = self.detector.check(sel, kwargs, cost_fn=cost_fn)
+        if new:
+            # a new signature compiles: the program as it now runs is what
+            # program_scopes() describes (the FULL argument list, abstract)
+            register_program(self.detector.name, self._fn, args, kwargs)
         if prof is not None:
             prof.note_dispatch(self.detector.name, self.detector.last_cost)
         if coll is not None:
@@ -316,3 +329,270 @@ def compile_counter(fn_name: str, registry=None):
         _COMPILES, "Distinct abstract input signatures (≈ XLA "
         "compilations) per jitted function", labels=("fn",)).labels(
         fn=fn_name)
+
+
+# ---------------------------------------------------------------------------
+# what a compiled program says of itself: device operation -> scope path
+# ---------------------------------------------------------------------------
+
+class ScopeRow(NamedTuple):
+    """One instruction of a compiled module."""
+
+    name: str      # as XLA prints it, without the ``%``: ``fusion.12``
+    shape: str     # its result's shape as XLA prints it, layout and all
+    opcode: str    # ``fusion``, ``custom-call``, ``while``, ``all-reduce`` ...
+    path: str      # scope path of its own ``op_name`` (``scope_path``)
+    # scope path -> (instructions, of them matrix products and kernel calls)
+    # of everything fused into it, or run by the computations it calls
+    fused: Dict[str, Tuple[int, int]]
+    # for an instruction without an ``op_name`` (XLA's own: the ``copy-done``
+    # of a prefetched weight, a ``ConcatBitcast``), the nearest instruction
+    # with one among those that consume its result; else ``""``
+    consumer: str = ""
+
+
+class ProgramScopes(NamedTuple):
+    program: str               # the name it was registered under
+    module: str                # the compiled module's own: ``jit_prefill_512``
+    rows: Tuple[ScopeRow, ...]
+
+
+# name -> the program traced at its abstract signature (``jax.stages.Traced``:
+# the jaxpr, no Python callable and no argument).  One table a process; a name
+# registered again (a new signature, a new model version) replaces its entry.
+# Nothing here keeps a network, a parameter or a pool alive: an entry still
+# answers after its owner has let everything go.
+_PROGRAMS: Dict[str, Any] = {}
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _abstract(tree):
+    """``tree`` with every array leaf as a ``jax.ShapeDtypeStruct`` of its
+    shape, dtype, weak type and, where the array is committed to one, its
+    sharding: what jit keys a program on.  (An uncommitted array lowers as
+    an argument without a sharding does; given one here, the same program
+    would lower to another module and miss the compile cache.)  A leaf that
+    is no array (a static argument) stays as it is."""
+    import jax
+
+    def leaf(x):
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return x
+        try:
+            aval = jax.typeof(x)
+        except TypeError:
+            return x
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype, sharding=sharding,
+                                    weak_type=aval.weak_type)
+
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+def register_program(name: str, jitted: Callable, args: Tuple,
+                     kwargs: Optional[Dict] = None) -> None:
+    """Remember how to describe the program ``jitted(*args, **kwargs)``
+    compiles to, under ``name``: ``jitted`` traced at the abstract signature
+    of the full argument list.  Meant for a path that runs once a signature,
+    just ahead of the call that compiles: ``instrument()``'s wrapper calls it
+    on a detector miss, ``GenerationPrograms.warm()`` for ``decode`` and
+    each ``prefill_<bucket>``.  The trace is the one the call itself needs
+    (jit finds it in its cache and does not trace again), so nothing is
+    added to the set-up; what is kept is the jaxpr alone."""
+    traced = jitted.trace(*_abstract(tuple(args)),
+                          **_abstract(dict(kwargs or {})))
+    with _PROGRAMS_LOCK:
+        _PROGRAMS[name] = traced
+
+
+def registered_programs() -> List[str]:
+    with _PROGRAMS_LOCK:
+        return sorted(_PROGRAMS)
+
+
+@contextlib.contextmanager
+def _scopes_in_cache_key():
+    """A persistent-cache hit returns the executable as the process that
+    compiled it wrote it, ``op_name`` s included, and jax leaves them out of
+    the key unless told otherwise: a program cached by older code would
+    describe itself by that code's scopes.  ``backend.compile_cache.
+    enable_compile_cache()`` tells it process-wide, so that this compile hits
+    the entry the program itself was served from; here for a process that set
+    its cache up another way (a miss then, never a stale table)."""
+    import jax
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
+
+
+def program_scopes(name: str) -> ProgramScopes:
+    """The compiled module of the program registered as ``name``, by
+    instruction: lowered and compiled from the kept trace (nothing executes,
+    nothing is donated; with a persistent compile cache it is a hit), then
+    read from the executable's text (jaxlib hands out the module's proto as
+    bytes only; the text is what Python can read without a second package).
+    Costs a compile-cache load and a parse of the text a call; nothing until
+    called."""
+    with _PROGRAMS_LOCK:
+        traced = _PROGRAMS.get(name)
+        known = sorted(_PROGRAMS)
+    if traced is None:
+        raise ValueError(f"no program registered as {name!r}; registered: "
+                         f"{known}")
+    with _scopes_in_cache_key():
+        compiled = traced.lower().compile()
+    module, rows = parse_module_text(compiled.as_text())
+    return ProgramScopes(name, module, rows)
+
+
+_WRAPPER = re.compile(r"^(\w+)\((.*)\)$")
+# scopes that name a traced FUNCTION, not a place in the model
+_FUNCTION_WRAPPERS = ("jit", "pjit")
+
+
+def scope_path(op_name: str) -> str:
+    """The ``jax.named_scope`` path of an ``op_name``:
+    ``jit(step)/transpose(jvp(layer_3))/ffn/dot_general`` -> ``layer_3/ffn``.
+    jax wraps one component a transform (``jvp(...)``, ``transpose(...)``,
+    ``vmap(...)``): the wrappers are peeled; a ``jit(...)`` component names a
+    function and is dropped, as is the last component, the primitive.
+    Where XLA merged instructions it joins their names with ``;``: the first
+    is read."""
+    parts = []
+    for part in op_name.split(";", 1)[0].split("/")[:-1]:
+        keep = True
+        while (m := _WRAPPER.match(part)) is not None:
+            keep = keep and m.group(1) not in _FUNCTION_WRAPPERS
+            part = m.group(2)
+        if keep and part:
+            parts.append(part)
+    return "/".join(parts)
+
+
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HEAD = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_CALLED = re.compile(r"\b(?:calls|body|condition|to_apply|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_CALLED_LIST = re.compile(r"\b(?:branch_computations|called_computations)="
+                          r"\{([^}]*)\}")
+# not work of their own: they leave a fusion's tally alone
+_PLUMBING = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+_MATMULS = ("dot", "convolution", "custom-call", "ragged-dot")
+
+
+def _balanced(text: str) -> int:
+    """Index of the ``)`` that closes the ``(`` ``text`` starts with."""
+    depth = 0
+    for end, ch in enumerate(text):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return end
+    return len(text) - 1
+
+
+class _Instruction(NamedTuple):
+    """One line of HLO text, as ``parse_module_text`` first reads it."""
+
+    name: str
+    shape: str
+    opcode: str
+    path: Optional[str]        # None: no ``op_name`` of its own
+    called: List[str]          # the computations it calls
+    operands: List[str]
+
+
+def _split_instruction(line: str):
+    """``(name, shape, opcode, operand names)`` of one instruction line of
+    HLO text."""
+    name, rest = line.split(" = ", 1)
+    if rest.startswith("("):                 # a tuple: balanced parentheses
+        end = _balanced(rest)
+        shape, rest = rest[:end + 1], rest[end + 1:].lstrip()
+    else:
+        shape, _, rest = rest.partition(" ")
+    opcode, _, args = rest.partition("(")
+    operands = _OPERAND.findall(args[:_balanced("(" + args)])
+    return name.lstrip("%"), shape, opcode, operands
+
+
+def parse_module_text(text: str) -> Tuple[str, Tuple[ScopeRow, ...]]:
+    """``(module name, rows)`` of a compiled module's ``as_text()``: a row
+    for every instruction of every computation, in the text's order."""
+    module, current = "", None
+    bodies: Dict[str, List[_Instruction]] = {}    # by computation
+    for raw in text.splitlines():
+        line = raw.strip()
+        if current is None:
+            if not module and (m := _MODULE.match(line)):
+                module = m.group(1)
+            elif " = " not in line and (m := _HEAD.match(line)):
+                current = bodies.setdefault(m.group(1), [])
+        elif line == "}":
+            current = None
+        elif " = " in line:
+            if line.startswith("ROOT "):
+                line = line[5:]
+            name, shape, opcode, operands = _split_instruction(line)
+            m = _OP_NAME.search(line)
+            called = _CALLED.findall(line)
+            for group in _CALLED_LIST.findall(line):
+                called += [c.strip().lstrip("%") for c in group.split(",")]
+            # an op_name without a "/" is an argument's label (XLA's copy of
+            # ``params['layer_1']['W']``), not a place in the program
+            own = m is not None and "/" in m.group(1)
+            current.append(_Instruction(
+                name, shape, opcode, scope_path(m.group(1)) if own else None,
+                called, operands))
+
+    tallies: Dict[str, Dict[str, Tuple[int, int]]] = {}
+
+    def add(out, called):
+        for inner in called:
+            for p, (n, k) in tally(inner).items():
+                n0, k0 = out.get(p, (0, 0))
+                out[p] = (n0 + n, k0 + k)
+        return out
+
+    def tally(comp: str) -> Dict[str, Tuple[int, int]]:
+        """Scope path -> (instructions, matmuls) of a computation and of
+        everything it calls."""
+        if comp not in tallies:
+            out = tallies[comp] = {}      # set first: a cycle ends here
+            for ins in bodies.get(comp, ()):
+                if ins.opcode not in _PLUMBING:
+                    add(out, ins.called)
+                    n, k = out.get(ins.path or "", (0, 0))
+                    out[ins.path or ""] = (n + 1,
+                                           k + (ins.opcode in _MATMULS))
+        return tallies[comp]
+
+    rows = []
+    for lines in bodies.values():
+        named = {ins.name for ins in lines if ins.path is not None}
+        users: Dict[str, List[str]] = {}
+        for ins in lines:
+            for operand in ins.operands:
+                users.setdefault(operand, []).append(ins.name)
+        for ins in lines:
+            consumer = ""
+            if ins.path is None:
+                # breadth first through what consumes it, plumbing included
+                seen, queue = {ins.name}, list(users.get(ins.name, ()))
+                while queue and not consumer:
+                    user = queue.pop(0)
+                    if user in named:
+                        consumer = user
+                    elif user not in seen:
+                        seen.add(user)
+                        queue += users.get(user, ())
+            rows.append(ScopeRow(ins.name, ins.shape, ins.opcode,
+                                 ins.path or "", add({}, ins.called),
+                                 consumer))
+    return module, tuple(rows)

@@ -172,6 +172,9 @@ def normalize_tree(cfg: UpdaterConfig, grads):
             for lname, lgrads in grads.items()}
 
 
+# every trainer's step reaches the optimizer through these two functions, so
+# the ``updater`` scope on its device operations is entered here, once
+@jax.named_scope("updater")
 def update(
     cfg: UpdaterConfig,
     grads,
@@ -258,6 +261,7 @@ def update(
     return updates, new_state
 
 
+@jax.named_scope("updater")
 def apply_updates(params, updates):
     return jax.tree_util.tree_map(lambda p, u: p - u, params, updates)
 

@@ -25,9 +25,15 @@ def test_compile_cache_dir_env_wins_else_fixed_checkout_path(tmp_path):
         "enable_compile_cache\n"
         "{guard}"
         "print(enable_compile_cache())\n"
-        "print(jax.config.jax_compilation_cache_dir)\n")
-    # placed from outside: JAX reads the variable, the code sets nothing
-    guard = ("def _no(*a, **k): raise AssertionError('config.update called')\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        # a hit must not return another commit's scope names
+        "assert jax.config.jax_compilation_cache_include_metadata_in_key\n")
+    # placed from outside: JAX reads the variable, the code places nothing
+    # (what it does set either way is the key's metadata flag, below)
+    guard = ("_update = jax.config.update\n"
+             "def _no(name, value):\n"
+             "    assert 'cache_dir' not in name, name\n"
+             "    _update(name, value)\n"
              "jax.config.update = _no\n")
     placed = str(tmp_path / "elsewhere")
     out = _run(["-c", probe.format(guard=guard)],

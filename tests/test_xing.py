@@ -296,7 +296,8 @@ def test_a_decoded_token_takes_the_blocks_whole_path():
 # Kimi's and are checked again here, so the three older nets stand side by
 # side in the file that adds the fourth block).  Since PR 36 with the
 # streamed experts' kernel withheld from the seam, as there: the ``ragged``
-# path is the parent's text
+# path is the parent's text.  Since PR 37 with the parent's module name put
+# back on a ``prefill_<bucket>``, as there
 LAGUNA_PARENT = {"prefill_16": "e13b53b397c337f7",
                  "prefill_32": "a1a2c4405c090765",
                  "decode": "a10054bc2e4fbff9"}
@@ -315,6 +316,7 @@ def test_older_nets_lower_to_the_programs_of_the_parent(family, monkeypatch):
     net, _ = test_laguna.toy_net()
     progs = GenerationPrograms(net, slots=4, pages_per_slot=6, page_size=8,
                                num_pages=25, prefill_buckets=(16, 32))
-    got = {name: hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
+    got = {name: hashlib.sha256(low.as_text().replace(
+        f"@jit_{name} ", "@jit_prefill ").encode()).hexdigest()[:16]
            for name, low in progs.lowered().items()}
     assert got == LAGUNA_PARENT
