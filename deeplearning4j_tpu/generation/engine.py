@@ -594,6 +594,9 @@ class GenerationEngine:
             self.scheduler.install(req, base_key)
         for path in progs.expert_paths.get(bucket, ()):
             self.metrics.moe_expert_steps.inc(stage="prefill", path=path)
+        for path in progs.latent_paths.get((bucket, req.shared_len == 0), ()):
+            self.metrics.latent_attention_steps.inc(stage="prefill",
+                                                    path=path)
         self._firsts.append((req, tok, mv.name,
                              SAMPLING_PATHS[sampling_path(*policy)]))
 
@@ -637,6 +640,8 @@ class GenerationEngine:
             mode="sync" if self._in_flight is None else "ahead")
         for path in progs.expert_paths.get("decode", ()):
             self.metrics.moe_expert_steps.inc(stage="decode", path=path)
+        for path in progs.latent_paths.get(("decode", False), ()):
+            self.metrics.latent_attention_steps.inc(stage="decode", path=path)
         return _Step(sampled, rows, mv.name,
                      SAMPLING_PATHS[sampling_path(*policy[2:])])
 

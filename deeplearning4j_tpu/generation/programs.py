@@ -62,6 +62,7 @@ from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
 from deeplearning4j_tpu.nn.layers.composite import gauging
+from deeplearning4j_tpu.nn.layers.latent_attention import LatentAttentionLayer
 from deeplearning4j_tpu.nn.layers.moe import RoutedMoELayer, counting
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
@@ -252,6 +253,23 @@ class GenerationPrograms:
         self.expert_paths = {
             name: tuple(sorted({l.path(t) for l in self.expert_layers}))
             for name, t in rows.items()} if self.expert_layers else {}
+        # how each compute program attends over its latent layers' pages
+        # (``LatentAttentionLayer.path``, the rule the layer branches on
+        # when the program is traced): the paths taken, by (``"decode"`` /
+        # bucket, whether the dispatch starts at position 0 — the branch a
+        # prefill takes on the device); nothing for a net without them
+        self.latent_layers = _layers_of_kind(net, LatentAttentionLayer)
+        # the dtype the layers are traced in: the stored one where no
+        # compute dtype is set (a float64 net keeps the gather)
+        dtype = jnp.dtype(net.conf.compute_dtype or next(
+            (l.dtype for l in jax.tree_util.tree_leaves(net.params)
+             if jnp.issubdtype(l.dtype, jnp.floating)), jnp.float32))
+        self.latent_paths = {
+            (name, zero): tuple(sorted({
+                l.path(t, zero, self.page_size, dtype)
+                for l in self.latent_layers}))
+            for name, t in {**rows, "decode": 1}.items()
+            for zero in (False, True)} if self.latent_layers else {}
         self.num_window_pages = window_pool_pages(self.slots, self.ring)
         # validate pageability eagerly (raises on recurrent stacks)
         seed_paged_pools(net, 2, page_size, net.conf.compute_dtype,
@@ -506,6 +524,30 @@ class GenerationPrograms:
                     f" (a ring, window {window})" if window else "",
                     ppb, tq, b, -(-t // tq), vmem / 2 ** 20)
 
+    def _log_latent_tiling(self) -> None:
+        """How ``latent_paged_attention`` tiles the decode step of a net
+        whose latent layers take the ``paged`` path, once a shape (the
+        prefills attend the expanded way or over the gathered pages)."""
+        from deeplearning4j_tpu.helpers import paged_attention as pa
+
+        if ("paged" not in self.latent_paths.get(("decode", False), ())
+                or pa.default_impl() != "pallas"):
+            return
+        dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
+        for h, w, v in sorted({(l.n_heads, l._pool_width, l.kv_rank)
+                               for l in self.latent_layers}):
+            ppb, _, vmem = pa.paged_tiling(
+                self.slots, 1, h, 1, w, self.page_size, self.pages_per_slot,
+                dtype, v)
+            logger.info(
+                "generation.decode: latent_paged_attention q [%d, 1, %d, "
+                "%d] over %d pages of %d, the value the first %d columns: "
+                "%d pages a block (%.2f MB a copy), grid (%d, 1), %.2f MB "
+                "of VMEM", self.slots, h, w, self.pages_per_slot,
+                self.page_size, v, ppb,
+                ppb * self.page_size * w * dtype.itemsize / 2 ** 20,
+                self.slots, vmem / 2 ** 20)
+
     def _log_expert_tiling(self) -> None:
         """How ``grouped_experts`` tiles each compute program whose expert
         layers take the ``streamed`` path, once a program and shape."""
@@ -589,6 +631,7 @@ class GenerationPrograms:
             register_program(f"generation.{name}", jitted,
                              (params, net_state, pools) + tail)
         self._log_paged_tiling()
+        self._log_latent_tiling()
         self._log_expert_tiling()
         coll = shardstats.active_collector()
         if coll is not None:

@@ -57,6 +57,18 @@ columns it has reached, all of the ring once it has wrapped.  The engine
 calls it so for decoded tokens only (a window layer's prefill chunk
 attends over its own keys).
 
+A LATENT pool (``LatentAttentionLayer``: ``pc`` [P, page_size, W], one
+latent row a token, read by every head) is this layout with one kv head,
+and its value is a prefix of its key: ``paged_latent_attention`` runs the
+same kernel (named ``latent_paged_attention`` there) and the same lax loop
+with ``v_width`` set — no V pool and no V buffer, the second product
+against the first ``v_width`` columns of the key slab just copied, so a
+page crosses from HBM once — a scale handed in, and a block sized by its
+bytes (``paged_tiling(v_width=)``, ``SLAB_BLOCK_BYTES``).  It serves the
+layer's single-token step; the oracle there is the layer's own
+``pool[block]`` + ``_absorbed``.  See docs/serving.md "Latent pages while
+decoding".
+
 Semantics match the legacy pair exactly (the flag-selectable oracle):
 GQA contracts the UNEXPANDED kv heads, masking is per-row
 ``q_positions >= key_position`` where a key's global position is its
@@ -70,7 +82,9 @@ Mode toggle (trace-time, like ``enable_helpers``):
 ``set_paged_attention_mode("gather")`` or env DL4J_TPU_PAGED_GATHER=1
 routes ``SelfAttentionLayer._apply_paged`` back through the legacy
 gather+softmax path — the bit-compatible oracle the parity tests compare
-against, and the other side of the in-cell comparison (PERF.md §6, PR 32).
+against, and the other side of the in-cell comparison (PERF.md §6, PR 32);
+``LatentAttentionLayer``'s single-token step goes back to its gather with
+it.
 """
 
 from __future__ import annotations
@@ -199,31 +213,37 @@ def ring_pages(q_positions: jax.Array, page_size: int,
 # lax fallback: fori_loop over live pages, online softmax
 # ---------------------------------------------------------------------------
 
-def _lax_paged(q, pk, pv, block, q_positions, window=None):
+def _lax_paged(q, pk, pv, block, q_positions, window=None, scale=None,
+               v_width=None):
     """Compiled page-streaming fallback for non-TPU backends.  One
     ``[B, Hkv, page_size, D]`` slab in flight at a time; loop bound is
     the dynamic live-page watermark (traced -> while_loop -> zero
     steady-state recompiles).  With ``window`` the table is a ring
-    (``ring_pages`` gives each column's logical page)."""
+    (``ring_pages`` gives each column's logical page).  With ``v_width``
+    there is no ``pv``: a page's value is the first ``v_width`` columns
+    of its key slab (a latent pool), and the result is that wide."""
     b, t, hq, d = q.shape
     hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
     maxp = block.shape[1]
     acc_dt = jnp.promote_types(q.dtype, jnp.float32)
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    dv = d if v_width is None else v_width
     offs = jnp.arange(page_size, dtype=block.dtype)
     # [B, T, Hkv, G, D] — contract the UNEXPANDED kv heads (GQA)
     qg = q.reshape(b, t, hkv, g, d).astype(acc_dt)
     m0 = jnp.full((b, hkv, g, t), NEG_INF, acc_dt)
     l0 = jnp.zeros((b, hkv, g, t), acc_dt)
-    a0 = jnp.zeros((b, t, hkv, g, d), acc_dt)
+    a0 = jnp.zeros((b, t, hkv, g, dv), acc_dt)
     if window is not None:
         lpage = ring_pages(q_positions, page_size, maxp)      # [B, R]
 
     def body(p, carry):
         m, l, acc = carry
         k = pk[block[:, p]].astype(acc_dt)            # [B, Hkv, ps, D]
-        v = pv[block[:, p]].astype(acc_dt)
+        v = (pv[block[:, p]].astype(acc_dt) if v_width is None
+             else k[..., :v_width])
         kpos = p * page_size + offs
         s = jnp.einsum("bthgd,bhkd->bhgtk", qg, k) * scale
         qp = q_positions[:, None, None, :, None]
@@ -247,7 +267,7 @@ def _lax_paged(q, pk, pv, block, q_positions, window=None):
     m, l, acc = jax.lax.fori_loop(0, live, body, (m0, l0, a0))
     safe = jnp.where(l > 0, l, 1.0)                   # NaN-safe idle rows
     o = acc / safe.transpose(0, 3, 1, 2)[..., None]
-    return o.reshape(b, t, hq, d).astype(q.dtype)
+    return o.reshape(b, t, hq, dv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -256,6 +276,12 @@ def _lax_paged(q, pk, pv, block, q_positions, window=None):
 
 BLOCK_KEYS = 128             # key positions a block of pages aims at
 VMEM_BUDGET = 8 * 2 ** 20    # bytes one grid step's buffers may take
+# bytes of one block of a latent pool's pages (``paged_tiling(v_width=)``):
+# 8 pages of 64 x 640 bf16.  A LatentAttentionLayer decode call alone on
+# the chip (PERF.md PR 38; ms a layer at Xing's / k2's shape, the gather
+# 1.45 / 1.81): 0.47 / 0.80 at 2 pages (what BLOCK_KEYS alone gives), 0.36
+# / 0.66 at 4, 0.33 / 0.63 at 6, 0.33 / 0.61 at 8, 0.35 / 0.62 at 12
+SLAB_BLOCK_BYTES = 640 * 2 ** 10
 
 
 def _sublanes(dtype) -> int:
@@ -269,7 +295,8 @@ def _round_up(x: int, m: int) -> int:
 
 
 def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
-                 maxp: int, dtype) -> Tuple[int, int, int]:
+                 maxp: int, dtype, v_width: Optional[int] = None
+                 ) -> Tuple[int, int, int]:
     """How the Pallas kernel tiles ``q`` [b, t, hq, d] over pools
     [P, hkv, page_size, d] of ``dtype`` behind a block table [b, maxp]:
     ``(pages_per_block, row_tile, vmem_bytes)``.
@@ -286,19 +313,33 @@ def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
     and V page buffers, and one head's scores and probabilities.  The
     grid is ``(b, cdiv(t, row_tile))``.  A pure function of the shapes:
     the kernel calls it, and so can whoever wants to know how it engaged.
+
+    ``v_width`` (a latent pool: no V pool, a page's value is the first
+    ``v_width`` columns of its key slab) sizes the block by its BYTES
+    instead: a latent pool has one kv head, so ``BLOCK_KEYS`` keys are a
+    fifth of what a GQA block moves, and the fixed cost of a loop step
+    (its copies' issue and wait, the softmax state's update) would be most
+    of the step.  A block is then the pages ``SLAB_BLOCK_BYTES`` hold
+    (never under ``BLOCK_KEYS`` keys, never more than the table has).
     """
     item = jnp.dtype(dtype).itemsize
     sub = _sublanes(dtype)
     g = hq // hkv
     dpad = _round_up(d, LANES)
-    ppb = max(1, min(BLOCK_KEYS // page_size, maxp))
+    ppb = BLOCK_KEYS // page_size
+    pools = 2                                          # K and V
+    dvpad = dpad
+    if v_width is not None:
+        ppb = max(ppb, SLAB_BLOCK_BYTES // (hkv * page_size * dpad * item))
+        pools, dvpad = 1, _round_up(v_width, LANES)
+    ppb = max(1, min(ppb, maxp))
     bk = ppb * page_size
-    pages = 2 * 2 * ppb * hkv * page_size * dpad * item
+    pages = 2 * pools * ppb * hkv * page_size * dpad * item
 
     def vmem(tq):
         rows = _round_up(g * tq, sub)
-        state = dpad * 4 + 2 * LANES * 4               # acc, m, l
-        tiles = 2 * 2 * dpad * item                    # q and o, 2 buffers
+        state = dvpad * 4 + 2 * LANES * 4              # acc, m, l
+        tiles = 2 * (dpad + dvpad) * item              # q and o, 2 buffers
         return (pages + hkv * rows * (state + tiles)
                 + 2 * rows * LANES * 4                 # positions
                 + rows * bk * (4 + 4 + item))          # s, p, p cast
@@ -310,13 +351,19 @@ def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
 
 
 def _paged_kernel(blk_ref, qmax_ref, *refs, scale, page_size, maxp,
-                  window=None):
+                  window=None, value_in_key=False):
     if window is not None:
         # a ring table: the logical page of each of its columns rides a
         # third scalar prefetch (``ring_pages``)
         lp_ref, *refs = refs
-    (qp_ref, q_ref, k_hbm, v_hbm, o_ref,
-     kbuf, vbuf, sem, m_scr, l_scr, acc_scr) = refs
+    if value_in_key:
+        # a latent pool: the value is a prefix of the key slab, so a page
+        # is copied once and there is no V pool and no V buffer
+        (qp_ref, q_ref, k_hbm, o_ref,
+         kbuf, sem, m_scr, l_scr, acc_scr) = refs
+    else:
+        (qp_ref, q_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sem, m_scr, l_scr, acc_scr) = refs
     b, ti = pl.program_id(0), pl.program_id(1)
     _, ppb, hkv, _, dpad = kbuf.shape
     rows = q_ref.shape[1]
@@ -342,8 +389,9 @@ def _paged_kernel(blk_ref, qmax_ref, *refs, scale, page_size, maxp,
             page = blk_ref[b * maxp + p]
             out.append(pltpu.make_async_copy(
                 k_hbm.at[page], kbuf.at[slot, i], sem.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[slot, i], sem.at[1, slot]))
+            if not value_in_key:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[page], vbuf.at[slot, i], sem.at[1, slot]))
         return out
 
     for c in copies(0, 0):
@@ -387,7 +435,8 @@ def _paged_kernel(blk_ref, qmax_ref, *refs, scale, page_size, maxp,
             keep = (qp >= kpos) & (kpos >= 0) & (kpos > qp - window)
         for h in range(hkv):
             k = kbuf[slot, :, h].reshape(bk, dpad)
-            v = vbuf[slot, :, h].reshape(bk, dpad)
+            v = (k[:, :acc_scr.shape[2]] if value_in_key
+                 else vbuf[slot, :, h].reshape(bk, dpad))
             s = _dot_f32(q_ref[h], k, trans_b=True) * scale   # [rows, bk]
             s = jnp.where(keep, s, NEG_INF)
             m_prev = m_scr[h, :, :1]
@@ -411,26 +460,38 @@ def _paged_kernel(blk_ref, qmax_ref, *refs, scale, page_size, maxp,
 # one trace and one lowering: the kernel's unrolled copies and heads make
 # those the dear part (six layers x three programs took 3 s of the serve
 # cell's set-up without it; XLA inlines the calls, the program is the same)
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
-def _pallas_paged(q, pk, pv, block, q_positions, interpret, window=None):
+@functools.partial(jax.jit, static_argnames=("interpret", "window", "scale",
+                                             "v_width"))
+def _pallas_paged(q, pk, pv, block, q_positions, interpret, window=None,
+                  scale=None, v_width=None):
+    """``v_width`` (with ``pv`` None): a latent pool, whose pages' values
+    are the first ``v_width`` columns of their key slabs; the kernel
+    multiplies whole lanes (``dvpad`` columns: the extra ones are further
+    output columns nobody reads) and the result is sliced to ``v_width``."""
     b, t, hq, d = q.shape
     hkv, page_size = pk.shape[1], pk.shape[2]
     g = hq // hkv
     maxp = block.shape[1]
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
     sub = _sublanes(pk.dtype)
     if not interpret and page_size % sub:
         raise ValueError(
             f"page_size={page_size} cannot tile a {pk.dtype} KV pool on "
             f"TPU: one page is one (page_size, D) tile per kv head, so "
             f"page_size must be a multiple of {sub} for this dtype")
+    latent = v_width is not None
+    pools = (pk,) if latent else (pk, pv)
     dp = (-d) % LANES
     if dp:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        pk = jnp.pad(pk, ((0, 0), (0, 0), (0, 0), (0, dp)))
-        pv = jnp.pad(pv, ((0, 0), (0, 0), (0, 0), (0, dp)))
+        pools = tuple(jnp.pad(p, ((0, 0), (0, 0), (0, 0), (0, dp)))
+                      for p in pools)
     dpad = d + dp
-    ppb, tq, _ = paged_tiling(b, t, hq, hkv, d, page_size, maxp, pk.dtype)
+    dv = v_width if latent else d
+    dvpad = _round_up(dv, LANES)
+    ppb, tq, _ = paged_tiling(b, t, hq, hkv, d, page_size, maxp, pk.dtype,
+                              v_width)
     nt = -(-t // tq)
     tpad = nt * tq - t
     # [B, tiles, Hkv, G*tq, D]: one grid step owns one (batch row, tile of
@@ -451,7 +512,8 @@ def _pallas_paged(q, pk, pv, block, q_positions, interpret, window=None):
     qmax = jnp.max(qpos, axis=2).reshape(-1)
     prefetch = (block.astype(jnp.int32).reshape(-1), qmax)
     kern = functools.partial(_paged_kernel, scale=scale,
-                             page_size=page_size, maxp=maxp)
+                             page_size=page_size, maxp=maxp,
+                             value_in_key=latent)
     if window is not None:
         # the ring is written up to the ROW's highest position, whatever
         # tile a query sits in
@@ -473,28 +535,27 @@ def _pallas_paged(q, pk, pv, block, q_positions, interpret, window=None):
                 pl.BlockSpec((None, None, rows, LANES),
                              lambda bi, ti, *prefetched: (bi, ti, 0, 0)),
                 pl.BlockSpec((None, None, hkv, rows, dpad), tile_idx),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((None, None, hkv, rows, dpad), tile_idx),
+            ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+            out_specs=pl.BlockSpec((None, None, hkv, rows, dvpad), tile_idx),
             scratch_shapes=[
-                pltpu.VMEM((2, ppb, hkv, page_size, dpad), pk.dtype),
-                pltpu.VMEM((2, ppb, hkv, page_size, dpad), pv.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((2, ppb, hkv, page_size, dpad), p.dtype)
+                for p in pools
+            ] + [
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.VMEM((hkv, rows, LANES), jnp.float32),
                 pltpu.VMEM((hkv, rows, LANES), jnp.float32),
-                pltpu.VMEM((hkv, rows, dpad), jnp.float32),
+                pltpu.VMEM((hkv, rows, dvpad), jnp.float32),
             ],
         ),
-        out_shape=_sds((b, nt, hkv, rows, dpad), q.dtype, q),
+        out_shape=_sds((b, nt, hkv, rows, dvpad), q.dtype, q),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-        name="fused_paged_attention",
-    )(*prefetch, qrows, qb, pk, pv)
-    o = (o[:, :, :, :g * tq].reshape(b, nt, hkv, g, tq, dpad)
-         .transpose(0, 1, 4, 2, 3, 5).reshape(b, nt * tq, hq, dpad))
-    return o[:, :t, :, :d]
+        name="latent_paged_attention" if latent else "fused_paged_attention",
+    )(*prefetch, qrows, qb, *pools)
+    o = (o[:, :, :, :g * tq].reshape(b, nt, hkv, g, tq, dvpad)
+         .transpose(0, 1, 4, 2, 3, 5).reshape(b, nt * tq, hq, dvpad))
+    return o[:, :t, :, :dv]
 
 
 # ---------------------------------------------------------------------------
@@ -561,6 +622,52 @@ def paged_decode_attention(q: jax.Array, pk: jax.Array, pv: jax.Array,
     return _pallas_paged(q, pk, pv, block, q_positions, interpret, window)
 
 
+def paged_latent_attention(q: jax.Array, pc: jax.Array, block: jax.Array,
+                           q_positions: jax.Array, *, v_width: int,
+                           scale: float, impl: Optional[str] = None,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """Per-row causal attention of ``q`` [B, T, H, W] directly over a LATENT
+    pool ``pc`` [P, page_size, W] (``LatentAttentionLayer.
+    init_paged_cache``) through the block table ``block`` [B, MAXP]:
+    multi-query attention of the ``H`` query rows over one key of width
+    ``W`` whose first ``v_width`` columns are also the value; returns
+    [B, T, H, v_width].  Scores are ``q . k * scale`` in float32, masked by
+    ``q_positions >= key position`` (which hides trash-page-0 entries and
+    unwritten pages as in ``paged_decode_attention``), and stay float32
+    through the online softmax.
+
+    The pool is PR 32's layout with one kv head (``[P, page, W]`` is
+    ``[P, 1, page, W]`` for free), so this is ``fused_paged_attention``'s
+    kernel with a group of ``H`` rows, no V pool (a page is copied once
+    and multiplied twice) and a block sized by its bytes
+    (``paged_tiling(v_width=)``); it is named ``latent_paged_attention``
+    in a trace.  ``impl``: None picks ``"pallas"`` on TPU and ``"lax"``
+    (the compiled page loop) elsewhere; the gather oracle is the layer's
+    own ``pool[block]`` + ``_absorbed``."""
+    b, t, h, w = q.shape
+    if pc.ndim != 3 or pc.shape[2] != w or not 0 < v_width <= w:
+        raise ValueError(
+            f"a latent pool must be [P, page_size, W={w}] with a value "
+            f"width in (0, W]; got pc {pc.shape}, v_width {v_width}")
+    if block.ndim != 2 or block.shape[0] != b or q_positions.shape != (b, t):
+        raise ValueError(
+            f"block table {block.shape} / q_positions {q_positions.shape} "
+            f"do not match q [B, T] = {(b, t)}")
+    pk = pc[:, None]
+    q = q.astype(pc.dtype)
+    if impl is None:
+        impl = default_impl()
+    if impl == "lax":
+        return _lax_paged(q, pk, None, block, q_positions, scale=scale,
+                          v_width=v_width)
+    if impl != "pallas":
+        raise ValueError(f"impl={impl!r} not one of pallas/lax")
+    if interpret is None:
+        interpret = _interpret()
+    return _pallas_paged(q, pk, None, block, q_positions, interpret,
+                         scale=float(scale), v_width=int(v_width))
+
+
 class PagedAttentionHelper:
     """Discovery-seam wrapper for the paged decode path (≙ the cuDNN
     helper SPI, like FlashAttentionHelper): ``SelfAttentionLayer.
@@ -569,7 +676,9 @@ class PagedAttentionHelper:
     unsupported.  Unlike the flash helper, the fused path is the
     DEFAULT on every backend — off TPU it routes to the compiled lax
     page-streaming fallback, not the Pallas interpreter, so CPU decode
-    gets the live-page watermark win too."""
+    gets the live-page watermark win too.  ``LatentAttentionLayer``'s
+    single-token step asks the same seam (``supports_latent`` /
+    ``attend_latent``) and falls back to its own gather + ``_absorbed``."""
 
     name = "PagedAttentionHelper"
 
@@ -580,3 +689,18 @@ class PagedAttentionHelper:
                window: Optional[int] = None) -> jax.Array:
         return paged_decode_attention(q, pk, pv, block, q_positions,
                                       window=window)
+
+    def supports_latent(self, width: int, page_size: int, dtype) -> bool:
+        """Whether a latent pool [P, page_size, width] of ``dtype`` can be
+        read in place: always by the lax page loop; by the compiled kernel
+        when a page is whole tiles (whole lanes wide, whole sublane tiles
+        long)."""
+        if paged_attention_mode() != "fused":
+            return False
+        return default_impl() != "pallas" or (
+            width % LANES == 0 and page_size % _sublanes(dtype) == 0)
+
+    def attend_latent(self, q, pc, block, q_positions, *, v_width: int,
+                      scale: float) -> jax.Array:
+        return paged_latent_attention(q, pc, block, q_positions,
+                                      v_width=v_width, scale=scale)

@@ -30,6 +30,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu import helpers
 from deeplearning4j_tpu.nn import initializers
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.attention import (
@@ -42,6 +43,26 @@ from deeplearning4j_tpu.nn.layers.normalization import rms_norm
 # behind a shared prefix): the scores held are [B, H, ROWS, context]
 ABSORBED_ROWS = 128
 LANES = 128
+
+LATENT_PATHS = ("paged", "gathered", "expanded")
+
+
+def latent_path(t: int, from_zero: bool, kernel: bool = True) -> str:
+    """Which of ``LATENT_PATHS`` a paged call of ``t`` query positions a
+    row takes (``apply_with_carry``).  A single token (``t == 1``, a decode
+    step) attends the absorbed way: ``"paged"`` — one kernel reads the
+    rows' live latent pages where they lie — when the helper seam offers it
+    (``kernel``), else ``"gathered"``, the absorbed way over
+    ``pool[block]``, every page of every row's table.  A chunk goes
+    ``"expanded"`` (flash attention over its own decompressed keys) when
+    every row starts at position 0 (``from_zero``: the program branches on
+    it on the device, the host knows it as a request with nothing shared),
+    else ``"gathered"`` (a suffix behind a shared prefix).  Pure: the layer
+    calls it while it is traced, the engine on the host to count
+    ``dl4j_latent_attention_steps_total``."""
+    if t == 1:
+        return "paged" if kernel else "gathered"
+    return "expanded" if from_zero else "gathered"
 
 
 @register_layer
@@ -164,14 +185,22 @@ class LatentAttentionLayer(Layer):
         with jax.named_scope("attention_core"):
             helper = None
             if q.dtype != jnp.float64:
-                from deeplearning4j_tpu.helpers import get_helper
-
-                helper = get_helper("attention")
+                helper = helpers.get_helper("attention")
             if helper is not None and helper.supports(t, q.shape[3]):
                 return helper.attend(q, k, v, causal=True,
                                      scale=self.softmax_scale)
             return dot_product_attention(q, k, v, causal=True,
                                          scale=self.softmax_scale)
+
+    def _absorbed_query(self, wk, q_nope, q_rope, width):
+        """The query against latent rows of ``width`` columns, [B, T, H,
+        width]: ``W_kvb``'s key half folded in, the rotary part beside it,
+        zero over the pool's padding."""
+        q_lat = jnp.einsum("bthd,chd->bthc", q_nope, wk)
+        pad = width - self.kv_rank - self.rope_dim
+        return jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (pad,),
+                                      q_rope.dtype)], axis=-1)
 
     def _absorbed(self, params, q_nope, q_rope, context, q_positions):
         """Attention of ``q`` rows at per-row ``q_positions`` [B, T] over
@@ -180,11 +209,7 @@ class LatentAttentionLayer(Layer):
         [B, T, H, v_dim]."""
         wk, wv = self._kvb(params)
         acc = jnp.promote_types(q_nope.dtype, jnp.float32)
-        q_lat = jnp.einsum("bthd,chd->bthc", q_nope, wk)
-        pad = context.shape[-1] - self.kv_rank - self.rope_dim
-        q = jnp.concatenate(
-            [q_lat, q_rope, jnp.zeros(q_rope.shape[:-1] + (pad,),
-                                      q_rope.dtype)], axis=-1)
+        q = self._absorbed_query(wk, q_nope, q_rope, context.shape[-1])
         with jax.named_scope("attention_core"):
             # scores leave the product in f32, as the flash kernel holds
             # them: rounded to bf16 ahead of the softmax they were the
@@ -199,6 +224,33 @@ class LatentAttentionLayer(Layer):
             o_lat = jnp.einsum("bhtl,blc->bthc", w.astype(context.dtype),
                                context[..., :self.kv_rank])
         return jnp.einsum("bthc,chd->bthd", o_lat, wv)
+
+    def _absorbed_paged(self, params, q_nope, q_rope, pool, block,
+                        q_positions, helper):
+        """``_absorbed`` over the pages where they lie: the same query
+        against the latent pool through the block table, one kernel
+        (``helpers/paged_attention.py: paged_latent_attention``) in place
+        of the gathered view, its scores and its softmax; only the rows'
+        live blocks are read, each page once for keys and values."""
+        wk, wv = self._kvb(params)
+        q = self._absorbed_query(wk, q_nope, q_rope, pool.shape[-1])
+        with jax.named_scope("attention_core"):
+            o_lat = helper.attend_latent(
+                q, pool, block, q_positions, v_width=self.kv_rank,
+                scale=self.softmax_scale)
+        return jnp.einsum("bthc,chd->bthd", o_lat.astype(q.dtype), wv)
+
+    def path(self, t: int, from_zero: bool, page_size: int, dtype) -> str:
+        """``latent_path`` of a paged call of ``t`` query positions a row
+        on this layer as the process stands: with the kernel only if the
+        helper seam offers it (helpers enabled, the fused mode, not
+        float64) for a pool of these pages."""
+        helper = None
+        if jnp.dtype(dtype) != jnp.float64:
+            helper = helpers.get_helper("paged_attention")
+        return latent_path(t, from_zero, helper is not None
+                           and helper.supports_latent(
+                               self._pool_width, page_size, dtype))
 
     def _out(self, params, o):
         b, t = o.shape[:2]
@@ -275,7 +327,12 @@ class LatentAttentionLayer(Layer):
                 return self._absorbed(params, qn, qr, context, qpos)
 
             if t == 1:
-                o = over_pages(q_nope, q_rope, new_pos)
+                if self.path(1, False, ps, x.dtype) == "paged":
+                    o = self._absorbed_paged(
+                        params, q_nope, q_rope, pool, block, new_pos,
+                        helpers.get_helper("paged_attention"))
+                else:
+                    o = over_pages(q_nope, q_rope, new_pos)
             else:
                 rows = ABSORBED_ROWS if t % ABSORBED_ROWS == 0 else t
 

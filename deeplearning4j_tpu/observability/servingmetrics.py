@@ -224,6 +224,18 @@ class GenerationMetrics:
             "it (few rows: a decode step), ragged = rows sorted by expert "
             "through ragged_dot in blocks (a prefill bucket; any program "
             "where the kernel gives way)", labels=("stage", "path"))
+        self.latent_attention_steps = reg.counter(
+            "dl4j_latent_attention_steps_total",
+            "Dispatched decode steps (stage=decode) and prefills "
+            "(stage=prefill) of a net with latent attention layers, by how "
+            "the program attends over their pages "
+            "(nn.layers.latent_attention.latent_path): paged = one kernel "
+            "reads the rows' live latent pages where they lie (a decode "
+            "step), gathered = the absorbed way over pool[block], every "
+            "page of every row's table (a suffix behind a shared prefix; "
+            "a decode step where the kernel gives way), expanded = flash "
+            "attention over the chunk's own decompressed keys (a prompt "
+            "prefilled from position 0)", labels=("stage", "path"))
         self.mhc_row_sum_error = reg.gauge(
             "dl4j_mhc_row_sum_error",
             "Largest distance from 1 of a row sum or a column sum of H_res "

@@ -474,7 +474,11 @@ def test_a_model_whose_ring_the_manager_was_not_built_for_is_refused():
 # parent's, hash for hash, so the ``ragged`` path is the parent's own.
 # PR 37 (a kind scope on every layer; ``prefill_<bucket>`` names its module
 # ``jit_prefill_<bucket>``): scopes are metadata and leave the text alone;
-# with the parent's module name put back the prefills' text is the parent's
+# with the parent's module name put back the prefills' text is the parent's.
+# PR 38 (the latent pages read in place): kimi's ``decode`` takes the page
+# loop (the kernel on a TPU) for its single-token attention; with that
+# withheld from the seam (``supports_latent``) the gather + ``_absorbed``
+# fallback is the parent's text, hash for hash
 PARENT_PROGRAMS = {
     "starcoder2": {"prefill_16": "487447505f7cdceb",
                    "prefill_32": "b7803529ed6ac46d",
@@ -516,6 +520,8 @@ def test_accepted_nets_lower_to_the_programs_of_the_parent(family,
         pool.at[page, :, off].set(rows.astype(pool.dtype)))
     monkeypatch.setattr(GroupedExpertsHelper, "supports",
                         lambda self, *widths: False)
+    monkeypatch.setattr(pa.PagedAttentionHelper, "supports_latent",
+                        lambda self, *pool: False)
     progs = GenerationPrograms(_accepted_toy_net(family), slots=4,
                                pages_per_slot=6, page_size=8, num_pages=25,
                                prefill_buckets=(16, 32))

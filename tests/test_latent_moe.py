@@ -15,6 +15,7 @@ from deeplearning4j_tpu.generation.programs import GenerationPrograms
 from deeplearning4j_tpu.nn.layers import GatedMLP, RMSNorm
 from deeplearning4j_tpu.nn.layers.moe import counting
 from deeplearning4j_tpu.observability.metrics import MetricsRegistry
+from tests.test_paged_kernel import _in_mode
 
 # original_max_position_embeddings 16: the sequences below run past it, so
 # YaRN's blended frequencies and its softmax temperature are in every test
@@ -293,6 +294,131 @@ def test_engine_serves_the_toy_model_as_the_reference_and_counts_it():
     assert "moe_counters" in stages["admit"]
     assert "moe_counters" in stages["decode"]
     assert "moe_counters" not in eng.phases.as_dict()["phases"]
+
+
+# ------------------------------------- (d') the latent pages, read in place
+def test_engine_serves_the_same_tokens_in_place_and_through_the_gather():
+    """The toy model through the engine with the single-token attention
+    over the pages where they lie (here the lax page loop; the kernel on a
+    TPU) and under the oracle switch (``pool[block]`` + ``_absorbed``):
+    the same greedy tokens, joins and leaves included."""
+    rng = np.random.default_rng(2)
+    requests = [(rng.integers(0, 97, n).tolist(), m)
+                for n, m in ((21, 9), (7, 14), (30, 5), (12, 11), (3, 8))]
+
+    def serve():
+        eng, served = run_engine(toy_net()[0], requests)
+        progs = next(iter(eng._programs.values()))
+        return progs.latent_paths[("decode", False)], served
+
+    path, in_place = serve()
+    oracle_path, gathered = _in_mode("gather", serve)
+    assert path == ("paged",) and oracle_path == ("gathered",)
+    for a, b in zip(in_place, gathered):
+        assert a.tolist() == b.tolist()
+
+
+def test_the_engine_counts_each_dispatch_by_its_latent_path():
+    """``dl4j_latent_attention_steps_total``: ``paged`` for every decode
+    dispatch, ``expanded`` for a prompt prefilled whole, ``gathered`` for
+    a suffix behind a shared prefix — what the host rule says, which is
+    what the programs were traced to do."""
+    net, _ = toy_net()
+    eng = GenerationEngine(net, slots=4, page_size=8, max_context=48,
+                           prefill_buckets=(16, 32), prefix_cache=True,
+                           registry=MetricsRegistry()).start()
+    try:
+        rng = np.random.default_rng(3)
+        first = rng.integers(0, 97, 21).tolist()
+        served = [eng.submit(first, max_new_tokens=5).result(),
+                  eng.submit(rng.integers(0, 97, 9).tolist(),
+                             max_new_tokens=4).result()]
+        again = eng.submit(first[:18] + [5, 6], max_new_tokens=3)
+        served.append(again.result())
+    finally:
+        eng.stop()
+    assert [len(t) for t in served] == [5, 4, 3] and again.shared_len == 16
+    progs = next(iter(eng._programs.values()))
+    assert progs.latent_paths == {
+        ("decode", False): ("paged",), ("decode", True): ("paged",),
+        (16, True): ("expanded",), (16, False): ("gathered",),
+        (32, True): ("expanded",), (32, False): ("gathered",)}
+    reg = eng.metrics.registry
+
+    def count(stage, path):
+        return reg.get_value("dl4j_latent_attention_steps_total",
+                             stage=stage, path=path) or 0
+
+    dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
+                     for m in ("ahead", "sync"))
+    assert count("decode", "paged") == dispatched > 0
+    assert count("decode", "gathered") == count("decode", "expanded") == 0
+    assert count("prefill", "expanded") == 2
+    assert count("prefill", "gathered") == 1
+    assert count("prefill", "paged") == 0
+
+
+@pytest.mark.parametrize("mode", ["fused", "gather"])
+def test_the_traced_branch_is_the_one_the_host_rule_names(mode, monkeypatch):
+    """What ``LatentAttentionLayer.path`` says on the host is what
+    ``apply_with_carry`` traces: lowered for a TPU, a single-token call
+    holds the kernel exactly when the rule says ``paged``, and a chunk
+    never does."""
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+
+    monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    cfg = dict(TOY)
+    layer = attention_layer(cfg)
+    _, params = layer_leaves(cfg, 0, model_k2._ATTN, jnp.bfloat16)
+    ps = 16
+    pool = layer.init_paged_cache(9, ps, jnp.bfloat16)
+
+    def lowered(t):
+        carry = {**pool, "block": jnp.zeros((2, 4), jnp.int32),
+                 "pos": jnp.zeros((2,), jnp.int32)}
+        fn = jax.jit(lambda x, c: layer.apply_with_carry(params, {}, x, c))
+        with jax.enable_x64(False):
+            return fn.trace(jnp.zeros((2, t, cfg["hidden_size"]),
+                                      jnp.bfloat16), carry).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+    def run():
+        return (layer.path(1, False, ps, jnp.bfloat16), lowered(1),
+                lowered(16))
+
+    said, decode, chunk = run() if mode == "fused" else _in_mode(mode, run)
+    assert said == {"fused": "paged", "gather": "gathered"}[mode]
+    assert (decode.count('kernel_name = "latent_paged_attention"')
+            == (said == "paged"))
+    assert "latent_paged_attention" not in chunk
+
+
+def test_programs_log_the_latent_tiling_once(monkeypatch, caplog):
+    import logging
+
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+
+    progs = GenerationPrograms(toy_net()[0], slots=4, pages_per_slot=6,
+                               page_size=8, num_pages=25,
+                               prefill_buckets=(16, 32))
+
+    def lines():
+        caplog.clear()
+        with caplog.at_level(logging.INFO,
+                             logger="deeplearning4j_tpu.generation"):
+            progs._log_latent_tiling()
+        return [r.getMessage() for r in caplog.records]
+
+    assert lines() == []                      # CPU: the lax page loop
+    monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
+    said = lines()
+    assert len(said) == 1 and said[0].startswith(
+        "generation.decode: latent_paged_attention q [4, 1, 4, 128] over 6 "
+        "pages of 8, the value the first 32 columns: 6 pages a block")
+    assert GenerationPrograms(_kv_lm(), slots=2, pages_per_slot=4,
+                              page_size=4, num_pages=9,
+                              prefill_buckets=(8,)).latent_paths == {}
 
 
 def test_counting_leaves_out_padding_and_idle_rows():

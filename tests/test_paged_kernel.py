@@ -26,9 +26,9 @@ from deeplearning4j_tpu.helpers.fused_epilogue import (
     FusedEpilogueHelper, dropout_residual_norm,
 )
 from deeplearning4j_tpu.helpers.paged_attention import (
-    VMEM_BUDGET, PagedAttentionHelper, paged_attention_mode,
-    paged_decode_attention, paged_tiling, set_paged_attention_mode,
-    write_token_rows,
+    SLAB_BLOCK_BYTES, VMEM_BUDGET, PagedAttentionHelper,
+    paged_attention_mode, paged_decode_attention, paged_latent_attention,
+    paged_tiling, set_paged_attention_mode, write_token_rows,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     SelfAttentionLayer, gather_pages, paged_attention,
@@ -287,6 +287,174 @@ def test_grouped_experts_compiles_for_v5e(v5e_chip, t, d, hidden, count):
     assert not [c for c in copied if c.startswith(f"{count},")]
 
 
+# ------------------------------------------- the latent pool, read in place
+# the two served latent shapes: (slots, heads, pages a slot) of
+# xing.serve-reason and k2.serve-docqa; width 640, value 512, page 64
+LATENT_SHAPES = {"xing": (64, 32, 48), "k2": (48, 64, 72)}
+
+
+class _LatentSeam:
+    """``PagedAttentionHelper.attend_latent`` held to one implementation
+    (the seam picks by the backend: the lax loop here)."""
+
+    def __init__(self, impl):
+        self.impl = impl
+
+    def attend_latent(self, q, pc, block, q_positions, *, v_width, scale):
+        return paged_latent_attention(q, pc, block, q_positions,
+                                      v_width=v_width, scale=scale,
+                                      impl=self.impl, interpret=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [32, 64])
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+def test_latent_pages_read_in_place_match_gather_and_absorbed(impl, heads,
+                                                               dtype):
+    """``LatentAttentionLayer._absorbed_paged`` (the kernel interpreted,
+    and the lax page loop) against ``pool[block]`` + ``_absorbed`` at the
+    served widths (640 / 512, page 64, groups of 32 and 64 rows): rows at
+    position 0, at a block's last key, at the next block's first key, at
+    the end of the table, inside a block; dead entries point at trash page
+    0; the table (10 pages) is not whole blocks (8 pages of bf16, 4 of
+    f32)."""
+    from deeplearning4j_tpu.nn.layers.latent_attention import (
+        LatentAttentionLayer)
+
+    layer = LatentAttentionLayer(n_in=64, n_out=64, n_heads=heads, q_rank=16,
+                                 kv_rank=512, nope_dim=128, rope_dim=64,
+                                 v_dim=128)
+    ps, maxp, pages, w = 64, 10, 30, 640
+    ppb = paged_tiling(5, 1, heads, 1, w, ps, maxp, dtype, 512)[0]
+    assert maxp % ppb and ppb * ps * w * jnp.dtype(dtype).itemsize \
+        <= SLAB_BLOCK_BYTES
+    qlast = np.array([0, ppb * ps - 1, ppb * ps, maxp * ps - 1, 100])
+    b = len(qlast)
+    rng = np.random.default_rng(heads)
+    block = rng.integers(1, pages, size=(b, maxp))
+    for i in range(b):
+        block[i, qlast[i] // ps + 1:] = 0
+    pool = rng.standard_normal((pages, ps, w))
+    pool[..., 576:] = 0                       # the pool's padding columns
+    pool = jnp.asarray(pool, dtype)
+    params = {"Wkvb": jnp.asarray(
+        0.05 * rng.standard_normal((512, heads * 256)), dtype)}
+    q_nope = jnp.asarray(rng.standard_normal((b, 1, heads, 128)), dtype)
+    q_rope = jnp.asarray(rng.standard_normal((b, 1, heads, 64)), dtype)
+    block = jnp.asarray(block, jnp.int32)
+    qpos = jnp.asarray(qlast[:, None], jnp.int32)
+    want = layer._absorbed(params, q_nope, q_rope,
+                           pool[block].reshape(b, -1, w), qpos)
+    got = layer._absorbed_paged(params, q_nope, q_rope, pool, block, qpos,
+                                _LatentSeam(impl))
+    assert got.shape == want.shape == (b, 1, heads, 128)
+    assert got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               **_tolerance(want))
+
+
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+def test_latent_all_trash_row_is_finite(impl):
+    """An idle slot (position 0, every entry the trash page) attends to
+    the one key at position 0 and nothing else."""
+    rng = np.random.default_rng(5)
+    pool = jnp.asarray(rng.standard_normal((6, 8, 128)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((2, 1, 4, 128)), jnp.float32)
+    block = jnp.asarray([[0, 0, 0], [3, 4, 0]], jnp.int32)
+    got = paged_latent_attention(q, pool, block,
+                                 jnp.asarray([[0], [12]], jnp.int32),
+                                 v_width=32, scale=0.1, impl=impl,
+                                 interpret=True)
+    assert got.shape == (2, 1, 4, 32)
+    np.testing.assert_allclose(
+        np.asarray(got[0, 0]), np.broadcast_to(pool[0, 0, :32], (4, 32)),
+        rtol=1e-6)
+
+
+def test_latent_pool_of_another_shape_is_refused():
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="latent pool"):
+        paged_latent_attention(z((2, 1, 4, 128)), z((6, 8, 64)),
+                               z((2, 3), jnp.int32), z((2, 1), jnp.int32),
+                               v_width=32, scale=0.1)
+    with pytest.raises(ValueError, match="q_positions"):
+        paged_latent_attention(z((2, 1, 4, 128)), z((6, 8, 128)),
+                               z((2, 3), jnp.int32), z((2, 2), jnp.int32),
+                               v_width=32, scale=0.1)
+
+
+@pytest.mark.parametrize("cell", sorted(LATENT_SHAPES))
+def test_latent_tiling_sizes_a_block_by_its_bytes(cell):
+    """One kv head: ``BLOCK_KEYS`` keys are 160 kB, a fifth of a GQA
+    block, so the latent block is the pages ``SLAB_BLOCK_BYTES`` hold —
+    from the shapes alone, the same at both served shapes."""
+    b, h, maxp = LATENT_SHAPES[cell]
+    ppb, tq, vmem = paged_tiling(b, 1, h, 1, 640, 64, maxp, jnp.bfloat16,
+                                 512)
+    assert ppb == SLAB_BLOCK_BYTES // (64 * 640 * 2) and tq == 1
+    assert 128 < ppb * 64 and 2 * ppb * 64 * 640 * 2 < vmem <= VMEM_BUDGET
+    # never under BLOCK_KEYS keys, never more than the table has
+    assert paged_tiling(b, 1, h, 1, 640, 64, 2, jnp.bfloat16, 512)[0] == 2
+    assert paged_tiling(b, 1, h, 1, 4096, 64, maxp, jnp.float32,
+                        512)[0] == 2
+
+
+# (b, t, hq, hkv, page, maxp) -> (pages a block, row tile, VMEM bytes) as
+# the parent commit d0c4831 gives them: the GQA cells' calls (head 128,
+# bf16) must not move when the latent pool gets a block of its own
+GQA_TILINGS = {
+    (32, 1, 36, 4, 16, 36): (8, 1, 724992),      # sc2-7b.serve-complete
+    (1, 256, 36, 4, 16, 36): (8, 64, 7749632),
+    (1, 512, 36, 4, 16, 36): (8, 64, 7749632),
+    (32, 1, 36, 4, 16, 72): (8, 1, 724992),      # sc2-7b.serve-complete-1k
+    (1, 1024, 36, 4, 16, 72): (8, 64, 7749632),
+    (32, 1, 48, 8, 64, 136): (2, 1, 1413120),    # laguna: a full layer
+    (32, 1, 72, 8, 64, 9): (2, 1, 1413120),      # a ring of 9 pages
+    (1, 1024, 48, 8, 64, 136): (2, 32, 5423104),
+    (1, 8192, 48, 8, 64, 136): (2, 32, 5423104),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(GQA_TILINGS))
+def test_gqa_tilings_are_what_they_were(shape):
+    b, t, hq, hkv, ps, maxp = shape
+    assert paged_tiling(b, t, hq, hkv, 128, ps, maxp,
+                        jnp.bfloat16) == GQA_TILINGS[shape]
+
+
+def _latent_args(cell, sharding=None):
+    b, h, maxp = LATENT_SHAPES[cell]
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    return (sds((b, 1, h, 640), jnp.bfloat16),
+            sds((b * maxp + 1, 64, 640), jnp.bfloat16),
+            sds((b, maxp), jnp.int32), sds((b, 1), jnp.int32))
+
+
+_latent_call = jax.jit(lambda *a: paged_latent_attention(
+    *a, v_width=512, scale=0.0722, impl="pallas", interpret=False))
+
+
+@pytest.mark.parametrize("cell", sorted(LATENT_SHAPES))
+def test_latent_kernel_lowers_for_tpu_at_serving_shapes(cell):
+    with jax.enable_x64(False):
+        text = _latent_call.trace(*_latent_args(cell)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count('kernel_name = "latent_paged_attention"') == 1
+    assert "fused_paged_attention" not in text
+
+
+@pytest.mark.parametrize("cell", sorted(LATENT_SHAPES))
+def test_latent_kernel_compiles_for_v5e(v5e_chip, cell):
+    """The two served latent shapes through the chip's compiler: the
+    lane-aligned slice of the key slab that is the value, 0.66 MB copy
+    sets in two slots, the VMEM the kernel takes."""
+    with jax.enable_x64(False):
+        compiled = _latent_call.lower(*_latent_args(cell, v5e_chip)).compile()
+    assert "latent_paged_attention" in compiled.as_text()
+
+
 @pytest.mark.parametrize("n", [4, 40])   # a decode step's rows, a chunk
 def test_rows_of_a_table_write_what_the_slab_scatter_writes(n):
     """``write_token_rows`` against ``pool.at[page, :, off].set``, trash
@@ -324,6 +492,41 @@ def test_mode_toggle_and_helper_gating():
         set_paged_attention_mode("fused")
     with pytest.raises(ValueError):
         set_paged_attention_mode("einsum")
+
+
+def test_latent_path_follows_the_seam_and_the_dtype():
+    """``LatentAttentionLayer.path``: the kernel for a single token while
+    the seam offers it; the gather under the oracle switch, with helpers
+    disabled, in float64, and on a TPU for a page the dtype cannot tile;
+    a chunk never takes it."""
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+    from deeplearning4j_tpu.nn.layers.latent_attention import (
+        LATENT_PATHS, LatentAttentionLayer, latent_path)
+
+    layer = LatentAttentionLayer(n_in=8, n_heads=2, q_rank=4, kv_rank=8,
+                                 nope_dim=4, rope_dim=2, v_dim=4)
+    assert layer.path(1, False, 8, jnp.float32) == "paged"
+    assert layer.path(1, False, 8, jnp.bfloat16) == "paged"
+    assert layer.path(1, False, 8, jnp.float64) == "gathered"
+    assert _in_mode("gather", lambda: layer.path(
+        1, False, 8, jnp.float32)) == "gathered"
+    helpers.enable_helpers(False)
+    try:
+        assert layer.path(1, False, 8, jnp.float32) == "gathered"
+    finally:
+        helpers.enable_helpers(True)
+    assert layer.path(16, True, 8, jnp.float32) == "expanded"
+    assert layer.path(16, False, 8, jnp.float32) == "gathered"
+    assert {latent_path(t, z, k) for t in (1, 16) for z in (False, True)
+            for k in (False, True)} == set(LATENT_PATHS)
+    seam = PagedAttentionHelper()
+    assert seam.supports_latent(128, 8, jnp.bfloat16)      # the lax loop
+    orig, pa.default_impl = pa.default_impl, lambda: "pallas"
+    try:
+        assert not seam.supports_latent(128, 8, jnp.bfloat16)
+        assert seam.supports_latent(640, 64, jnp.bfloat16)
+    finally:
+        pa.default_impl = orig
 
 
 def test_lax_fallback_zero_recompiles_across_fill_levels():

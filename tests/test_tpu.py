@@ -407,6 +407,49 @@ def test_paged_attention_compiled_parity(name, b, t, hq, hkv):
     np.testing.assert_allclose(out32, ref32, rtol=0, atol=_bf16_atol(ref32))
 
 
+@pytest.mark.parametrize("heads,maxp", [(32, 48), (64, 72)])
+def test_latent_pages_compiled_parity(heads, maxp):
+    """``latent_paged_attention`` compiled against ``pool[block]`` +
+    ``_absorbed`` at the two served latent shapes (width 640, value 512,
+    page 64, bf16): rows at position 0, at a block's last and the next
+    block's first key, at the table's end, and drawn."""
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+    from deeplearning4j_tpu.nn.layers.latent_attention import (
+        LatentAttentionLayer)
+
+    assert not pa._interpret(), "must compile for real on TPU"
+    layer = LatentAttentionLayer(n_in=64, n_out=64, n_heads=heads, q_rank=16,
+                                 kv_rank=512, nope_dim=128, rope_dim=64,
+                                 v_dim=128)
+    b, ps, w = 16, 64, 640
+    ppb = pa.paged_tiling(b, 1, heads, 1, w, ps, maxp, jnp.bfloat16, 512)[0]
+    rs = np.random.RandomState(38)
+    qlast = rs.randint(0, maxp * ps, size=(b,))
+    qlast[:4] = [0, ppb * ps - 1, ppb * ps, maxp * ps - 1]
+    block = 1 + rs.permutation(b * maxp).reshape(b, maxp)
+    for i in range(b):
+        block[i, qlast[i] // ps + 1:] = 0
+    pool = rs.randn(b * maxp + 1, ps, w)
+    pool[..., 576:] = 0
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    params = {"Wkvb": jnp.asarray(0.05 * rs.randn(512, heads * 256),
+                                  jnp.bfloat16)}
+    q_nope = jnp.asarray(rs.randn(b, 1, heads, 128), jnp.bfloat16)
+    q_rope = jnp.asarray(rs.randn(b, 1, heads, 64), jnp.bfloat16)
+    block = jnp.asarray(block, jnp.int32)
+    qpos = jnp.asarray(qlast[:, None], jnp.int32)
+    helper = pa.PagedAttentionHelper()
+    assert helper.supports_latent(w, ps, jnp.bfloat16)
+    out = jax.jit(lambda *a: layer._absorbed_paged(*a, helper))(
+        params, q_nope, q_rope, pool, block, qpos)
+    ref = jax.jit(lambda p, qn, qr, pc, blk, pos: layer._absorbed(
+        p, qn, qr, pc[blk].reshape(b, -1, w), pos))(
+            params, q_nope, q_rope, pool, block, qpos)
+    out32, ref32 = (np.asarray(a.astype(jnp.float32)) for a in (out, ref))
+    assert np.all(np.isfinite(out32))
+    np.testing.assert_allclose(out32, ref32, rtol=0, atol=_bf16_atol(ref32))
+
+
 def test_paged_attention_rejects_untileable_page_size():
     """A bf16 page needs 16 rows to fill a tile; 8 must fail loudly at
     trace time, not at the first request."""
