@@ -73,7 +73,8 @@ from deeplearning4j_tpu.generation.prefix_cache import (
     PrefixCache, PrefixCacheConfig,
 )
 from deeplearning4j_tpu.generation.programs import (
-    GenerationPrograms, sampled_ids, window_pool_pages, window_ring_pages,
+    GenerationPrograms, has_state_pools, sampled_ids, window_pool_pages,
+    window_ring_pages,
 )
 from deeplearning4j_tpu.generation.scheduler import (
     DecodeScheduler, GenerationRequest,
@@ -152,7 +153,17 @@ class GenerationEngine:
         # pages by layer kind, learnt from the layers as the pools are: a
         # net with sliding-window attention layers holds a ring of
         # ceil(window / page) + 1 pages a slot in pools of their own kind
-        ring = self._window_ring(model, page_size)
+        served = self._served_net(model)
+        ring = window_ring_pages(served, page_size) if served else 0
+        # state slots, learnt the same way: a net with recurrent layers
+        # holds one row of state a slot, begun anew at admission
+        state = bool(served) and has_state_pools(served)
+        if state and prefix_cache:
+            raise ValueError(
+                "prefix_cache cannot serve a net with recurrent state "
+                "layers: a cached page skips its prefill, and the state "
+                "that prefill would have built does not exist (in-flight "
+                "prefix sharing is off for such a net for the same reason)")
         if ring and prefix_cache:
             raise ValueError(
                 "prefix_cache cannot serve a net with sliding-window "
@@ -161,7 +172,8 @@ class GenerationEngine:
                 "prefix sharing is off for such a net for the same reason)")
         self.cache = PagedKVCache(
             num_pages, page_size, pages_per_slot, window_pages_per_slot=ring,
-            num_window_pages=window_pool_pages(slots, ring))
+            num_window_pages=window_pool_pages(slots, ring),
+            state_slots=state)
         # persistent radix-tree prefix cache (opt-in retention policy):
         # prefix_cache=True for defaults, a PrefixCacheConfig for knobs,
         # None/False keeps PR-13 free-on-release behavior bit-identical
@@ -202,16 +214,15 @@ class GenerationEngine:
         # being written; 0 disables and changes nothing.
         self.decode_step_floor_s = float(decode_step_floor_s)
 
-    def _window_ring(self, model, page_size: int) -> int:
-        """The ring of the net this engine is built around (the one
-        given, or the registry's active default): 0 without window
-        layers, or when no model is registered yet."""
-        if model is None:
-            try:
-                model = self.models.active(self.default_model).model
-            except ModelNotFoundError:
-                return 0
-        return window_ring_pages(model, page_size)
+    def _served_net(self, model):
+        """The net this engine is built around (the one given, or the
+        registry's active default); None when no model is registered yet."""
+        if model is not None:
+            return model
+        try:
+            return self.models.active(self.default_model).model
+        except ModelNotFoundError:
+            return None
 
     # ------------------------------------------------------------- lifecycle
     def start(self) -> "GenerationEngine":
@@ -425,6 +436,13 @@ class GenerationEngine:
                 f"{progs.ring} pages a slot, the page manager was built "
                 f"for {self.cache.window_pages_per_slot} (the engine "
                 "learns the ring from the model it is constructed with)")
+        if progs.state != self.cache.state_slots:
+            raise ValueError(
+                f"cannot serve {mv.key}: it "
+                f"{'keeps' if progs.state else 'keeps no'} recurrent state "
+                "in state slots, the page manager was built for a net that "
+                f"{'does' if self.cache.state_slots else 'does not'} (the "
+                "engine learns it from the model it is constructed with)")
         if self._pools is not None:
             live = jax.tree_util.tree_map(
                 lambda a: (a.shape, str(a.dtype)), self._pools)
@@ -564,8 +582,9 @@ class GenerationEngine:
         phase = self.phases.phase
         # a window layer's chunk is written into its ring as a prompt
         # prefilled whole from position 0 (_apply_window_paged)
-        assert not (progs.ring and req.shared_len), (
-            "a prefix was shared under window layers")
+        # and a state layer's slot begins anew at position 0
+        assert not ((progs.ring or progs.state) and req.shared_len), (
+            "a prefix was shared under window or state layers")
         with phase("page_gather", stage="admit"):
             suffix = req.prompt[req.shared_len:]
             bucket = progs.bucket_for(len(suffix))
@@ -597,6 +616,10 @@ class GenerationEngine:
         for path in progs.latent_paths.get((bucket, req.shared_len == 0), ()):
             self.metrics.latent_attention_steps.inc(stage="prefill",
                                                     path=path)
+        for path in progs.state_space_paths.get(bucket, ()):
+            self.metrics.state_space_steps.inc(stage="prefill", path=path)
+        if progs.state:
+            self.metrics.state_slot_resets.inc()
         self._firsts.append((req, tok, mv.name,
                              SAMPLING_PATHS[sampling_path(*policy)]))
 
@@ -642,6 +665,8 @@ class GenerationEngine:
             self.metrics.moe_expert_steps.inc(stage="decode", path=path)
         for path in progs.latent_paths.get(("decode", False), ()):
             self.metrics.latent_attention_steps.inc(stage="decode", path=path)
+        for path in progs.state_space_paths.get("decode", ()):
+            self.metrics.state_space_steps.inc(stage="decode", path=path)
         return _Step(sampled, rows, mv.name,
                      SAMPLING_PATHS[sampling_path(*policy[2:])])
 
@@ -702,7 +727,10 @@ class GenerationEngine:
                                                       expert=str(expert))
 
     def _refresh_gauges(self) -> None:
-        self.metrics.active_slots.set(len(self.scheduler.active_slots()))
+        active = len(self.scheduler.active_slots())
+        self.metrics.active_slots.set(active)
+        if self.cache.state_slots:
+            self.metrics.state_slots_in_use.set(active)
         self.metrics.page_util.set(self.cache.utilization())
         for kind in self.cache.KINDS:
             if self.cache.pages_total(kind):

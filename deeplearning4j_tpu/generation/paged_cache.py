@@ -34,6 +34,14 @@ layers: a shared page skips its prefill, which would leave the sharer's
 ring unfilled (the persistent ``PrefixCache`` is refused at engine set-up
 for the same reason).
 
+**State slots.**  A net with recurrent layers (``MambaLayer``,
+``GravesLSTM``) keeps a third kind of pool, one ROW of state a slot
+(``generation/programs.py``).  It needs no allocator here — a request's row
+is its slot's, held for its life — but it turns prefix sharing off
+(``state_slots=True``) as window layers do, and for the same reason: a
+shared page skips its prefill, and the state that prefill would have built
+does not exist.
+
 Page 0 is reserved as the TRASH page: unallocated block-table entries
 point at it, so bucket-padding positions and idle decode slots scatter
 their garbage somewhere harmless that no causal mask ever lets a real
@@ -78,12 +86,14 @@ class PagedKVCache:
     context ceiling is ``pages_per_slot * page_size``).
     ``window_pages_per_slot`` (0: the net has no window layer) is the ring
     a request holds of the window kind, ``num_window_pages`` that kind's
-    pool, its trash page included."""
+    pool, its trash page included.  ``state_slots``: the net keeps recurrent
+    state a slot, so nothing is shared in flight."""
 
     KINDS = ("global", "window")
 
     def __init__(self, num_pages: int, page_size: int, pages_per_slot: int,
-                 window_pages_per_slot: int = 0, num_window_pages: int = 0):
+                 window_pages_per_slot: int = 0, num_window_pages: int = 0,
+                 state_slots: bool = False):
         if num_pages < 2:
             raise ValueError(f"num_pages={num_pages} must be >= 2 "
                              "(page 0 is the reserved trash page)")
@@ -94,6 +104,7 @@ class PagedKVCache:
         self.pages_per_slot = int(pages_per_slot)
         self.window_pages_per_slot = int(window_pages_per_slot)
         self.num_window_pages = int(num_window_pages)
+        self.state_slots = bool(state_slots)
         if self.window_pages_per_slot and self.num_window_pages < 2:
             raise ValueError(
                 f"num_window_pages={num_window_pages} must be >= 2 with "
@@ -191,8 +202,9 @@ class PagedKVCache:
         # the first sample and are not cached with the pages)
         shared: List[int] = []
         key: Optional[bytes] = None
-        # under window layers nothing is shared or indexed (module docstring)
-        sharing = not self.window_pages_per_slot
+        # under window or state layers nothing is shared or indexed (module
+        # docstring)
+        sharing = not (self.window_pages_per_slot or self.state_slots)
         max_share = min(len(self._full_prompt_pages(prompt)),
                         (len(prompt) - 1) // self.page_size) * sharing
         for i in range(max_share):

@@ -25,11 +25,19 @@ next program without having seen a value (``generation/engine.py``).
 A POOL is whatever page-major arrays a layer's ``init_paged_cache``
 returns: ``pk``/``pv`` [P, Hkv, page, D] for ``SelfAttentionLayer``
 (``wk``/``wv`` [Pw, Hkv, page, D], the WINDOW kind, where it has a
-``window``), one latent ``pc`` [P, page, W] for ``LatentAttentionLayer``.
-Every function here reaches them through one walker, ``map_pools``.
-Under window layers the dispatch's block table is two tables side by side
-(``PagedKVCache.table_width``): ``_attach`` hands a window pool the ring
-columns, every other pool the global ones.
+``window``), one latent ``pc`` [P, page, W] for ``LatentAttentionLayer``,
+and the STATE kind, ``sh``/``sc`` [slots + 1, ...] — one row a slot, not
+pages — for a layer that carries recurrent state (``MambaLayer``,
+``GravesLSTM``).  Every function here reaches them through one walker,
+``map_pools``.  Under window layers the dispatch's block table is two tables
+side by side (``PagedKVCache.table_width``): ``_attach`` hands a window pool
+the ring columns, every other paged pool the global ones; a state pool gets
+no table but a prefill's SLOT ROW (``slot + 1``) or, in the decode step,
+whose lane ``i`` owns row ``i + 1``, the mask of the lanes that run a
+request (an idle lane moves no state).  The page transport (``read_page`` /
+``write_page`` / ``page_nbytes``) SKIPS the state kind: a page is a span of
+positions, a state row is a whole prefix, and no page boundary has a state
+of its own (``docs/serving.md``, "State slots beside pages").
 
 Shapes are closed by construction (slot count, pool size, block-table
 width, bucket lengths are all fixed at engine construction), so steady
@@ -64,6 +72,7 @@ from deeplearning4j_tpu.models.decode import (
 from deeplearning4j_tpu.nn.layers.composite import gauging
 from deeplearning4j_tpu.nn.layers.latent_attention import LatentAttentionLayer
 from deeplearning4j_tpu.nn.layers.moe import RoutedMoELayer, counting
+from deeplearning4j_tpu.nn.layers.state_space import MambaLayer
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
 
@@ -86,17 +95,22 @@ def named_layers_of(net) -> List[Tuple[str, object]]:
             if net.nodes[n].layer is not None]
 
 
-def _layers_of_kind(net, kind):
-    """Every layer of class ``kind`` in ``net``, those inside composite
-    layers too."""
+def _layers_where(net, wanted):
+    """Every layer of ``net`` that ``wanted(layer)`` holds of, those inside
+    composite layers too."""
 
     def walk(layer):
-        if isinstance(layer, kind):
+        if wanted(layer):
             yield layer
         for sub in getattr(layer, "layers", ()):
             yield from walk(sub)
 
     return [a for _, l in named_layers_of(net) for a in walk(l)]
+
+
+def _layers_of_kind(net, kind):
+    """Every layer of class ``kind`` in ``net``."""
+    return _layers_where(net, lambda l: isinstance(l, kind))
 
 
 def _self_attention_layers(net):
@@ -120,6 +134,14 @@ def window_ring_pages(net, page_size: int) -> int:
                 for a in _self_attention_layers(net)), default=0)
 
 
+def has_state_pools(net) -> bool:
+    """Whether a layer of ``net`` keeps its streaming state in STATE SLOTS
+    (``holds_state_slots`` on its class: one row a slot, begun anew at
+    admission)."""
+    return bool(_layers_where(
+        net, lambda l: getattr(l, "holds_state_slots", False)))
+
+
 def window_pool_pages(slots: int, ring: int) -> int:
     """Pages of the window kind's pools: every slot's ring and the trash
     page; 0 for a net without window layers."""
@@ -127,29 +149,34 @@ def window_pool_pages(slots: int, ring: int) -> int:
 
 
 def seed_paged_pools(net, num_pages: int, page_size: int,
-                     dtype=None, window_pages: Optional[int] = None) -> Dict:
-    """Paged pools for every pageable layer of ``net`` (the paged
-    analog of ``models.common.seed_stream_caches``).  Raises when the
-    net carries state that cannot be paged (recurrent hidden state) —
-    the engine must fail at setup, not serve wrong tokens.
-    ``window_pages`` sizes the pools of window layers."""
+                     dtype=None, window_pages: Optional[int] = None,
+                     state_slots: Optional[int] = None) -> Dict:
+    """The pools of every layer of ``net`` that keeps state while streaming
+    (the paged analog of ``models.common.seed_stream_caches``).  Raises on a
+    layer whose carry the engine cannot address — the engine must fail at
+    set-up, not serve wrong tokens.  ``window_pages`` sizes the pools of
+    window layers, ``state_slots`` those of state layers."""
     cache_dtype = (jnp.dtype(dtype) if dtype else jnp.float32)
     pools = {}
     for name, layer in named_layers_of(net):
         if hasattr(layer, "init_paged_cache"):
             c = layer.init_paged_cache(num_pages, page_size, cache_dtype,
-                                       window_pages=window_pages)
+                                       window_pages=window_pages,
+                                       state_slots=state_slots)
             if c is not None:
                 pools[name] = c
         elif hasattr(layer, "apply_with_carry"):
             raise ValueError(
-                f"layer '{name}' ({type(layer).__name__}) carries "
-                "non-pageable state; the generation engine only serves "
-                "attention-cached (transformer) stacks")
+                f"layer '{name}' ({type(layer).__name__}) takes a carry "
+                "(apply_with_carry) but has no init_paged_cache; the "
+                "generation engine serves a layer that keeps state only "
+                "through pools it can address: pages by a block table, or "
+                "state slots by a request's slot")
     if not pools:
         raise ValueError(
-            "no pageable attention layers found — the generation engine "
-            "needs at least one causal attention layer with a paged cache")
+            "no layer with a paged cache or state slots found — the "
+            "generation engine needs at least one causal attention or "
+            "recurrent layer with init_paged_cache")
     return pools
 
 
@@ -169,25 +196,49 @@ def map_pools(fn, pools, *others):
     return {k: walk(v, *(x[k] for x in others)) for k, v in pools.items()}
 
 
-def _attach(pools, block, pos, maxp=None, live=None):
+def _attach(pools, block, pos, maxp=None, live=None, rows=None, lanes=None):
     """Insert the dispatch's block table / positions beside every pool's
     arrays (the pool pytree holds the arrays alone between dispatches).
     ``maxp`` (a net with window layers): ``block`` holds the global table in
     its first ``maxp`` columns and the ring table after them, and each pool
     gets the one that addresses it; a window pool (``pool_kind``) also gets
-    ``live`` [B], the chunk's real tokens, where the dispatch has padding."""
-    if maxp is None:
+    ``live`` [B], the chunk's real tokens, where the dispatch has padding.
+    A net with state layers: a state pool gets, in place of a table, the
+    dispatch's slot ``rows`` [B] (a prefill's one row, ``slot + 1``) or its
+    ``lanes`` [B] bool (the decode step, whose lane ``i`` owns row ``i + 1``:
+    True where the lane runs a request), with ``pos`` and ``live``."""
+    if maxp is None and rows is None and lanes is None:
         return map_pools(lambda c: {**c, "block": block, "pos": pos}, pools)
-    tables = block[:, :maxp], block[:, maxp:]
+    tables = (block, block) if maxp is None else (block[:, :maxp],
+                                                  block[:, maxp:])
 
     def attach(c):
-        ring = pool_kind(c) == "window"
-        out = {**c, "block": tables[ring], "pos": pos}
-        if ring and live is not None:
+        kind = pool_kind(c)
+        if kind == "state":
+            out = {**c, "pos": pos, **({"rows": rows} if lanes is None
+                                       else {"lanes": lanes})}
+        else:
+            out = {**c, "block": tables[kind == "window"], "pos": pos}
+        if kind != "global" and live is not None:
             out["live"] = live
         return out
 
     return map_pools(attach, pools)
+
+
+def _overlay(whole, part):
+    """``whole`` with ``part``'s entries in place of its own."""
+    return {k: ((_overlay(v, part[k]) if isinstance(v, dict) else part[k])
+                if k in part else v) for k, v in whole.items()}
+
+
+def _paged_only(pools):
+    """``pools`` without the state kind: what the page transport moves."""
+    def prune(c):
+        if not any(isinstance(v, dict) for v in c.values()):
+            return {} if pool_kind(c) == "state" else c
+        return {k: p for k, v in c.items() if (p := prune(v))}
+    return prune(pools)
 
 
 def _strip(carries, pools):
@@ -271,9 +322,20 @@ class GenerationPrograms:
             for name, t in {**rows, "decode": 1}.items()
             for zero in (False, True)} if self.latent_layers else {}
         self.num_window_pages = window_pool_pages(self.slots, self.ring)
-        # validate pageability eagerly (raises on recurrent stacks)
-        seed_paged_pools(net, 2, page_size, net.conf.compute_dtype,
-                         window_pages=2)
+        # state slots: whether a layer keeps one row of state a slot, and
+        # how each compute program runs its state-space layers' recurrence
+        # (``MambaLayer.path`` of its positions a row, the rule the layer
+        # branches on when the program is traced), by ``"decode"`` / bucket
+        self.state = has_state_pools(net)
+        self.state_space_layers = _layers_of_kind(net, MambaLayer)
+        self.state_space_paths = {
+            name: tuple(sorted({l.path(t) for l in self.state_space_layers}))
+            for name, t in {**rows, "decode": 1}.items()
+        } if self.state_space_layers else {}
+        # validate eagerly (raises on a carry no pool can hold)
+        jax.eval_shape(lambda: seed_paged_pools(
+            net, 2, page_size, net.conf.compute_dtype, window_pages=2,
+            state_slots=1))
         self._decode = jax.jit(self._make_decode(), donate_argnums=(2,))
         self._prefill = {
             b: jax.jit(self._make_prefill(b), donate_argnums=(2,))
@@ -334,7 +396,8 @@ class GenerationPrograms:
     def fresh_pools(self):
         return seed_paged_pools(self.net, self.num_pages, self.page_size,
                                 self.net.conf.compute_dtype,
-                                window_pages=self.num_window_pages)
+                                window_pages=self.num_window_pages,
+                                state_slots=self.slots)
 
     def bucket_for(self, length: int) -> int:
         for b in self.prefill_buckets:
@@ -353,6 +416,7 @@ class GenerationPrograms:
     def _make_decode(self):
         fwd, encode = self._fwd, self._encode
         maxp = self.pages_per_slot if self.ring else None
+        state = self.state
 
         def decode_step(params, net_state, pools, block, pos, tokens,
                         keys, token_idx, temps, top_ks, top_ps):
@@ -363,9 +427,11 @@ class GenerationPrograms:
             def real():      # an idle slot's table points at the trash page
                 return block[:, :1] != TRASH_PAGE
 
+            # a lane's state row is its slot's; an idle lane moves none
+            lanes = real()[:, 0] if state else None
             with counting(real) as counts, gauging(real) as gauges:
                 pre, nc = fwd(params, net_state, x,
-                              _attach(pools, block, pos, maxp))
+                              _attach(pools, block, pos, maxp, lanes=lanes))
             with jax.named_scope("sample"):
                 logits = pre[:, -1].astype(jnp.float32)
                 nxt = sample_tokens(logits, keys, token_idx, temps, top_ks,
@@ -378,6 +444,7 @@ class GenerationPrograms:
     def _make_prefill(self, bucket: int):
         fwd, encode = self._fwd, self._encode
         maxp = self.pages_per_slot if self.ring else None
+        state = self.state
 
         def prefill(params, net_state, pools, block, start, last_idx,
                     tokens, keys, token_idx, temps, top_ks, top_ps, ids,
@@ -397,7 +464,8 @@ class GenerationPrograms:
             with counting(real) as counts, gauging(real) as gauges:
                 pre, nc = fwd(params, net_state, x,
                               _attach(pools, block, start, maxp,
-                                      (last_idx + 1)[None]))
+                                      (last_idx + 1)[None],
+                                      (slot + 1)[None] if state else None))
             with jax.named_scope("sample"):
                 logits = jnp.take(pre[0], last_idx, axis=0)[None]
                 tok = sample_tokens(logits.astype(jnp.float32), keys,
@@ -413,24 +481,26 @@ class GenerationPrograms:
 
     def _make_read_page(self):
         def read_page(pools, page):
-            """One page's slice of every pool's arrays (the offload side
-            of the host tier)."""
+            """One page's slice of every paged pool's arrays (the offload
+            side of the host tier; state pools have no pages)."""
             return map_pools(
                 lambda c: {k: jax.lax.dynamic_index_in_dim(
                     a, page, 0, keepdims=False) for k, a in c.items()},
-                pools)
+                _paged_only(pools))
 
         return read_page
 
     def _make_write_page(self):
         def write_page(pools, page, payload):
-            """One page's slices back into every pool (the restore side);
-            pools are donated, so the write is in place."""
-            return map_pools(
+            """One page's slices back into every paged pool (the restore
+            side); pools are donated, so the write is in place, and a state
+            pool goes back as it came."""
+            written = map_pools(
                 lambda c, p: {k: jax.lax.dynamic_update_index_in_dim(
                     a, p[k].astype(a.dtype), page, 0)
                     for k, a in c.items()},
-                pools, payload)
+                _paged_only(pools), payload)
+            return _overlay(pools, written)
 
         return write_page
 
@@ -473,7 +543,7 @@ class GenerationPrograms:
         """Host bytes one offloaded page costs (one page of every pool
         array) — the unit of the prefix cache's host-tier budget."""
         return sum(a.nbytes // a.shape[0]
-                   for a in jax.tree_util.tree_leaves(pools))
+                   for a in jax.tree_util.tree_leaves(_paged_only(pools)))
 
     # --------------------------------------------------------------- warmup
     def _compute_programs(self) -> Dict[str, Tuple]:
@@ -547,6 +617,27 @@ class GenerationPrograms:
                 self.page_size, v, ppb,
                 ppb * self.page_size * w * dtype.itemsize / 2 ** 20,
                 self.slots, vmem / 2 ** 20)
+
+    def _log_state_space(self) -> None:
+        """How each compute program runs its state-space layers' recurrence
+        and what a slot's state weighs, once a program and shape."""
+        from deeplearning4j_tpu.helpers import get_helper
+
+        helper = get_helper("selective_scan")
+        shapes = sorted({(l.d_inner, l.d_state, l.d_conv)
+                         for l in self.state_space_layers})
+        for name, paths in self.state_space_paths.items():
+            t = 1 if name == "decode" else name
+            how = ("one pass over the rows' states" if t == 1
+                   else helper.describe(t) if helper is not None
+                   else f"lax scan, {t} trips of 1 time step")
+            for d, n, k in shapes:
+                logger.info(
+                    "generation.%s: state-space layers of %d channels x %d "
+                    "state columns (%s): %s; %d state slots + the trash row, "
+                    "%.1f kB of float32 state and a tail of %d rows a slot a "
+                    "layer", name if t == 1 else f"prefill_{name}", d, n,
+                    "/".join(paths), how, self.slots, d * n * 4 / 1e3, k - 1)
 
     def _log_expert_tiling(self) -> None:
         """How ``grouped_experts`` tiles each compute program whose expert
@@ -633,6 +724,7 @@ class GenerationPrograms:
         self._log_paged_tiling()
         self._log_latent_tiling()
         self._log_expert_tiling()
+        self._log_state_space()
         coll = shardstats.active_collector()
         if coll is not None:
             # census at the exact warmup signatures; lower-only, so the

@@ -162,14 +162,21 @@ def _check_shapes(q, pk, pv, block, q_positions):
 # leaves of a WINDOW layer's pool (``SelfAttentionLayer.init_paged_cache``);
 # every other pool is of the global kind
 WINDOW_POOL_LEAVES = ("wk", "wv")
+# leaves of a STATE pool (``MambaLayer.init_paged_cache``, ``GravesLSTM``'s):
+# one row a slot, not pages
+STATE_POOL_LEAVES = ("sh", "sc")
 
 
 def pool_kind(pool) -> str:
-    """The page manager's kind (``PagedKVCache.KINDS``) of a layer's pool
-    dict: ``"window"`` where a request holds a ring of pages, ``"global"``
-    where it holds its whole context."""
-    return ("window" if any(n in pool for n in WINDOW_POOL_LEAVES)
-            else "global")
+    """The kind of a layer's pool dict, one of three: ``"window"`` where
+    a request holds a ring of pages, ``"state"`` where it holds one row of
+    recurrent state for its life (addressed by its slot, not by the block
+    table), ``"global"`` where it holds its whole context in pages."""
+    if any(n in pool for n in WINDOW_POOL_LEAVES):
+        return "window"
+    if any(n in pool for n in STATE_POOL_LEAVES):
+        return "state"
+    return "global"
 
 
 def write_token_rows(pool: jax.Array, page: jax.Array, off: jax.Array,

@@ -350,6 +350,11 @@ def register_default_helpers() -> None:
             GroupedExpertsHelper)
 
         _helpers.register_helper("grouped_experts", GroupedExpertsHelper())
+    if "selective_scan" not in _helpers._registry:
+        from deeplearning4j_tpu.helpers.selective_scan import (
+            SelectiveScanHelper)
+
+        _helpers.register_helper("selective_scan", SelectiveScanHelper())
     if "epilogue" not in _helpers._registry:
         from deeplearning4j_tpu.helpers.fused_epilogue import FusedEpilogueHelper
 

@@ -491,7 +491,8 @@ class SelfAttentionLayer(Layer):
         return -(-self.window // page_size) + 1
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         dtype=jnp.float32, window_pages: Optional[int] = None
+                         dtype=jnp.float32, window_pages: Optional[int] = None,
+                         state_slots: Optional[int] = None
                          ) -> Dict[str, jax.Array]:
         """KV pool for PAGED streaming inference (the continuous-batching
         generation engine, ``deeplearning4j_tpu/generation/``): instead of

@@ -129,29 +129,33 @@ class _SublayerChain(Layer):
         return carry if carryable else None
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         dtype=jnp.float32, window_pages=None):
-        """Paged-pool carries for pageable sublayers (attention KV pools —
-        see ``SelfAttentionLayer.init_paged_cache``).  A sublayer that is
-        carryable but NOT pageable (recurrent state) makes the whole block
-        unpageable: the continuous-batching engine needs every carry to be
-        slot-addressable through the block table, and recurrent hidden
-        state is not — it raises so the engine fails loudly at setup.
-        ``window_pages`` sizes the pools of window sublayers."""
+                         dtype=jnp.float32, window_pages=None,
+                         state_slots=None):
+        """The pools of the sublayers that keep state while streaming
+        (attention K/V pages — ``SelfAttentionLayer.init_paged_cache`` —
+        or a recurrent sublayer's state slots,
+        ``MambaLayer.init_paged_cache``).  A sublayer that takes a carry and
+        has no ``init_paged_cache`` makes the whole block unservable: the
+        engine must reach every carry through a dispatch's block table or
+        slot rows, so it raises and the engine fails at set-up.
+        ``window_pages`` sizes the pools of window sublayers,
+        ``state_slots`` those of state sublayers."""
         carry = {}
         pageable = False
         for i, sub in enumerate(self.layers):
             if hasattr(sub, "init_paged_cache"):
                 pageable = True
                 c = sub.init_paged_cache(num_pages, page_size, dtype,
-                                         window_pages=window_pages)
+                                         window_pages=window_pages,
+                                         state_slots=state_slots)
                 if c is not None:
                     carry[f"sub{i}"] = c
             elif self._forms[i][0]:
                 raise ValueError(
                     f"{type(self).__name__} sublayer {type(sub).__name__} "
-                    "carries state but has no paged-cache form; the "
-                    "generation engine only serves fully pageable "
-                    "(attention-cached) stacks")
+                    "takes a carry (apply_with_carry) but has no "
+                    "init_paged_cache; the generation engine serves a layer "
+                    "that keeps state only through pools it can address")
         return carry if pageable else None
 
     def _reg_chain(self, params):

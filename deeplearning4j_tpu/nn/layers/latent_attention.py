@@ -275,8 +275,8 @@ class LatentAttentionLayer(Layer):
             "cache")
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         dtype=jnp.float32, window_pages=None
-                         ) -> Dict[str, jax.Array]:
+                         dtype=jnp.float32, window_pages=None,
+                         state_slots=None) -> Dict[str, jax.Array]:
         """ONE latent pool ``pc`` [num_pages, page_size, W]: page-major
         like ``SelfAttentionLayer``'s ``pk``/``pv`` and addressed through
         the same block tables, with no head axis (every head reads the same

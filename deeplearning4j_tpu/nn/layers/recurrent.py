@@ -119,6 +119,8 @@ class GravesLSTM(Layer):
     (reference ``nn/layers/recurrent/GravesLSTM.java:38``)."""
 
     kind = "recurrent"
+    # served by the generation engine through state slots (init_paged_cache)
+    holds_state_slots = True
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None
@@ -148,13 +150,45 @@ class GravesLSTM(Layer):
         form of the reference's TBPTT state plumbing
         (``MultiLayerNetwork.java:1176`` rnnActivateUsingStoredState)."""
         x = self.maybe_dropout(x, train=train, rng=rng)
+        slots = carry if isinstance(carry, dict) else None
+        if slots is not None:
+            # the engine's state slots (``MambaLayer``'s contract): a row
+            # at position 0 starts from zero, padding past ``live`` freezes
+            # the state as a masked step does
+            rows = slots.get("rows")
+            if rows is None:     # the decode step: lane i owns row i + 1
+                rows = jnp.where(slots["lanes"],
+                                 jnp.arange(1, x.shape[0] + 1), 0)
+            fresh = (slots["pos"] == 0)[:, None]
+            carry = tuple(jnp.where(fresh, 0.0, slots[k][rows]).astype(x.dtype)
+                          for k in ("sh", "sc"))
+            if "live" in slots:
+                mask = (jnp.arange(x.shape[1])[None]
+                        < slots["live"][:, None]).astype(x.dtype)
         h0, c0 = carry if carry is not None else (None, None)
         ys, (hT, cT) = _scan_lstm(
             params, activations.get(self.activation),
             activations.get(self.gate_activation), self.peephole, x, mask,
             h0=h0, c0=c0,
         )
+        if slots is not None:
+            return ys, state, {
+                **slots, "sh": slots["sh"].at[rows].set(hT.astype(
+                    slots["sh"].dtype)),
+                "sc": slots["sc"].at[rows].set(cT.astype(slots["sc"].dtype))}
         return ys, state, (hT, cT)
+
+    def init_paged_cache(self, num_pages: int, page_size: int,
+                         dtype=jnp.float32, window_pages=None,
+                         state_slots: Optional[int] = None):
+        """The layer's STATE SLOTS for the generation engine: ``sh`` / ``sc``
+        [slots + 1, n_out], a slot's ``(h, c)`` and the trash row (row 0)."""
+        if state_slots is None:
+            raise ValueError(
+                "a recurrent layer keeps one row of state a slot: "
+                "init_paged_cache needs state_slots, the engine's slot count")
+        shape = (int(state_slots) + 1, self.n_out)
+        return {"sh": jnp.zeros(shape, dtype), "sc": jnp.zeros(shape, dtype)}
 
     # -- streaming inference (reference rnnTimeStep / stateMap) ------------
     def initial_carry(self, batch: int, dtype=jnp.float32):

@@ -693,6 +693,8 @@ def kv_page_ledger(pools: Dict[str, Any],
 
     out: Dict[str, Any] = {}
     for layer, pool in _leaf_pools(pools):
+        if pool_kind(pool) == "state":
+            continue     # rows of recurrent state, not K/V pages
         layer_entry: Dict[str, Any] = {}
         ids = (allocated[pool_kind(pool)] if isinstance(allocated, dict)
                else allocated)

@@ -236,6 +236,27 @@ class GenerationMetrics:
             "a decode step where the kernel gives way), expanded = flash "
             "attention over the chunk's own decompressed keys (a prompt "
             "prefilled from position 0)", labels=("stage", "path"))
+        self.state_space_steps = reg.counter(
+            "dl4j_state_space_steps_total",
+            "Dispatched decode steps (stage=decode) and prefills "
+            "(stage=prefill) of a net with state-space layers, by how the "
+            "program runs their recurrence "
+            "(nn.layers.state_space.state_space_path): step = one pass over "
+            "the rows' states, no loop (a single token a row: the decode "
+            "step), scan = the helper seam's chunked lax scan (a prefill "
+            "bucket), kernel = a Pallas kernel where the seam offers one",
+            labels=("stage", "path"))
+        self.state_slot_resets = reg.counter(
+            "dl4j_state_slot_resets_total",
+            "Admissions that began a state slot's recurrent state anew (a "
+            "prefill from position 0 of a net with state slots: the row "
+            "starts from zero whatever its last tenant left)",
+            labels=("engine",)).labels(engine=self.engine_id)
+        self.state_slots_in_use = reg.gauge(
+            "dl4j_state_slots_in_use",
+            "State slots held by running requests (a net with recurrent "
+            "layers: one row of state a slot, held for the request's life)",
+            labels=("engine",)).labels(engine=self.engine_id)
         self.mhc_row_sum_error = reg.gauge(
             "dl4j_mhc_row_sum_error",
             "Largest distance from 1 of a row sum or a column sum of H_res "

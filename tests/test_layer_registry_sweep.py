@@ -41,6 +41,7 @@ _KWARGS = {
                                  kv_rank=4, nope_dim=4, rope_dim=2, v_dim=4),
     "LayerNorm": dict(n_in=5),
     "LocalResponseNormalization": dict(),
+    "MambaLayer": dict(n_in=4, n_out=4, d_state=3, dt_rank=2),
     "MoELayer": dict(n_in=4, n_out=4, num_experts=2),
     "OutputLayer": dict(n_in=4, n_out=3),
     "RBM": dict(n_in=6, n_out=4),
@@ -73,6 +74,7 @@ _INPUTS = {
     "LatentAttentionLayer": (2, 5, 8),
     "LayerNorm": (2, 5),
     "LocalResponseNormalization": (2, 4, 4, 3),
+    "MambaLayer": (2, 5, 4),
     "MoELayer": (2, 4),
     "OutputLayer": (2, 4),
     "RBM": (2, 6),

@@ -882,3 +882,60 @@ def test_trust_registry_gate_flags_mismatch(tmp_path):
     from deeplearning4j_tpu.observability.kerneldiff import check_registry
 
     assert check_registry(str(p)) == 1
+
+
+@pytest.mark.parametrize("b,t", [(128, 1), (1, 1024)])
+def test_jamba_shapes_compile_for_v5e(v5e_chip, b, t):
+    """``jamba2-3b``'s attention calls (20 query heads over ONE kv head of
+    128, pages of 64, 24 a slot): a group of 20 rows a kv head, against 6,
+    9 and 12 in the older cells, through the chip's compiler."""
+    ps, hq, hkv, d, maxp = 64, 20, 1, 128, 24
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    pool = sds((128 * maxp + 1, hkv, ps, d), jnp.bfloat16)
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, impl="pallas", interpret=False))
+    with jax.enable_x64(False):
+        compiled = fn.lower(sds((b, t, hq, d), jnp.bfloat16), pool, pool,
+                            sds((b, maxp), jnp.int32),
+                            sds((b, t), jnp.int32)).compile()
+    assert "fused_paged_attention" in compiled.as_text()
+    ppb, tq, vmem = paged_tiling(b, t, hq, hkv, d, ps, maxp, jnp.bfloat16)
+    assert vmem <= VMEM_BUDGET and (tq == t or t % tq == 0)
+
+
+@pytest.mark.parametrize("lanes,t", [(128, 1), (1, 1024)])
+def test_the_state_slots_are_stepped_in_place_on_v5e(v5e_chip, lanes, t):
+    """``MambaLayer`` at ``jamba2-3b``'s widths over its state slots,
+    compiled for the described chip with the pools donated: the decode step
+    (128 lanes) is one pass over the pool where it lies, a prefill bucket
+    a scatter of one row — neither re-lays the float32 pool out (a
+    pool-sized ``copy`` of ``sh`` would cost 42 MB a layer a step)."""
+    import re
+
+    from deeplearning4j_tpu.nn.layers import MambaLayer
+
+    layer = MambaLayer(n_in=2560, n_out=2560, dt_rank=160, name="m")
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    with jax.enable_x64(False):
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+        pool = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                lambda: layer.init_paged_cache(2, 64, jnp.bfloat16,
+                                               state_slots=128)))
+
+        def run(p, u, pool, where, pos):
+            carry = {**pool, "pos": pos,
+                     **({"lanes": where > 0} if t == 1 else
+                        {"rows": where, "live": pos + t - 5})}
+            y, _, new = layer.apply_with_carry(p, {}, u, carry)
+            return y, {k: new[k] for k in pool}
+
+        text = jax.jit(run, donate_argnums=(2,)).lower(
+            params, sds((lanes, t, 2560), jnp.bfloat16), pool,
+            sds((lanes,), jnp.int32), sds((lanes,), jnp.int32)
+        ).compile().as_text()
+    copies = re.findall(r"= f32\[129,16,5120\][^ ]* copy\(", text)
+    assert not copies, copies
+    assert ("while(" in text) == (t > 1)        # the chunked scan's loop

@@ -29,7 +29,7 @@ from deeplearning4j_tpu.observability import recompile
 VOCABULARY = set(base.KINDS) | {
     "loss", "updater", "param_cast", "sample", "mhc_coeffs", "mhc_sinkhorn",
     "mla_attention", "attention_core", "attn_gate", "moe_router",
-    "moe_experts", "moe_shared_expert"}
+    "moe_experts", "moe_shared_expert", "ssm_proj", "ssm_conv", "ssm_scan"}
 MATMULS = ("dot", "convolution", "custom-call")
 
 
