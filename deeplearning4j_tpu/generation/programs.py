@@ -641,25 +641,36 @@ class GenerationPrograms:
 
     def _log_expert_tiling(self) -> None:
         """How ``grouped_experts`` tiles each compute program whose expert
-        layers take the ``streamed`` path, once a program and shape."""
+        layers take the ``streamed`` or ``sorted`` path, once a program
+        and shape."""
         from deeplearning4j_tpu.helpers import grouped_experts as ge
 
         dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
-        shapes = sorted({(l.held[1], l.n_in, l.hidden, l.n_out)
-                         for l in self.expert_layers})
+        shapes = sorted({(l.held[1], l.n_experts, l.top_k, l.n_in, l.hidden,
+                          l.n_out) for l in self.expert_layers})
         for name, paths in self.expert_paths.items():
-            if "streamed" not in paths:
-                continue
             t = self.slots if name == "decode" else name
-            for count, d, hidden, n_out in shapes:
-                rows, tf, vmem = ge.expert_tiling(t, d, hidden, n_out, dtype)
-                logger.info(
-                    "generation.%s: grouped_experts tokens [%d, %d] over %d "
-                    "held experts of width %d: %d rows, hidden tiles of %d, "
-                    "grid (%d, %d), %.2f MB of VMEM",
-                    name if name == "decode" else f"prefill_{name}", t, d,
-                    count, hidden, rows, tf, count, hidden // tf,
-                    vmem / 2 ** 20)
+            program = name if name == "decode" else f"prefill_{name}"
+            for count, n, k, d, hidden, n_out in shapes:
+                if "streamed" in paths:
+                    rows, tf, vmem = ge.expert_tiling(t, d, hidden, n_out,
+                                                      dtype)
+                    logger.info(
+                        "generation.%s: grouped_experts tokens [%d, %d] over "
+                        "%d held experts of width %d: %d rows, hidden tiles "
+                        "of %d, grid (%d, %d), %.2f MB of VMEM", program, t,
+                        d, count, hidden, rows, tf, count, hidden // tf,
+                        vmem / 2 ** 20)
+                if "sorted" in paths:
+                    tm, tf, r, vmem = ge.sorted_tiling(d, hidden, n_out,
+                                                       dtype, t * k // n)
+                    logger.info(
+                        "generation.%s: sorted_experts tokens [%d, %d] over "
+                        "%d held experts of width %d: blocks of %d sorted "
+                        "rows, row tiles of %d, hidden tiles of %d, %d row "
+                        "tiles a visit, %.2f MB of VMEM", program, t, d,
+                        count, hidden, ge.sorted_block(t * k, count, n), tm,
+                        tf, r, vmem / 2 ** 20)
 
     def lowered(self) -> Dict[str, "jax.stages.Lowered"]:
         """Each compute program lowered at its serving signature (abstract

@@ -156,7 +156,7 @@ def counting(valid):
 # which way the held experts are multiplied (RoutedMoELayer._held_experts)
 # ---------------------------------------------------------------------------
 
-EXPERT_PATHS = ("streamed", "ragged")
+EXPERT_PATHS = ("streamed", "sorted", "ragged")
 
 # Rows up to which a call streams its experts (helpers/grouped_experts.py).
 # The kernel multiplies EVERY row by every touched expert: 6 * d * hidden
@@ -167,25 +167,28 @@ EXPERT_PATHS = ("streamed", "ragged")
 # 1.86 / 1.90 / 2.03 ms at 64 / 128 / 256 rows (1.72 ms of bytes) and 3.02 /
 # 4.17 at 384 / 512, where the arithmetic has taken over; at 256 rows it
 # is ahead of the sorted groups at all three served widths (2.03 against
-# 4.74 ms, 1.66 against 2.38, 0.86 against 1.42).  Past the bound the
-# sorted groups' arithmetic (top_k * held / n_experts of the dense form's)
-# is the smaller cost to grow.
+# 4.74 ms, 1.66 against 2.38, 0.86 against 1.42).  Past the bound each row
+# meets the experts it chose alone (top_k * held / n_experts of the dense
+# form's arithmetic), in the sorted kernel.
 STREAMED_ROWS = 256
 
 
 def expert_path(rows: int, train: bool = False, kernel: bool = True) -> str:
     """Which of ``EXPERT_PATHS`` a call of ``rows`` rows (static: the
-    flattened leading axes of the layer's input) takes: ``"streamed"`` —
-    one fused kernel that reads each touched held expert's weights once,
-    dense over the rows — when the rows are few, no gradient is wanted
-    (``train``: a ``fit`` step keeps the sorted groups, whose backward it
-    needs) and the kernel is there (``kernel``: the helper seam offers it
-    and takes the widths); else ``"ragged"``, rows sorted by expert through
-    ``jax.lax.ragged_dot`` in blocks.  Pure: the layer calls it while it is
-    traced, the engine on the host to count
+    flattened leading axes of the layer's input) takes.  With the kernel
+    (``kernel``: the helper seam offers it and takes the widths) and no
+    gradient wanted (``train``: a ``fit`` step keeps the sorted groups of
+    ``ragged_dot``, whose backward it needs): ``"streamed"`` — every row
+    against each touched held expert, whose weights one kernel reads once
+    — up to ``STREAMED_ROWS`` rows, ``"sorted"`` — rows sorted by expert,
+    each expert's rows against its weights read once, in one kernel —
+    past them; else ``"ragged"``, rows sorted by expert through
+    ``jax.lax.ragged_dot`` in blocks.  Pure: the layer calls it while it
+    is traced, the engine on the host to count
     ``dl4j_moe_expert_steps_total``."""
-    streams = kernel and not train and rows <= STREAMED_ROWS
-    return "streamed" if streams else "ragged"
+    if train or not kernel:
+        return "ragged"
+    return "streamed" if rows <= STREAMED_ROWS else "sorted"
 
 
 @register_layer
@@ -207,11 +210,14 @@ class RoutedMoELayer(Layer):
 
     ``experts_held = (first, count)`` names the experts whose weights this
     layer has (``W_gate/W_up/W_down`` are ``[count, ...]``); None holds
-    all.  One algorithm under two schedules (``expert_path`` picks by the
-    call's row count): many rows are sorted by held expert and multiplied
-    group by group (``jax.lax.ragged_dot``); a few rows — a decode step —
-    are all multiplied by every expert one of them chose, whose weights one
-    kernel streams once (``helpers/grouped_experts.py``).  Assignments to
+    all.  One algorithm under three schedules (``expert_path`` picks by
+    the call's row count): a few rows — a decode step — are all multiplied
+    by every expert one of them chose, whose weights one kernel streams
+    once; many rows — a prefill — are sorted by held expert and each
+    expert's rows multiplied by its weights, read once, in another
+    (``helpers/grouped_experts.py``); a training call, or one where the
+    kernels give way, sorts them and multiplies group by group
+    (``jax.lax.ragged_dot``), the other two's backward.  Assignments to
     experts held elsewhere add nothing either way."""
 
     kind = "experts"
@@ -314,9 +320,10 @@ class RoutedMoELayer(Layer):
         the path ``expert_path`` names."""
         args = (params["W_gate"], params["W_up"], params["W_down"], tokens,
                 ids, w)
-        if self.path(tokens.shape[0], train) == "streamed":
-            return _held_streamed(self, *args)
-        return self._held_ragged(*args)
+        path = self.path(tokens.shape[0], train)
+        if path == "ragged":
+            return self._held_ragged(*args)
+        return _held_kernel(self, path, *args)
 
     def _held_ragged(self, w_gate, w_up, w_down, tokens, ids, w):
         """The ``ragged`` path.  Assignments are sorted by held expert
@@ -397,21 +404,27 @@ class RoutedMoELayer(Layer):
         return super().from_dict(d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_streamed(layer: RoutedMoELayer, w_gate, w_up, w_down, tokens, ids,
-                   w):
-    """The ``streamed`` path: ``layer``'s held experts through the helper's
-    kernel.  Differentiated (``jax.grad`` through an inference call), its
-    backward is the ``ragged`` path's: the two compute one function."""
-    return helpers.get_helper("grouped_experts").apply(
-        tokens, w_gate, w_up, w_down, ids, w, layer.held, layer.activation)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_kernel(layer: RoutedMoELayer, path: str, w_gate, w_up, w_down,
+                 tokens, ids, w):
+    """The ``streamed`` or ``sorted`` path: ``layer``'s held experts
+    through the helper's kernels.  Differentiated (``jax.grad`` through an
+    inference call), its backward is the ``ragged`` path's: the three
+    compute one function."""
+    helper = helpers.get_helper("grouped_experts")
+    if path == "sorted":
+        return helper.apply_sorted(tokens, w_gate, w_up, w_down, ids, w,
+                                   layer.held, layer.n_experts,
+                                   layer.activation)
+    return helper.apply(tokens, w_gate, w_up, w_down, ids, w, layer.held,
+                        layer.activation)
 
 
-def _held_streamed_fwd(layer, *args):
-    return _held_streamed(layer, *args), args
+def _held_kernel_fwd(layer, path, *args):
+    return _held_kernel(layer, path, *args), args
 
 
-def _held_streamed_bwd(layer, args, g):
+def _held_kernel_bwd(layer, path, args, g):
     w_gate, w_up, w_down, tokens, ids, w = args
     _, vjp = jax.vjp(
         lambda wg, wu, wd, x, ww: layer._held_ragged(wg, wu, wd, x, ids, ww),
@@ -420,4 +433,4 @@ def _held_streamed_bwd(layer, args, g):
     return (*floats, np.zeros(ids.shape, jax.dtypes.float0), dw)
 
 
-_held_streamed.defvjp(_held_streamed_fwd, _held_streamed_bwd)
+_held_kernel.defvjp(_held_kernel_fwd, _held_kernel_bwd)

@@ -2,7 +2,7 @@
 
 The repo's fused kernels (Pallas flash attention, the fused LRN/BN
 passes, the paged-attention decode path, the streamed experts of a decode
-step) were validated by their unit
+step and the sorted experts of a prefill) were validated by their unit
 tests — which is trust by sampling.  This module is trust by SWEEP: run
 every fused kernel against an independent float64 numpy reference over
 a shape × dtype × masking grid, record per-config max-abs / max-rel
@@ -436,6 +436,10 @@ def _grouped_experts_configs(full: bool):
     # 1/16 share with ~1 row an expert (k2.serve-docqa), a 1/8 share under
     # top-10 (laguna.serve-mixed-8k); in both an expert no row chose
     grids = [{"shape": [8, 64, 128], "top_k": 4, "held": [0, 8], "experts": 8}]
+    # the sorted form (a prefill's schedule): rows over several tiles of an
+    # expert and over blocks of twice the held share
+    grids += [{"shape": [300, 64, 128], "top_k": 4, "held": [2, 4],
+               "experts": 8, "form": "sorted"}]
     if full:
         grids += [
             {"shape": [12, 128, 256], "top_k": 8, "held": [40, 6],
@@ -446,17 +450,27 @@ def _grouped_experts_configs(full: bool):
             {"shape": [1, 64, 128], "top_k": 2, "held": [0, 4], "experts": 4},
             {"shape": [37, 64, 128], "top_k": 2, "held": [2, 4],
              "experts": 8},
+            # the sorted form at the three cells' widths (d, hidden: k2's
+            # four hidden tiles and y held for four row tiles, Laguna's
+            # and Xing's one) and their top-k, toy rows and held experts
+            {"shape": [96, 7168, 2048], "top_k": 8, "held": [5, 2],
+             "experts": 24, "form": "sorted"},
+            {"shape": [160, 3072, 1024], "top_k": 10, "held": [8, 3],
+             "experts": 24, "form": "sorted"},
+            {"shape": [200, 3584, 1024], "top_k": 4, "held": [0, 4],
+             "experts": 4, "form": "sorted"},
         ]
     for g in grids:
         for dtype in ("float32", "bfloat16"):
             yield dict(g, dtype=dtype)
 
 def _run_grouped_experts(cfg) -> Tuple[Any, np.ndarray]:
-    """The interpreted Pallas kernel on ids drawn as a router draws them
-    (top-k of random scores over ALL experts, weights normalised) against
-    the f64 per-expert loop on the same rounded inputs."""
+    """The interpreted Pallas kernel (the streamed form, or with ``"form":
+    "sorted"`` the sorted one) on ids drawn as a router draws them (top-k
+    of random scores over ALL experts, weights normalised) against the f64
+    per-expert loop on the same rounded inputs."""
     from deeplearning4j_tpu.helpers.grouped_experts import (
-        combine_matrix, grouped_experts)
+        combine_matrix, grouped_experts, sorted_experts)
     t, d, hidden = cfg["shape"]
     first, count = cfg["held"]
     dt = jnp.dtype(cfg["dtype"])
@@ -469,9 +483,13 @@ def _run_grouped_experts(cfg) -> Tuple[Any, np.ndarray]:
     ids = np.argsort(-scores, axis=1)[:, :cfg["top_k"]]
     w = np.take_along_axis(scores, ids, axis=1)
     w = (w / w.sum(axis=1, keepdims=True)).astype(np.float32)
-    c, touched = combine_matrix(jnp.asarray(ids, jnp.int32), jnp.asarray(w),
-                                first, count)
-    out = grouped_experts(x, wg, wu, wd, c, touched, interpret=True)
+    ids = jnp.asarray(ids, jnp.int32)
+    c, touched = combine_matrix(ids, jnp.asarray(w), first, count)
+    if cfg.get("form") == "sorted":
+        out = sorted_experts(x, wg, wu, wd, ids, jnp.asarray(w), first=first,
+                             n_experts=cfg["experts"], interpret=True)
+    else:
+        out = grouped_experts(x, wg, wu, wd, c, touched, interpret=True)
     return out, _np_grouped_experts(x, wg, wu, wd, c)
 
 def _pallas2d_configs(full: bool):

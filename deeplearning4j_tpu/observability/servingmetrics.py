@@ -221,9 +221,11 @@ class GenerationMetrics:
             "program multiplies their held experts, from its row count "
             "(nn.layers.moe.expert_path): streamed = one fused kernel "
             "reads each touched expert's weights once, every row against "
-            "it (few rows: a decode step), ragged = rows sorted by expert "
-            "through ragged_dot in blocks (a prefill bucket; any program "
-            "where the kernel gives way)", labels=("stage", "path"))
+            "it (at most 256 rows: a decode step), sorted = one fused "
+            "kernel reads each touched expert's weights once, its sorted "
+            "rows alone against it (more rows: a prefill bucket), ragged "
+            "= rows sorted by expert through ragged_dot in blocks (any "
+            "program where the kernels give way)", labels=("stage", "path"))
         self.latent_attention_steps = reg.counter(
             "dl4j_latent_attention_steps_total",
             "Dispatched decode steps (stage=decode) and prefills "

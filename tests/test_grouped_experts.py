@@ -1,6 +1,7 @@
-"""The ``streamed`` path of ``RoutedMoELayer`` (``helpers/grouped_experts.py``:
-every touched held expert's weights read once, dense over the rows) against
-the layer's ``ragged`` path and the plain reference of
+"""The ``streamed`` and ``sorted`` paths of ``RoutedMoELayer``
+(``helpers/grouped_experts.py``: every touched held expert's weights read
+once, dense over a few rows or each expert's sorted rows alone) against the
+layer's ``ragged`` path and the plain reference of
 ``tests/test_latent_moe.py``, at toy widths in interpret mode; the rule that
 picks the path, the gradient, and the engine's counter."""
 
@@ -12,7 +13,8 @@ import pytest
 from deeplearning4j_tpu import helpers
 from deeplearning4j_tpu.generation.engine import GenerationEngine
 from deeplearning4j_tpu.helpers.grouped_experts import (
-    GroupedExpertsHelper, combine_matrix, expert_tiling, grouped_experts)
+    SORTED_TILE, GroupedExpertsHelper, combine_matrix, expert_tiling,
+    grouped_experts, sorted_block, sorted_experts, sorted_tiling)
 from deeplearning4j_tpu.nn.layers import RoutedMoELayer
 from deeplearning4j_tpu.nn.layers.moe import (
     EXPERT_PATHS, STREAMED_ROWS, expert_path)
@@ -34,6 +36,20 @@ def calls(monkeypatch):
         return apply(self, tokens, *a)
 
     monkeypatch.setattr(GroupedExpertsHelper, "apply", spy)
+    return seen
+
+
+@pytest.fixture
+def sorted_calls(monkeypatch):
+    """How often the sorted kernel's seam was taken while the test ran."""
+    seen = []
+    apply = GroupedExpertsHelper.apply_sorted
+
+    def spy(self, tokens, *a):
+        seen.append(tokens.shape[0])
+        return apply(self, tokens, *a)
+
+    monkeypatch.setattr(GroupedExpertsHelper, "apply_sorted", spy)
     return seen
 
 
@@ -126,12 +142,13 @@ def test_bfloat16_agrees_within_its_rounding(name):
 
 
 @pytest.mark.parametrize("rows,path", [
-    (1, "streamed"), (STREAMED_ROWS, "streamed"), (STREAMED_ROWS + 1, "ragged")])
-def test_the_row_count_picks_the_path(rows, path, calls):
+    (1, "streamed"), (STREAMED_ROWS, "streamed"), (STREAMED_ROWS + 1, "sorted")])
+def test_the_row_count_picks_the_path(rows, path, calls, sorted_calls):
     _, layer, _, params, x = case("uniform", rows=(rows,))
     assert layer.path(rows) == path
     got, _ = layer.apply(params, {}, x)
     assert calls == ([rows] if path == "streamed" else [])
+    assert sorted_calls == ([rows] if path == "sorted" else [])
     assert np.abs(np.asarray(got) - plain(layer, params, x)).max() < 1e-4
 
 
@@ -240,26 +257,30 @@ def test_a_training_call_keeps_the_sorted_groups(calls):
 
 
 def test_expert_path_is_a_pure_function_of_rows_train_and_kernel():
-    assert EXPERT_PATHS == ("streamed", "ragged")
+    assert EXPERT_PATHS == ("streamed", "sorted", "ragged")
     for rows in (1, 32, 48, 64, STREAMED_ROWS):
         assert expert_path(rows) == "streamed"
         assert expert_path(rows, train=True) == "ragged"
         assert expert_path(rows, kernel=False) == "ragged"
     for rows in (STREAMED_ROWS + 1, 512, 8192):
-        assert expert_path(rows) == "ragged"
+        assert expert_path(rows) == "sorted"
+        assert expert_path(rows, train=True) == "ragged"
+        assert expert_path(rows, kernel=False) == "ragged"
+    assert {expert_path(r, t, k) for r in (1, 8192) for t in (False, True)
+            for k in (False, True)} == set(EXPERT_PATHS)
 
 
 def test_the_kernel_gives_way_like_every_helper():
     layer = moe_layer(dict(TOY))
-    assert layer.path(4) == "streamed"
+    assert layer.path(4) == "streamed" and layer.path(512) == "sorted"
     helpers.enable_helpers(False)
     try:
-        assert layer.path(4) == "ragged"
+        assert layer.path(4) == layer.path(512) == "ragged"
     finally:
         helpers.enable_helpers(True)
     mesh = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
     with helpers.auto_partitioned(mesh):
-        assert layer.path(4) == "ragged"
+        assert layer.path(4) == layer.path(512) == "ragged"
     assert layer.path(4) == "streamed"
     # compiled, a tile is whole lanes of every width; interpreted, any width
     assert GroupedExpertsHelper().supports(64, 24, 64) == helpers.interpret_mode()
@@ -274,7 +295,7 @@ def test_shapes_that_do_not_fit_are_refused():
 
 def test_the_engine_counts_each_dispatch_by_its_path():
     """Decode steps (4 rows) and a prefill bucket under the bound stream
-    their experts; a bucket past it keeps the sorted groups."""
+    their experts; a bucket past it takes the sorted kernel."""
     net, _ = toy_net()
     big = STREAMED_ROWS + 32
     eng = GenerationEngine(net, slots=4, page_size=8, max_context=big + 16,
@@ -290,7 +311,7 @@ def test_the_engine_counts_each_dispatch_by_its_path():
     assert [len(s) for s in served] == [6, 6, 6]
     progs = next(iter(eng._programs.values()))
     assert progs.expert_paths == {"decode": ("streamed",),
-                                  32: ("streamed",), big: ("ragged",)}
+                                  32: ("streamed",), big: ("sorted",)}
     reg = eng.metrics.registry
 
     def count(stage, path):
@@ -300,9 +321,10 @@ def test_the_engine_counts_each_dispatch_by_its_path():
     dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
                      for m in ("ahead", "sync"))
     assert count("decode", "streamed") == dispatched > 0
-    assert count("decode", "ragged") == 0
+    assert count("decode", "sorted") == count("decode", "ragged") == 0
     assert count("prefill", "streamed") == 2      # 21 and 9 tokens
-    assert count("prefill", "ragged") == 1        # 40 tokens
+    assert count("prefill", "sorted") == 1        # 40 tokens
+    assert count("prefill", "ragged") == 0
 
 
 def test_a_net_without_expert_layers_counts_nothing():
@@ -312,3 +334,165 @@ def test_a_net_without_expert_layers_counts_nothing():
     progs = GenerationPrograms(_kv_lm(), slots=2, pages_per_slot=4,
                                page_size=4, num_pages=9, prefill_buckets=(8,))
     assert progs.expert_layers == [] and progs.expert_paths == {}
+
+
+# ------------------------------------------------- the sorted path (PR 40)
+SORTED_ROWS = (2, 150)        # 300 rows: past the bound, not whole tiles
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_sorted_equals_ragged_and_the_reference(name, calls, sorted_calls):
+    cfg, layer, w, params, x = case(name, rows=SORTED_ROWS)
+    got, _ = jax.jit(lambda p, xx: layer.apply(p, {}, xx))(params, x)
+    assert sorted_calls == [300] and calls == []
+    want = np.stack([np.asarray(ref.moe(r, w, cfg, "f32")) for r in x])
+    assert np.abs(np.asarray(got) - want).max() < 1e-4
+    assert np.abs(np.asarray(got) - np.asarray(ragged(layer, params, x))
+                  ).max() < 1e-4
+
+
+@pytest.mark.parametrize("scoring,held", [
+    ("sigmoid", (3, 5)), ("sigmoid", None), ("softmax", (3, 5)),
+    ("softmax", None)])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 3e-2)])
+def test_sorted_takes_both_scorings_and_any_share(scoring, held, dtype, tol,
+                                                  sorted_calls):
+    """``count < n_experts`` (blocks of twice the held share) and every
+    expert held (one block, all the assignments)."""
+    layer = RoutedMoELayer(n_in=32, n_out=32, n_experts=12, top_k=4,
+                           hidden=40, shared=16, experts_held=held,
+                           routed_scaling_factor=2.5, scoring=scoring)
+    params = layer.init(jax.random.PRNGKey(2), dtype)
+    x = jax.random.normal(jax.random.PRNGKey(3), (290, 32), dtype)
+    got, _ = layer.apply(params, {}, x)
+    assert sorted_calls == [290] and got.dtype == dtype
+    want = plain(layer, params, x)
+    assert (np.abs(np.asarray(got, np.float64) - want).max()
+            < tol * max(1.0, np.abs(want).max()))
+
+
+def test_no_held_assignment_reads_nothing_and_adds_nothing():
+    """Every token routed elsewhere: the grid has no step, so even weights
+    that are all NaN leave the result zero."""
+    _, layer, _, params, x = case("none_held", rows=SORTED_ROWS)
+    tokens = x.reshape(-1, x.shape[-1])
+    ids, w = layer.route(params, tokens)
+    nan = {k: jnp.full_like(params[k], jnp.nan)
+           for k in ("W_gate", "W_up", "W_down")}
+    got = sorted_experts(tokens, nan["W_gate"], nan["W_up"], nan["W_down"],
+                         ids, w, first=4, n_experts=16)
+    assert got.shape == (300, layer.n_out) and not np.asarray(got).any()
+
+
+def test_sorted_reads_no_untouched_expert():
+    _, layer, _, params, x = case("skewed_two_held_untouched",
+                                  rows=SORTED_ROWS)
+    clean, _ = layer.apply(params, {}, x)
+    poisoned = dict(params)
+    for k in ("W_gate", "W_up", "W_down"):
+        poisoned[k] = params[k].at[2:].set(jnp.nan)
+    got, _ = layer.apply(poisoned, {}, x)
+    assert (np.asarray(got) == np.asarray(clean)).all()
+
+
+@pytest.mark.parametrize("budget,tiling", [
+    (None, (SORTED_TILE, 512, 1)),     # one hidden tile: weights resident
+    (700_000, (SORTED_TILE, 128, 2)),  # four, y held for 2 row tiles
+    (400_000, (SORTED_TILE, 128, 1))])  # four, a visit a row tile
+@pytest.mark.parametrize("route", [None, (5, 0, 9, 1), (4, 5, 6, 7)])
+def test_hidden_tiles_visits_and_blocks_agree(budget, tiling, route,
+                                              monkeypatch):
+    """Every combination of the kernel's schedules on one layer of 16
+    experts, 4 held, top 4, 600 rows (150 an expert on average: visits of
+    two row tiles where they fit): uniform routing (one block), every
+    token on held expert 5 (600 rows: one expert over five tiles, three
+    visits), every assignment held (2,400 rows over blocks of 1,280: two
+    blocks, the second added to the first)."""
+    from deeplearning4j_tpu.helpers import grouped_experts as ge
+
+    if budget:
+        monkeypatch.setattr(ge, "VMEM_BUDGET", budget)
+    jax.clear_caches()
+    layer = RoutedMoELayer(n_in=32, n_out=32, n_experts=16, top_k=4,
+                           hidden=512, experts_held=(4, 4))
+    params = layer.init(jax.random.PRNGKey(1), jnp.float32)
+    if route is not None:
+        params["b_router"] = jnp.zeros(16).at[jnp.asarray(route)].set(100.0)
+    x = jax.random.normal(jax.random.PRNGKey(2), (600, 32))
+    ids, w = layer.route(params, x)
+    assert sorted_tiling(32, 512, 32, jnp.float32, 150)[:3] == tiling
+    assert sorted_block(2400, 4, 16) == 1280
+    got = sorted_experts(x, params["W_gate"], params["W_up"],
+                         params["W_down"], ids, w, first=4, n_experts=16)
+    jax.clear_caches()
+    want = layer._held_ragged(params["W_gate"], params["W_up"],
+                              params["W_down"], x, ids, w)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    assert np.abs(np.asarray(want)).max() > 0.05
+
+
+@pytest.mark.parametrize("cell,d,hidden,rows,tiling", [
+    ("xing", 3584, 1024, 128, (128, 1024, 1)),
+    ("laguna", 3072, 1024, 320, (128, 1024, 1)),
+    ("k2", 7168, 2048, 85, (128, 256, 1)),      # bucket 4,096: 85 an expert
+    ("k2", 7168, 2048, 400, (128, 256, 4))])    # more rows: y for 4 tiles
+def test_sorted_tiling_at_the_served_widths_fits_the_budget(cell, d, hidden,
+                                                            rows, tiling):
+    from deeplearning4j_tpu.helpers.grouped_experts import VMEM_BUDGET
+
+    tm, tf, r, vmem = sorted_tiling(d, hidden, d, jnp.bfloat16, rows)
+    assert (tm, tf, r) == tiling and vmem <= VMEM_BUDGET
+    assert hidden % tf == 0 and tf % 128 == 0
+
+
+@pytest.mark.parametrize("assignments,count,n,block", [
+    (4096 * 8, 12, 384, 2048),      # k2's bucket 4096: twice 1,024
+    (8192 * 10, 32, 256, 20480),    # Laguna's 8192
+    (2048 * 4, 64, 64, 8192),       # Xing holds all: every assignment
+    (300, 1, 384, 128)])            # at least one tile
+def test_a_block_is_twice_the_held_share(assignments, count, n, block):
+    assert sorted_block(assignments, count, n) == block
+
+
+def test_grad_through_a_sorted_call_is_the_ragged_paths(monkeypatch,
+                                                        sorted_calls):
+    _, layer, _, params, x = case("uniform", rows=(STREAMED_ROWS + 11,))
+    cot = jax.random.normal(jax.random.PRNGKey(9),
+                            (STREAMED_ROWS + 11, layer.n_out))
+
+    def loss(p, xx):
+        return jnp.sum(layer.apply(p, {}, xx)[0] * cot)
+
+    got = jax.grad(loss, argnums=(0, 1))(params, x)
+    assert sorted_calls == [STREAMED_ROWS + 11]   # the forward: the kernel
+    monkeypatch.setattr(GroupedExpertsHelper, "supports",
+                        lambda self, *widths: False)
+    want = jax.grad(loss, argnums=(0, 1))(params, x)
+    assert sorted_calls == [STREAMED_ROWS + 11]
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.abs(np.asarray(g) - np.asarray(w)).max() < 1e-5
+    assert np.abs(np.asarray(got[0]["W_down"])).max() > 0
+
+
+@pytest.mark.parametrize("cell,t,d,hidden,count,n,k,calls", [
+    # every expert held: one block, the kernel once; a share: the first
+    # block, and the loop over the blocks a skewed batch adds
+    ("xing", 512, 3584, 1024, 64, 64, 4, 1),
+    ("laguna", 1024, 3072, 1024, 32, 256, 10, 2),
+    ("k2", 1024, 7168, 2048, 12, 384, 8, 2)])
+def test_sorted_kernel_lowers_for_tpu_at_the_served_widths(cell, t, d,
+                                                           hidden, count, n,
+                                                           k, calls):
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    fn = jax.jit(lambda *a: sorted_experts(*a, n_experts=n,
+                                           interpret=False))
+    with jax.enable_x64(False):
+        text = fn.trace(
+            sds((t, d), bf), sds((count, d, hidden), bf),
+            sds((count, d, hidden), bf), sds((count, hidden, d), bf),
+            sds((t, k), jnp.int32), sds((t, k), jnp.float32)).lower(
+                lowering_platforms=("tpu",)).as_text()
+    assert text.count('kernel_name = "sorted_experts"') == calls

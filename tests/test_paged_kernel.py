@@ -287,6 +287,34 @@ def test_grouped_experts_compiles_for_v5e(v5e_chip, t, d, hidden, count):
     assert not [c for c in copied if c.startswith(f"{count},")]
 
 
+@pytest.mark.parametrize("t,d,hidden,count,n,k", [
+    (512, 3584, 1024, 64, 64, 4),      # xing.serve-reason's prefill_512
+    (1024, 7168, 2048, 12, 384, 8),    # k2.serve-docqa's prefill_1024
+    (1024, 3072, 1024, 32, 256, 10)])  # laguna.serve-mixed-8k's
+def test_sorted_experts_compiles_for_v5e(v5e_chip, t, d, hidden, count, n,
+                                         k):
+    """The sorted path of a prefill bucket at the expert cells' widths
+    through the chip's compiler (k2's four row tiles of f32 ``y`` beside
+    its weight tiles are the most VMEM), the weight stacks read as they
+    are stored."""
+    import re
+
+    from deeplearning4j_tpu.helpers.grouped_experts import sorted_experts
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    bf = jnp.bfloat16
+    fn = jax.jit(lambda *a: sorted_experts(*a, n_experts=n, interpret=False))
+    with jax.enable_x64(False):
+        text = fn.lower(
+            sds((t, d), bf), sds((count, d, hidden), bf),
+            sds((count, d, hidden), bf), sds((count, hidden, d), bf),
+            sds((t, k), jnp.int32), sds((t, k), jnp.float32)
+        ).compile().as_text()
+    assert "sorted_experts" in text
+    copied = re.findall(r"= bf16\[([\d,]+)\][^ ]* copy\(", text)
+    assert not [c for c in copied if c.startswith(f"{count},")]
+
+
 # ------------------------------------------- the latent pool, read in place
 # the two served latent shapes: (slots, heads, pages a slot) of
 # xing.serve-reason and k2.serve-docqa; width 640, value 512, page 64
