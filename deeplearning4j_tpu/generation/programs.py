@@ -70,6 +70,7 @@ from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
 from deeplearning4j_tpu.nn.layers.composite import gauging
+from deeplearning4j_tpu.nn.layers.delta_net import GatedDeltaNetLayer
 from deeplearning4j_tpu.nn.layers.latent_attention import LatentAttentionLayer
 from deeplearning4j_tpu.nn.layers.moe import RoutedMoELayer, counting
 from deeplearning4j_tpu.nn.layers.state_space import MambaLayer
@@ -323,11 +324,13 @@ class GenerationPrograms:
             for zero in (False, True)} if self.latent_layers else {}
         self.num_window_pages = window_pool_pages(self.slots, self.ring)
         # state slots: whether a layer keeps one row of state a slot, and
-        # how each compute program runs its state-space layers' recurrence
-        # (``MambaLayer.path`` of its positions a row, the rule the layer
-        # branches on when the program is traced), by ``"decode"`` / bucket
+        # how each compute program runs its state-space and delta-rule
+        # layers' recurrence (``MambaLayer.path`` / ``GatedDeltaNetLayer.
+        # path`` of its positions a row, the rule the layer branches on when
+        # the program is traced), by ``"decode"`` / bucket
         self.state = has_state_pools(net)
-        self.state_space_layers = _layers_of_kind(net, MambaLayer)
+        self.state_space_layers = _layers_of_kind(
+            net, (MambaLayer, GatedDeltaNetLayer))
         self.state_space_paths = {
             name: tuple(sorted({l.path(t) for l in self.state_space_layers}))
             for name, t in {**rows, "decode": 1}.items()
@@ -619,25 +622,36 @@ class GenerationPrograms:
                 self.slots, vmem / 2 ** 20)
 
     def _log_state_space(self) -> None:
-        """How each compute program runs its state-space layers' recurrence
-        and what a slot's state weighs, once a program and shape."""
+        """How each compute program runs its state-space and delta-rule
+        layers' recurrence and what a slot's state weighs, once a program
+        and shape."""
         from deeplearning4j_tpu.helpers import get_helper
 
-        helper = get_helper("selective_scan")
-        shapes = sorted({(l.d_inner, l.d_state, l.d_conv)
-                         for l in self.state_space_layers})
-        for name, paths in self.state_space_paths.items():
-            t = 1 if name == "decode" else name
-            how = ("one pass over the rows' states" if t == 1
-                   else helper.describe(t) if helper is not None
-                   else f"lax scan, {t} trips of 1 time step")
-            for d, n, k in shapes:
-                logger.info(
-                    "generation.%s: state-space layers of %d channels x %d "
-                    "state columns (%s): %s; %d state slots + the trash row, "
-                    "%.1f kB of float32 state and a tail of %d rows a slot a "
-                    "layer", name if t == 1 else f"prefill_{name}", d, n,
-                    "/".join(paths), how, self.slots, d * n * 4 / 1e3, k - 1)
+        kinds = (
+            (MambaLayer, get_helper("selective_scan"),
+             lambda l: (f"state-space layers of {l.d_inner} channels x "
+                        f"{l.d_state} state columns", l.d_inner * l.d_state,
+                        l.d_conv)),
+            (GatedDeltaNetLayer, get_helper("delta_rule"),
+             lambda l: (f"delta-rule layers of {l.n_heads} heads x "
+                        f"[{l.d_k}, {l.d_v}] (rows {list(l.state_shape())})",
+                        l.n_heads * l.d_k * l.d_v, l.d_conv)))
+        for cls, helper, what in kinds:
+            shapes = sorted({what(l) for l in self.state_space_layers
+                             if isinstance(l, cls)})
+            for name, paths in self.state_space_paths.items():
+                t = 1 if name == "decode" else name
+                how = ("one pass over the rows' states" if t == 1
+                       else helper.describe(t) if helper is not None
+                       else f"lax scan, {t} trips of 1 time step")
+                for label, entries, k in shapes:
+                    logger.info(
+                        "generation.%s: %s (%s): %s; %d state slots + the "
+                        "trash row, %.1f kB of float32 state and a tail of "
+                        "%d rows a slot a layer",
+                        name if t == 1 else f"prefill_{name}", label,
+                        "/".join(paths), how, self.slots, entries * 4 / 1e3,
+                        k - 1)
 
     def _log_expert_tiling(self) -> None:
         """How ``grouped_experts`` tiles each compute program whose expert

@@ -1,0 +1,208 @@
+"""The gated delta rule of a Gated DeltaNet mixer (Yang, Kautz and
+Hatamizadeh, arXiv:2412.06464), behind the helper seam
+``get_helper("delta_rule")``.
+
+Per batch row and head, a state ``S`` [d_k, d_v], all in float32::
+
+    S_t = a_t (I - b_t k_t k_t^T) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T q_t
+
+with the decay ``a_t = exp(g_t)`` handed in as its logarithm ``g_t <= 0``.
+
+Two layouts of the state.  THE HEAD LAYOUT ``[B, H, d_k, d_v]`` is the one
+the mathematics reads, and the chunked form's.  THE SLOT LAYOUT ``[B, P, d_k,
+G * d_v]`` is the one a state slot stores and the decode step reads: the
+heads in ``P = H / G`` groups of ``G`` (``slot_group``), a group's heads
+side by side on the minor axis, so that a group is whole lanes of 128.  At
+d_v = 192 a head alone would pad 192 lanes to 256 (a third more bytes on
+every decode step); two heads are 384 = 3 x 128 lanes with nothing padded,
+and the 96 rows of d_k are whole sublanes.  In that layout the decode step
+is elementwise: head ``m`` of a group owns lanes ``[m d_v, (m + 1) d_v)``,
+and a head's scalar or d_k-vector is laid along its lanes by a select on
+the lane index (``_on_lanes``), so no reshape of the state's minor axis
+(which would re-lay the whole state out in memory) is ever made.
+
+Three forms.  ``chunked`` is the form every backend runs for a chunk or a
+sequence (the WY form): per chunk of ``DELTA_CHUNK`` positions, with ``G``
+the cumulative log decay inside the chunk,
+
+    A = strict_tril((b K K^T) * exp(G_i - G_j)),   T = (I + A)^-1
+    W = T (b exp(G) K),   U = T (b V)            (a triangular solve)
+    O  = (Q exp(G)) S + tril(Q K^T * exp(G_i - G_j)) (U - W S)
+    S' = exp(G_C) S + (K exp(G_C - G))^T (U - W S)
+
+every chunk's ``A``, ``W``, ``U`` and masked ``Q K^T`` made at once, then a
+``lax.scan`` over the chunks carrying ``S`` (matrix products only).
+``stepwise`` is the recurrence a position at a time, the plain form and the
+layer's built-in path where helpers are off.  ``single_step`` is the decode
+step on the slot layout.  The small products run at ``highest`` precision.
+No Pallas kernel ships: ``PERF.md`` (PR 41) holds what the chip showed.
+
+``live`` [B] (a prefill bucket's real tokens): positions at or past it leave
+the state untouched (a decay of 1 and a ``b`` of 0); their outputs are
+finite and mean nothing.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# positions a chunk of the WY form; chosen on the chip (PERF.md, PR 41)
+DELTA_CHUNK = 64
+_HIGHEST = lax.Precision.HIGHEST
+_LANES = 128
+
+
+def slot_group(heads: int, d_v: int) -> int:
+    """Heads side by side in a group of the slot layout: the fewest that
+    divide ``heads`` and fill whole lanes of 128, or 1 where none does."""
+    for g in range(1, heads + 1):
+        if heads % g == 0 and (g * d_v) % _LANES == 0:
+            return g
+    return 1
+
+
+def to_heads(s, heads: int):
+    """Slot layout [B, P, d_k, G d_v] -> head layout [B, H, d_k, d_v]."""
+    b, p, dk, lanes = s.shape
+    g = heads // p
+    s = s.reshape(b, p, dk, g, lanes // g)
+    return jnp.moveaxis(s, 3, 2).reshape(b, heads, dk, lanes // g)
+
+
+def to_slots(s, group: int):
+    """Head layout [B, H, d_k, d_v] -> slot layout [B, H / G, d_k, G d_v]."""
+    b, h, dk, dv = s.shape
+    s = jnp.moveaxis(s.reshape(b, h // group, group, dk, dv), 2, 3)
+    return s.reshape(b, h // group, dk, group * dv)
+
+
+def mask_padding(g, beta, live):
+    """Decay 1 and no write at positions at or past ``live``."""
+    if live is None:
+        return g, beta
+    valid = (jnp.arange(g.shape[1])[None] < live[:, None])[..., None]
+    return jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+
+
+def stepwise(q, k, v, g, beta, s0, live=None):
+    """The recurrence one position a trip.  ``q``, ``k`` [B, T, H, d_k];
+    ``v`` [B, T, H, d_v]; ``g``, ``beta`` [B, T, H]; ``s0`` [B, H, d_k, d_v]
+    (head layout).  Returns ``(o [B, T, H, d_v], S_T)``."""
+    g, beta = mask_padding(g, beta, live)
+
+    def step(s, inp):
+        q_t, k_t, v_t, g_t, b_t = inp
+        ks = jnp.einsum("bhk,bhkv->bhv", k_t, s, precision=_HIGHEST)
+        a = jnp.exp(g_t)[..., None]
+        w = b_t[..., None] * (v_t - a * ks)
+        s = a[..., None] * s + k_t[..., None] * w[:, :, None, :]
+        return s, jnp.einsum("bhk,bhkv->bhv", q_t, s, precision=_HIGHEST)
+
+    s, o = lax.scan(step, s0, tuple(jnp.moveaxis(x, 1, 0)
+                                    for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+def chunked(q, k, v, g, beta, s0, live=None, chunk=None):
+    """As ``stepwise``, in the WY form (module docstring), ``chunk``
+    (``DELTA_CHUNK``) positions a trip of the loop; a length that ``chunk``
+    does not divide is padded with positions that keep the state."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    c = max(1, min(int(chunk or DELTA_CHUNK), t))
+    n = -(-t // c)
+    pad = n * c - t
+    if live is None and pad:
+        live = jnp.full((bsz,), t, jnp.int32)
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+    g, beta = mask_padding(g, beta, live)
+
+    def chunks(x):          # [B, n c, H, ...] -> [n, B, H, c, ...]
+        x = x.reshape((bsz, n, c) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=-1)                          # [n, B, H, c]
+    i = jnp.arange(c)
+    lower = i[:, None] >= i[None, :]
+    decay = jnp.exp(jnp.where(lower, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))                  # [.., c, c]
+    kk = jnp.einsum("...ik,...jk->...ij", k, k, precision=_HIGHEST)
+    a = jnp.where(i[:, None] > i[None, :],
+                  beta[..., :, None] * kk * decay, 0.0)
+    rhs = jnp.concatenate([(beta * jnp.exp(cum))[..., None] * k,
+                           beta[..., None] * v], axis=-1)
+    wu = lax.linalg.triangular_solve(
+        a + jnp.eye(c, dtype=a.dtype), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    w, u = wu[..., :dk], wu[..., dk:]
+    qk = jnp.einsum("...ik,...jk->...ij", q, k, precision=_HIGHEST) * decay
+    qg = q * jnp.exp(cum)[..., None]
+    kd = k * jnp.exp(cum[..., -1:] - cum)[..., None]
+    last = jnp.exp(cum[..., -1])[..., None, None]          # [n, B, H, 1, 1]
+
+    def trip(s, inp):
+        w_c, u_c, qg_c, qk_c, kd_c, last_c = inp
+        d = u_c - jnp.einsum("bhck,bhkv->bhcv", w_c, s, precision=_HIGHEST)
+        o = (jnp.einsum("bhck,bhkv->bhcv", qg_c, s, precision=_HIGHEST)
+             + jnp.einsum("bhcj,bhjv->bhcv", qk_c, d, precision=_HIGHEST))
+        s = last_c * s + jnp.einsum("bhck,bhcv->bhkv", kd_c, d,
+                                    precision=_HIGHEST)
+        return s, o
+
+    s, o = lax.scan(trip, s0, (w, u, qg, qk, kd, last))  # o [n, B, H, c, dv]
+    o = jnp.moveaxis(o, 0, 1)                             # [B, n, H, c, dv]
+    o = jnp.moveaxis(o, 3, 2).reshape(bsz, n * c, h, dv)
+    return (o[:, :t] if pad else o), s
+
+
+def _on_lanes(x, group: int, d_v: int):
+    """``x`` [B, H, ...] (a head's scalar, or its d_k-vector) -> [B, P, ...,
+    G d_v]: at lane ``j`` of group ``p`` the value of head ``p G + j //
+    d_v``, by selects on the lane index (no reshape of the minor axis)."""
+    b, h = x.shape[:2]
+    x = x.reshape((b, h // group, group) + x.shape[2:])
+    lane = lax.broadcasted_iota(jnp.int32, (group * d_v,), 0)
+    out = x[:, :, 0, ..., None]
+    for m in range(1, group):
+        out = jnp.where(lane >= m * d_v, x[:, :, m, ..., None], out)
+    return jnp.broadcast_to(out, out.shape[:-1] + (group * d_v,))
+
+
+def single_step(q, k, v, g, beta, s):
+    """One token a row on the SLOT layout, no loop: ``q``, ``k`` [B, H, d_k];
+    ``v`` [B, H, d_v]; ``g``, ``beta`` [B, H]; ``s`` [B, P, d_k, G d_v].
+    Returns ``(o [B, H, d_v], s')``: one pass reads the state for ``S^T k``
+    and ``S^T q``, a second writes ``a S + k w^T`` with ``w = b (v - a S^T
+    k)``; ``o = a S^T q + (k . q) w``."""
+    bsz, p, _, lanes = s.shape
+    h, dv = q.shape[1], v.shape[-1]
+    group = h // p
+    k_rows = _on_lanes(k, group, dv)                      # [B, P, d_k, L]
+    ks = jnp.sum(k_rows * s, axis=2)                      # [B, P, L]
+    qs = jnp.sum(_on_lanes(q, group, dv) * s, axis=2)
+    a = _on_lanes(jnp.exp(g), group, dv)                  # [B, P, L]
+    w = _on_lanes(beta, group, dv) * (v.reshape(bsz, p, lanes) - a * ks)
+    s = a[:, :, None, :] * s + k_rows * w[:, :, None, :]
+    kq = _on_lanes(jnp.sum(k * q, axis=-1), group, dv)
+    return (a * qs + kq * w).reshape(bsz, h, dv), s
+
+
+class DeltaRuleHelper:
+    """The seam's object: ``chunked`` for a chunk or a sequence, the ``lax``
+    WY form on every backend."""
+
+    def chunked(self, q, k, v, g, beta, s0, live=None):
+        return chunked(q, k, v, g, beta, s0, live)
+
+    def describe(self, t: int) -> str:
+        """How a program of ``t`` positions a row is chunked, for the
+        warm-up's log."""
+        c = max(1, min(DELTA_CHUNK, t))
+        return (f"lax WY form, {-(-t // c)} chunks of {c} positions "
+                f"(a triangular solve and matrix products a chunk)")

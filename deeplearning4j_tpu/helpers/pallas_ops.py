@@ -355,6 +355,10 @@ def register_default_helpers() -> None:
             SelectiveScanHelper)
 
         _helpers.register_helper("selective_scan", SelectiveScanHelper())
+    if "delta_rule" not in _helpers._registry:
+        from deeplearning4j_tpu.helpers.delta_rule import DeltaRuleHelper
+
+        _helpers.register_helper("delta_rule", DeltaRuleHelper())
     if "epilogue" not in _helpers._registry:
         from deeplearning4j_tpu.helpers.fused_epilogue import FusedEpilogueHelper
 

@@ -30,6 +30,7 @@ import numpy as np
 from deeplearning4j_tpu.nn import activations, initializers
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
+from deeplearning4j_tpu.nn.layers.normalization import rms_norm
 
 
 def split_heads(x: jax.Array, n_heads: int) -> jax.Array:
@@ -327,6 +328,10 @@ class SelfAttentionLayer(Layer):
     # "per_head": head h's output times sigmoid(x Wg)[h] ahead of Wo
     # (a head-wise output gate computed from the layer's input)
     gate: Optional[str] = None
+    # Olmo's whole-width q/k norm: an RMSNorm of this eps with a gain
+    # (q_norm [H * D], k_norm [Hkv * D]) over all of q and all of k, ahead
+    # of the heads' split and the rotation; None = no norm
+    qk_norm_eps: Optional[float] = None
 
     def setup(self, input_type: InputType) -> "SelfAttentionLayer":
         upd = {}
@@ -395,6 +400,9 @@ class SelfAttentionLayer(Layer):
             p["Wg"] = initializers.init(
                 self.weight_init, jax.random.fold_in(ks[3], 1),
                 (self.n_in, self.n_heads), dtype)
+        if self.qk_norm_eps is not None:
+            p["q_norm"] = jnp.ones((q_out,), dtype)
+            p["k_norm"] = jnp.ones((kv_out,), dtype)
         return p
 
     # ------------------------------------------------------------ the parts
@@ -404,8 +412,15 @@ class SelfAttentionLayer(Layer):
             y = x @ params[w]
             return y + params[b] if self.bias else y
 
-        return (split_heads(lin("Wq", "bq"), self.n_heads),
-                split_heads(lin("Wk", "bk"), self._kv_heads),
+        def normed(w, b, gain):
+            y = lin(w, b)
+            if self.qk_norm_eps is None:
+                return y
+            with jax.named_scope("qk_norm"):
+                return rms_norm(y, params[gain], self.qk_norm_eps)
+
+        return (split_heads(normed("Wq", "bq", "q_norm"), self.n_heads),
+                split_heads(normed("Wk", "bk", "k_norm"), self._kv_heads),
                 split_heads(lin("Wv", "bv"), self._kv_heads))
 
     def _rotate(self, x, positions):

@@ -246,7 +246,11 @@ class GenerationMetrics:
             "(nn.layers.state_space.state_space_path): step = one pass over "
             "the rows' states, no loop (a single token a row: the decode "
             "step), scan = the helper seam's chunked lax scan (a prefill "
-            "bucket), kernel = a Pallas kernel where the seam offers one",
+            "bucket), kernel = a Pallas kernel where the seam offers one; "
+            "for delta-rule layers "
+            "(nn.layers.delta_net.delta_rule_path): delta_step = the decode "
+            "step on the slot layout, delta_chunk = the seam's chunked WY "
+            "form (a prefill bucket)",
             labels=("stage", "path"))
         self.state_slot_resets = reg.counter(
             "dl4j_state_slot_resets_total",

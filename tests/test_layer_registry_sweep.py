@@ -26,6 +26,7 @@ _KWARGS = {
     "DenseLayer": dict(n_in=4, n_out=3),
     "DropoutLayer": dict(dropout=0.5),
     "EmbeddingLayer": dict(n_in=7, n_out=4),
+    "GatedDeltaNetLayer": dict(n_in=4, n_out=4, n_heads=2, d_k=3, d_v=2),
     "GatedMLP": dict(n_in=4, n_out=4, hidden=6),
     "GlobalPoolingLayer": dict(),
     "GravesBidirectionalLSTM": dict(n_in=3, n_out=4),
@@ -63,6 +64,7 @@ _INPUTS = {
     "DenseLayer": (2, 4),
     "DropoutLayer": (2, 5),
     "EmbeddingLayer": (2, 3),          # integer ids
+    "GatedDeltaNetLayer": (2, 5, 4),
     "GatedMLP": (2, 4),
     "GlobalPoolingLayer": (2, 4, 4, 3),
     "GravesBidirectionalLSTM": (2, 5, 3),

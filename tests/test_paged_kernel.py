@@ -967,3 +967,66 @@ def test_the_state_slots_are_stepped_in_place_on_v5e(v5e_chip, lanes, t):
     copies = re.findall(r"= f32\[129,16,5120\][^ ]* copy\(", text)
     assert not copies, copies
     assert ("while(" in text) == (t > 1)        # the chunked scan's loop
+
+
+@pytest.mark.parametrize("b,t", [(128, 1), (1, 512)])
+def test_olmo_hybrid_shapes_compile_for_v5e(v5e_chip, b, t):
+    """``olmo-hybrid-7b-pp4``'s full layers: 30 query heads over 30 kv heads
+    of 128 (a group of ONE row a kv head, plain multi-head attention),
+    pages of 64, 24 a slot, through the chip's compiler."""
+    ps, hq, hkv, d, maxp = 64, 30, 30, 128, 24
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    pool = sds((128 * maxp + 1, hkv, ps, d), jnp.bfloat16)
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, impl="pallas", interpret=False))
+    with jax.enable_x64(False):
+        compiled = fn.lower(sds((b, t, hq, d), jnp.bfloat16), pool, pool,
+                            sds((b, maxp), jnp.int32),
+                            sds((b, t), jnp.int32)).compile()
+    assert "fused_paged_attention" in compiled.as_text()
+    ppb, tq, vmem = paged_tiling(b, t, hq, hkv, d, ps, maxp, jnp.bfloat16)
+    assert vmem <= VMEM_BUDGET and (tq == t or t % tq == 0)
+
+
+@pytest.mark.parametrize("lanes,t", [(128, 1), (1, 512)])
+def test_the_delta_rule_slots_are_stepped_in_place_on_v5e(v5e_chip, lanes, t):
+    """``GatedDeltaNetLayer`` at ``olmo-hybrid-7b-pp4``'s widths over its
+    state slots, compiled for the described chip with the pools donated:
+    the slot layout [15, 96, 384] is whole tiles, and neither the decode
+    step (128 lanes: two passes over the pool where it lies, the second
+    writing in place) nor a prefill bucket (one row scattered back) makes a
+    pool-sized ``copy`` of ``sh`` (283 MB a layer a step) or a gather of
+    the state rows."""
+    import re
+
+    from deeplearning4j_tpu.nn.layers import GatedDeltaNetLayer
+
+    layer = GatedDeltaNetLayer(n_in=3840, n_out=3840, n_heads=30, d_k=96,
+                               d_v=192, allow_neg_eigval=True, name="g")
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    with jax.enable_x64(False):
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+        pool = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                lambda: layer.init_paged_cache(2, 64, jnp.bfloat16,
+                                               state_slots=128)))
+
+        def run(p, u, pool, where, pos):
+            carry = {**pool, "pos": pos,
+                     **({"lanes": where > 0} if t == 1 else
+                        {"rows": where, "live": pos + t - 5})}
+            y, _, new = layer.apply_with_carry(p, {}, u, carry)
+            return y, {k: new[k] for k in pool}
+
+        text = jax.jit(run, donate_argnums=(2,)).lower(
+            params, sds((lanes, t, 3840), jnp.bfloat16), pool,
+            sds((lanes,), jnp.int32), sds((lanes,), jnp.int32)
+        ).compile().as_text()
+    state = r"f32\[(129|128),15,96,384\][^ ]* "
+    assert not re.findall(state + r"(copy|gather|scatter)\(", text)
+    assert re.findall(r"= f32\[129,15,96,384\][^ ]* dynamic-update-slice\(",
+                      text)
+    # the chunked form's solve (a prefill) is the chip's own expansion
+    assert ("InvertDiagBlocksLowerTriangular" in text) == (t > 1)
