@@ -22,7 +22,7 @@ and a head's scalar or d_k-vector is laid along its lanes by a select on
 the lane index (``_on_lanes``), so no reshape of the state's minor axis
 (which would re-lay the whole state out in memory) is ever made.
 
-Three forms.  ``chunked`` is the form every backend runs for a chunk or a
+Four forms.  ``chunked`` is the form every backend runs for a chunk or a
 sequence (the WY form): per chunk of ``DELTA_CHUNK`` positions, with ``G``
 the cumulative log decay inside the chunk,
 
@@ -35,8 +35,18 @@ every chunk's ``A``, ``W``, ``U`` and masked ``Q K^T`` made at once, then a
 ``lax.scan`` over the chunks carrying ``S`` (matrix products only).
 ``stepwise`` is the recurrence a position at a time, the plain form and the
 layer's built-in path where helpers are off.  ``single_step`` is the decode
-step on the slot layout.  The small products run at ``highest`` precision.
-No Pallas kernel ships: ``PERF.md`` (PR 41) holds what the chip showed.
+step on the slot layout, in ``jnp``: XLA makes it a reduction pass over the
+rows for ``S^T k`` and ``S^T q`` and an update pass that reads and writes
+them again.  ``step_slots`` is the same step as ONE Pallas kernel over a
+pool of state slots: a grid over the lanes, each lane's row copied into
+VMEM once, stepped there (``S^T k``, ``S^T q`` and the update as exact
+float32 elementwise arithmetic, a head's vectors laid along its lanes by
+selects as ``_on_lanes`` lays them) and written back once, in place (the
+pool is aliased to the result).  The helper offers it on the TPU
+(``DeltaRuleHelper.kernel``), where the engine's decode step on the
+state slots takes it; elsewhere, and for every other call of a single
+token, ``single_step`` runs.  The small products of the other forms run at
+``highest`` precision.
 
 ``live`` [B] (a prefill bucket's real tokens): positions at or past it leave
 the state untouched (a decay of 1 and a ``b`` of 0); their outputs are
@@ -45,14 +55,23 @@ finite and mean nothing.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.helpers import interpret_mode as _interpret
 
 # positions a chunk of the WY form; chosen on the chip (PERF.md, PR 41)
 DELTA_CHUNK = 64
 _HIGHEST = lax.Precision.HIGHEST
 _LANES = 128
+# VMEM for the step kernel's own temporaries on top of its pipeline's rows
+STEP_VMEM_HEADROOM = 8 * 2 ** 20
 
 
 def slot_group(heads: int, d_v: int) -> int:
@@ -193,9 +212,122 @@ def single_step(q, k, v, g, beta, s):
     return (a * qs + kq * w).reshape(bsz, h, dv), s
 
 
+def step_vmem_bytes(row_shape) -> int:
+    """VMEM the kernel's pipeline holds for rows of ``row_shape`` [P, d_k,
+    G d_v] float32: a row copied in and one copied out, each twice."""
+    return 4 * 4 * math.prod(row_shape)
+
+
+def _step_kernel(fresh_ref, lanes_ref, tab_ref, v_ref, s_ref, s_out, o_ref,
+                 *, heads, group, d_v):
+    """One lane a grid step: ``s_ref`` its row [P, d_k, L] in VMEM, ``tab_ref``
+    [d_k + 8, 2 H] its heads' ``k`` (columns ``[0, H)``) and ``q`` (``[H,
+    2 H)``) down the first d_k rows, then ``exp(g)``, ``beta`` and ``k . q``
+    in three rows under ``k``'s columns; ``v_ref`` [P, L] its ``v`` on the
+    slot layout."""
+    b = pl.program_id(0)
+    pairs, dk, lanes = s_ref.shape
+    fresh = jnp.full((dk, lanes), fresh_ref[b]) != 0
+    live = jnp.full((dk, lanes), lanes_ref[b]) != 0
+    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    tab = tab_ref[...]
+
+    def on_lanes(rows, col):
+        """Head ``col + m``'s column ``tab[rows, col + m]`` over lanes ``[m
+        d_v, (m + 1) d_v)``."""
+        out = tab[rows, col:col + 1]
+        for m in range(1, group):
+            out = jnp.where(lane >= m * d_v, tab[rows, col + m:col + m + 1],
+                            out)
+        return jnp.broadcast_to(out, (out.shape[0], lanes))
+
+    for p in range(pairs):
+        h = p * group
+        s_was = s_ref[p]
+        s = jnp.where(fresh, 0.0, s_was)
+        k = on_lanes(slice(0, dk), h)
+        ks = jnp.sum(k * s, axis=0, keepdims=True)          # [1, L]
+        qs = jnp.sum(on_lanes(slice(0, dk), heads + h) * s, axis=0,
+                     keepdims=True)
+        a = on_lanes(slice(dk, dk + 1), h)
+        w = on_lanes(slice(dk + 1, dk + 2), h) * (v_ref[p:p + 1, :] - a * ks)
+        o_ref[p:p + 1, :] = a * qs + on_lanes(slice(dk + 2, dk + 3), h) * w
+        s_out[p] = jnp.where(live, a * s + k * w, s_was)
+
+
+# jitted so that the layers of a program share one trace and one lowering
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_step(q, k, v, g, beta, sh, fresh, lanes, interpret):
+    bsz, heads, dk = q.shape
+    dv = v.shape[-1]
+    _, pairs, _, width = sh.shape
+    ext = jnp.stack([jnp.exp(g), beta, jnp.sum(k * q, axis=-1)], axis=1)
+    tab = jnp.concatenate(
+        [jnp.swapaxes(jnp.concatenate([k, q], axis=1), 1, 2),
+         jnp.pad(ext, ((0, 0), (0, 5), (0, heads)))], axis=1)
+    row = pl.BlockSpec((None, pairs, dk, width),
+                       lambda b, fresh, lanes: (b + 1, 0, 0, 0))
+    on_slots = pl.BlockSpec((None, pairs, width),
+                            lambda b, fresh, lanes: (b, 0, 0))
+    s, o = pl.pallas_call(
+        functools.partial(_step_kernel, heads=heads, group=heads // pairs,
+                          d_v=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bsz,),
+            in_specs=[pl.BlockSpec((None, dk + 8, 2 * heads),
+                                   lambda b, fresh, lanes: (b, 0, 0)),
+                      on_slots, row],
+            out_specs=[row, on_slots]),
+        out_shape=[jax.ShapeDtypeStruct(sh.shape, sh.dtype),
+                   jax.ShapeDtypeStruct((bsz, pairs, width), jnp.float32)],
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(step_vmem_bytes(sh.shape[1:])
+                              + STEP_VMEM_HEADROOM)),
+        interpret=interpret,
+        name="delta_state_step",
+    )(fresh.astype(jnp.int32), lanes.astype(jnp.int32), tab,
+      v.reshape(bsz, pairs, width), sh)
+    return o.reshape(bsz, heads, dv), s
+
+
+def step_slots(q, k, v, g, beta, sh, fresh, lanes, *, interpret=None):
+    """``single_step`` on a pool of STATE SLOTS in place, as one Pallas
+    kernel: ``sh`` [B + 1, P, d_k, G d_v] float32 (row 0 the trash row, lane
+    ``i``'s row ``i + 1``), ``q``, ``k`` [B, H, d_k], ``v`` [B, H, d_v],
+    ``g``, ``beta`` [B, H], ``fresh`` / ``lanes`` [B] bool.  A ``fresh``
+    lane steps from zero state; a lane not in ``lanes`` keeps its row as it
+    was, bit for bit; row 0 is not touched.  Returns ``(o [B, H, d_v],
+    sh')``, ``sh'`` aliasing ``sh``'s buffer where the caller donated it."""
+    bsz, heads, dk = q.shape
+    if (sh.ndim != 4 or sh.shape[0] != bsz + 1 or sh.shape[2] != dk
+            or heads % sh.shape[1] or sh.dtype != jnp.float32
+            or sh.shape[3] != heads // sh.shape[1] * v.shape[-1]):
+        raise ValueError(
+            f"step_slots: pool {sh.shape} {sh.dtype} does not hold {bsz} "
+            f"lanes of {heads} heads x [{dk}, {v.shape[-1]}] float32 on the "
+            "slot layout behind a trash row")
+    if interpret is None:
+        interpret = _interpret()
+    return _pallas_step(q, k, v, g, beta, sh, fresh, lanes, interpret)
+
+
 class DeltaRuleHelper:
     """The seam's object: ``chunked`` for a chunk or a sequence, the ``lax``
-    WY form on every backend."""
+    WY form on every backend; ``step_slots``, the decode step on the state
+    slots in one kernel, where ``kernel`` offers it."""
+
+    @property
+    def kernel(self) -> bool:
+        """Whether the decode step on state slots runs as ``step_slots``:
+        compiled, on the TPU; the host counts
+        ``dl4j_state_space_steps_total{path}`` by it."""
+        return not _interpret()
+
+    def step_slots(self, q, k, v, g, beta, sh, fresh, lanes):
+        return step_slots(q, k, v, g, beta, sh, fresh, lanes)
 
     def chunked(self, q, k, v, g, beta, s0, live=None):
         return chunked(q, k, v, g, beta, s0, live)

@@ -39,8 +39,12 @@ Three carries, one layer, as ``MambaLayer``'s:
   ``live`` move neither state nor tail, and the decode step updates the
   pool in place by ``lanes``.
 
-A chunk or a sequence goes through one seam, ``get_helper("delta_rule")``;
-the single-token step is plain ``jnp`` on the slot layout.
+A chunk or a sequence goes through one seam, ``get_helper("delta_rule")``.
+So does the decode step on the state slots where the seam offers its
+kernel (the TPU): one Pallas kernel reads each lane's row once and writes
+it once, in place (``delta_rule.step_slots``, the ``delta_kernel`` path).
+Every other single-token step — the contiguous carry, another backend — is
+plain ``jnp`` on the slot layout (``delta_rule.single_step``).
 """
 
 from __future__ import annotations
@@ -60,20 +64,24 @@ from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.nn.layers.normalization import rms_norm
 
-DELTA_RULE_PATHS = ("delta_step", "delta_chunk")
+DELTA_RULE_PATHS = ("delta_step", "delta_chunk", "delta_kernel")
 # FLA's init: A uniform in (0, A_INIT_MAX], the step log-uniform in this range
 A_INIT_MAX = 16.0
 DT_INIT_MIN, DT_INIT_MAX = 1e-3, 1e-1
 L2_EPS = 1e-6
 
 
-def delta_rule_path(t: int) -> str:
-    """Which of ``DELTA_RULE_PATHS`` a call of ``t`` positions a row takes:
-    ``"delta_step"`` for a single token (the slot layout, no loop), else
-    ``"delta_chunk"``, the helper seam's WY form.  Pure: the layer branches
-    on it while it is traced, the engine calls it on the host to count
-    ``dl4j_state_space_steps_total``."""
-    return "delta_step" if t == 1 else "delta_chunk"
+def delta_rule_path(t: int, kernel: bool = False) -> str:
+    """Which of ``DELTA_RULE_PATHS`` a call of ``t`` positions a row on the
+    state slots takes: for a single token ``"delta_kernel"`` where the
+    helper seam offers its kernel (``kernel``: the pool stepped in place by
+    ``delta_rule.step_slots``), else ``"delta_step"`` (the slot layout in
+    ``jnp``, no loop); for more, ``"delta_chunk"``, the seam's WY form.
+    Pure: the layer branches on it while it is traced, the engine calls it
+    on the host to count ``dl4j_state_space_steps_total``."""
+    if t == 1:
+        return "delta_kernel" if kernel else "delta_step"
+    return "delta_chunk"
 
 
 def _l2_normalize(x):
@@ -164,8 +172,11 @@ class GatedDeltaNetLayer(Layer):
 
     # ------------------------------------------------------------ the parts
     def path(self, t: int) -> str:
-        """``delta_rule_path`` of a call of ``t`` positions a row."""
-        return delta_rule_path(t)
+        """``delta_rule_path`` of a call of ``t`` positions a row on the
+        state slots, as the process stands (the kernel only where the
+        helper seam offers it)."""
+        helper = helpers.get_helper("delta_rule")
+        return delta_rule_path(t, helper is not None and helper.kernel)
 
     def _conv(self, params, window):
         """Step 2 on ``window`` [B, d_conv - 1 + T, q + k + v channels] (the
@@ -206,10 +217,12 @@ class GatedDeltaNetLayer(Layer):
             y = o.reshape(bsz, t, -1) * jax.nn.silu(z.astype(jnp.float32))
             return y.astype(z.dtype) @ params["W_o"]
 
-    def _sequence(self, params, u, s0, tail, live=None):
+    def _sequence(self, params, u, s0, tail, live=None, step=None):
         """A chunk ``u`` [B, T, F] from state ``s0`` (slot layout) and
         ``tail`` [B, d_conv - 1, channels]: ``(out, S, tail')``; positions
-        at or past ``live`` [B] move neither."""
+        at or past ``live`` [B] move neither.  ``step``: a single token's
+        rule ``(q, k, v, g, beta) -> (o, S)`` in place of ``single_step``
+        from ``s0``."""
         k_rows = self.d_conv - 1
         t = u.shape[1]
         with jax.named_scope("gdn_proj"):
@@ -219,13 +232,13 @@ class GatedDeltaNetLayer(Layer):
             z = u @ params["W_g"]
         window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
         x = self._conv(params, window)
-        step = self.path(t) == "delta_step"
-        with jax.named_scope("gdn_state" if step else "gdn_chunk"):
+        with jax.named_scope("gdn_state" if t == 1 else "gdn_chunk"):
             q, k, v, g, beta = self._rule(params, x, a, b)
-            if step:
+            if t == 1:
                 g, beta = dr.mask_padding(g, beta, live)
-                o, s = dr.single_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                      beta[:, 0], s0)
+                one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                o, s = (step(*one) if step is not None
+                        else dr.single_step(*one, s0))
                 o = o[:, None]
             else:
                 helper = helpers.get_helper("delta_rule")
@@ -292,24 +305,37 @@ class GatedDeltaNetLayer(Layer):
         """The paged carry (module docstring), as ``MambaLayer``'s: a
         prefill's ``rows`` are gathered and scattered; the decode step
         steps the whole pool past the trash row where it lies, an idle
-        lane's row kept as it was."""
+        lane's row kept as it was — on the ``delta_kernel`` path in one
+        kernel that reads and writes each row once."""
         sh, sc, lanes = carry["sh"], carry["sc"], carry.get("lanes")
         fresh = carry["pos"] == 0
         rows = carry.get("rows")
         scope = "gdn_state" if x.shape[1] == 1 else "gdn_chunk"
-        with jax.named_scope(scope):
-            s_was = sh[1:] if rows is None else sh[rows]
-            s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                           s_was).astype(jnp.float32)
+        step = s0 = None
+        if rows is None and self.path(x.shape[1]) == "delta_kernel":
+            helper = helpers.get_helper("delta_rule")
+
+            def step(*one):
+                return helper.step_slots(*one, sh, fresh, lanes)
+        else:
+            with jax.named_scope(scope):
+                s_was = sh[1:] if rows is None else sh[rows]
+                s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                               s_was).astype(jnp.float32)
         with jax.named_scope("gdn_conv"):
             tail_was = sc[1:] if rows is None else sc[rows]
             tail = jnp.where(fresh[:, None, None], jnp.zeros((), sc.dtype),
                              tail_was)
-        out, s, tail = self._sequence(params, x, s0, tail, carry.get("live"))
+        out, s, tail = self._sequence(params, x, s0, tail, carry.get("live"),
+                                      step)
         with jax.named_scope(scope):
-            sh = (sh.at[1:].set(jnp.where(lanes[:, None, None, None], s,
-                                          s_was))
-                  if rows is None else sh.at[rows].set(s))
+            if step is not None:
+                sh = s
+            elif rows is None:
+                sh = sh.at[1:].set(jnp.where(lanes[:, None, None, None], s,
+                                             s_was))
+            else:
+                sh = sh.at[rows].set(s)
         with jax.named_scope("gdn_conv"):
             sc = (sc.at[1:].set(jnp.where(lanes[:, None, None], tail,
                                           tail_was))

@@ -2,7 +2,8 @@
 
 The repo's fused kernels (Pallas flash attention, the fused LRN/BN
 passes, the paged-attention decode path, the streamed experts of a decode
-step and the sorted experts of a prefill) were validated by their unit
+step and the sorted experts of a prefill, the delta rule's decode step on
+state slots) were validated by their unit
 tests — which is trust by sampling.  This module is trust by SWEEP: run
 every fused kernel against an independent float64 numpy reference over
 a shape × dtype × masking grid, record per-config max-abs / max-rel
@@ -181,6 +182,17 @@ def _np_grouped_experts(x, wg, wu, wd, c) -> np.ndarray:
         h = g / (1.0 + np.exp(-g)) * (x @ np.asarray(wu[e], np.float64))
         y += c[:, e:e + 1] * (h @ np.asarray(wd[e], np.float64))
     return y
+
+def _np_delta_step(q, k, v, g, beta, s) -> Tuple[np.ndarray, np.ndarray]:
+    """float64 gated delta rule, one token a head on the HEAD layout
+    (``helpers/delta_rule.py``): ``S' = a S + k w^T`` with ``w = beta (v -
+    a S^T k)``, ``o = S'^T q``."""
+    q, k, v, s = (np.asarray(x, np.float64) for x in (q, k, v, s))
+    a = np.exp(np.asarray(g, np.float64))[..., None]
+    w = np.asarray(beta, np.float64)[..., None] * (
+        v - a * np.einsum("bhk,bhkv->bhv", k, s))
+    s = a[..., None] * s + k[..., None] * w[:, :, None, :]
+    return np.einsum("bhk,bhkv->bhv", q, s), s
 
 def _np_lrn(x2d, k, n, alpha, beta) -> np.ndarray:
     x = np.asarray(x2d, np.float64)
@@ -492,6 +504,51 @@ def _run_grouped_experts(cfg) -> Tuple[Any, np.ndarray]:
         out = grouped_experts(x, wg, wu, wd, c, touched, interpret=True)
     return out, _np_grouped_experts(x, wg, wu, wd, c)
 
+def _delta_step_configs(full: bool):
+    # the toy's heads (two of d_v 64 to a row of 128 lanes) and Olmo-Hybrid's
+    # row [15, 96, 384]; lanes fresh, stepped and idle in each
+    grids = [{"shape": [4, 4, 16, 64]}, {"shape": [4, 30, 96, 192]}]
+    if full:
+        grids += [{"shape": [6, 3, 8, 128]}]     # a group of one head
+    for g in grids:
+        yield dict(g, dtype="float32")
+
+def _run_delta_step(cfg) -> Tuple[Any, np.ndarray]:
+    """The decode step on a pool of state slots both ways behind the seam
+    (``single_step`` with the pool's selects, and the interpreted Pallas
+    kernel ``step_slots``) against one f64 step on the head layout, output
+    and pool in one flat comparison: lane 0 steps from zero state (fresh),
+    lane 1 keeps its row (idle), the others step."""
+    from deeplearning4j_tpu.helpers import delta_rule as dr
+    b, h, dk, dv = cfg["shape"]
+    group = dr.slot_group(h, dv)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(_rng(b, h, dk, dtype=jnp.float32, seed=50)) * dk ** -0.5
+    k = unit(_rng(b, h, dk, dtype=jnp.float32, seed=51))
+    v = _rng(b, h, dv, dtype=jnp.float32, seed=52)
+    g = -jax.nn.softplus(_rng(b, h, dtype=jnp.float32, seed=53))
+    beta = 2.0 * jax.nn.sigmoid(_rng(b, h, dtype=jnp.float32, seed=54))
+    sh = _rng(b + 1, h // group, dk, group * dv, dtype=jnp.float32, seed=55)
+    fresh = jnp.arange(b) == 0
+    lanes = jnp.arange(b) != 1
+    s_was = sh[1:]
+    o_jnp, s_jnp = dr.single_step(
+        q, k, v, g, beta, jnp.where(fresh[:, None, None, None], 0.0, s_was))
+    s_jnp = jnp.where(lanes[:, None, None, None], s_jnp, s_was)
+    o_pl, pool = dr.step_slots(q, k, v, g, beta, sh, fresh, lanes,
+                               interpret=True)
+    s0 = np.asarray(dr.to_heads(s_was, h), np.float64)
+    s0[0] = 0.0
+    ro, rs = _np_delta_step(q, k, v, g, beta, s0)
+    rs[1] = np.asarray(dr.to_heads(s_was, h))[1]
+    # to the slot layout (dr.to_slots, kept in float64)
+    rs = np.moveaxis(rs.reshape(b, h // group, group, dk, dv), 2, 3).reshape(
+        b, h // group, dk, group * dv)
+    out = jnp.concatenate([x.reshape(-1) for x in
+                           (o_jnp, s_jnp, o_pl, pool[1:])])
+    ref = np.concatenate([x.reshape(-1) for x in (ro, rs, ro, rs)])
+    return out, ref
+
 def _pallas2d_configs(full: bool):
     shapes = [(32, 24)]
     if full:
@@ -540,6 +597,7 @@ KERNELS: Dict[str, Tuple[Callable, Callable, bool]] = {
     "fused_dropout_residual_norm": (_epilogue_configs, _run_epilogue, False),
     "grouped_experts": (_grouped_experts_configs, _run_grouped_experts,
                         False),
+    "delta_state_step": (_delta_step_configs, _run_delta_step, False),
     "pallas_lrn": (_pallas2d_configs, _run_lrn, False),
     "pallas_bn_inference": (_pallas2d_configs, _run_bn_inference, False),
     "pallas_bn_training": (_pallas2d_configs, _run_bn_training, False),

@@ -248,9 +248,12 @@ class GenerationMetrics:
             "step), scan = the helper seam's chunked lax scan (a prefill "
             "bucket), kernel = a Pallas kernel where the seam offers one; "
             "for delta-rule layers "
-            "(nn.layers.delta_net.delta_rule_path): delta_step = the decode "
-            "step on the slot layout, delta_chunk = the seam's chunked WY "
-            "form (a prefill bucket)",
+            "(nn.layers.delta_net.delta_rule_path): delta_kernel = the "
+            "decode step on the state slots as one Pallas kernel that reads "
+            "and writes each row once, in place (where the seam offers it: "
+            "the TPU), delta_step = the decode step on the slot layout in "
+            "jnp, delta_chunk = the seam's chunked WY form (a prefill "
+            "bucket)",
             labels=("stage", "path"))
         self.state_slot_resets = reg.counter(
             "dl4j_state_slot_resets_total",

@@ -2,12 +2,14 @@
 delta-rule layers on state slots beside multi-head full attention with
 whole-width q/k norms on pages — against the plain reference
 ``benchmark/reference_olmo_hybrid.py`` at a toy size on seeded random
-weights: the three forms of the delta rule against each other, the layer
-alone, the q/k-norm attention layer, the whole model through ``net.output``,
-through ``models.decode.generate`` / ``rnn_time_step`` and through
+weights: the forms of the delta rule against each other (the decode step's
+Pallas kernel on a pool of state slots interpreted), the layer alone, the
+q/k-norm attention layer, the whole model through ``net.output``, through
+``models.decode.generate`` / ``rnn_time_step`` and through
 ``GenerationEngine`` (bucketed prefill, then decode through slots and
-pages, slots reused, bucket padding), the faults that must fail the same
-comparison, the slot's bytes and the parameter count."""
+pages, slots reused, bucket padding; with and without the kernel), the
+faults that must fail the same comparison, the slot's bytes and the
+parameter count."""
 
 import json
 import os
@@ -160,11 +162,89 @@ def test_the_decode_step_on_the_slot_layout_is_one_step(h, dv, group):
 
 
 def test_the_path_rule_is_pure():
-    took = [delta_rule_path(t) for t in (1, 2, 512)]
-    assert took == ["delta_step", "delta_chunk", "delta_chunk"]
+    took = [delta_rule_path(t, k) for t, k in
+            ((1, False), (2, False), (512, True), (1, True))]
+    assert took == ["delta_step", "delta_chunk", "delta_chunk",
+                    "delta_kernel"]
     assert set(took) == set(DELTA_RULE_PATHS)
+    assert [delta_rule_path(1, True) for _ in range(3)] == ["delta_kernel"] * 3
+    # off the TPU the seam offers no kernel
     layer, _, _, _ = mixer_and_leaves()
     assert (layer.path(1), layer.path(256)) == ("delta_step", "delta_chunk")
+
+
+def slot_pool_inputs(b, h, dk, dv, seed):
+    """One token a lane, a pool of ``b`` slots behind a trash row on the slot
+    layout, and ``fresh`` / ``lanes`` with both values among the lanes."""
+    one = rule_inputs(b=b, t=1, h=h, dk=dk, dv=dv, seed=seed)[:5]
+    group = dr.slot_group(h, dv)
+    sh = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                           (b + 1, h // group, dk, group * dv), jnp.float32)
+    fresh = jnp.arange(b) % 3 == 1
+    lanes = jnp.arange(b) % 4 != 2
+    return (tuple(x[:, 0].astype(jnp.float32) for x in one), sh, fresh,
+            lanes)
+
+
+def jnp_step_on_slots(one, sh, fresh, lanes):
+    """The ``delta_step`` path's sequence on the pool: ``single_step`` from
+    the rows (zero where ``fresh``), written back where ``lanes``."""
+    s_was = sh[1:]
+    s0 = jnp.where(fresh[:, None, None, None], 0.0, s_was)
+    o, s = dr.single_step(*one, s0)
+    return o, sh.at[1:].set(jnp.where(lanes[:, None, None, None], s, s_was))
+
+
+@pytest.mark.parametrize("b,h,dk,dv", [(3, 4, 16, 64), (5, 4, 8, 8),
+                                       (4, 3, 8, 128), (3, 30, 96, 192),
+                                       (5, 30, 96, 192)])
+def test_the_kernel_steps_the_slots_as_the_jnp_step(b, h, dk, dv):
+    """``step_slots`` (the Pallas kernel, interpreted) against
+    ``single_step`` and the pool's ``where`` / ``.at[1:].set`` at the toy's
+    widths, at groups of one head, and at Olmo's row ``[15, 96, 384]``:
+    one output and one pool to float32 rounding; an idle lane's row and the
+    trash row bit for bit; a fresh lane from zero state."""
+    one, sh, fresh, lanes = slot_pool_inputs(b, h, dk, dv, seed=b + h)
+    o, pool = dr.step_slots(*one, sh, fresh, lanes)
+    want_o, want_pool = jnp_step_on_slots(one, sh, fresh, lanes)
+    assert pool.shape == sh.shape and pool.dtype == jnp.float32
+    assert _gap(o, want_o) < 1e-5 and _gap(pool, want_pool) < 1e-5
+    assert (np.asarray(pool[0]) == np.asarray(sh[0])).all()
+    idle = np.flatnonzero(~np.asarray(lanes)) + 1
+    assert len(idle) and (np.asarray(pool)[idle] == np.asarray(sh)[idle]).all()
+    # a fresh lane's step does not read its row: any row gives one result
+    fresh_live = np.flatnonzero(np.asarray(fresh & lanes))
+    assert len(fresh_live)
+    noise = sh.at[1 + fresh_live].set(1e3)
+    o2, pool2 = dr.step_slots(*one, noise, fresh, lanes)
+    assert _gap(o2[fresh_live], o[fresh_live]) == 0.0
+    assert _gap(pool2[1 + fresh_live], pool[1 + fresh_live]) == 0.0
+    _, zero_s = dr.single_step(*(x[fresh_live] for x in one),
+                               jnp.zeros_like(sh[1 + fresh_live]))
+    assert _gap(pool[1 + fresh_live], zero_s) < 1e-5
+
+
+def test_the_kernel_refuses_a_pool_it_cannot_step():
+    one, sh, fresh, lanes = slot_pool_inputs(3, 4, 16, 64, seed=0)
+    with pytest.raises(ValueError, match="trash row"):
+        dr.step_slots(*one, sh[1:], fresh, lanes)
+    with pytest.raises(ValueError, match="float32"):
+        dr.step_slots(*one, sh.astype(jnp.bfloat16), fresh, lanes)
+
+
+def test_the_seam_offers_the_kernel_on_the_tpu(monkeypatch):
+    helper = helpers.get_helper("delta_rule")
+    layer, _, _, _ = mixer_and_leaves()
+    assert not helper.kernel and layer.path(1) == "delta_step"     # the CPU
+    monkeypatch.setattr(dr, "_interpret", lambda: False)
+    assert helper.kernel
+    assert (layer.path(1), layer.path(2)) == ("delta_kernel", "delta_chunk")
+    helpers.enable_helpers(False)
+    try:
+        assert layer.path(1) == "delta_step"
+    finally:
+        helpers.enable_helpers(True)
+    assert dr.step_vmem_bytes((15, 96, 384)) == 4 * 2_211_840
 
 
 # ----------------------------------------------------- (b) the layer alone
@@ -349,6 +429,51 @@ def test_engine_serves_the_toy_model_as_the_reference():
     assert reg.get_value("dl4j_state_slots_in_use", engine=eid) == 0
     assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
                          path="step") is None
+
+
+def test_engine_serves_the_reference_through_the_kernel(monkeypatch):
+    """The seam made to offer the kernel (interpreted off the chip): the
+    decode program steps every delta-rule layer's pool by ``step_slots``,
+    the served tokens stay the reference's, and the counter reads
+    ``delta_kernel`` once a dispatched decode step, ``delta_step`` never."""
+    monkeypatch.setattr(dr.DeltaRuleHelper, "kernel", True)
+    net, cfg = toy_net()
+    eng, gaps = served_gaps(net, cfg, some_requests(count=5, seed=3))
+    assert gaps.max() < TOL, gaps
+    reg = eng.metrics.registry
+    dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
+                     for m in ("ahead", "sync"))
+    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
+                         path="delta_kernel") == dispatched > 0
+    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
+                         path="delta_step") is None
+    assert reg.get_value("dl4j_state_space_steps_total", stage="prefill",
+                         path="delta_chunk") == 5
+
+
+# The toy net's programs as the parent commit d30b33b lowers them, where the
+# seam offers no kernel (every backend but the TPU): the decode step's jnp
+# form is the same program instruction for instruction
+OLMO_PARENT = {"prefill_16": "163a72a5cd7d33ee",
+               "prefill_32": "b04af1423a6cf4c9",
+               "decode": "ad5408fd14b19165"}
+
+
+def test_without_the_kernel_the_programs_are_the_parents():
+    import hashlib
+
+    from deeplearning4j_tpu.generation.programs import GenerationPrograms
+
+    net, _ = toy_net()
+    progs = GenerationPrograms(net, slots=4, pages_per_slot=6, page_size=8,
+                               num_pages=25, prefill_buckets=(16, 32))
+    assert progs.state_space_paths == {"decode": ("delta_step",),
+                                       16: ("delta_chunk",),
+                                       32: ("delta_chunk",)}
+    got = {name: hashlib.sha256(low.as_text().replace(
+        f"@jit_{name} ", "@jit_prefill ").encode()).hexdigest()[:16]
+           for name, low in progs.lowered().items()}
+    assert got == OLMO_PARENT
 
 
 @pytest.mark.parametrize("fault", ["token_altered", "state_not_reset",

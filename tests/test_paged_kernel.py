@@ -1030,3 +1030,46 @@ def test_the_delta_rule_slots_are_stepped_in_place_on_v5e(v5e_chip, lanes, t):
                       text)
     # the chunked form's solve (a prefill) is the chip's own expansion
     assert ("InvertDiagBlocksLowerTriangular" in text) == (t > 1)
+
+
+def test_the_delta_rule_kernel_steps_the_slots_in_place_on_v5e(v5e_chip,
+                                                               monkeypatch):
+    """The same decode step where the seam offers the kernel (as on the
+    chip): one ``delta_state_step`` call a layer, the pool aliased to its
+    result — no pool-sized ``copy``, gather, scatter or update of ``sh``
+    beside it, so each row is read once and written once."""
+    import re
+
+    from deeplearning4j_tpu.helpers import delta_rule
+    from deeplearning4j_tpu.nn.layers import GatedDeltaNetLayer
+
+    monkeypatch.setattr(delta_rule, "_interpret", lambda: False)
+    layer = GatedDeltaNetLayer(n_in=3840, n_out=3840, n_heads=30, d_k=96,
+                               d_v=192, allow_neg_eigval=True, name="g")
+    assert layer.path(1) == "delta_kernel"
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    with jax.enable_x64(False):
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+        pool = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                lambda: layer.init_paged_cache(2, 64, jnp.bfloat16,
+                                               state_slots=128)))
+
+        def run(p, u, pool, lanes, pos):
+            carry = {**pool, "pos": pos, "lanes": lanes > 0}
+            y, _, new = layer.apply_with_carry(p, {}, u, carry)
+            return y, {k: new[k] for k in pool}
+
+        compiled = jax.jit(run, donate_argnums=(2,)).lower(
+            params, sds((128, 1, 3840), jnp.bfloat16), pool,
+            sds((128,), jnp.int32), sds((128,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"= \(f32\[129,15,96,384\][^=]*custom-call\(", text)
+    assert len(calls) == 1 and "delta_state_step" in text
+    state = r"f32\[(129|128),15,96,384\][^ ]* "
+    assert not re.findall(
+        state + r"(copy|gather|scatter|dynamic-update-slice|fusion)\(", text)
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 129 * 2_211_840
