@@ -616,6 +616,8 @@ class GenerationEngine:
         for path in progs.latent_paths.get((bucket, req.shared_len == 0), ()):
             self.metrics.latent_attention_steps.inc(stage="prefill",
                                                     path=path)
+        for path in progs.paged_paths.get(bucket, ()):
+            self.metrics.paged_attention_steps.inc(stage="prefill", path=path)
         for path in progs.state_space_paths.get(bucket, ()):
             self.metrics.state_space_steps.inc(stage="prefill", path=path)
         if progs.state:
@@ -665,6 +667,8 @@ class GenerationEngine:
             self.metrics.moe_expert_steps.inc(stage="decode", path=path)
         for path in progs.latent_paths.get(("decode", False), ()):
             self.metrics.latent_attention_steps.inc(stage="decode", path=path)
+        for path in progs.paged_paths.get("decode", ()):
+            self.metrics.paged_attention_steps.inc(stage="decode", path=path)
         for path in progs.state_space_paths.get("decode", ()):
             self.metrics.state_space_steps.inc(stage="decode", path=path)
         return _Step(sampled, rows, mv.name,

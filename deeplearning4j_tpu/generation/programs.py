@@ -322,6 +322,20 @@ class GenerationPrograms:
                 for l in self.latent_layers}))
             for name, t in {**rows, "decode": 1}.items()
             for zero in (False, True)} if self.latent_layers else {}
+        # how each compute program attends over its SelfAttentionLayers'
+        # pages (``paged_attention.paged_path``, the rule ``_apply_paged``
+        # follows when the program is traced), by ``"decode"`` / bucket; a
+        # window layer's prefill chunk attends over its own keys, not pages
+        from deeplearning4j_tpu.helpers.paged_attention import paged_path
+
+        shapes = _paged_attention_shapes(net)
+        self.paged_paths = {
+            name: tuple(sorted({
+                paged_path(t, hq, hkv, self.page_size,
+                           self.ring if window else self.pages_per_slot,
+                           window or None)
+                for hq, hkv, _, window in shapes if t == 1 or not window}))
+            for name, t in {**rows, "decode": 1}.items()} if shapes else {}
         self.num_window_pages = window_pool_pages(self.slots, self.ring)
         # state slots: whether a layer keeps one row of state a slot, and
         # how each compute program runs its state-space and delta-rule
@@ -588,14 +602,17 @@ class GenerationPrograms:
             pages = self.ring if window else self.pages_per_slot
             for name, b, t in programs[-1:] if window else programs:
                 ppb, tq, vmem = pa.paged_tiling(
-                    b, t, hq, hkv, d, self.page_size, pages, dtype)
+                    b, t, hq, hkv, d, self.page_size, pages, dtype,
+                    window=window or None)
                 logger.info(
                     "generation.%s: fused_paged_attention q [%d, %d, %d, "
                     "%d] over %d pages of %d%s: %d pages a block, %d query "
-                    "positions a tile, grid (%d, %d), %.2f MB of VMEM",
-                    name, b, t, hq, d, pages, self.page_size,
+                    "positions a tile, grid (%d, %d), %.2f MB of VMEM, the "
+                    "%s form", name, b, t, hq, d, pages, self.page_size,
                     f" (a ring, window {window})" if window else "",
-                    ppb, tq, b, -(-t // tq), vmem / 2 ** 20)
+                    ppb, tq, b, -(-t // tq), vmem / 2 ** 20,
+                    pa.paged_form(t, hq, hkv, self.page_size, pages,
+                                  window or None))
 
     def _log_latent_tiling(self) -> None:
         """How ``latent_paged_attention`` tiles the decode step of a net

@@ -37,6 +37,23 @@ Two implementations behind one public op:
   ``row_tile`` the largest that keeps one grid step's buffers within
   ``VMEM_BUDGET`` (8 MB of the chip's 16 MB scoped limit) at any group
   size and bucket; no argument or environment variable tunes it.
+  That is the ``"rows"`` form.  Where each kv head has ONE query row
+  (``t == 1`` and ``Hq == Hkv``: multi-head attention's decode step) a
+  kv head's tile would be one real row in a padded sublane tile and the
+  block's work 30 short chains of two products and a softmax, one a
+  head, bound by their latencies and not by the copy they overlap.  The
+  ``"heads"`` form (``paged_form``, from the shapes alone) stacks every
+  kv head's row into ONE tile instead: a block's pages land head-major
+  in VMEM, so K of every head is one ``[Hkv * bk, D]`` operand; one
+  product gives each row its scores against all heads' keys, an iota
+  mask keeps the row's own head's, one online-softmax update serves all
+  heads, and one product of the block-diagonal probabilities with V
+  updates every head's accumulator.  Copies, dead-block rule, masking
+  and f32 arithmetic are the ``"rows"`` form's.  At
+  ``olmoh.serve-think``'s decode shape (30 heads, pages of 64, 128
+  lanes) a call took 3.20 ms in ``"rows"`` and takes 1.76 in
+  ``"heads"``, against 1.64 for a kernel that only copies the same
+  blocks (one TPU v5e; PERF.md §6, the ``heads`` form).
 - ``impl="lax"`` (default elsewhere): a compiled ``lax.fori_loop`` over
   pages with the same online-softmax accumulator, gathering only one
   ``[B, Hkv, page_size, D]`` page slab per iteration.  The loop bound
@@ -301,9 +318,50 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+# how a program attends over a SelfAttentionLayer's pages: the kernel in one
+# of its two forms (``paged_form``), the compiled lax page loop, or the
+# gather oracle
+PAGED_PATHS = ("heads", "rows", "lax", "gather")
+
+
+def paged_form(t: int, hq: int, hkv: int, page_size: int, maxp: int,
+               window: Optional[int] = None,
+               v_width: Optional[int] = None) -> str:
+    """How the Pallas kernel lays out its rows, from the shapes alone:
+    ``"heads"`` where each kv head has ONE query row (``t == 1``, a group
+    of one: multi-head attention's decode step) over a global pool whose
+    block is whole lanes of keys — every kv head's row stacked in one tile,
+    one product for all heads a block; ``"rows"`` otherwise — each kv
+    head's ``G * row_tile`` rows in a tile of their own (a window's ring
+    and a latent pool always take it)."""
+    bk = max(1, min(BLOCK_KEYS // page_size, maxp)) * page_size   # a block
+    if (t == 1 and hq == hkv and window is None and v_width is None
+            and bk % LANES == 0):
+        return "heads"
+    return "rows"
+
+
+def paged_path(t: int, hq: int, hkv: int, page_size: int, maxp: int,
+               window: Optional[int] = None) -> str:
+    """How a paged call of ``SelfAttentionLayer`` attends, one of
+    ``PAGED_PATHS``: the gather oracle where the seam gives way (no
+    helper, ``set_paged_attention_mode("gather")``), the lax page loop
+    off the TPU, else the kernel in its ``paged_form``.  The rule
+    ``_apply_paged`` follows when a program is traced; the engine counts
+    ``dl4j_paged_attention_steps_total`` by it."""
+    from deeplearning4j_tpu.helpers import get_helper
+
+    if (get_helper("paged_attention") is None
+            or paged_attention_mode() != "fused"):
+        return "gather"
+    if default_impl() != "pallas":
+        return "lax"
+    return paged_form(t, hq, hkv, page_size, maxp, window)
+
+
 def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
-                 maxp: int, dtype, v_width: Optional[int] = None
-                 ) -> Tuple[int, int, int]:
+                 maxp: int, dtype, v_width: Optional[int] = None,
+                 window: Optional[int] = None) -> Tuple[int, int, int]:
     """How the Pallas kernel tiles ``q`` [b, t, hq, d] over pools
     [P, hkv, page_size, d] of ``dtype`` behind a block table [b, maxp]:
     ``(pages_per_block, row_tile, vmem_bytes)``.
@@ -328,6 +386,11 @@ def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
     (its copies' issue and wait, the softmax state's update) would be most
     of the step.  A block is then the pages ``SLAB_BLOCK_BYTES`` hold
     (never under ``BLOCK_KEYS`` keys, never more than the table has).
+
+    In the ``"heads"`` form (``paged_form``) the row tile is the one
+    position and the buffers are the page slots, the tile of every kv
+    head's row (q, output, f32 softmax state) and the scores and
+    probabilities of all heads' keys of a block.
     """
     item = jnp.dtype(dtype).itemsize
     sub = _sublanes(dtype)
@@ -342,6 +405,11 @@ def paged_tiling(b: int, t: int, hq: int, hkv: int, d: int, page_size: int,
     ppb = max(1, min(ppb, maxp))
     bk = ppb * page_size
     pages = 2 * pools * ppb * hkv * page_size * dpad * item
+    if paged_form(t, hq, hkv, page_size, maxp, window, v_width) == "heads":
+        rows = _round_up(hkv, sub)
+        return ppb, 1, (pages + rows * (2 * 2 * dpad * item    # q, o
+                                        + dpad * 4 + 2 * LANES * 4
+                                        + hkv * bk * (4 + 4 + item)))
 
     def vmem(tq):
         rows = _round_up(g * tq, sub)
@@ -463,6 +531,119 @@ def _paged_kernel(blk_ref, qmax_ref, *refs, scale, page_size, maxp,
     o_ref[...] = (acc_scr[...] / safe).astype(o_ref.dtype)
 
 
+def _heads_kernel(blk_ref, qpos_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                  sem, m_scr, l_scr, acc_scr, *, scale, page_size, maxp):
+    """The ``"heads"`` form: one query row a kv head, the rows of ``q_ref``
+    [rows, D] being the heads.  A block's pages land head-major in
+    ``kbuf`` [2, Hkv, bk, D], so K of every head is one [Hkv * bk, D]
+    operand: one product gives every row's scores against every head's
+    keys, of which row ``h`` keeps its own ``bk`` columns; the online
+    softmax runs once for all heads; the probabilities go back into row
+    ``h``'s own columns of a block-diagonal [rows, Hkv * bk] (zeros
+    elsewhere) for one product with V.  Copies, dead-block rule and
+    masking are the ``"rows"`` form's."""
+    b = pl.program_id(0)
+    _, hkv, bk, dpad = kbuf.shape
+    ppb = bk // page_size
+    rows = q_ref.shape[0]
+    qpos = qpos_ref[b]
+    nblk = jnp.minimum(qpos // bk + 1, pl.cdiv(maxp, ppb))
+
+    def copies(j, slot):
+        out = []
+        for i in range(ppb):
+            p = j * ppb + i
+            if maxp % ppb:
+                p = jnp.minimum(p, maxp - 1)
+            page = blk_ref[b * maxp + p]
+            keys = pl.ds(i * page_size, page_size)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], kbuf.at[slot, :, keys], sem.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], vbuf.at[slot, :, keys], sem.at[1, slot]))
+        return out
+
+    for c in copies(0, 0):
+        c.start()
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    head = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+
+    def block_step(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < nblk)
+        def _prefetch():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        s_all = _dot_f32(q_ref[...], kbuf[slot].reshape(hkv * bk, dpad),
+                         trans_b=True)                 # [rows, Hkv * bk]
+        s = s_all[:, :bk]
+        for h in range(1, hkv):
+            s = jnp.where(head == h, s_all[:, h * bk:(h + 1) * bk], s)
+        s = jnp.where(j * bk + col <= qpos, s * scale, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p_exp = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p_exp, axis=1, keepdims=True)
+        p_bd = jnp.concatenate([jnp.where(head == h, p_exp, 0.0)
+                                for h in range(hkv)], axis=1)
+        acc_scr[...] = acc_scr[...] * alpha + _dot_f32(
+            p_bd.astype(vbuf.dtype), vbuf[slot].reshape(hkv * bk, dpad))
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    jax.lax.fori_loop(0, nblk, block_step, None)
+    l = l_scr[:, :1]
+    safe = jnp.where(l > 0, l, 1.0)                   # the padding rows
+    o_ref[...] = (acc_scr[...] / safe).astype(o_ref.dtype)
+
+
+def _pallas_heads(q, pools, block, q_positions, interpret, scale, ppb):
+    """The ``"heads"`` form's call: ``q`` [B, 1, H, D] (D whole lanes) over
+    ``pools`` (K, V) [P, H, page_size, D], grid ``(B,)``, a lane's position
+    by scalar prefetch."""
+    b, _, h, dpad = q.shape
+    page_size, maxp = pools[0].shape[2], block.shape[1]
+    rows = _round_up(h, _sublanes(q.dtype))
+    qh = jnp.pad(q.reshape(b, h, dpad), ((0, 0), (0, rows - h), (0, 0)))
+    prefetch = (block.astype(jnp.int32).reshape(-1),
+                q_positions[:, 0].astype(jnp.int32))
+    tile = pl.BlockSpec((None, rows, dpad), lambda bi, *prefetched: (bi, 0, 0))
+    o = pl.pallas_call(
+        functools.partial(_heads_kernel, scale=scale, page_size=page_size,
+                          maxp=maxp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b,),
+            in_specs=[tile] + [pl.BlockSpec(memory_space=pl.ANY)
+                               for _ in pools],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((2, h, ppb * page_size, dpad), p.dtype)
+                for p in pools
+            ] + [
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((rows, LANES), jnp.float32),
+                pltpu.VMEM((rows, LANES), jnp.float32),
+                pltpu.VMEM((rows, dpad), jnp.float32),
+            ],
+        ),
+        out_shape=_sds((b, rows, dpad), q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="fused_paged_attention",
+    )(*prefetch, qh, *pools)
+    return o[:, None, :h]
+
+
 # jitted so that the layers of a program that call it at one shape share
 # one trace and one lowering: the kernel's unrolled copies and heads make
 # those the dear part (six layers x three programs took 3 s of the serve
@@ -498,7 +679,10 @@ def _pallas_paged(q, pk, pv, block, q_positions, interpret, window=None,
     dv = v_width if latent else d
     dvpad = _round_up(dv, LANES)
     ppb, tq, _ = paged_tiling(b, t, hq, hkv, d, page_size, maxp, pk.dtype,
-                              v_width)
+                              v_width, window)
+    if paged_form(t, hq, hkv, page_size, maxp, window, v_width) == "heads":
+        return _pallas_heads(q, pools, block, q_positions, interpret, scale,
+                             ppb)[..., :d]
     nt = -(-t // tq)
     tpad = nt * tq - t
     # [B, tiles, Hkv, G*tq, D]: one grid step owns one (batch row, tile of

@@ -371,6 +371,11 @@ def _fused_paged_configs(full: bool):
              "d": 128, "b": 4, "t": 1},
             {"pages": 50, "page_size": 16, "maxp": 20, "hq": 18, "hkv": 2,
              "d": 128, "b": 2, "t": 40},
+            # multi-head decode (one query row a kv head): the kernel's
+            # ``heads`` form, blocks of 2 pages of 64 over a table that is
+            # not whole blocks
+            {"pages": 30, "page_size": 64, "maxp": 5, "hq": 6, "hkv": 6,
+             "d": 128, "b": 4, "t": 1},
         ]
     for g in grids:
         for dtype in ("float32", "bfloat16"):

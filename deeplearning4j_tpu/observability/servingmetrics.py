@@ -238,6 +238,20 @@ class GenerationMetrics:
             "a decode step where the kernel gives way), expanded = flash "
             "attention over the chunk's own decompressed keys (a prompt "
             "prefilled from position 0)", labels=("stage", "path"))
+        self.paged_attention_steps = reg.counter(
+            "dl4j_paged_attention_steps_total",
+            "Dispatched decode steps (stage=decode) and prefills "
+            "(stage=prefill) of a net with paged self-attention layers, by "
+            "how the program attends over their pages "
+            "(helpers.paged_attention.paged_path): heads = the kernel with "
+            "every kv head's one query row in one tile, one product for "
+            "all heads a block (a group of one row a kv head: a "
+            "multi-head decode step), rows = the kernel with each kv "
+            "head's query rows in a tile of their own (grouped heads, a "
+            "prefill chunk, a window's ring), lax = the compiled page loop "
+            "(every backend but the TPU), gather = the gathered view and "
+            "one softmax (the oracle switch, helpers disabled)",
+            labels=("stage", "path"))
         self.state_space_steps = reg.counter(
             "dl4j_state_space_steps_total",
             "Dispatched decode steps (stage=decode) and prefills "

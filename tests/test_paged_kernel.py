@@ -26,9 +26,10 @@ from deeplearning4j_tpu.helpers.fused_epilogue import (
     FusedEpilogueHelper, dropout_residual_norm,
 )
 from deeplearning4j_tpu.helpers.paged_attention import (
-    SLAB_BLOCK_BYTES, VMEM_BUDGET, PagedAttentionHelper,
-    paged_attention_mode, paged_decode_attention, paged_latent_attention,
-    paged_tiling, set_paged_attention_mode, write_token_rows,
+    PAGED_PATHS, SLAB_BLOCK_BYTES, VMEM_BUDGET, PagedAttentionHelper,
+    paged_attention_mode, paged_decode_attention, paged_form,
+    paged_latent_attention, paged_path, paged_tiling,
+    set_paged_attention_mode, write_token_rows,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     SelfAttentionLayer, gather_pages, paged_attention,
@@ -104,6 +105,19 @@ CONFIGS = {
     "g12_bf16_chunk": dict(pages=40, page_size=16, maxp=12, b=1, t=48,
                            hq=24, hkv=2, d=128, dtype=jnp.bfloat16,
                            trash_row=False, qlast=[130 + 47]),
+    # a group of ONE row a kv head (multi-head decode: the kernel's
+    # ``heads`` form), head 128, page 64: a block is 2 pages, so the
+    # 5-page table ends in a block of 1.  Rows: the all-trash row, one
+    # that ends mid-page, one at the end of a block's last page, one at
+    # the end of the table's last page, one inside a block
+    "mha_decode": dict(pages=30, page_size=64, maxp=5, b=5, t=1, hq=6,
+                       hkv=6, d=128,
+                       qlast=[0, 64 + 10, 2 * 64 - 1, 5 * 64 - 1, 3 * 64 + 5]),
+    # olmo-hybrid-7b's 30 heads in bf16
+    "mha_decode_bf16": dict(pages=30, page_size=64, maxp=5, b=5, t=1, hq=30,
+                            hkv=30, d=128, dtype=jnp.bfloat16,
+                            qlast=[0, 64 + 10, 2 * 64 - 1, 5 * 64 - 1,
+                                   3 * 64 + 5]),
 }
 
 
@@ -201,6 +215,16 @@ def test_tiling_at_serving_shapes(b, t, hq, hkv, maxp):
     # the bound is what tiles the rows: one more doubling would pass it
     assert tq == t or paged_tiling(
         b, tq * 2, hq, hkv, 128, 16, maxp, jnp.bfloat16)[1] == tq
+
+
+def test_the_mha_configs_take_the_heads_form():
+    for name in ("mha_decode", "mha_decode_bf16"):
+        cfg = CONFIGS[name]
+        assert paged_form(cfg["t"], cfg["hq"], cfg["hkv"], cfg["page_size"],
+                          cfg["maxp"]) == "heads", name
+    assert {paged_form(c["t"], c["hq"], c["hkv"], c["page_size"], c["maxp"])
+            for n, c in CONFIGS.items() if not n.startswith("mha_d")} == {
+                "rows"}
 
 
 def test_tiling_never_takes_more_pages_than_the_table_has():
@@ -431,7 +455,11 @@ def test_latent_tiling_sizes_a_block_by_its_bytes(cell):
 
 # (b, t, hq, hkv, page, maxp) -> (pages a block, row tile, VMEM bytes) as
 # the parent commit d0c4831 gives them: the GQA cells' calls (head 128,
-# bf16) must not move when the latent pool gets a block of its own
+# bf16) must not move when the latent pool gets a block of its own.  The
+# last eight, as the parent commit 3147cf0 gives them, must not move when a
+# group of one gets a form of its own: jamba2-3b's calls (20 heads over one
+# kv head), olmo-hybrid-7b's prefills (30 over 30, 32 positions a tile),
+# sc2-7b.serve-generate's
 GQA_TILINGS = {
     (32, 1, 36, 4, 16, 36): (8, 1, 724992),      # sc2-7b.serve-complete
     (1, 256, 36, 4, 16, 36): (8, 64, 7749632),
@@ -442,7 +470,18 @@ GQA_TILINGS = {
     (32, 1, 72, 8, 64, 9): (2, 1, 1413120),      # a ring of 9 pages
     (1, 1024, 48, 8, 64, 136): (2, 32, 5423104),
     (1, 8192, 48, 8, 64, 136): (2, 32, 5423104),
+    (128, 1, 20, 1, 64, 24): (2, 1, 286720),     # jamba2.serve-chat
+    (1, 256, 20, 1, 64, 24): (2, 64, 6356992),
+    (1, 512, 20, 1, 64, 24): (2, 64, 6356992),
+    (1, 1024, 20, 1, 64, 24): (2, 64, 6356992),
+    (1, 256, 30, 30, 64, 24): (2, 32, 6463488),  # olmoh.serve-think
+    (1, 512, 30, 30, 64, 24): (2, 32, 6463488),
+    (16, 1, 36, 4, 16, 32): (8, 1, 724992),      # sc2-7b.serve-generate
+    (1, 128, 36, 4, 16, 32): (8, 64, 7749632),
 }
+# olmoh.serve-think's decode step: 128 lanes, 30 heads over 30 kv heads of
+# 128, pages of 64, 24 a lane -- a group of one row a kv head
+OLMOH_DECODE = (128, 1, 30, 30, 64, 24)
 
 
 @pytest.mark.parametrize("shape", sorted(GQA_TILINGS))
@@ -450,6 +489,132 @@ def test_gqa_tilings_are_what_they_were(shape):
     b, t, hq, hkv, ps, maxp = shape
     assert paged_tiling(b, t, hq, hkv, 128, ps, maxp,
                         jnp.bfloat16) == GQA_TILINGS[shape]
+    assert paged_form(t, hq, hkv, ps, maxp) == "rows"
+
+
+def test_the_form_is_heads_at_a_group_of_one_alone():
+    """``paged_form`` picks ``heads`` for olmoh.serve-think's decode step
+    and ``rows`` for every call above (its prefills, Jamba's group of 20
+    over one kv head, the GQA cells'), a window's ring at a group of one,
+    a latent pool, a chunk of multi-head attention, and a block that is
+    not whole lanes."""
+    b, t, hq, hkv, ps, maxp = OLMOH_DECODE
+    assert paged_form(t, hq, hkv, ps, maxp) == "heads"
+    ppb, tq, vmem = paged_tiling(b, t, hq, hkv, 128, ps, maxp, jnp.bfloat16)
+    assert (ppb, tq) == (2, 1) and vmem <= VMEM_BUDGET
+    assert paged_form(1, 30, 30, ps, 9, window=512) == "rows"
+    assert paged_form(1, 30, 30, ps, maxp, v_width=512) == "rows"
+    assert paged_form(2, 30, 30, ps, maxp) == "rows"
+    assert paged_form(1, 30, 30, 16, 4) == "rows"        # 64 keys a block
+    assert paged_form(1, 30, 30, 16, 8) == "heads"       # 128 keys
+
+
+def test_the_path_follows_the_seam_and_the_backend(monkeypatch):
+    """``paged_path``: the gather oracle where the seam gives way, the lax
+    loop off the TPU, the kernel's form on it."""
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+
+    shape = OLMOH_DECODE[1:]
+    assert paged_path(*shape) == "lax"
+    monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
+    assert paged_path(*shape) == "heads"
+    assert paged_path(512, 30, 30, 64, 24) == "rows"
+    assert _in_mode("gather", lambda: paged_path(*shape)) == "gather"
+    helpers.enable_helpers(False)
+    try:
+        assert paged_path(*shape) == "gather"
+    finally:
+        helpers.enable_helpers(True)
+    assert set(PAGED_PATHS) == {"heads", "rows", "lax", "gather"}
+
+
+def _kernel_text(fn, *args):
+    """``fn`` lowered for the tpu platform, each Mosaic kernel's serialized
+    body replaced by its module printed without source locations (the
+    body carries the kernel's source lines, which any edit above it
+    moves)."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    with jax.enable_x64(False):
+        text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+
+    def body(m):
+        with ctx, ir.Location.unknown():
+            return ir.Module.parse(base64.b64decode(m.group(1))).operation \
+                .get_asm(enable_debug_info=False)
+
+    return re.sub(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", body, text)
+
+
+# sha256 (first 16 hex) of ``_kernel_text`` of the kernel's call at each
+# other cell's decode step and one prefill bucket, (b, t, hq, hkv, page,
+# maxp, window), as the parent commit 3147cf0 lowers it: the ``rows`` form
+# is the parent's kernel, instruction for instruction
+PARENT_KERNELS = {
+    (32, 1, 36, 4, 16, 36, None): "cb7c6269e60029f0",    # sc2-7b.serve-
+    (1, 512, 36, 4, 16, 36, None): "1d381d49228f3577",   # complete
+    (32, 1, 36, 4, 16, 72, None): "0ab46ce7f6dbc365",    # -1k
+    (1, 1024, 36, 4, 16, 72, None): "9a8483cfd36dbf4f",
+    (16, 1, 36, 4, 16, 32, None): "f0de2e5676ec5955",    # -generate
+    (1, 128, 36, 4, 16, 32, None): "a71dbe7b6d4aaa88",
+    (32, 1, 48, 8, 64, 136, None): "43b117fb776f0434",   # laguna
+    (32, 1, 72, 8, 64, 9, 512): "ac54ec1a9ca0a6bd",
+    (1, 8192, 48, 8, 64, 136, None): "4d74ad4c79bb36e9",
+    (128, 1, 20, 1, 64, 24, None): "aafce970d677cb3e",   # jamba2
+    (1, 1024, 20, 1, 64, 24, None): "a1fb28f62a1b52ec",
+    (1, 512, 30, 30, 64, 24, None): "d5766e8477807cc5",  # olmoh's prefill
+}
+PARENT_LATENT_KERNELS = {"xing": "06736aaf2aac6689", "k2": "310c78d32d2cd584"}
+
+
+@pytest.mark.parametrize("shape", list(PARENT_KERNELS), ids=str)
+def test_the_rows_form_lowers_as_on_the_parent(shape):
+    import hashlib
+
+    b, t, hq, hkv, ps, maxp, window = shape
+    sds = jax.ShapeDtypeStruct
+    pool = sds((b * maxp + 1, hkv, ps, 128), jnp.bfloat16)
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, window=window, impl="pallas", interpret=False))
+    text = _kernel_text(fn, sds((b, t, hq, 128), jnp.bfloat16), pool, pool,
+                        sds((b, maxp), jnp.int32), sds((b, t), jnp.int32))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_KERNELS[shape]
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_LATENT_KERNELS))
+def test_the_latent_kernel_lowers_as_on_the_parent(cell):
+    import hashlib
+
+    text = _kernel_text(_latent_call, *_latent_args(cell))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_LATENT_KERNELS[cell]
+
+
+def _olmoh_decode_args(sharding=None):
+    b, t, hq, hkv, ps, maxp = OLMOH_DECODE
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    pool = sds((b * maxp + 1, hkv, ps, 128), jnp.bfloat16)
+    return (sds((b, t, hq, 128), jnp.bfloat16), pool, pool,
+            sds((b, maxp), jnp.int32), sds((b, t), jnp.int32))
+
+
+def test_the_heads_form_lowers_for_tpu_once():
+    """At olmoh.serve-think's decode shape the kernel is one
+    ``fused_paged_attention`` call, its Mosaic module the ``heads`` form:
+    one product for every head's keys where the ``rows`` form unrolls a
+    body a kv head."""
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, impl="pallas", interpret=False))
+    text = _kernel_text(fn, *_olmoh_decode_args())
+    assert text.count('kernel_name = "fused_paged_attention"') == 1
+    assert text.count("tpu.matmul") == 2
 
 
 def _latent_args(cell, sharding=None):
@@ -749,6 +914,47 @@ def test_engine_hot_swap_parity(rng):
     assert fused[0] != fused[1]       # the swap actually changed weights
 
 
+def test_engine_serves_mha_through_the_heads_form(rng, monkeypatch):
+    """Multi-head attention (4 heads over 4 kv heads) with the seam made to
+    take the Pallas path (interpreted off the chip), pages of 16 in a
+    128-position context: a decode step takes the ``heads`` form (a block
+    of 8 pages, 128 keys), a prefill the ``rows`` form.  The served tokens
+    are the gather oracle's, and ``dl4j_paged_attention_steps_total``
+    reads ``heads`` once a dispatched decode step, ``rows`` once a
+    prefill."""
+    from deeplearning4j_tpu.generation import GenerationEngine
+    from deeplearning4j_tpu.helpers import paged_attention as pa
+    from deeplearning4j_tpu.observability.metrics import MetricsRegistry
+
+    lm = _small_lm()
+    prompts = [rng.randint(0, VOCAB, rng.randint(1, 12)).tolist()
+               for _ in range(5)]
+    lens = [int(rng.randint(2, 10)) for _ in prompts]
+
+    def run():
+        eng = GenerationEngine(lm, slots=4, page_size=16, max_context=128,
+                               max_queue=64, deadline_s=120.0,
+                               registry=MetricsRegistry()).start()
+        try:
+            handles = [eng.submit(p, n) for p, n in zip(prompts, lens)]
+            return eng, [h.result(timeout=300) for h in handles]
+        finally:
+            eng.stop()
+
+    oracle, want = _in_mode("gather", run)
+    monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
+    eng, got = run()
+    assert got == want
+    steps = lambda e, **kw: e.metrics.registry.get_value(
+        "dl4j_paged_attention_steps_total", **kw)
+    dispatched = sum(eng.metrics.registry.get_value(
+        "dl4j_decode_dispatch_total", mode=m) or 0 for m in ("ahead", "sync"))
+    assert steps(eng, stage="decode", path="heads") == dispatched > 0
+    assert steps(eng, stage="prefill", path="rows") == len(prompts)
+    assert steps(eng, stage="decode", path="rows") is None
+    assert steps(oracle, stage="prefill", path="gather") == len(prompts)
+
+
 # ------------------------------------------------------- fused epilogue
 def _np_ref(h, res, gamma, beta, eps, mask, keep):
     x = np.asarray(h, np.float64)
@@ -973,8 +1179,12 @@ def test_the_state_slots_are_stepped_in_place_on_v5e(v5e_chip, lanes, t):
 def test_olmo_hybrid_shapes_compile_for_v5e(v5e_chip, b, t):
     """``olmo-hybrid-7b-pp4``'s full layers: 30 query heads over 30 kv heads
     of 128 (a group of ONE row a kv head, plain multi-head attention),
-    pages of 64, 24 a slot, through the chip's compiler."""
+    pages of 64, 24 a slot, through the chip's compiler: the decode step in
+    the ``heads`` form, a prefill bucket in the ``rows`` form, both within
+    ``VMEM_BUDGET``."""
     ps, hq, hkv, d, maxp = 64, 30, 30, 128, 24
+    assert paged_form(t, hq, hkv, ps, maxp) == ("heads" if t == 1
+                                                else "rows")
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
     pool = sds((128 * maxp + 1, hkv, ps, d), jnp.bfloat16)
     fn = jax.jit(lambda *a: paged_decode_attention(
