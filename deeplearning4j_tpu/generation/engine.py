@@ -105,13 +105,12 @@ _OK_REASONS = ("length", "stop")
 class _Step:
     """A decode step in flight: dispatched, its ids not yet harvested."""
 
-    __slots__ = ("sampled", "rows", "model", "path")
+    __slots__ = ("sampled", "rows", "model")
 
-    def __init__(self, sampled, rows, model: str, path: str):
+    def __init__(self, sampled, rows, model: str):
         self.sampled = sampled      # device: ids, or (ids, counts)
         self.rows = rows            # (lane, slot) as dispatched
         self.model = model
-        self.path = path            # sampling path of the rows as dispatched
 
 
 class GenerationEngine:
@@ -245,13 +244,6 @@ class GenerationEngine:
             self.prefix_cache.attach(self,
                                      progs.page_nbytes(self._pools))
         self.scheduler.reopen()   # a restart re-arms admission
-        from deeplearning4j_tpu.helpers import helpers_enabled
-        from deeplearning4j_tpu.helpers.paged_attention import (
-            paged_attention_mode)
-
-        self.metrics.fused_attention.set(
-            1.0 if helpers_enabled()
-            and paged_attention_mode() == "fused" else 0.0)
         self._stop_event.clear()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="generation-decode")
@@ -611,22 +603,15 @@ class GenerationEngine:
                 _copy_to_host_async(tok)
         with phase("page_gather", stage="admit"):
             self.scheduler.install(req, base_key)
-        for path in progs.expert_paths.get(bucket, ()):
-            self.metrics.moe_expert_steps.inc(stage="prefill", path=path)
-        for path in progs.latent_paths.get((bucket, req.shared_len == 0), ()):
-            self.metrics.latent_attention_steps.inc(stage="prefill",
-                                                    path=path)
-        for path in progs.paged_paths.get(bucket, ()):
-            self.metrics.paged_attention_steps.inc(stage="prefill", path=path)
-        for path in progs.state_space_paths.get(bucket, ()):
-            self.metrics.state_space_steps.inc(stage="prefill", path=path)
+        self._count_paths("prefill", progs.paths[(bucket,
+                                                  req.shared_len == 0)],
+                          policy)
         if progs.state:
             self.metrics.state_slot_resets.inc()
-        self._firsts.append((req, tok, mv.name,
-                             SAMPLING_PATHS[sampling_path(*policy)]))
+        self._firsts.append((req, tok, mv.name))
 
-    def _harvest_first(self, req: GenerationRequest, tok, model: str,
-                       path: str) -> None:
+    def _harvest_first(self, req: GenerationRequest, tok,
+                       model: str) -> None:
         """Wait for one prefill's sample and deliver it.  The prefill is
         ahead of the decode step just dispatched in the device's queue, so
         the device is busy with that step while the host is here."""
@@ -642,7 +627,6 @@ class GenerationEngine:
             self.metrics.prefix_pages.inc(len(req.pages) - shared_pages,
                                           outcome="fresh")
             self.metrics.tokens.inc(model=model)
-            self.metrics.sampling_steps.inc(stage="admit", path=path)
             self._refresh_gauges()
 
     def _dispatch(self, progs: GenerationPrograms, mv: ModelVersion,
@@ -663,16 +647,18 @@ class GenerationEngine:
                 s.advance(rows)
         self.metrics.decode_dispatch.inc(
             mode="sync" if self._in_flight is None else "ahead")
-        for path in progs.expert_paths.get("decode", ()):
-            self.metrics.moe_expert_steps.inc(stage="decode", path=path)
-        for path in progs.latent_paths.get(("decode", False), ()):
-            self.metrics.latent_attention_steps.inc(stage="decode", path=path)
-        for path in progs.paged_paths.get("decode", ()):
-            self.metrics.paged_attention_steps.inc(stage="decode", path=path)
-        for path in progs.state_space_paths.get("decode", ()):
-            self.metrics.state_space_steps.inc(stage="decode", path=path)
-        return _Step(sampled, rows, mv.name,
-                     SAMPLING_PATHS[sampling_path(*policy[2:])])
+        self._count_paths("decode", progs.paths[("decode", False)],
+                          policy[2:])
+        return _Step(sampled, rows, mv.name)
+
+    def _count_paths(self, stage: str, paths, policy) -> None:
+        """One dispatched program: each distinct (kind, path) its layers
+        take (``GenerationPrograms.paths``) and its sampling epilogue's,
+        from the rows' ``policy`` (kind ``head``), once."""
+        sampled = ("head", SAMPLING_PATHS[sampling_path(*policy)])
+        for kind, path in (*paths, sampled):
+            self.metrics.layer_path_steps.inc(stage=stage, kind=kind,
+                                              path=path)
 
     def _harvest(self, step: _Step) -> None:
         """Wait for a dispatched step's ids and deliver them; the device
@@ -688,8 +674,6 @@ class GenerationEngine:
             self.steady_deliveries += delivered
             with phase("gauges", stage="decode", child=True):
                 self.metrics.steps.inc()
-                self.metrics.sampling_steps.inc(stage="decode",
-                                                path=step.path)
                 self.metrics.tokens.inc(delivered, model=step.model)
                 self.metrics.batch_occupancy.observe(
                     len(step.rows) / s.num_slots)

@@ -69,11 +69,9 @@ from deeplearning4j_tpu.models.common import cast_to_compute
 from deeplearning4j_tpu.models.decode import (
     _cg_single_io, _ids_need_time_axis, _last_logits_fwd,
 )
+from deeplearning4j_tpu.nn.layers.base import ServingCall
 from deeplearning4j_tpu.nn.layers.composite import gauging
-from deeplearning4j_tpu.nn.layers.delta_net import GatedDeltaNetLayer
-from deeplearning4j_tpu.nn.layers.latent_attention import LatentAttentionLayer
-from deeplearning4j_tpu.nn.layers.moe import RoutedMoELayer, counting
-from deeplearning4j_tpu.nn.layers.state_space import MambaLayer
+from deeplearning4j_tpu.nn.layers.moe import counting
 from deeplearning4j_tpu.utils.sampling import _resolve_encoding, sample_tokens
 
 
@@ -109,30 +107,12 @@ def _layers_where(net, wanted):
     return [a for _, l in named_layers_of(net) for a in walk(l)]
 
 
-def _layers_of_kind(net, kind):
-    """Every layer of class ``kind`` in ``net``."""
-    return _layers_where(net, lambda l: isinstance(l, kind))
-
-
-def _self_attention_layers(net):
-    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionLayer
-
-    return _layers_of_kind(net, SelfAttentionLayer)
-
-
-def _paged_attention_shapes(net) -> List[Tuple[int, int, int, int]]:
-    """``(q heads, kv heads, head_dim, window or 0)`` of every
-    ``SelfAttentionLayer`` of ``net``, each shape once."""
-    return sorted({(a.n_heads, a._kv_heads, a._d_head, a.window or 0)
-                   for a in _self_attention_layers(net)})
-
-
 def window_ring_pages(net, page_size: int) -> int:
     """Pages a slot that ``net``'s window layers ring through (their
     ``paged_ring``; the widest, since one manager's page ids address every
     window pool), 0 for a net with none."""
-    return max((a.paged_ring(page_size) or 0
-                for a in _self_attention_layers(net)), default=0)
+    return max((l.paged_ring(page_size) or 0 for l in _layers_where(
+        net, lambda l: hasattr(l, "paged_ring"))), default=0)
 
 
 def has_state_pools(net) -> bool:
@@ -296,59 +276,32 @@ class GenerationPrograms:
         # pages by layer kind: the ring a slot holds of window pages, and
         # that kind's pool; 0 and 0 for a net without window layers
         self.ring = window_ring_pages(net, self.page_size)
-        # how each compute program multiplies its expert layers' held
-        # experts (``RoutedMoELayer.path`` of its rows, the rule the layer
-        # branches on when the program is traced): the paths taken, by
-        # ``"decode"`` / bucket; nothing for a net without expert layers
-        self.expert_layers = _layers_of_kind(net, RoutedMoELayer)
-        rows = {"decode": self.slots, **{b: b for b in self.prefill_buckets}}
-        self.expert_paths = {
-            name: tuple(sorted({l.path(t) for l in self.expert_layers}))
-            for name, t in rows.items()} if self.expert_layers else {}
-        # how each compute program attends over its latent layers' pages
-        # (``LatentAttentionLayer.path``, the rule the layer branches on
-        # when the program is traced): the paths taken, by (``"decode"`` /
-        # bucket, whether the dispatch starts at position 0 — the branch a
-        # prefill takes on the device); nothing for a net without them
-        self.latent_layers = _layers_of_kind(net, LatentAttentionLayer)
+        self.num_window_pages = window_pool_pages(self.slots, self.ring)
+        # state slots: whether a layer keeps one row of state a slot
+        self.state = has_state_pools(net)
         # the dtype the layers are traced in: the stored one where no
         # compute dtype is set (a float64 net keeps the gather)
         dtype = jnp.dtype(net.conf.compute_dtype or next(
             (l.dtype for l in jax.tree_util.tree_leaves(net.params)
              if jnp.issubdtype(l.dtype, jnp.floating)), jnp.float32))
-        self.latent_paths = {
-            (name, zero): tuple(sorted({
-                l.path(t, zero, self.page_size, dtype)
-                for l in self.latent_layers}))
-            for name, t in {**rows, "decode": 1}.items()
-            for zero in (False, True)} if self.latent_layers else {}
-        # how each compute program attends over its SelfAttentionLayers'
-        # pages (``paged_attention.paged_path``, the rule ``_apply_paged``
-        # follows when the program is traced), by ``"decode"`` / bucket; a
-        # window layer's prefill chunk attends over its own keys, not pages
-        from deeplearning4j_tpu.helpers.paged_attention import paged_path
-
-        shapes = _paged_attention_shapes(net)
-        self.paged_paths = {
-            name: tuple(sorted({
-                paged_path(t, hq, hkv, self.page_size,
-                           self.ring if window else self.pages_per_slot,
-                           window or None)
-                for hq, hkv, _, window in shapes if t == 1 or not window}))
-            for name, t in {**rows, "decode": 1}.items()} if shapes else {}
-        self.num_window_pages = window_pool_pages(self.slots, self.ring)
-        # state slots: whether a layer keeps one row of state a slot, and
-        # how each compute program runs its state-space and delta-rule
-        # layers' recurrence (``MambaLayer.path`` / ``GatedDeltaNetLayer.
-        # path`` of its positions a row, the rule the layer branches on when
-        # the program is traced), by ``"decode"`` / bucket
-        self.state = has_state_pools(net)
-        self.state_space_layers = _layers_of_kind(
-            net, (MambaLayer, GatedDeltaNetLayer))
-        self.state_space_paths = {
-            name: tuple(sorted({l.path(t) for l in self.state_space_layers}))
-            for name, t in {**rows, "decode": 1}.items()
-        } if self.state_space_layers else {}
+        # each compute program as its layers see it, by ("decode" / bucket,
+        # whether every row starts at position 0: the branch a latent
+        # layer's prefill takes on the device)
+        shapes = {**{b: (1, b) for b in self.prefill_buckets},
+                  "decode": (self.slots, 1)}
+        self.calls = {
+            (name, zero): ServingCall(batch, t, zero, self.page_size,
+                                      self.pages_per_slot, self.ring,
+                                      self.slots, dtype)
+            for name, (batch, t) in shapes.items() for zero in (False, True)}
+        # the path each layer takes in each program (``Layer.serving_path``,
+        # the rule it branches on when the program is traced): its distinct
+        # (kind, path) pairs, by the same keys
+        self._layers = _layers_where(net, lambda l: True)
+        self.paths = {
+            key: tuple(sorted({(l.kind, p) for l in self._layers
+                               if (p := l.serving_path(call)) is not None}))
+            for key, call in self.calls.items()}
         # validate eagerly (raises on a carry no pool can hold)
         jax.eval_shape(lambda: seed_paged_pools(
             net, 2, page_size, net.conf.compute_dtype, window_pages=2,
@@ -583,125 +536,19 @@ class GenerationPrograms:
             z((s,), np.int32), np.ones((s,), np.float32)))
         return progs
 
-    def _log_paged_tiling(self) -> None:
-        """How ``fused_paged_attention`` tiles each compute program, once a
-        program.  The choice is static per program, a function of its
-        shapes, so this is the whole account of how the kernel engaged."""
-        from deeplearning4j_tpu.helpers import helpers_enabled
-        from deeplearning4j_tpu.helpers import paged_attention as pa
-
-        if not (helpers_enabled() and pa.paged_attention_mode() == "fused"
-                and pa.default_impl() == "pallas"):
-            return
-        dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
-        programs = [(f"prefill_{b}", 1, b) for b in self.prefill_buckets]
-        programs.append(("decode", self.slots, 1))
-        for hq, hkv, d, window in _paged_attention_shapes(self.net):
-            # a window layer's kernel runs over its ring, and in the
-            # decode step alone (its prefill chunk attends over itself)
-            pages = self.ring if window else self.pages_per_slot
-            for name, b, t in programs[-1:] if window else programs:
-                ppb, tq, vmem = pa.paged_tiling(
-                    b, t, hq, hkv, d, self.page_size, pages, dtype,
-                    window=window or None)
-                logger.info(
-                    "generation.%s: fused_paged_attention q [%d, %d, %d, "
-                    "%d] over %d pages of %d%s: %d pages a block, %d query "
-                    "positions a tile, grid (%d, %d), %.2f MB of VMEM, the "
-                    "%s form", name, b, t, hq, d, pages, self.page_size,
-                    f" (a ring, window {window})" if window else "",
-                    ppb, tq, b, -(-t // tq), vmem / 2 ** 20,
-                    pa.paged_form(t, hq, hkv, self.page_size, pages,
-                                  window or None))
-
-    def _log_latent_tiling(self) -> None:
-        """How ``latent_paged_attention`` tiles the decode step of a net
-        whose latent layers take the ``paged`` path, once a shape (the
-        prefills attend the expanded way or over the gathered pages)."""
-        from deeplearning4j_tpu.helpers import paged_attention as pa
-
-        if ("paged" not in self.latent_paths.get(("decode", False), ())
-                or pa.default_impl() != "pallas"):
-            return
-        dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
-        for h, w, v in sorted({(l.n_heads, l._pool_width, l.kv_rank)
-                               for l in self.latent_layers}):
-            ppb, _, vmem = pa.paged_tiling(
-                self.slots, 1, h, 1, w, self.page_size, self.pages_per_slot,
-                dtype, v)
-            logger.info(
-                "generation.decode: latent_paged_attention q [%d, 1, %d, "
-                "%d] over %d pages of %d, the value the first %d columns: "
-                "%d pages a block (%.2f MB a copy), grid (%d, 1), %.2f MB "
-                "of VMEM", self.slots, h, w, self.pages_per_slot,
-                self.page_size, v, ppb,
-                ppb * self.page_size * w * dtype.itemsize / 2 ** 20,
-                self.slots, vmem / 2 ** 20)
-
-    def _log_state_space(self) -> None:
-        """How each compute program runs its state-space and delta-rule
-        layers' recurrence and what a slot's state weighs, once a program
-        and shape."""
-        from deeplearning4j_tpu.helpers import get_helper
-
-        kinds = (
-            (MambaLayer, get_helper("selective_scan"),
-             lambda l: (f"state-space layers of {l.d_inner} channels x "
-                        f"{l.d_state} state columns", l.d_inner * l.d_state,
-                        l.d_conv)),
-            (GatedDeltaNetLayer, get_helper("delta_rule"),
-             lambda l: (f"delta-rule layers of {l.n_heads} heads x "
-                        f"[{l.d_k}, {l.d_v}] (rows {list(l.state_shape())})",
-                        l.n_heads * l.d_k * l.d_v, l.d_conv)))
-        for cls, helper, what in kinds:
-            shapes = sorted({what(l) for l in self.state_space_layers
-                             if isinstance(l, cls)})
-            for name, paths in self.state_space_paths.items():
-                t = 1 if name == "decode" else name
-                how = ("one pass over the rows' states" if t == 1
-                       else helper.describe(t) if helper is not None
-                       else f"lax scan, {t} trips of 1 time step")
-                for label, entries, k in shapes:
-                    logger.info(
-                        "generation.%s: %s (%s): %s; %d state slots + the "
-                        "trash row, %.1f kB of float32 state and a tail of "
-                        "%d rows a slot a layer",
-                        name if t == 1 else f"prefill_{name}", label,
-                        "/".join(paths), how, self.slots, entries * 4 / 1e3,
-                        k - 1)
-
-    def _log_expert_tiling(self) -> None:
-        """How ``grouped_experts`` tiles each compute program whose expert
-        layers take the ``streamed`` or ``sorted`` path, once a program
-        and shape."""
-        from deeplearning4j_tpu.helpers import grouped_experts as ge
-
-        dtype = jnp.dtype(self.net.conf.compute_dtype or jnp.float32)
-        shapes = sorted({(l.held[1], l.n_experts, l.top_k, l.n_in, l.hidden,
-                          l.n_out) for l in self.expert_layers})
-        for name, paths in self.expert_paths.items():
-            t = self.slots if name == "decode" else name
+    def _log_tiling(self) -> None:
+        """How each compute program's layers run their kernels
+        (``Layer.describe_serving``), each distinct line once a program.
+        The choice is static per program, a function of its shapes, so
+        this is the whole account of how the kernels engaged."""
+        for name in dict.fromkeys(name for name, _ in self.calls):
+            said = dict.fromkeys(
+                line for zero in (False, True) for l in self._layers
+                if (line := l.describe_serving(self.calls[(name, zero)]))
+                is not None)
             program = name if name == "decode" else f"prefill_{name}"
-            for count, n, k, d, hidden, n_out in shapes:
-                if "streamed" in paths:
-                    rows, tf, vmem = ge.expert_tiling(t, d, hidden, n_out,
-                                                      dtype)
-                    logger.info(
-                        "generation.%s: grouped_experts tokens [%d, %d] over "
-                        "%d held experts of width %d: %d rows, hidden tiles "
-                        "of %d, grid (%d, %d), %.2f MB of VMEM", program, t,
-                        d, count, hidden, rows, tf, count, hidden // tf,
-                        vmem / 2 ** 20)
-                if "sorted" in paths:
-                    tm, tf, r, vmem = ge.sorted_tiling(d, hidden, n_out,
-                                                       dtype, t * k // n)
-                    logger.info(
-                        "generation.%s: sorted_experts tokens [%d, %d] over "
-                        "%d held experts of width %d: blocks of %d sorted "
-                        "rows, row tiles of %d, hidden tiles of %d, %d row "
-                        "tiles a visit, %.2f MB of VMEM", program, t, d,
-                        count, hidden, ge.sorted_block(t * k, count, n), tm,
-                        tf, r, vmem / 2 ** 20)
+            for line in said:
+                logger.info("generation.%s: %s", program, line)
 
     def lowered(self) -> Dict[str, "jax.stages.Lowered"]:
         """Each compute program lowered at its serving signature (abstract
@@ -763,10 +610,7 @@ class GenerationPrograms:
         for name, (jitted, tail) in progs.items():
             register_program(f"generation.{name}", jitted,
                              (params, net_state, pools) + tail)
-        self._log_paged_tiling()
-        self._log_latent_tiling()
-        self._log_expert_tiling()
-        self._log_state_space()
+        self._log_tiling()
         coll = shardstats.active_collector()
         if coll is not None:
             # census at the exact warmup signatures; lower-only, so the

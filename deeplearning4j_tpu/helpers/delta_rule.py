@@ -322,8 +322,7 @@ class DeltaRuleHelper:
     @property
     def kernel(self) -> bool:
         """Whether the decode step on state slots runs as ``step_slots``:
-        compiled, on the TPU; the host counts
-        ``dl4j_state_space_steps_total{path}`` by it."""
+        compiled, on the TPU (``delta_net.delta_rule_path``)."""
         return not _interpret()
 
     def step_slots(self, q, k, v, g, beta, sh, fresh, lanes):

@@ -86,7 +86,8 @@ layer's single-token step; the oracle there is the layer's own
 ``pool[block]`` + ``_absorbed``.  See docs/serving.md "Latent pages while
 decoding".
 
-Semantics match the legacy pair exactly (the flag-selectable oracle):
+Semantics match the legacy pair exactly (the oracle the tests compare
+against, and the path where the seam gives way):
 GQA contracts the UNEXPANDED kv heads, masking is per-row
 ``q_positions >= key_position`` where a key's global position is its
 logical slot index ``p*page_size + i`` — which also hides unwritten
@@ -95,19 +96,15 @@ the row's position).  See docs/serving.md "The fused decode kernel"
 for the seam contract, including the plan to dequantize int8/fp8 pages
 (ROADMAP item 3) inside this kernel.
 
-Mode toggle (trace-time, like ``enable_helpers``):
-``set_paged_attention_mode("gather")`` or env DL4J_TPU_PAGED_GATHER=1
-routes ``SelfAttentionLayer._apply_paged`` back through the legacy
-gather+softmax path — the bit-compatible oracle the parity tests compare
-against, and the other side of the in-cell comparison (PERF.md §6, PR 32);
-``LatentAttentionLayer``'s single-token step goes back to its gather with
-it.
+Where the seam gives way (helpers disabled, or no ``paged_attention``
+helper) ``SelfAttentionLayer``'s paged calls take the legacy
+gather+softmax pair and ``LatentAttentionLayer``'s single-token step its
+own gather (``paged_path``, ``latent_path``).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -119,28 +116,6 @@ from deeplearning4j_tpu.helpers import interpret_mode as _interpret
 
 LANES = 128
 NEG_INF = -1e30
-
-_VALID_MODES = ("fused", "gather")
-_mode = ("gather" if os.environ.get("DL4J_TPU_PAGED_GATHER", "0") == "1"
-         else "fused")
-
-
-def set_paged_attention_mode(mode: str) -> None:
-    """Select the paged decode path: ``"fused"`` (default — this module)
-    or ``"gather"`` (the legacy gather+softmax oracle).  NOTE: routing
-    happens at TRACE time; already-compiled decode programs (a started
-    GenerationEngine's warmed program set) keep whichever path they were
-    traced with — toggle BEFORE building the engine."""
-    if mode not in _VALID_MODES:
-        raise ValueError(f"paged attention mode {mode!r} not in "
-                         f"{_VALID_MODES}")
-    global _mode
-    _mode = mode
-
-
-def paged_attention_mode() -> str:
-    return _mode
-
 
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying ``like``'s varying-mesh-axes set (see
@@ -344,15 +319,14 @@ def paged_form(t: int, hq: int, hkv: int, page_size: int, maxp: int,
 def paged_path(t: int, hq: int, hkv: int, page_size: int, maxp: int,
                window: Optional[int] = None) -> str:
     """How a paged call of ``SelfAttentionLayer`` attends, one of
-    ``PAGED_PATHS``: the gather oracle where the seam gives way (no
-    helper, ``set_paged_attention_mode("gather")``), the lax page loop
-    off the TPU, else the kernel in its ``paged_form``.  The rule
-    ``_apply_paged`` follows when a program is traced; the engine counts
-    ``dl4j_paged_attention_steps_total`` by it."""
+    ``PAGED_PATHS``: the gather oracle where the seam gives way (helpers
+    disabled, or no helper), the lax page loop off the TPU, else the kernel
+    in its ``paged_form``.  The rule ``SelfAttentionLayer``'s paged
+    branches follow when a program is traced; the engine counts
+    ``dl4j_layer_path_steps_total`` by it."""
     from deeplearning4j_tpu.helpers import get_helper
 
-    if (get_helper("paged_attention") is None
-            or paged_attention_mode() != "fused"):
+    if get_helper("paged_attention") is None:
         return "gather"
     if default_impl() != "pallas":
         return "lax"
@@ -861,20 +835,17 @@ def paged_latent_attention(q: jax.Array, pc: jax.Array, block: jax.Array,
 
 class PagedAttentionHelper:
     """Discovery-seam wrapper for the paged decode path (≙ the cuDNN
-    helper SPI, like FlashAttentionHelper): ``SelfAttentionLayer.
-    _apply_paged`` asks ``helpers.get_helper("paged_attention")`` and
-    falls back to the legacy gather+softmax pair when this returns
-    unsupported.  Unlike the flash helper, the fused path is the
-    DEFAULT on every backend — off TPU it routes to the compiled lax
-    page-streaming fallback, not the Pallas interpreter, so CPU decode
-    gets the live-page watermark win too.  ``LatentAttentionLayer``'s
+    helper SPI, like FlashAttentionHelper): ``SelfAttentionLayer``'s paged
+    branches take ``attend`` wherever ``helpers.get_helper(
+    "paged_attention")`` offers it (``paged_path``) and the legacy
+    gather+softmax pair where it gives way.  Unlike the flash helper, the
+    fused path is the DEFAULT on every backend — off TPU it routes to the
+    compiled lax page-streaming fallback, not the Pallas interpreter, so
+    CPU decode gets the live-page watermark win too.  ``LatentAttentionLayer``'s
     single-token step asks the same seam (``supports_latent`` /
     ``attend_latent``) and falls back to its own gather + ``_absorbed``."""
 
     name = "PagedAttentionHelper"
-
-    def supports(self, q, page_size: int) -> bool:
-        return paged_attention_mode() == "fused"
 
     def attend(self, q, pk, pv, block, q_positions,
                window: Optional[int] = None) -> jax.Array:
@@ -886,8 +857,6 @@ class PagedAttentionHelper:
         read in place: always by the lax page loop; by the compiled kernel
         when a page is whole tiles (whole lanes wide, whole sublane tiles
         long)."""
-        if paged_attention_mode() != "fused":
-            return False
         return default_impl() != "pallas" or (
             width % LANES == 0 and page_size % _sublanes(dtype) == 0)
 

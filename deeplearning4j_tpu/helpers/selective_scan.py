@@ -95,11 +95,8 @@ def single_step(x, dt, a, b, c, h):
 
 
 class SelectiveScanHelper:
-    """The seam's object: ``scan`` for a chunk or a sequence.  ``kernel`` is
-    False on every backend (the ``lax`` form); the host counts
-    ``dl4j_state_space_steps_total{path}`` by it."""
-
-    kernel = False
+    """The seam's object: ``scan`` for a chunk or a sequence, the ``lax``
+    form on every backend."""
 
     def scan(self, x, dt, a, b, c, h0, live=None):
         return chunked_scan(x, dt, a, b, c, h0, live)

@@ -206,8 +206,8 @@ def gather_pages(pages: jax.Array, block: jax.Array) -> jax.Array:
     fused decode-attention helper (``helpers/paged_attention.py``)
     replaces exactly this gather + the softmax that follows, and is the
     DEFAULT decode path; this function + ``paged_attention`` remain the
-    flag-selectable bit-compatible oracle (``DL4J_TPU_PAGED_GATHER=1`` or
-    ``set_paged_attention_mode("gather")``)."""
+    bit-compatible oracle the tests compare against, and the path where the
+    helper seam gives way (``paged_path``)."""
     b, maxp = block.shape
     _, hkv, ps, d = pages.shape
     return (pages[block].transpose(0, 1, 3, 2, 4)
@@ -505,6 +505,41 @@ class SelfAttentionLayer(Layer):
             return None
         return -(-self.window // page_size) + 1
 
+    def serving_path(self, call) -> Optional[str]:
+        """``paged_path`` of the program's paged call: over the ring in
+        the decode step for a window layer (whose prefill chunk attends
+        over its own keys: None), over the global table otherwise."""
+        from deeplearning4j_tpu.helpers.paged_attention import paged_path
+
+        if self.window is not None and call.t > 1:
+            return None
+        return paged_path(call.t, self.n_heads, self._kv_heads,
+                          call.page_size,
+                          call.ring if self.window else call.pages,
+                          self.window)
+
+    def describe_serving(self, call) -> Optional[str]:
+        """How ``fused_paged_attention`` tiles the program's call, where
+        the kernel runs it."""
+        from deeplearning4j_tpu.helpers import paged_attention as pa
+
+        form = self.serving_path(call)
+        if form not in ("heads", "rows"):
+            return None
+        hq, hkv, d, window = (self.n_heads, self._kv_heads, self._d_head,
+                              self.window)
+        pages = call.ring if window else call.pages
+        b, t = call.batch, call.t
+        ppb, tq, vmem = pa.paged_tiling(b, t, hq, hkv, d, call.page_size,
+                                        pages, call.dtype, window=window)
+        return (
+            f"fused_paged_attention q [{b}, {t}, {hq}, {d}] over {pages} "
+            f"pages of {call.page_size}"
+            f"{f' (a ring, window {window})' if window else ''}: {ppb} "
+            f"pages a block, {tq} query positions a tile, grid ({b}, "
+            f"{-(-t // tq)}), {vmem / 2 ** 20:.2f} MB of VMEM, the "
+            f"{form} form")
+
     def init_paged_cache(self, num_pages: int, page_size: int,
                          dtype=jnp.float32, window_pages: Optional[int] = None,
                          state_slots: Optional[int] = None
@@ -574,26 +609,27 @@ class SelfAttentionLayer(Layer):
         hkv, dh = k.shape[2], k.shape[3]
         from deeplearning4j_tpu.helpers import get_helper
         from deeplearning4j_tpu.helpers.paged_attention import (
-            write_token_rows)
+            paged_path, write_token_rows)
 
         # one [Hkv, D] slab per new token at (page, :, offset, :), as Hkv
         # rows of the pool seen as a table
         pk = write_token_rows(carry["pk"], page, off, k.reshape(-1, hkv, dh))
         pv = write_token_rows(carry["pv"], page, off, v.reshape(-1, hkv, dh))
-        helper = get_helper("paged_attention")
         # one scope whichever path does the work, so a trace reader can
         # find attention by its scope and not by a kernel's name
         with jax.named_scope("attention_core"):
-            if helper is not None and helper.supports(q, ps):
-                # fused paged decode attention (roadmap item 1): attends
-                # straight off the pool + block table, never materializing
-                # the gathered [B, MAXP*page_size, Hkv, D] view
-                o = helper.attend(q, pk, pv, block, new_pos)
-            else:
-                # legacy gather+softmax oracle (DL4J_TPU_PAGED_GATHER=1)
+            if paged_path(t_new, self.n_heads, hkv, ps,
+                          block.shape[1]) == "gather":
+                # the seam gives way: the legacy gather+softmax pair
                 gk = gather_pages(pk, block).astype(q.dtype)
                 gv = gather_pages(pv, block).astype(q.dtype)
                 o = paged_attention(q, gk, gv, new_pos)
+            else:
+                # fused paged attention: straight off the pool + block
+                # table, never materializing the gathered
+                # [B, MAXP*page_size, Hkv, D] view
+                o = get_helper("paged_attention").attend(q, pk, pv, block,
+                                                         new_pos)
         new_carry = {"pk": pk, "pv": pv, "block": block, "pos": pos + t_new}
         return self._out(params, o, x), state, new_carry
 
@@ -612,7 +648,8 @@ class SelfAttentionLayer(Layer):
         ``carry["live"]`` real tokens are written -- bucket padding and
         what has already left the band go to the trash page, 0."""
         from deeplearning4j_tpu.helpers.paged_attention import (
-            paged_decode_attention, ring_column, write_token_rows)
+            paged_decode_attention, paged_path, ring_column,
+            write_token_rows)
 
         ring_tbl, pos = carry["block"], carry["pos"]   # [B, R], [B]
         ps, ring = carry["wk"].shape[2], ring_tbl.shape[1]
@@ -650,16 +687,15 @@ class SelfAttentionLayer(Layer):
         if t_new == 1:
             from deeplearning4j_tpu.helpers import get_helper
 
-            helper = get_helper("paged_attention")
             with jax.named_scope("attention_core"):
-                if helper is not None and helper.supports(q, ps):
-                    o = helper.attend(q, wk, wv, ring_tbl, new_pos,
-                                      window=self.window)
-                else:
-                    # legacy gather+softmax oracle (DL4J_TPU_PAGED_GATHER=1)
+                if paged_path(1, self.n_heads, hkv, ps, ring,
+                              self.window) == "gather":
                     o = paged_decode_attention(
                         q, wk, wv, ring_tbl, new_pos, window=self.window,
                         impl="gather")
+                else:
+                    o = get_helper("paged_attention").attend(
+                        q, wk, wv, ring_tbl, new_pos, window=self.window)
         new_carry = {"wk": wk, "wv": wv, "block": ring_tbl,
                      "pos": pos + t_new}
         return self._out(params, o, x), state, new_carry

@@ -45,6 +45,27 @@ KINDS = ("embed", "norm", "attention", "ffn", "experts", "head", "conv",
          "recurrent", "mhc_mix")
 
 
+@dataclasses.dataclass(frozen=True)
+class ServingCall:
+    """One compute program of the generation engine as its layers see it,
+    built once a program (``generation.programs.GenerationPrograms``):
+    ``batch`` rows of ``t`` positions (the slots and 1 in the decode step,
+    1 and the bucket in a prefill), whether every row starts at position 0
+    (``from_zero``: a prompt with nothing shared), pages of ``page_size``,
+    ``pages`` columns of a row's global block table and ``ring`` of its
+    ring table (0 without window layers), ``slots`` state slots, and the
+    ``dtype`` the layers are traced in."""
+
+    batch: int
+    t: int
+    from_zero: bool
+    page_size: int
+    pages: int
+    ring: int
+    slots: int
+    dtype: Any
+
+
 def register_layer(cls: Type["Layer"]) -> Type["Layer"]:
     """Class decorator: register a layer type for JSON round-trip
     (the Jackson ``@JsonSubTypes`` equivalent; custom layers use this too,
@@ -160,6 +181,19 @@ class Layer:
         keep = 1.0 - self.dropout
         mask = jax.random.bernoulli(rng, keep, W.shape)
         return jnp.where(mask, W / keep, 0.0)
+
+    # ---- serving --------------------------------------------------------
+    def serving_path(self, call: "ServingCall") -> Optional[str]:
+        """The path this layer takes in the generation program ``call``
+        describes — the same rule its traced branch follows, asked on the
+        host; None for a layer with one way through (the engine counts
+        ``dl4j_layer_path_steps_total{stage, kind, path}`` by it)."""
+        return None
+
+    def describe_serving(self, call: "ServingCall") -> Optional[str]:
+        """One warm-up log line on how this layer's kernel tiles in
+        ``call``'s program, None where there is nothing to say."""
+        return None
 
     # ---- regularization -------------------------------------------------
     def reg_score(self, params: Dict[str, jax.Array]) -> jax.Array:

@@ -39,7 +39,8 @@ Three carries, one layer, as ``MambaLayer``'s:
   ``live`` move neither state nor tail, and the decode step updates the
   pool in place by ``lanes``.
 
-A chunk or a sequence goes through one seam, ``get_helper("delta_rule")``.
+A chunk or a sequence goes through one seam, ``get_helper("delta_rule")``
+(the plain recurrence where it gives way: ``delta_rule_path``).
 So does the decode step on the state slots where the seam offers its
 kernel (the TPU): one Pallas kernel reads each lane's row once and writes
 it once, in place (``delta_rule.step_slots``, the ``delta_kernel`` path).
@@ -63,25 +64,29 @@ from deeplearning4j_tpu.nn import initializers
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+from deeplearning4j_tpu.nn.layers.state_space import describe_slots
 
-DELTA_RULE_PATHS = ("delta_step", "delta_chunk", "delta_kernel")
+DELTA_RULE_PATHS = ("delta_step", "delta_chunk", "delta_kernel",
+                    "delta_stepwise")
 # FLA's init: A uniform in (0, A_INIT_MAX], the step log-uniform in this range
 A_INIT_MAX = 16.0
 DT_INIT_MIN, DT_INIT_MAX = 1e-3, 1e-1
 L2_EPS = 1e-6
 
 
-def delta_rule_path(t: int, kernel: bool = False) -> str:
+def delta_rule_path(t: int, kernel: bool = False, seam: bool = True) -> str:
     """Which of ``DELTA_RULE_PATHS`` a call of ``t`` positions a row on the
     state slots takes: for a single token ``"delta_kernel"`` where the
     helper seam offers its kernel (``kernel``: the pool stepped in place by
     ``delta_rule.step_slots``), else ``"delta_step"`` (the slot layout in
-    ``jnp``, no loop); for more, ``"delta_chunk"``, the seam's WY form.
-    Pure: the layer branches on it while it is traced, the engine calls it
-    on the host to count ``dl4j_state_space_steps_total``."""
+    ``jnp``, no loop); for more, ``"delta_chunk"``, the seam's WY form,
+    where the seam is there (``seam``), and ``"delta_stepwise"``, the plain
+    recurrence one position a trip, where it gives way.  Pure: the layer
+    branches on it while it is traced, the engine calls it on the host to
+    count ``dl4j_layer_path_steps_total``."""
     if t == 1:
         return "delta_kernel" if kernel else "delta_step"
-    return "delta_chunk"
+    return "delta_chunk" if seam else "delta_stepwise"
 
 
 def _l2_normalize(x):
@@ -176,7 +181,18 @@ class GatedDeltaNetLayer(Layer):
         state slots, as the process stands (the kernel only where the
         helper seam offers it)."""
         helper = helpers.get_helper("delta_rule")
-        return delta_rule_path(t, helper is not None and helper.kernel)
+        return delta_rule_path(t, helper is not None and helper.kernel,
+                               helper is not None)
+
+    def serving_path(self, call) -> str:
+        return self.path(call.t)
+
+    def describe_serving(self, call) -> str:
+        return describe_slots(
+            call, f"delta-rule layers of {self.n_heads} heads x "
+            f"[{self.d_k}, {self.d_v}] (rows {list(self.state_shape())})",
+            self.serving_path(call), helpers.get_helper("delta_rule"),
+            self.n_heads * self.d_k * self.d_v, self.d_conv)
 
     def _conv(self, params, window):
         """Step 2 on ``window`` [B, d_conv - 1 + T, q + k + v channels] (the
@@ -241,8 +257,8 @@ class GatedDeltaNetLayer(Layer):
                         else dr.single_step(*one, s0))
                 o = o[:, None]
             else:
-                helper = helpers.get_helper("delta_rule")
-                rule = helper.chunked if helper is not None else dr.stepwise
+                rule = (helpers.get_helper("delta_rule").chunked
+                        if self.path(t) == "delta_chunk" else dr.stepwise)
                 o, s = rule(q, k, v, g, beta, dr.to_heads(s0, self.n_heads),
                             live)
                 s = dr.to_slots(s, self.group)

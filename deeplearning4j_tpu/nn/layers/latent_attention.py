@@ -59,7 +59,7 @@ def latent_path(t: int, from_zero: bool, kernel: bool = True) -> str:
     it on the device, the host knows it as a request with nothing shared),
     else ``"gathered"`` (a suffix behind a shared prefix).  Pure: the layer
     calls it while it is traced, the engine on the host to count
-    ``dl4j_latent_attention_steps_total``."""
+    ``dl4j_layer_path_steps_total``."""
     if t == 1:
         return "paged" if kernel else "gathered"
     return "expanded" if from_zero else "gathered"
@@ -243,14 +243,35 @@ class LatentAttentionLayer(Layer):
     def path(self, t: int, from_zero: bool, page_size: int, dtype) -> str:
         """``latent_path`` of a paged call of ``t`` query positions a row
         on this layer as the process stands: with the kernel only if the
-        helper seam offers it (helpers enabled, the fused mode, not
-        float64) for a pool of these pages."""
+        helper seam offers it (helpers enabled, not float64) for a pool of
+        these pages."""
         helper = None
         if jnp.dtype(dtype) != jnp.float64:
             helper = helpers.get_helper("paged_attention")
         return latent_path(t, from_zero, helper is not None
                            and helper.supports_latent(
                                self._pool_width, page_size, dtype))
+
+    def serving_path(self, call) -> str:
+        return self.path(call.t, call.from_zero, call.page_size, call.dtype)
+
+    def describe_serving(self, call) -> Optional[str]:
+        """How ``latent_paged_attention`` tiles the decode step, where the
+        kernel runs it (the prefills attend the expanded way or over the
+        gathered pages)."""
+        from deeplearning4j_tpu.helpers import paged_attention as pa
+
+        if (self.serving_path(call) != "paged"
+                or pa.default_impl() != "pallas"):
+            return None
+        b, h, w, v = call.batch, self.n_heads, self._pool_width, self.kv_rank
+        ps, pages, dtype = call.page_size, call.pages, jnp.dtype(call.dtype)
+        ppb, _, vmem = pa.paged_tiling(b, 1, h, 1, w, ps, pages, dtype, v)
+        return (f"latent_paged_attention q [{b}, 1, {h}, {w}] over {pages} "
+                f"pages of {ps}, the value the first {v} columns: {ppb} "
+                f"pages a block ({ppb * ps * w * dtype.itemsize / 2 ** 20:.2f}"
+                f" MB a copy), grid ({b}, 1), {vmem / 2 ** 20:.2f} MB of "
+                "VMEM")
 
     def _out(self, params, o):
         b, t = o.shape[:2]

@@ -185,7 +185,7 @@ def expert_path(rows: int, train: bool = False, kernel: bool = True) -> str:
     past them; else ``"ragged"``, rows sorted by expert through
     ``jax.lax.ragged_dot`` in blocks.  Pure: the layer calls it while it
     is traced, the engine on the host to count
-    ``dl4j_moe_expert_steps_total``."""
+    ``dl4j_layer_path_steps_total``."""
     if train or not kernel:
         return "ragged"
     return "streamed" if rows <= STREAMED_ROWS else "sorted"
@@ -314,6 +314,34 @@ class RoutedMoELayer(Layer):
         kernel = helpers.get_helper("grouped_experts")
         return expert_path(rows, train, kernel is not None and kernel.supports(
             self.n_in, self.hidden, self.n_out))
+
+    def serving_path(self, call) -> str:
+        return self.path(call.batch * call.t)
+
+    def describe_serving(self, call) -> Optional[str]:
+        """How ``grouped_experts`` tiles the program's call, where it runs
+        the ``streamed`` or the ``sorted`` path."""
+        from deeplearning4j_tpu.helpers import grouped_experts as ge
+
+        path, t = self.serving_path(call), call.batch * call.t
+        count, n, k = self.held[1], self.n_experts, self.top_k
+        d, hidden, n_out = self.n_in, self.hidden, self.n_out
+        if path == "streamed":
+            rows, tf, vmem = ge.expert_tiling(t, d, hidden, n_out,
+                                              call.dtype)
+            return (f"grouped_experts tokens [{t}, {d}] over {count} held "
+                    f"experts of width {hidden}: {rows} rows, hidden tiles "
+                    f"of {tf}, grid ({count}, {hidden // tf}), "
+                    f"{vmem / 2 ** 20:.2f} MB of VMEM")
+        if path == "sorted":
+            tm, tf, r, vmem = ge.sorted_tiling(d, hidden, n_out, call.dtype,
+                                               t * k // n)
+            return (f"sorted_experts tokens [{t}, {d}] over {count} held "
+                    f"experts of width {hidden}: blocks of "
+                    f"{ge.sorted_block(t * k, count, n)} sorted rows, row "
+                    f"tiles of {tm}, hidden tiles of {tf}, {r} row tiles a "
+                    f"visit, {vmem / 2 ** 20:.2f} MB of VMEM")
+        return None
 
     def _held_experts(self, params, tokens, ids, w, train=False):
         """The held experts' part of the result, [T, n_out] float32, by

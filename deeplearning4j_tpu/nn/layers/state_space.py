@@ -40,7 +40,9 @@ Three carries, one layer:
   read and written in place.
 
 The scan over a chunk or a sequence goes through one seam,
-``get_helper("selective_scan")``; the single-token step is plain ``jnp``.
+``get_helper("selective_scan")``, and where the seam gives way through the
+plain scan of one time step a trip (``state_space_path``); the
+single-token step is plain ``jnp``.
 """
 
 from __future__ import annotations
@@ -59,22 +61,38 @@ from deeplearning4j_tpu.nn import initializers
 from deeplearning4j_tpu.nn.inputs import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 
-STATE_SPACE_PATHS = ("step", "scan", "kernel")
+# how a call runs the recurrence (``state_space_path``)
+STATE_SPACE_PATHS = ("step", "scan", "stepwise")
 # the DSL's own init of the step size (Mamba's): log-uniform in this range
 DT_INIT_MIN, DT_INIT_MAX = 1e-3, 1e-1
 _HIGHEST = lax.Precision.HIGHEST
 
 
-def state_space_path(t: int, kernel: bool = False) -> str:
+def state_space_path(t: int, seam: bool = True) -> str:
     """Which of ``STATE_SPACE_PATHS`` a call of ``t`` positions a row takes:
     ``"step"`` for a single token (one pass over the rows' states, no loop),
-    else the helper seam's scan — ``"kernel"`` where it offers one,
-    ``"scan"`` for the ``lax`` form.  Pure: the layer calls it while it is
-    traced, the engine on the host to count
-    ``dl4j_state_space_steps_total``."""
+    else ``"scan"``, the helper seam's chunked scan, where the seam offers
+    it (``seam``), and ``"stepwise"``, the plain scan of one time step a
+    trip, where it gives way.  Pure: the layer branches on it while it is
+    traced, the engine calls it on the host to count
+    ``dl4j_layer_path_steps_total``."""
     if t == 1:
         return "step"
-    return "kernel" if kernel else "scan"
+    return "scan" if seam else "stepwise"
+
+
+def describe_slots(call, label: str, path: str, helper, entries: int,
+                   d_conv: int) -> str:
+    """The warm-up line of a layer that keeps state slots: how ``call``'s
+    program runs its recurrence (``helper.describe`` for the seam's
+    chunked form) and what a slot's state weighs."""
+    t = call.t
+    how = ("one pass over the rows' states" if t == 1
+           else helper.describe(t) if helper is not None
+           else f"lax scan, {t} trips of 1 time step")
+    return (f"{label} ({path}): {how}; {call.slots} state slots + the trash "
+            f"row, {entries * 4 / 1e3:.1f} kB of float32 state and a tail "
+            f"of {d_conv - 1} rows a slot a layer")
 
 
 def _gain_norm(x, g, eps):
@@ -164,8 +182,18 @@ class MambaLayer(Layer):
     def path(self, t: int) -> str:
         """``state_space_path`` of a call of ``t`` positions a row on this
         layer as the process stands."""
-        helper = helpers.get_helper("selective_scan")
-        return state_space_path(t, helper is not None and helper.kernel)
+        return state_space_path(
+            t, helpers.get_helper("selective_scan") is not None)
+
+    def serving_path(self, call) -> str:
+        return self.path(call.t)
+
+    def describe_serving(self, call) -> str:
+        return describe_slots(
+            call, f"state-space layers of {self.d_inner} channels x "
+            f"{self.d_state} state columns", self.serving_path(call),
+            helpers.get_helper("selective_scan"),
+            self.d_inner * self.d_state, self.d_conv)
 
     def _in(self, params, u):
         """Step 1: ``u`` [B, T, F] -> ``x``, ``z`` [B, T, d]."""
@@ -223,14 +251,15 @@ class MambaLayer(Layer):
         window = jnp.concatenate([tail.astype(x_in.dtype), x_in], axis=1)
         x = self._conv(params, window)
         dt, b, c, a = self._selection(params, x)
+        path = self.path(t)
         with jax.named_scope("ssm_scan"):
-            if t == 1:
+            if path == "step":
                 y, h = ss.single_step(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
                                       h0)
                 y = y[:, None]
             else:
-                helper = helpers.get_helper("selective_scan")
-                scan = helper.scan if helper is not None else ss.stepwise_scan
+                scan = (helpers.get_helper("selective_scan").scan
+                        if path == "scan" else ss.stepwise_scan)
                 y, h = scan(x, dt, a, b, c, h0, live)
         with jax.named_scope("ssm_conv"):
             # the last d_conv - 1 rows ahead of the convolution, of the REAL

@@ -194,14 +194,20 @@ class GenerationMetrics:
             "the compute dtype for its generation programs (one per "
             "loaded version, one more each time its net's parameter "
             "tree is found changed at a dispatch)", labels=("model",))
-        self.sampling_steps = reg.counter(
-            "dl4j_sampling_steps_total",
+        self.layer_path_steps = reg.counter(
+            "dl4j_layer_path_steps_total",
             "Dispatched decode steps (stage=decode) and prefills "
-            "(stage=admit) by what their sampling epilogue ran, from the "
-            "rows' policy (utils.sampling.sampling_path): greedy = an "
-            "argmax, draw = temperature and the categorical draw, filter "
-            "= the top-k / top-p sorts for the whole batch",
-            labels=("stage", "path"))
+            "(stage=prefill), once for each distinct (kind, path) the "
+            "program takes: kind is the layer's (Layer.kind), path what "
+            "its Layer.serving_path names for the program's shapes, the "
+            "rule its traced branch follows (attention: heads / rows / "
+            "lax / gather for paged self-attention, paged / gathered / "
+            "expanded for latent attention; experts: streamed / sorted / "
+            "ragged; recurrent: step / scan / stepwise for state-space "
+            "layers, delta_kernel / delta_step / delta_chunk / "
+            "delta_stepwise for delta-rule layers); kind=head is the "
+            "sampling epilogue's path from the rows' policy: greedy / "
+            "draw / filter", labels=("stage", "kind", "path"))
         self.moe_tokens = reg.counter(
             "dl4j_moe_tokens_total",
             "Real tokens routed by the served net's expert layers, summed "
@@ -214,61 +220,6 @@ class GenerationMetrics:
             "ones, summed over expert layers; over "
             "dl4j_moe_tokens_total: top_k * held / n_experts when routing "
             "is uniform", labels=("expert",))
-        self.moe_expert_steps = reg.counter(
-            "dl4j_moe_expert_steps_total",
-            "Dispatched decode steps (stage=decode) and prefills "
-            "(stage=prefill) of a net with expert layers, by how the "
-            "program multiplies their held experts, from its row count "
-            "(nn.layers.moe.expert_path): streamed = one fused kernel "
-            "reads each touched expert's weights once, every row against "
-            "it (at most 256 rows: a decode step), sorted = one fused "
-            "kernel reads each touched expert's weights once, its sorted "
-            "rows alone against it (more rows: a prefill bucket), ragged "
-            "= rows sorted by expert through ragged_dot in blocks (any "
-            "program where the kernels give way)", labels=("stage", "path"))
-        self.latent_attention_steps = reg.counter(
-            "dl4j_latent_attention_steps_total",
-            "Dispatched decode steps (stage=decode) and prefills "
-            "(stage=prefill) of a net with latent attention layers, by how "
-            "the program attends over their pages "
-            "(nn.layers.latent_attention.latent_path): paged = one kernel "
-            "reads the rows' live latent pages where they lie (a decode "
-            "step), gathered = the absorbed way over pool[block], every "
-            "page of every row's table (a suffix behind a shared prefix; "
-            "a decode step where the kernel gives way), expanded = flash "
-            "attention over the chunk's own decompressed keys (a prompt "
-            "prefilled from position 0)", labels=("stage", "path"))
-        self.paged_attention_steps = reg.counter(
-            "dl4j_paged_attention_steps_total",
-            "Dispatched decode steps (stage=decode) and prefills "
-            "(stage=prefill) of a net with paged self-attention layers, by "
-            "how the program attends over their pages "
-            "(helpers.paged_attention.paged_path): heads = the kernel with "
-            "every kv head's one query row in one tile, one product for "
-            "all heads a block (a group of one row a kv head: a "
-            "multi-head decode step), rows = the kernel with each kv "
-            "head's query rows in a tile of their own (grouped heads, a "
-            "prefill chunk, a window's ring), lax = the compiled page loop "
-            "(every backend but the TPU), gather = the gathered view and "
-            "one softmax (the oracle switch, helpers disabled)",
-            labels=("stage", "path"))
-        self.state_space_steps = reg.counter(
-            "dl4j_state_space_steps_total",
-            "Dispatched decode steps (stage=decode) and prefills "
-            "(stage=prefill) of a net with state-space layers, by how the "
-            "program runs their recurrence "
-            "(nn.layers.state_space.state_space_path): step = one pass over "
-            "the rows' states, no loop (a single token a row: the decode "
-            "step), scan = the helper seam's chunked lax scan (a prefill "
-            "bucket), kernel = a Pallas kernel where the seam offers one; "
-            "for delta-rule layers "
-            "(nn.layers.delta_net.delta_rule_path): delta_kernel = the "
-            "decode step on the state slots as one Pallas kernel that reads "
-            "and writes each row once, in place (where the seam offers it: "
-            "the TPU), delta_step = the decode step on the slot layout in "
-            "jnp, delta_chunk = the seam's chunked WY form (a prefill "
-            "bucket)",
-            labels=("stage", "path"))
         self.state_slot_resets = reg.counter(
             "dl4j_state_slot_resets_total",
             "Admissions that began a state slot's recurrent state anew (a "
@@ -311,13 +262,6 @@ class GenerationMetrics:
                 "Usable pages of the paged KV pools (trash page excluded), "
                 "by layer kind", labels=("engine", "kind"))}
         self._kv_pages_children = {}
-        self.fused_attention = reg.gauge(
-            "dl4j_decode_fused_attention",
-            "1 when decode attention runs the fused paged kernel "
-            "(helpers/paged_attention.py, pool + block table streamed "
-            "through an online-softmax accumulator), 0 on the legacy "
-            "gather+softmax oracle (DL4J_TPU_PAGED_GATHER=1 or helpers "
-            "disabled)", labels=("engine",)).labels(engine=self.engine_id)
         self.prefix_cache_resident = reg.gauge(
             "dl4j_prefix_cache_resident_pages",
             "Device pages the prefix-cache radix tree currently keeps "

@@ -119,7 +119,8 @@ def sampling_path(temperature, top_k, top_p):
     row of a batch with these ``[B]`` policy arrays.  Pure and written
     against the array methods numpy and ``jnp`` share: ``sample_tokens``
     calls it on traced arrays to pick its branch, the engine on the
-    scheduler's numpy arrays to count ``dl4j_sampling_steps_total``."""
+    scheduler's numpy arrays to count ``dl4j_layer_path_steps_total``
+    (kind ``head``)."""
     draws = temperature > 0
     filters = draws & ((top_k >= 1) | (top_p < 1))
     return (draws.any().astype(np.int32)

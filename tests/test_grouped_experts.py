@@ -310,13 +310,15 @@ def test_the_engine_counts_each_dispatch_by_its_path():
         eng.stop()
     assert [len(s) for s in served] == [6, 6, 6]
     progs = next(iter(eng._programs.values()))
-    assert progs.expert_paths == {"decode": ("streamed",),
-                                  32: ("streamed",), big: ("sorted",)}
+    experts = {name: tuple(p for k, p in paths if k == "experts")
+               for (name, _), paths in progs.paths.items()}
+    assert experts == {"decode": ("streamed",), 32: ("streamed",),
+                       big: ("sorted",)}
     reg = eng.metrics.registry
 
     def count(stage, path):
-        return reg.get_value("dl4j_moe_expert_steps_total", stage=stage,
-                             path=path) or 0
+        return reg.get_value("dl4j_layer_path_steps_total", stage=stage,
+                             kind="experts", path=path) or 0
 
     dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
                      for m in ("ahead", "sync"))
@@ -333,7 +335,8 @@ def test_a_net_without_expert_layers_counts_nothing():
 
     progs = GenerationPrograms(_kv_lm(), slots=2, pages_per_slot=4,
                                page_size=4, num_pages=9, prefill_buckets=(8,))
-    assert progs.expert_layers == [] and progs.expert_paths == {}
+    assert all(k != "experts" for paths in progs.paths.values()
+               for k, _ in paths)
 
 
 # ------------------------------------------------- the sorted path (PR 40)

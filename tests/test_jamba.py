@@ -123,7 +123,7 @@ def test_every_form_of_the_scan_is_the_token_by_token_step(chunk,
     if chunk == 1:
         helpers.enable_helpers(False)
         try:
-            assert layer.path(37) == "scan"
+            assert layer.path(37) == "stepwise"
             off, _ = layer.apply(params, {}, u)
         finally:
             helpers.enable_helpers(True)
@@ -135,9 +135,9 @@ def test_every_form_of_the_scan_is_the_token_by_token_step(chunk,
 
 
 def test_the_path_rule_is_pure():
-    took = [state_space_path(t, k) for t, k in
-            ((1, False), (1, True), (2, False), (512, True))]
-    assert took == ["step", "step", "scan", "kernel"]
+    took = [state_space_path(t, seam) for t, seam in
+            ((1, False), (1, True), (2, True), (512, False))]
+    assert took == ["step", "step", "scan", "stepwise"]
     assert set(took) == set(STATE_SPACE_PATHS)
     layer, _, _, _ = mixer_and_leaves()
     assert (layer.path(1), layer.path(256)) == ("step", "scan")
@@ -258,15 +258,15 @@ def test_engine_serves_the_toy_model_as_the_reference(heads):
     # counted at dispatch: every decode step dispatched, one ahead or not
     dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
                      for m in ("ahead", "sync"))
-    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
-                         path="step") == dispatched
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="decode", path="step") == dispatched
     assert dispatched >= reg.get_value("dl4j_decode_steps_total") > 0
-    assert reg.get_value("dl4j_state_space_steps_total", stage="prefill",
-                         path="scan") == 7
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="prefill", path="scan") == 7
     assert reg.get_value("dl4j_state_slot_resets_total", engine=eid) == 7
     assert reg.get_value("dl4j_state_slots_in_use", engine=eid) == 0
-    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
-                         path="kernel") is None
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="decode", path="stepwise") is None
 
 
 @pytest.mark.parametrize("fault", ["token_altered", "state_not_reset",
@@ -335,7 +335,8 @@ def test_older_nets_lower_to_the_programs_of_the_parent(family, monkeypatch):
     net, _ = test_xing.toy_net()
     progs = GenerationPrograms(net, slots=4, pages_per_slot=6, page_size=8,
                                num_pages=25, prefill_buckets=(16, 32))
-    assert not progs.state and progs.state_space_paths == {}
+    assert not progs.state and all(
+        k != "recurrent" for paths in progs.paths.values() for k, _ in paths)
     got = {name: hashlib.sha256(low.as_text().replace(
         f"@jit_{name} ", "@jit_prefill ").encode()).hexdigest()[:16]
            for name, low in progs.lowered().items()}

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark import model_laguna, reference_laguna as ref
+from deeplearning4j_tpu import helpers
 from deeplearning4j_tpu.generation.engine import GenerationEngine
 from deeplearning4j_tpu.generation.paged_cache import (
     PagedKVCache, PageExhaustedError,
@@ -69,14 +70,16 @@ def layer_leaves(cfg, i, names, dtype=jnp.float32):
 
 @pytest.fixture
 def paged_impl(request, monkeypatch):
-    """Route the layers' paged attention through one implementation."""
+    """Route the layers' paged attention through one implementation: the
+    gather where the seam withholds the paged helper alone."""
     impl = request.param
     if impl == "gather":
-        pa.set_paged_attention_mode("gather")
+        get = helpers.get_helper
+        monkeypatch.setattr(helpers, "get_helper", lambda kind: (
+            None if kind == "paged_attention" else get(kind)))
     else:
         monkeypatch.setattr(pa, "default_impl", lambda: impl)
-    yield impl
-    pa.set_paged_attention_mode("fused")
+    return impl
 
 
 # -------------------------- (a) both layer kinds through their paged pools

@@ -15,7 +15,7 @@ from deeplearning4j_tpu.generation.programs import GenerationPrograms
 from deeplearning4j_tpu.nn.layers import GatedMLP, RMSNorm
 from deeplearning4j_tpu.nn.layers.moe import counting
 from deeplearning4j_tpu.observability.metrics import MetricsRegistry
-from tests.test_paged_kernel import _in_mode
+from tests.test_paged_kernel import _gather
 
 # original_max_position_embeddings 16: the sequences below run past it, so
 # YaRN's blended frequencies and its softmax temperature are in every test
@@ -300,7 +300,8 @@ def test_engine_serves_the_toy_model_as_the_reference_and_counts_it():
 def test_engine_serves_the_same_tokens_in_place_and_through_the_gather():
     """The toy model through the engine with the single-token attention
     over the pages where they lie (here the lax page loop; the kernel on a
-    TPU) and under the oracle switch (``pool[block]`` + ``_absorbed``):
+    TPU) and with the paged helper withheld (``pool[block]`` +
+    ``_absorbed``):
     the same greedy tokens, joins and leaves included."""
     rng = np.random.default_rng(2)
     requests = [(rng.integers(0, 97, n).tolist(), m)
@@ -309,17 +310,25 @@ def test_engine_serves_the_same_tokens_in_place_and_through_the_gather():
     def serve():
         eng, served = run_engine(toy_net()[0], requests)
         progs = next(iter(eng._programs.values()))
-        return progs.latent_paths[("decode", False)], served
+        return _latent(progs.paths[("decode", False)]), served
 
     path, in_place = serve()
-    oracle_path, gathered = _in_mode("gather", serve)
+    oracle_path, gathered = _gather(serve)
     assert path == ("paged",) and oracle_path == ("gathered",)
     for a, b in zip(in_place, gathered):
         assert a.tolist() == b.tolist()
 
 
+def _latent(paths):
+    """The latent layers' paths out of a program's (kind, path) pairs."""
+    from deeplearning4j_tpu.nn.layers.latent_attention import LATENT_PATHS
+
+    return tuple(p for k, p in paths if k == "attention" and p in LATENT_PATHS)
+
+
 def test_the_engine_counts_each_dispatch_by_its_latent_path():
-    """``dl4j_latent_attention_steps_total``: ``paged`` for every decode
+    """``dl4j_layer_path_steps_total`` of kind ``attention``: ``paged`` for
+    every decode
     dispatch, ``expanded`` for a prompt prefilled whole, ``gathered`` for
     a suffix behind a shared prefix — what the host rule says, which is
     what the programs were traced to do."""
@@ -339,15 +348,15 @@ def test_the_engine_counts_each_dispatch_by_its_latent_path():
         eng.stop()
     assert [len(t) for t in served] == [5, 4, 3] and again.shared_len == 16
     progs = next(iter(eng._programs.values()))
-    assert progs.latent_paths == {
+    assert {key: _latent(paths) for key, paths in progs.paths.items()} == {
         ("decode", False): ("paged",), ("decode", True): ("paged",),
         (16, True): ("expanded",), (16, False): ("gathered",),
         (32, True): ("expanded",), (32, False): ("gathered",)}
     reg = eng.metrics.registry
 
     def count(stage, path):
-        return reg.get_value("dl4j_latent_attention_steps_total",
-                             stage=stage, path=path) or 0
+        return reg.get_value("dl4j_layer_path_steps_total", stage=stage,
+                             kind="attention", path=path) or 0
 
     dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
                      for m in ("ahead", "sync"))
@@ -387,7 +396,7 @@ def test_the_traced_branch_is_the_one_the_host_rule_names(mode, monkeypatch):
         return (layer.path(1, False, ps, jnp.bfloat16), lowered(1),
                 lowered(16))
 
-    said, decode, chunk = run() if mode == "fused" else _in_mode(mode, run)
+    said, decode, chunk = run() if mode == "fused" else _gather(run)
     assert said == {"fused": "paged", "gather": "gathered"}[mode]
     assert (decode.count('kernel_name = "latent_paged_attention"')
             == (said == "paged"))
@@ -407,8 +416,9 @@ def test_programs_log_the_latent_tiling_once(monkeypatch, caplog):
         caplog.clear()
         with caplog.at_level(logging.INFO,
                              logger="deeplearning4j_tpu.generation"):
-            progs._log_latent_tiling()
-        return [r.getMessage() for r in caplog.records]
+            progs._log_tiling()
+        return [m for r in caplog.records
+                if "latent_paged_attention" in (m := r.getMessage())]
 
     assert lines() == []                      # CPU: the lax page loop
     monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
@@ -416,9 +426,9 @@ def test_programs_log_the_latent_tiling_once(monkeypatch, caplog):
     assert len(said) == 1 and said[0].startswith(
         "generation.decode: latent_paged_attention q [4, 1, 4, 128] over 6 "
         "pages of 8, the value the first 32 columns: 6 pages a block")
-    assert GenerationPrograms(_kv_lm(), slots=2, pages_per_slot=4,
-                              page_size=4, num_pages=9,
-                              prefill_buckets=(8,)).latent_paths == {}
+    kv = GenerationPrograms(_kv_lm(), slots=2, pages_per_slot=4,
+                            page_size=4, num_pages=9, prefill_buckets=(8,))
+    assert not any(_latent(paths) for paths in kv.paths.values())
 
 
 def test_counting_leaves_out_padding_and_idle_rows():

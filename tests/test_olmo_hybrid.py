@@ -162,10 +162,11 @@ def test_the_decode_step_on_the_slot_layout_is_one_step(h, dv, group):
 
 
 def test_the_path_rule_is_pure():
-    took = [delta_rule_path(t, k) for t, k in
-            ((1, False), (2, False), (512, True), (1, True))]
+    took = [delta_rule_path(t, k, seam) for t, k, seam in
+            ((1, False, True), (2, False, True), (512, True, True),
+             (1, True, True), (2, False, False))]
     assert took == ["delta_step", "delta_chunk", "delta_chunk",
-                    "delta_kernel"]
+                    "delta_kernel", "delta_stepwise"]
     assert set(took) == set(DELTA_RULE_PATHS)
     assert [delta_rule_path(1, True) for _ in range(3)] == ["delta_kernel"] * 3
     # off the TPU the seam offers no kernel
@@ -421,14 +422,15 @@ def test_engine_serves_the_toy_model_as_the_reference():
     reg, eid = eng.metrics.registry, eng.metrics.engine_id
     dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
                      for m in ("ahead", "sync"))
-    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
-                         path="delta_step") == dispatched > 0
-    assert reg.get_value("dl4j_state_space_steps_total", stage="prefill",
-                         path="delta_chunk") == 7
+    assert reg.get_value(
+        "dl4j_layer_path_steps_total", kind="recurrent", stage="decode",
+        path="delta_step") == dispatched > 0
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="prefill", path="delta_chunk") == 7
     assert reg.get_value("dl4j_state_slot_resets_total", engine=eid) == 7
     assert reg.get_value("dl4j_state_slots_in_use", engine=eid) == 0
-    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
-                         path="step") is None
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="decode", path="step") is None
 
 
 def test_engine_serves_the_reference_through_the_kernel(monkeypatch):
@@ -443,12 +445,13 @@ def test_engine_serves_the_reference_through_the_kernel(monkeypatch):
     reg = eng.metrics.registry
     dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
                      for m in ("ahead", "sync"))
-    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
-                         path="delta_kernel") == dispatched > 0
-    assert reg.get_value("dl4j_state_space_steps_total", stage="decode",
-                         path="delta_step") is None
-    assert reg.get_value("dl4j_state_space_steps_total", stage="prefill",
-                         path="delta_chunk") == 5
+    assert reg.get_value(
+        "dl4j_layer_path_steps_total", kind="recurrent", stage="decode",
+        path="delta_kernel") == dispatched > 0
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="decode", path="delta_step") is None
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="prefill", path="delta_chunk") == 5
 
 
 # The toy net's programs as the parent commit d30b33b lowers them, where the
@@ -467,9 +470,10 @@ def test_without_the_kernel_the_programs_are_the_parents():
     net, _ = toy_net()
     progs = GenerationPrograms(net, slots=4, pages_per_slot=6, page_size=8,
                                num_pages=25, prefill_buckets=(16, 32))
-    assert progs.state_space_paths == {"decode": ("delta_step",),
-                                       16: ("delta_chunk",),
-                                       32: ("delta_chunk",)}
+    assert {name: tuple(p for k, p in paths if k == "recurrent")
+            for (name, _), paths in progs.paths.items()} == {
+        "decode": ("delta_step",), 16: ("delta_chunk",),
+        32: ("delta_chunk",)}
     got = {name: hashlib.sha256(low.as_text().replace(
         f"@jit_{name} ", "@jit_prefill ").encode()).hexdigest()[:16]
            for name, low in progs.lowered().items()}

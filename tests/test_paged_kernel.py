@@ -27,9 +27,8 @@ from deeplearning4j_tpu.helpers.fused_epilogue import (
 )
 from deeplearning4j_tpu.helpers.paged_attention import (
     PAGED_PATHS, SLAB_BLOCK_BYTES, VMEM_BUDGET, PagedAttentionHelper,
-    paged_attention_mode, paged_decode_attention, paged_form,
-    paged_latent_attention, paged_path, paged_tiling,
-    set_paged_attention_mode, write_token_rows,
+    paged_decode_attention, paged_form, paged_latent_attention, paged_path,
+    paged_tiling, write_token_rows,
 )
 from deeplearning4j_tpu.nn.layers.attention import (
     SelfAttentionLayer, gather_pages, paged_attention,
@@ -519,7 +518,7 @@ def test_the_path_follows_the_seam_and_the_backend(monkeypatch):
     monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
     assert paged_path(*shape) == "heads"
     assert paged_path(512, 30, 30, 64, 24) == "rows"
-    assert _in_mode("gather", lambda: paged_path(*shape)) == "gather"
+    assert _gather(lambda: paged_path(*shape)) == "gather"
     helpers.enable_helpers(False)
     try:
         assert paged_path(*shape) == "gather"
@@ -673,25 +672,22 @@ def test_compiled_kernel_rejects_page_size_the_dtype_cannot_tile():
 
 
 def test_mode_toggle_and_helper_gating():
-    assert paged_attention_mode() == "fused"       # the default
-    helper = PagedAttentionHelper()
-    q = jnp.zeros((1, 1, 4, 32))
-    assert helper.supports(q, 4)
+    """The seam offers ``PagedAttentionHelper`` by default and withholds it
+    with helpers disabled: the one way back to the gather."""
+    assert isinstance(helpers.get_helper("paged_attention"),
+                      PagedAttentionHelper)
+    helpers.enable_helpers(False)
     try:
-        set_paged_attention_mode("gather")
-        assert paged_attention_mode() == "gather"
-        assert not helper.supports(q, 4)
+        assert helpers.get_helper("paged_attention") is None
     finally:
-        set_paged_attention_mode("fused")
-    with pytest.raises(ValueError):
-        set_paged_attention_mode("einsum")
+        helpers.enable_helpers(True)
 
 
 def test_latent_path_follows_the_seam_and_the_dtype():
     """``LatentAttentionLayer.path``: the kernel for a single token while
-    the seam offers it; the gather under the oracle switch, with helpers
-    disabled, in float64, and on a TPU for a page the dtype cannot tile;
-    a chunk never takes it."""
+    the seam offers it; the gather where the seam withholds the helper,
+    with helpers disabled, in float64, and on a TPU for a page the dtype
+    cannot tile; a chunk never takes it."""
     from deeplearning4j_tpu.helpers import paged_attention as pa
     from deeplearning4j_tpu.nn.layers.latent_attention import (
         LATENT_PATHS, LatentAttentionLayer, latent_path)
@@ -701,8 +697,7 @@ def test_latent_path_follows_the_seam_and_the_dtype():
     assert layer.path(1, False, 8, jnp.float32) == "paged"
     assert layer.path(1, False, 8, jnp.bfloat16) == "paged"
     assert layer.path(1, False, 8, jnp.float64) == "gathered"
-    assert _in_mode("gather", lambda: layer.path(
-        1, False, 8, jnp.float32)) == "gathered"
+    assert _gather(lambda: layer.path(1, False, 8, jnp.float32)) == "gathered"
     helpers.enable_helpers(False)
     try:
         assert layer.path(1, False, 8, jnp.float32) == "gathered"
@@ -767,11 +762,7 @@ def test_row_crosses_page_boundary_mid_decode():
         return outs
 
     fused = run()
-    set_paged_attention_mode("gather")
-    try:
-        oracle = run()
-    finally:
-        set_paged_attention_mode("fused")
+    oracle = _gather(run)
     for i, (a, b) in enumerate(zip(fused, oracle)):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-6,
@@ -793,12 +784,16 @@ def _engine(lm, **kw):
                             max_queue=64, deadline_s=60.0, **kw).start()
 
 
-def _in_mode(mode, fn):
-    set_paged_attention_mode(mode)
+def _gather(fn):
+    """``fn()`` with the seam withholding the paged-attention helper alone:
+    the layers take the gather oracle, every other helper as it was."""
+    get = helpers.get_helper
+    helpers.get_helper = lambda kind: (None if kind == "paged_attention"
+                                       else get(kind))
     try:
         return fn()
     finally:
-        set_paged_attention_mode("fused")
+        helpers.get_helper = get
 
 
 def test_programs_log_their_tiling_once_a_program(monkeypatch, caplog):
@@ -818,7 +813,7 @@ def test_programs_log_their_tiling_once_a_program(monkeypatch, caplog):
         caplog.clear()
         with caplog.at_level(logging.INFO,
                              logger="deeplearning4j_tpu.generation"):
-            progs._log_paged_tiling()
+            progs._log_tiling()
         return [r.getMessage() for r in caplog.records
                 if "fused_paged_attention" in r.getMessage()]
 
@@ -851,7 +846,7 @@ def test_engine_join_leave_parity_fused_vs_gather(rng):
         finally:
             eng.stop()
 
-    ref = _in_mode("gather", gather_sequential)
+    ref = _gather(gather_sequential)
 
     eng = _engine(lm)            # fused default, concurrent + staggered
     try:
@@ -887,7 +882,7 @@ def test_engine_prefix_cache_hit_parity(rng):
             eng.stop()
 
     fused_out, fused_shared = run()
-    gather_out, gather_shared = _in_mode("gather", run)
+    gather_out, gather_shared = _gather(run)
     assert fused_shared[1] > 0 and gather_shared[1] > 0   # hit path ran
     assert fused_out == gather_out
 
@@ -909,7 +904,7 @@ def test_engine_hot_swap_parity(rng):
             eng.stop()
 
     fused = run()
-    oracle = _in_mode("gather", run)
+    oracle = _gather(run)
     assert fused == oracle
     assert fused[0] != fused[1]       # the swap actually changed weights
 
@@ -919,9 +914,9 @@ def test_engine_serves_mha_through_the_heads_form(rng, monkeypatch):
     take the Pallas path (interpreted off the chip), pages of 16 in a
     128-position context: a decode step takes the ``heads`` form (a block
     of 8 pages, 128 keys), a prefill the ``rows`` form.  The served tokens
-    are the gather oracle's, and ``dl4j_paged_attention_steps_total``
-    reads ``heads`` once a dispatched decode step, ``rows`` once a
-    prefill."""
+    are the gather oracle's, and ``dl4j_layer_path_steps_total`` of kind
+    ``attention`` reads ``heads`` once a dispatched decode step, ``rows``
+    once a prefill."""
     from deeplearning4j_tpu.generation import GenerationEngine
     from deeplearning4j_tpu.helpers import paged_attention as pa
     from deeplearning4j_tpu.observability.metrics import MetricsRegistry
@@ -941,12 +936,12 @@ def test_engine_serves_mha_through_the_heads_form(rng, monkeypatch):
         finally:
             eng.stop()
 
-    oracle, want = _in_mode("gather", run)
+    oracle, want = _gather(run)
     monkeypatch.setattr(pa, "default_impl", lambda: "pallas")
     eng, got = run()
     assert got == want
     steps = lambda e, **kw: e.metrics.registry.get_value(
-        "dl4j_paged_attention_steps_total", **kw)
+        "dl4j_layer_path_steps_total", kind="attention", **kw)
     dispatched = sum(eng.metrics.registry.get_value(
         "dl4j_decode_dispatch_total", mode=m) or 0 for m in ("ahead", "sync"))
     assert steps(eng, stage="decode", path="heads") == dispatched > 0

@@ -8,8 +8,9 @@ one compiled program by ``sampling_path``.
 - the compiled programs hold their sorts inside a conditional's branch;
 - the numpy call of the path rule (the engine's counter) agrees with the
   ``jnp`` call (the program's branch);
-- an engine run moves ``dl4j_sampling_steps_total`` by the path each step
-  took, and a greedy request still equals ``models.decode.generate``.
+- an engine run moves ``dl4j_layer_path_steps_total{kind="head"}`` by the
+  path each step took, and a greedy request still equals
+  ``models.decode.generate``.
 """
 
 import re
@@ -185,8 +186,9 @@ def test_generation_programs_sort_only_inside_the_conditional(lm, program):
 
 def _counts(registry):
     return {(stage, path): registry.get_value(
-        "dl4j_sampling_steps_total", stage=stage, path=path) or 0
-        for stage in ("admit", "decode") for path in SAMPLING_PATHS}
+        "dl4j_layer_path_steps_total", stage=stage, kind="head",
+        path=path) or 0
+        for stage in ("prefill", "decode") for path in SAMPLING_PATHS}
 
 
 def test_engine_counts_each_step_under_its_path(lm, rng):
@@ -212,9 +214,9 @@ def test_engine_counts_each_step_under_its_path(lm, rng):
     assert greedy == generate(lm, prompt, 9, temperature=0.0)[0].tolist()
     assert greedy_filtered == greedy[:4]
     assert _counts(registry) == {
-        ("admit", "greedy"): 2, ("decode", "greedy"): 8 + 3,
-        ("admit", "draw"): 1, ("decode", "draw"): 5,
-        ("admit", "filter"): 2, ("decode", "filter"): 4 + 2}
+        ("prefill", "greedy"): 2, ("decode", "greedy"): 8 + 3,
+        ("prefill", "draw"): 1, ("decode", "draw"): 5,
+        ("prefill", "filter"): 2, ("decode", "filter"): 4 + 2}
     assert registry.get_value("dl4j_decode_steps_total") == 22
 
 
@@ -243,9 +245,9 @@ def test_engine_mixed_batch_takes_the_filter_path_and_greedy_holds(lm, rng):
                                   temperature=0.0)[0].tolist()
     assert got_sampled == alone
     counts = _counts(registry)
-    assert counts[("admit", "filter")] == 2
-    assert counts[("admit", "greedy")] == 1
-    assert counts[("admit", "draw")] == counts[("decode", "draw")] == 0
+    assert counts[("prefill", "filter")] == 2
+    assert counts[("prefill", "greedy")] == 1
+    assert counts[("prefill", "draw")] == counts[("decode", "draw")] == 0
     # each sampled request decodes 6 steps, all on the filter path; the
     # greedy one's 11 are filter steps while it shares the batch
     assert counts[("decode", "filter")] == 12

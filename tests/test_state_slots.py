@@ -230,4 +230,5 @@ def test_a_character_lstm_is_served_as_generate_samples_it():
     pools = jax.eval_shape(programs(net).fresh_pools)
     assert pools["layer_0"]["sh"].shape == (5, 16)
     assert eng.metrics.registry.get_value(
-        "dl4j_state_space_steps_total", stage="decode", path="step") is None
+        "dl4j_layer_path_steps_total", stage="decode", kind="recurrent",
+        path="step") is None
