@@ -51,6 +51,25 @@ token, ``single_step`` runs.  The small products of the other forms run at
 ``live`` [B] (a prefill bucket's real tokens): positions at or past it leave
 the state untouched (a decay of 1 and a ``b`` of 0); their outputs are
 finite and mean nothing.
+
+THE PER-CHANNEL DECAY (Kimi Delta Attention, arXiv:2510.26692): the decay
+is a vector over ``d_k``, ``D_t = Diag(exp(g_t))`` with ``g_t`` [d_k], and it
+acts before the delta::
+
+    S_t = (I - b_t k_t k_t^T) D_t S_{t-1} + b_t k_t v_t^T
+
+Its four forms carry a ``kda_`` prefix and take ``g`` [..., H, d_k].
+``kda_chunked`` is the WY form with the decay inside every ``k_i . k_j``
+product: ``A_ij = b_i sum_c k_ic k_jc exp(G_ic - G_jc)``.  Factored as
+``(K exp(G)) (K exp(-G))^T`` that overflows float32 (``exp(-G)`` reaches
+``exp(320)`` over 64 positions at a decay of ``exp(-5)``), so each product
+is taken relative to the cumulative decay at the start of the later
+sub-block of ``KDA_SUB`` positions: ``exp(G_i - R) exp(R - G_j)``, the first
+factor at most 1, the second at most 1 off the diagonal sub-blocks and at
+most ``exp(KDA_SUB |g_min|)`` on them (``exp(80)`` at the safe gate's floor
+of -5, finite).  ``kda_single_step`` and the kernel ``kda_step_slots``
+scale each ``d_k`` row of the state by its own factor, then step it as
+above.
 """
 
 from __future__ import annotations
@@ -68,6 +87,9 @@ from deeplearning4j_tpu.helpers import interpret_mode as _interpret
 
 # positions a chunk of the WY form; chosen on the chip (PERF.md, PR 41)
 DELTA_CHUNK = 64
+# positions a sub-block of the per-channel WY form: at a decay of at least
+# exp(-5) a position, exp(16 * 5) stays inside float32
+KDA_SUB = 16
 _HIGHEST = lax.Precision.HIGHEST
 _LANES = 128
 # VMEM for the step kernel's own temporaries on top of its pipeline's rows
@@ -103,7 +125,8 @@ def mask_padding(g, beta, live):
     if live is None:
         return g, beta
     valid = (jnp.arange(g.shape[1])[None] < live[:, None])[..., None]
-    return jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+    on_g = valid if g.ndim == beta.ndim else valid[..., None]
+    return jnp.where(on_g, g, 0.0), jnp.where(valid, beta, 0.0)
 
 
 def stepwise(q, k, v, g, beta, s0, live=None):
@@ -218,6 +241,28 @@ def step_vmem_bytes(row_shape) -> int:
     return 4 * 4 * math.prod(row_shape)
 
 
+def _lane_parts(fresh_ref, lanes_ref, tab_ref, s_ref, group, d_v):
+    """What a step kernel reads of its grid step: the lane's ``fresh`` and
+    ``live`` flags over its row [d_k, L], and ``on_lanes(rows, col)``, head
+    ``col + m``'s column ``tab[rows, col + m]`` laid over lanes ``[m d_v,
+    (m + 1) d_v)``."""
+    b = pl.program_id(0)
+    _, dk, lanes = s_ref.shape
+    fresh = jnp.full((dk, lanes), fresh_ref[b]) != 0
+    live = jnp.full((dk, lanes), lanes_ref[b]) != 0
+    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    tab = tab_ref[...]
+
+    def on_lanes(rows, col):
+        out = tab[rows, col:col + 1]
+        for m in range(1, group):
+            out = jnp.where(lane >= m * d_v, tab[rows, col + m:col + m + 1],
+                            out)
+        return jnp.broadcast_to(out, (out.shape[0], lanes))
+
+    return fresh, live, on_lanes
+
+
 def _step_kernel(fresh_ref, lanes_ref, tab_ref, v_ref, s_ref, s_out, o_ref,
                  *, heads, group, d_v):
     """One lane a grid step: ``s_ref`` its row [P, d_k, L] in VMEM, ``tab_ref``
@@ -225,22 +270,9 @@ def _step_kernel(fresh_ref, lanes_ref, tab_ref, v_ref, s_ref, s_out, o_ref,
     2 H)``) down the first d_k rows, then ``exp(g)``, ``beta`` and ``k . q``
     in three rows under ``k``'s columns; ``v_ref`` [P, L] its ``v`` on the
     slot layout."""
-    b = pl.program_id(0)
-    pairs, dk, lanes = s_ref.shape
-    fresh = jnp.full((dk, lanes), fresh_ref[b]) != 0
-    live = jnp.full((dk, lanes), lanes_ref[b]) != 0
-    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
-    tab = tab_ref[...]
-
-    def on_lanes(rows, col):
-        """Head ``col + m``'s column ``tab[rows, col + m]`` over lanes ``[m
-        d_v, (m + 1) d_v)``."""
-        out = tab[rows, col:col + 1]
-        for m in range(1, group):
-            out = jnp.where(lane >= m * d_v, tab[rows, col + m:col + m + 1],
-                            out)
-        return jnp.broadcast_to(out, (out.shape[0], lanes))
-
+    pairs, dk, _ = s_ref.shape
+    fresh, live, on_lanes = _lane_parts(fresh_ref, lanes_ref, tab_ref, s_ref,
+                                        group, d_v)
     for p in range(pairs):
         h = p * group
         s_was = s_ref[p]
@@ -258,24 +290,32 @@ def _step_kernel(fresh_ref, lanes_ref, tab_ref, v_ref, s_ref, s_out, o_ref,
 # jitted so that the layers of a program share one trace and one lowering
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_step(q, k, v, g, beta, sh, fresh, lanes, interpret):
-    bsz, heads, dk = q.shape
-    dv = v.shape[-1]
-    _, pairs, _, width = sh.shape
+    heads = q.shape[1]
     ext = jnp.stack([jnp.exp(g), beta, jnp.sum(k * q, axis=-1)], axis=1)
     tab = jnp.concatenate(
         [jnp.swapaxes(jnp.concatenate([k, q], axis=1), 1, 2),
          jnp.pad(ext, ((0, 0), (0, 5), (0, heads)))], axis=1)
+    return _slots_call(_step_kernel, "delta_state_step", tab, v, sh, fresh,
+                       lanes, interpret)
+
+
+def _slots_call(kernel, name, tab, v, sh, fresh, lanes, interpret):
+    """``kernel`` over a grid of the lanes: lane ``b``'s ``tab`` [d_k + 8,
+    *] and ``v`` on the slot layout in, its row ``b + 1`` of the pool in and
+    out (the pool aliased to the result), its output on the slot layout
+    out.  Returns ``(o [B, H, d_v], sh')``."""
+    bsz, heads, dv = v.shape
+    _, pairs, dk, width = sh.shape
     row = pl.BlockSpec((None, pairs, dk, width),
                        lambda b, fresh, lanes: (b + 1, 0, 0, 0))
     on_slots = pl.BlockSpec((None, pairs, width),
                             lambda b, fresh, lanes: (b, 0, 0))
     s, o = pl.pallas_call(
-        functools.partial(_step_kernel, heads=heads, group=heads // pairs,
-                          d_v=dv),
+        functools.partial(kernel, heads=heads, group=heads // pairs, d_v=dv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bsz,),
-            in_specs=[pl.BlockSpec((None, dk + 8, 2 * heads),
+            in_specs=[pl.BlockSpec((None,) + tab.shape[1:],
                                    lambda b, fresh, lanes: (b, 0, 0)),
                       on_slots, row],
             out_specs=[row, on_slots]),
@@ -287,7 +327,7 @@ def _pallas_step(q, k, v, g, beta, sh, fresh, lanes, interpret):
             vmem_limit_bytes=(step_vmem_bytes(sh.shape[1:])
                               + STEP_VMEM_HEADROOM)),
         interpret=interpret,
-        name="delta_state_step",
+        name=name,
     )(fresh.astype(jnp.int32), lanes.astype(jnp.int32), tab,
       v.reshape(bsz, pairs, width), sh)
     return o.reshape(bsz, heads, dv), s
@@ -301,17 +341,188 @@ def step_slots(q, k, v, g, beta, sh, fresh, lanes, *, interpret=None):
     lane steps from zero state; a lane not in ``lanes`` keeps its row as it
     was, bit for bit; row 0 is not touched.  Returns ``(o [B, H, d_v],
     sh')``, ``sh'`` aliasing ``sh``'s buffer where the caller donated it."""
+    _check_pool("step_slots", q, v, sh)
+    if interpret is None:
+        interpret = _interpret()
+    return _pallas_step(q, k, v, g, beta, sh, fresh, lanes, interpret)
+
+
+def _check_pool(name, q, v, sh):
     bsz, heads, dk = q.shape
     if (sh.ndim != 4 or sh.shape[0] != bsz + 1 or sh.shape[2] != dk
             or heads % sh.shape[1] or sh.dtype != jnp.float32
             or sh.shape[3] != heads // sh.shape[1] * v.shape[-1]):
         raise ValueError(
-            f"step_slots: pool {sh.shape} {sh.dtype} does not hold {bsz} "
+            f"{name}: pool {sh.shape} {sh.dtype} does not hold {bsz} "
             f"lanes of {heads} heads x [{dk}, {v.shape[-1]}] float32 on the "
             "slot layout behind a trash row")
+
+
+# ------------------------------------------------ the per-channel decay (KDA)
+def kda_stepwise(q, k, v, g, beta, s0, live=None):
+    """``stepwise`` with the per-channel decay (module docstring): ``g``
+    [B, T, H, d_k]; ``S~ = exp(g) S`` row by row, ``w = b (v - S~^T k)``,
+    ``S' = S~ + k w^T``, ``o = S'^T q``."""
+    g, beta = mask_padding(g, beta, live)
+
+    def step(s, inp):
+        q_t, k_t, v_t, g_t, b_t = inp
+        s = jnp.exp(g_t)[..., None] * s
+        ks = jnp.einsum("bhk,bhkv->bhv", k_t, s, precision=_HIGHEST)
+        s = s + k_t[..., None] * (b_t[..., None] * (v_t - ks))[:, :, None, :]
+        return s, jnp.einsum("bhk,bhkv->bhv", q_t, s, precision=_HIGHEST)
+
+    s, o = lax.scan(step, s0, tuple(jnp.moveaxis(x, 1, 0)
+                                    for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+def _decayed_products(a, b, cum, sub):
+    """``M_ij = sum_c a_ic b_jc exp(cum_ic - cum_jc)`` for ``j`` up to the end
+    of ``i``'s sub-block of ``sub`` rows (zero past it; the caller masks the
+    triangle it wants).  ``a``, ``b``, ``cum`` [..., c, d_k], ``cum`` the
+    cumulative log decay, falling along ``c``.  Each product is taken
+    relative to ``R``, the cumulative decay at the first row of ``i``'s
+    sub-block: ``(a_i exp(cum_i - R)) . (b_j exp(R - cum_j))``."""
+    c, dk = a.shape[-2:]
+    nb = c // sub
+    blocks = cum.reshape(cum.shape[:-2] + (nb, sub, dk))
+    ref = blocks[..., :1, :]                              # [.., nb, 1, dk]
+    a_rel = a.reshape(blocks.shape) * jnp.exp(blocks - ref)
+    j = jnp.arange(c)
+    upto = (j[None, :] < (jnp.arange(nb)[:, None] + 1) * sub)[..., None]
+    b_rel = b[..., None, :, :] * jnp.exp(jnp.where(
+        upto, ref - cum[..., None, :, :], -jnp.inf))      # [.., nb, c, dk]
+    m = jnp.einsum("...nik,...njk->...nij", a_rel, b_rel, precision=_HIGHEST)
+    return m.reshape(cum.shape[:-2] + (c, c))
+
+
+def kda_chunked(q, k, v, g, beta, s0, live=None, chunk=None):
+    """``kda_stepwise`` in the WY form, ``chunk`` (``DELTA_CHUNK``) positions
+    a trip of the loop, the intra-chunk decay by sub-blocks of ``KDA_SUB``
+    (``_decayed_products``).  With ``G`` the cumulative log decay inside
+    the chunk, per channel::
+
+        A = strict_tril(b_i sum_c k_ic k_jc exp(G_ic - G_jc)),  T = (I + A)^-1
+        W = T (b exp(G) K),   U = T (b V)
+        O  = (Q exp(G)) S + tril(sum_c q_ic k_jc exp(G_ic - G_jc)) (U - W S)
+        S' = exp(G_C) S + (K exp(G_C - G))^T (U - W S)
+
+    Every exponent but the sub-blocks' own is at most 0.  A length that
+    ``chunk`` does not divide is padded with positions that keep the
+    state."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    chunk = int(chunk or DELTA_CHUNK)
+    sub = min(KDA_SUB, chunk)
+    if chunk % sub:
+        raise ValueError(f"kda_chunked: a chunk of {chunk} is not whole "
+                         f"sub-blocks of {sub}")
+    c = min(chunk, -(-t // sub) * sub)
+    n = -(-t // c)
+    pad = n * c - t
+    if live is None and pad:
+        live = jnp.full((bsz,), t, jnp.int32)
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+    g, beta = mask_padding(g, beta, live)
+
+    def chunks(x):          # [B, n c, H, ...] -> [n, B, H, c, ...]
+        x = x.reshape((bsz, n, c) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=-2)                          # [n, B, H, c, dk]
+    i = jnp.arange(c)
+    a = jnp.where(i[:, None] > i[None, :], beta[..., :, None]
+                  * _decayed_products(k, k, cum, sub), 0.0)
+    rhs = jnp.concatenate([beta[..., None] * jnp.exp(cum) * k,
+                           beta[..., None] * v], axis=-1)
+    wu = lax.linalg.triangular_solve(
+        a + jnp.eye(c, dtype=a.dtype), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    w, u = wu[..., :dk], wu[..., dk:]
+    qk = jnp.where(i[:, None] >= i[None, :],
+                   _decayed_products(q, k, cum, sub), 0.0)
+    qg = q * jnp.exp(cum)
+    kd = k * jnp.exp(cum[..., -1:, :] - cum)
+    last = jnp.exp(cum[..., -1, :])[..., None]            # [n, B, H, dk, 1]
+
+    def trip(s, inp):
+        w_c, u_c, qg_c, qk_c, kd_c, last_c = inp
+        d = u_c - jnp.einsum("bhck,bhkv->bhcv", w_c, s, precision=_HIGHEST)
+        o = (jnp.einsum("bhck,bhkv->bhcv", qg_c, s, precision=_HIGHEST)
+             + jnp.einsum("bhcj,bhjv->bhcv", qk_c, d, precision=_HIGHEST))
+        s = last_c * s + jnp.einsum("bhck,bhcv->bhkv", kd_c, d,
+                                    precision=_HIGHEST)
+        return s, o
+
+    s, o = lax.scan(trip, s0, (w, u, qg, qk, kd, last))  # o [n, B, H, c, dv]
+    o = jnp.moveaxis(o, 0, 1)                             # [B, n, H, c, dv]
+    o = jnp.moveaxis(o, 3, 2).reshape(bsz, n * c, h, dv)
+    return (o[:, :t] if pad else o), s
+
+
+def kda_single_step(q, k, v, g, beta, s):
+    """``single_step`` with the per-channel decay: ``g`` [B, H, d_k]; the
+    state's ``d_k`` rows scaled each by its own factor, ``S~ = exp(g) S``,
+    then ``w = b (v - S~^T k)``, ``S' = S~ + k w^T``, ``o = S~^T q + (k .
+    q) w``."""
+    bsz, p, _, lanes = s.shape
+    h, dv = q.shape[1], v.shape[-1]
+    group = h // p
+    s = _on_lanes(jnp.exp(g), group, dv) * s              # [B, P, d_k, L]
+    k_rows = _on_lanes(k, group, dv)
+    ks = jnp.sum(k_rows * s, axis=2)                      # [B, P, L]
+    qs = jnp.sum(_on_lanes(q, group, dv) * s, axis=2)
+    w = _on_lanes(beta, group, dv) * (v.reshape(bsz, p, lanes) - ks)
+    kq = _on_lanes(jnp.sum(k * q, axis=-1), group, dv)
+    return (qs + kq * w).reshape(bsz, h, dv), s + k_rows * w[:, :, None, :]
+
+
+def _kda_step_kernel(fresh_ref, lanes_ref, tab_ref, v_ref, s_ref, s_out,
+                     o_ref, *, heads, group, d_v):
+    """``_step_kernel`` with the per-channel decay: ``tab_ref`` [d_k + 8,
+    3 H] holds the heads' ``k`` (columns ``[0, H)``), ``q`` (``[H, 2 H)``)
+    and ``exp(g)`` (``[2 H, 3 H)``) down the first d_k rows, then ``beta``
+    and ``k . q`` in two rows under ``k``'s columns."""
+    pairs, dk, _ = s_ref.shape
+    fresh, live, on_lanes = _lane_parts(fresh_ref, lanes_ref, tab_ref, s_ref,
+                                        group, d_v)
+    for p in range(pairs):
+        h = p * group
+        s_was = s_ref[p]
+        s = on_lanes(slice(0, dk), 2 * heads + h) * jnp.where(fresh, 0.0,
+                                                              s_was)
+        k = on_lanes(slice(0, dk), h)
+        ks = jnp.sum(k * s, axis=0, keepdims=True)          # [1, L]
+        qs = jnp.sum(on_lanes(slice(0, dk), heads + h) * s, axis=0,
+                     keepdims=True)
+        w = on_lanes(slice(dk, dk + 1), h) * (v_ref[p:p + 1, :] - ks)
+        o_ref[p:p + 1, :] = qs + on_lanes(slice(dk + 1, dk + 2), h) * w
+        s_out[p] = jnp.where(live, s + k * w, s_was)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_kda_step(q, k, v, g, beta, sh, fresh, lanes, interpret):
+    heads = q.shape[1]
+    ext = jnp.stack([beta, jnp.sum(k * q, axis=-1)], axis=1)
+    tab = jnp.concatenate(
+        [jnp.swapaxes(jnp.concatenate([k, q, jnp.exp(g)], axis=1), 1, 2),
+         jnp.pad(ext, ((0, 0), (0, 6), (0, 2 * heads)))], axis=1)
+    return _slots_call(_kda_step_kernel, "kda_state_step", tab, v, sh, fresh,
+                       lanes, interpret)
+
+
+def kda_step_slots(q, k, v, g, beta, sh, fresh, lanes, *, interpret=None):
+    """``step_slots`` with the per-channel decay (``g`` [B, H, d_k]): one
+    Pallas kernel, each lane's row read once and written once in place, an
+    idle lane's row and row 0 untouched bit for bit."""
+    _check_pool("kda_step_slots", q, v, sh)
     if interpret is None:
         interpret = _interpret()
-    return _pallas_step(q, k, v, g, beta, sh, fresh, lanes, interpret)
+    return _pallas_kda_step(q, k, v, g, beta, sh, fresh, lanes, interpret)
 
 
 class DeltaRuleHelper:
@@ -330,6 +541,12 @@ class DeltaRuleHelper:
 
     def chunked(self, q, k, v, g, beta, s0, live=None):
         return chunked(q, k, v, g, beta, s0, live)
+
+    def kda_step_slots(self, q, k, v, g, beta, sh, fresh, lanes):
+        return kda_step_slots(q, k, v, g, beta, sh, fresh, lanes)
+
+    def kda_chunked(self, q, k, v, g, beta, s0, live=None):
+        return kda_chunked(q, k, v, g, beta, s0, live)
 
     def describe(self, t: int) -> str:
         """How a program of ``t`` positions a row is chunked, for the

@@ -33,6 +33,8 @@ from deeplearning4j_tpu.nn.layers.recurrent import (
     RnnOutputLayer,
 )
 from deeplearning4j_tpu.nn.layers.state_space import MambaLayer
-from deeplearning4j_tpu.nn.layers.delta_net import GatedDeltaNetLayer
+from deeplearning4j_tpu.nn.layers.delta_net import (
+    GatedDeltaNetLayer, KimiDeltaAttentionLayer,
+)
 from deeplearning4j_tpu.nn.layers.autoencoder import AutoEncoder, RBM
 from deeplearning4j_tpu.nn.layers.moe import MoELayer, RoutedMoELayer

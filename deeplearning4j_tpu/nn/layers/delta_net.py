@@ -68,14 +68,18 @@ from deeplearning4j_tpu.nn.layers.state_space import describe_slots
 
 DELTA_RULE_PATHS = ("delta_step", "delta_chunk", "delta_kernel",
                     "delta_stepwise")
+# the same four paths of the per-channel decay (KimiDeltaAttentionLayer)
+KDA_PATHS = ("kda_step", "kda_chunk", "kda_kernel", "kda_stepwise")
 # FLA's init: A uniform in (0, A_INIT_MAX], the step log-uniform in this range
 A_INIT_MAX = 16.0
 DT_INIT_MIN, DT_INIT_MAX = 1e-3, 1e-1
 L2_EPS = 1e-6
 
 
-def delta_rule_path(t: int, kernel: bool = False, seam: bool = True) -> str:
-    """Which of ``DELTA_RULE_PATHS`` a call of ``t`` positions a row on the
+def delta_rule_path(t: int, kernel: bool = False, seam: bool = True,
+                    paths=DELTA_RULE_PATHS) -> str:
+    """Which of ``paths`` (``DELTA_RULE_PATHS``, or ``KDA_PATHS`` for the
+    per-channel decay, in that order) a call of ``t`` positions a row on the
     state slots takes: for a single token ``"delta_kernel"`` where the
     helper seam offers its kernel (``kernel``: the pool stepped in place by
     ``delta_rule.step_slots``), else ``"delta_step"`` (the slot layout in
@@ -84,9 +88,10 @@ def delta_rule_path(t: int, kernel: bool = False, seam: bool = True) -> str:
     recurrence one position a trip, where it gives way.  Pure: the layer
     branches on it while it is traced, the engine calls it on the host to
     count ``dl4j_layer_path_steps_total``."""
+    step, chunk, kern, stepwise = paths
     if t == 1:
-        return "delta_kernel" if kernel else "delta_step"
-    return "delta_chunk" if seam else "delta_stepwise"
+        return kern if kernel else step
+    return chunk if seam else stepwise
 
 
 def _l2_normalize(x):
@@ -101,6 +106,8 @@ class GatedDeltaNetLayer(Layer):
     kind = "recurrent"
     # served by the generation engine through state slots (init_paged_cache)
     holds_state_slots = True
+    # the names of its paths, delta_rule_path's order
+    PATHS = DELTA_RULE_PATHS
 
     n_in: Optional[int] = None
     n_out: Optional[int] = None
@@ -182,7 +189,13 @@ class GatedDeltaNetLayer(Layer):
         helper seam offers it)."""
         helper = helpers.get_helper("delta_rule")
         return delta_rule_path(t, helper is not None and helper.kernel,
-                               helper is not None)
+                               helper is not None, self.PATHS)
+
+    def _forms(self):
+        """The rule's forms: (the decode step in ``jnp`` on the slot layout,
+        the plain recurrence, the seam's chunked form and its kernel, by
+        the helper's attribute names)."""
+        return dr.single_step, dr.stepwise, "chunked", "step_slots"
 
     def serving_path(self, call) -> str:
         return self.path(call.t)
@@ -205,18 +218,22 @@ class GatedDeltaNetLayer(Layer):
             y = sum(win[:, j:j + t] * w[:, j] for j in range(self.d_conv))
             return jax.nn.silu(y)
 
-    def _rule(self, params, x, a, b):
-        """Step 3 on the convolved ``x`` [B, T, channels] and ``a``, ``b``
-        [B, T, H]: ``(q, k [B, T, H, d_k], v [B, T, H, d_v], g, beta [B, T,
-        H])``, float32."""
-        f32 = jnp.float32
+    def _heads(self, x):
+        """``q`` (normalised, scaled), ``k`` (normalised) [B, T, H, d_k] and
+        ``v`` [B, T, H, d_v] of the convolved ``x``."""
         bsz, t, _ = x.shape
         wq, wk, _ = self._widths
         q = x[..., :wq].reshape(bsz, t, self.n_heads, self.d_k)
         k = x[..., wq:wq + wk].reshape(bsz, t, self.n_heads, self.d_k)
         v = x[..., wq + wk:].reshape(bsz, t, self.n_heads, self.d_v)
-        q = _l2_normalize(q) * self.d_k ** -0.5
-        k = _l2_normalize(k)
+        return _l2_normalize(q) * self.d_k ** -0.5, _l2_normalize(k), v
+
+    def _rule(self, params, x, a, b):
+        """Step 3 on the convolved ``x`` [B, T, channels] and ``a``, ``b``
+        [B, T, H]: ``(q, k [B, T, H, d_k], v [B, T, H, d_v], g, beta [B, T,
+        H])``, float32."""
+        f32 = jnp.float32
+        q, k, v = self._heads(x)
         beta = jax.nn.sigmoid(b.astype(f32))
         if self.allow_neg_eigval:
             beta = 2.0 * beta
@@ -248,17 +265,18 @@ class GatedDeltaNetLayer(Layer):
             z = u @ params["W_g"]
         window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
         x = self._conv(params, window)
+        single, plain, chunked, _ = self._forms()
         with jax.named_scope("gdn_state" if t == 1 else "gdn_chunk"):
             q, k, v, g, beta = self._rule(params, x, a, b)
             if t == 1:
                 g, beta = dr.mask_padding(g, beta, live)
                 one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
                 o, s = (step(*one) if step is not None
-                        else dr.single_step(*one, s0))
+                        else single(*one, s0))
                 o = o[:, None]
             else:
-                rule = (helpers.get_helper("delta_rule").chunked
-                        if self.path(t) == "delta_chunk" else dr.stepwise)
+                rule = (getattr(helpers.get_helper("delta_rule"), chunked)
+                        if self.path(t) == self.PATHS[1] else plain)
                 o, s = rule(q, k, v, g, beta, dr.to_heads(s0, self.n_heads),
                             live)
                 s = dr.to_slots(s, self.group)
@@ -328,11 +346,12 @@ class GatedDeltaNetLayer(Layer):
         rows = carry.get("rows")
         scope = "gdn_state" if x.shape[1] == 1 else "gdn_chunk"
         step = s0 = None
-        if rows is None and self.path(x.shape[1]) == "delta_kernel":
-            helper = helpers.get_helper("delta_rule")
+        if rows is None and self.path(x.shape[1]) == self.PATHS[2]:
+            kernel = getattr(helpers.get_helper("delta_rule"),
+                             self._forms()[3])
 
             def step(*one):
-                return helper.step_slots(*one, sh, fresh, lanes)
+                return kernel(*one, sh, fresh, lanes)
         else:
             with jax.named_scope(scope):
                 s_was = sh[1:] if rows is None else sh[rows]
@@ -357,3 +376,84 @@ class GatedDeltaNetLayer(Layer):
                                           tail_was))
                   if rows is None else sc.at[rows].set(tail))
         return out, state, {**carry, "sh": sh, "sc": sc}
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class KimiDeltaAttentionLayer(GatedDeltaNetLayer):
+    """Kimi Delta Attention (KDA; Kimi Linear, arXiv:2510.26692, FLA's
+    ``KimiDeltaAttention``) as Ling-3.0 carries it: ``GatedDeltaNetLayer``'s
+    projections, convolutions, state slots, scopes and carries, with a
+    decay a ``d_k`` channel and a gate a head.
+
+    In place of steps 3-5 of the module docstring:
+
+    - ``beta = sigmoid(u W_b)`` [H]; the log decay per channel, the SAFE
+      GATE ``g = lower_bound * sigmoid(exp(A_log_h) (u W_a + dt_bias))`` in
+      ``(lower_bound, 0)^{d_k}`` (``W_a`` [n_in, H d_k] full rank,
+      ``dt_bias`` [H d_k], ``A_log`` [H]).
+    - per head, ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} +
+      beta_t k_t v_t^T``; ``o_t = S_t^T q_t`` (``helpers/delta_rule.py``'s
+      ``kda_`` forms, paths ``KDA_PATHS``).
+    - ``y_h = RMSNorm_{d_v}(o_h) w_h sigmoid(u W_g)_h``: a norm over each
+      head's ``d_v`` with a gain of its own (``o_norm`` [H d_v]) and one
+      gate a head (``W_g`` [n_in, H]); then ``y W_o``.
+
+    The state's slot layout, tail and pools are the parent's."""
+
+    # the per-channel forms' sub-blocks hold exp(KDA_SUB * -lower_bound)
+    lower_bound: float = -5.0
+
+    PATHS = KDA_PATHS
+
+    def validate(self) -> None:
+        super().validate()
+        if self.allow_neg_eigval:
+            raise ValueError("KimiDeltaAttentionLayer's beta is sigmoid(b)")
+        if not 0 < -self.lower_bound * dr.KDA_SUB < 88:
+            raise ValueError(
+                f"lower_bound={self.lower_bound}: the chunked form needs "
+                f"exp({-dr.KDA_SUB} * lower_bound) inside float32")
+
+    def init(self, key, dtype=jnp.float32):
+        h, hk = self.n_heads, self.n_heads * self.d_k
+        p = super().init(key, dtype)
+        ks = jax.random.split(jax.random.fold_in(key, 1), 4)
+
+        def w(k, shape):
+            return initializers.init(self.weight_init, k, shape, dtype)
+
+        step = jnp.exp(jax.random.uniform(ks[3], (hk,), jnp.float32)
+                       * (math.log(DT_INIT_MAX) - math.log(DT_INIT_MIN))
+                       + math.log(DT_INIT_MIN))
+        p.update({"W_a": w(ks[0], (self.n_in, hk)),
+                  "W_g": w(ks[1], (self.n_in, h)),
+                  "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
+                  "o_norm": jnp.ones((h * self.d_v,), dtype)})
+        return p
+
+    def _forms(self):
+        return (dr.kda_single_step, dr.kda_stepwise, "kda_chunked",
+                "kda_step_slots")
+
+    def _rule(self, params, x, a, b):
+        """Step 3 with the safe gate: ``g`` [B, T, H, d_k]."""
+        f32 = jnp.float32
+        bsz, t, _ = x.shape
+        q, k, v = self._heads(x)
+        beta = jax.nn.sigmoid(b.astype(f32))
+        arg = (a.astype(f32).reshape(bsz, t, self.n_heads, self.d_k)
+               + params["dt_bias"].astype(f32).reshape(self.n_heads,
+                                                       self.d_k))
+        g = self.lower_bound * jax.nn.sigmoid(
+            jnp.exp(params["A_log"].astype(f32))[:, None] * arg)
+        return q, k, v, g, beta
+
+    def _out(self, params, o, z):
+        """The norm a head and the gate a head; ``z`` [B, T, H]."""
+        with jax.named_scope("gdn_proj"):
+            bsz, t = o.shape[:2]
+            o = rms_norm(o, params["o_norm"].reshape(self.n_heads, self.d_v),
+                         self.eps)
+            y = o * jax.nn.sigmoid(z.astype(jnp.float32))[..., None]
+            return y.reshape(bsz, t, -1).astype(z.dtype) @ params["W_o"]

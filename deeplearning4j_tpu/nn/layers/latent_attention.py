@@ -73,9 +73,12 @@ class LatentAttentionLayer(Layer):
     Params: ``Wqa`` [n_in, q_rank], ``q_norm`` [q_rank], ``Wqb`` [q_rank,
     H * (nope_dim + rope_dim)], ``Wkva`` [n_in, kv_rank + rope_dim],
     ``kv_norm`` [kv_rank], ``Wkvb`` [kv_rank, H * (nope_dim + v_dim)],
-    ``Wo`` [H * v_dim, n_out].  ``rope_factor`` > 1 turns on YaRN
-    (frequencies blended by ``yarn_inv_freq``; the softmax scale times
-    ``yarn_mscale(rope_factor, rope_mscale_all_dim)`` squared)."""
+    ``Wo`` [H * v_dim, n_out].  ``q_rank`` 0: no low-rank query, ``Wq``
+    [n_in, H * (nope_dim + rope_dim)] in place of ``Wqa``, ``q_norm`` and
+    ``Wqb``.  ``gate="per_head"``: head ``h``'s output times ``sigmoid(x
+    Wg)[h]`` ahead of ``Wo`` (``Wg`` [n_in, H]).  ``rope_factor`` > 1 turns
+    on YaRN (frequencies blended by ``yarn_inv_freq``; the softmax scale
+    times ``yarn_mscale(rope_factor, rope_mscale_all_dim)`` squared)."""
 
     kind = "attention"
 
@@ -96,6 +99,8 @@ class LatentAttentionLayer(Layer):
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # "per_head": a head-wise sigmoid output gate from the layer's input
+    gate: Optional[str] = None
 
     def setup(self, input_type: InputType) -> "LatentAttentionLayer":
         n_in = self.n_in if self.n_in is not None else input_type.size
@@ -109,10 +114,14 @@ class LatentAttentionLayer(Layer):
         super().validate()
         sizes = (self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim,
                  self.v_dim)
-        if min(sizes) < 1 or self.rope_dim % 2:
+        if min(sizes[1:]) < 1 or self.q_rank < 0 or self.rope_dim % 2:
             raise ValueError(
-                "LatentAttentionLayer needs q_rank, kv_rank, nope_dim, "
-                f"v_dim >= 1 and an even rope_dim >= 2; got {sizes}")
+                "LatentAttentionLayer needs q_rank >= 0 (0: no low-rank "
+                "query), kv_rank, nope_dim, v_dim >= 1 and an even rope_dim "
+                f">= 2; got {sizes}")
+        if self.gate not in (None, "per_head"):
+            raise ValueError(f"gate={self.gate!r} not one of None, "
+                             "'per_head'")
 
     def init(self, key, dtype=jnp.float32) -> Dict[str, jax.Array]:
         h, qk = self.n_heads, self.nope_dim + self.rope_dim
@@ -121,11 +130,21 @@ class LatentAttentionLayer(Layer):
                   "Wkva": (self.n_in, self.kv_rank + self.rope_dim),
                   "Wkvb": (self.kv_rank, h * (self.nope_dim + self.v_dim)),
                   "Wo": (h * self.v_dim, self.n_out)}
+        if not self.q_rank:
+            del shapes["Wqa"], shapes["Wqb"]
+            shapes = {"Wq": (self.n_in, h * qk), **shapes}
+        keys = jax.random.split(key, len(shapes))
         p = {name: initializers.init(self.weight_init, k, shape, dtype)
-             for (name, shape), k in zip(shapes.items(),
-                                         jax.random.split(key, len(shapes)))}
-        p["q_norm"] = jnp.ones((self.q_rank,), dtype)
+             for (name, shape), k in zip(shapes.items(), keys)}
+        if self.q_rank:
+            p["q_norm"] = jnp.ones((self.q_rank,), dtype)
         p["kv_norm"] = jnp.ones((self.kv_rank,), dtype)
+        if self.gate is not None:
+            # a key derived from the last, so that the others stay what a
+            # layer without a gate draws
+            p["Wg"] = initializers.init(
+                self.weight_init, jax.random.fold_in(keys[-1], 1),
+                (self.n_in, h), dtype)
         return p
 
     # ------------------------------------------------------------ the parts
@@ -155,8 +174,11 @@ class LatentAttentionLayer(Layer):
         (rotated), latent [B, T, kv_rank + rope_dim]: the normed ``c_kv``
         beside the rotated ``k_r``, which is what a cache holds."""
         b, t, _ = x.shape
-        cq = rms_norm(x @ params["Wqa"], params["q_norm"], self.eps)
-        q = (cq @ params["Wqb"]).reshape(b, t, self.n_heads, -1)
+        if self.q_rank:
+            cq = rms_norm(x @ params["Wqa"], params["q_norm"], self.eps)
+            q = (cq @ params["Wqb"]).reshape(b, t, self.n_heads, -1)
+        else:
+            q = (x @ params["Wq"]).reshape(b, t, self.n_heads, -1)
         q_nope, q_rope = q[..., :self.nope_dim], q[..., self.nope_dim:]
         kv = x @ params["Wkva"]
         c_kv = rms_norm(kv[..., :self.kv_rank], params["kv_norm"], self.eps)
@@ -273,8 +295,14 @@ class LatentAttentionLayer(Layer):
                 f" MB a copy), grid ({b}, 1), {vmem / 2 ** 20:.2f} MB of "
                 "VMEM")
 
-    def _out(self, params, o):
+    def _out(self, params, o, x):
+        """Heads [B, T, H, v_dim] -> [B, T, n_out]: the per-head gate (from
+        the layer's input ``x``) where there is one, then ``Wo``."""
         b, t = o.shape[:2]
+        if self.gate is not None:
+            with jax.named_scope("attn_gate"):
+                g = jax.nn.sigmoid((x @ params["Wg"]).astype(jnp.float32))
+                o = (o * g[..., None]).astype(o.dtype)
         return o.reshape(b, t, -1) @ params["Wo"]
 
     # ------------------------------------------------------------- forward
@@ -286,7 +314,7 @@ class LatentAttentionLayer(Layer):
             q_nope, q_rope, latent = self._project(
                 params, x, jnp.arange(x.shape[1]))
             y = self._out(params, self._expanded(params, q_nope, q_rope,
-                                                 latent))
+                                                 latent), x)
         return y, state
 
     def init_cache(self, batch: int, dtype=jnp.float32):
@@ -372,5 +400,5 @@ class LatentAttentionLayer(Layer):
                     jnp.all(pos == 0),
                     lambda _: self._expanded(params, q_nope, q_rope, latent),
                     in_blocks, None)
-            y = self._out(params, o)
+            y = self._out(params, o, x)
         return y, state, {"pc": pool, "block": block, "pos": pos + t}

@@ -201,6 +201,11 @@ class RoutedMoELayer(Layer):
     over all ``n_experts``; the ``top_k`` largest of ``s + b_router`` are
     chosen; their weights are the chosen ``s`` (without the bias), divided
     by their sum when ``norm_topk_prob``, times ``routed_scaling_factor``.
+    With ``n_group`` > 1 the choice is GROUP-LIMITED (DeepSeek-V3's
+    ``noaux_tc``): the experts fall in ``n_group`` groups of consecutive
+    ids, a group scores the sum of its two largest ``s + b_router``, and
+    the ``top_k`` are chosen among the experts of the ``topk_group``
+    best-scoring groups only.
     ``scoring="softmax"`` (the Qwen-MoE family's gate): ``s = softmax(x
     W_router)`` over all ``n_experts``, the ``top_k`` largest chosen, no
     selection bias (no ``b_router`` leaf), the rest alike.
@@ -233,6 +238,9 @@ class RoutedMoELayer(Layer):
     routed_scaling_factor: float = 1.0
     activation: str = "silu"
     scoring: str = "sigmoid"
+    # group-limited choice: n_group groups, the top_k from topk_group of them
+    n_group: int = 1
+    topk_group: int = 1
 
     def setup(self, input_type: InputType) -> "RoutedMoELayer":
         n_in = self.n_in if self.n_in is not None else input_type.flat_size()
@@ -264,6 +272,16 @@ class RoutedMoELayer(Layer):
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring={self.scoring!r} not one of "
                              "'sigmoid', 'softmax'")
+        if self.n_group > 1 and (
+                self.scoring != "sigmoid" or self.n_experts % self.n_group
+                or self.n_experts // self.n_group < 2
+                or not 1 <= self.topk_group <= self.n_group
+                or self.top_k > self.topk_group * (self.n_experts
+                                                   // self.n_group)):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group}: "
+                f"sigmoid scores, groups of 2 or more of the {self.n_experts}"
+                f" experts, and top_k={self.top_k} inside the groups kept")
 
     def init(self, key, dtype=jnp.float32) -> Dict[str, jax.Array]:
         count = self.held[1]
@@ -299,8 +317,11 @@ class RoutedMoELayer(Layer):
                 _, ids = jax.lax.top_k(scores, self.top_k)
             else:
                 scores = jax.nn.sigmoid(logits)
-                _, ids = jax.lax.top_k(
-                    scores + params["b_router"].astype(f32), self.top_k)
+                choice = scores + params["b_router"].astype(f32)
+                if self.n_group > 1:
+                    choice = limit_to_groups(choice, self.n_group,
+                                             self.topk_group)
+                _, ids = jax.lax.top_k(choice, self.top_k)
             w = jnp.take_along_axis(scores, ids, axis=1)
             if self.norm_topk_prob:
                 w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
@@ -430,6 +451,18 @@ class RoutedMoELayer(Layer):
         if d.get("experts_held") is not None:
             d["experts_held"] = tuple(d["experts_held"])
         return super().from_dict(d)
+
+
+def limit_to_groups(choice, n_group: int, topk_group: int):
+    """``choice`` [T, E] with every expert outside the ``topk_group`` groups
+    of highest score set to -inf: ``n_group`` groups of consecutive ids, a
+    group's score the sum of its two largest entries."""
+    t, e = choice.shape
+    grouped = choice.reshape(t, n_group, e // n_group)
+    best, _ = jax.lax.top_k(grouped, 2)
+    _, kept = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(t, e)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

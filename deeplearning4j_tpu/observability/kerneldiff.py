@@ -194,6 +194,17 @@ def _np_delta_step(q, k, v, g, beta, s) -> Tuple[np.ndarray, np.ndarray]:
     s = a[..., None] * s + k[..., None] * w[:, :, None, :]
     return np.einsum("bhk,bhkv->bhv", q, s), s
 
+def _np_kda_step(q, k, v, g, beta, s) -> Tuple[np.ndarray, np.ndarray]:
+    """float64 delta rule with a per-channel decay (Kimi Delta Attention),
+    one token a head on the HEAD layout: ``S~ = Diag(exp(g)) S``, ``S' = S~ +
+    k w^T`` with ``w = beta (v - S~^T k)``, ``o = S'^T q``."""
+    q, k, v, s = (np.asarray(x, np.float64) for x in (q, k, v, s))
+    s = np.exp(np.asarray(g, np.float64))[..., None] * s
+    w = np.asarray(beta, np.float64)[..., None] * (
+        v - np.einsum("bhk,bhkv->bhv", k, s))
+    s = s + k[..., None] * w[:, :, None, :]
+    return np.einsum("bhk,bhkv->bhv", q, s), s
+
 def _np_lrn(x2d, k, n, alpha, beta) -> np.ndarray:
     x = np.asarray(x2d, np.float64)
     half = n // 2
@@ -554,6 +565,50 @@ def _run_delta_step(cfg) -> Tuple[Any, np.ndarray]:
     ref = np.concatenate([x.reshape(-1) for x in (ro, rs, ro, rs)])
     return out, ref
 
+def _kda_step_configs(full: bool):
+    # four heads of d_v 32 to a row of 128 lanes, and Ling-3.0-flash's row
+    # [32, 128, 128]; lanes fresh, stepped and idle in each
+    grids = [{"shape": [4, 4, 16, 32]}, {"shape": [4, 32, 128, 128]}]
+    if full:
+        grids += [{"shape": [6, 3, 8, 128]}]     # a group of one head
+    for g in grids:
+        yield dict(g, dtype="float32")
+
+def _run_kda_step(cfg) -> Tuple[Any, np.ndarray]:
+    """``_run_delta_step`` for the per-channel decay: ``kda_single_step``
+    with the pool's selects and the interpreted kernel ``kda_step_slots``
+    against one f64 step on the head layout, decays over the safe gate's
+    range (-5, 0) a channel."""
+    from deeplearning4j_tpu.helpers import delta_rule as dr
+    b, h, dk, dv = cfg["shape"]
+    group = dr.slot_group(h, dv)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(_rng(b, h, dk, dtype=jnp.float32, seed=60)) * dk ** -0.5
+    k = unit(_rng(b, h, dk, dtype=jnp.float32, seed=61))
+    v = _rng(b, h, dv, dtype=jnp.float32, seed=62)
+    g = -5.0 * jax.nn.sigmoid(3.0 * _rng(b, h, dk, dtype=jnp.float32,
+                                         seed=63))
+    beta = jax.nn.sigmoid(_rng(b, h, dtype=jnp.float32, seed=64))
+    sh = _rng(b + 1, h // group, dk, group * dv, dtype=jnp.float32, seed=65)
+    fresh = jnp.arange(b) == 0
+    lanes = jnp.arange(b) != 1
+    s_was = sh[1:]
+    o_jnp, s_jnp = dr.kda_single_step(
+        q, k, v, g, beta, jnp.where(fresh[:, None, None, None], 0.0, s_was))
+    s_jnp = jnp.where(lanes[:, None, None, None], s_jnp, s_was)
+    o_pl, pool = dr.kda_step_slots(q, k, v, g, beta, sh, fresh, lanes,
+                                   interpret=True)
+    s0 = np.asarray(dr.to_heads(s_was, h), np.float64)
+    s0[0] = 0.0
+    ro, rs = _np_kda_step(q, k, v, g, beta, s0)
+    rs[1] = np.asarray(dr.to_heads(s_was, h))[1]
+    rs = np.moveaxis(rs.reshape(b, h // group, group, dk, dv), 2, 3).reshape(
+        b, h // group, dk, group * dv)
+    out = jnp.concatenate([x.reshape(-1) for x in
+                           (o_jnp, s_jnp, o_pl, pool[1:])])
+    ref = np.concatenate([x.reshape(-1) for x in (ro, rs, ro, rs)])
+    return out, ref
+
 def _pallas2d_configs(full: bool):
     shapes = [(32, 24)]
     if full:
@@ -603,6 +658,7 @@ KERNELS: Dict[str, Tuple[Callable, Callable, bool]] = {
     "grouped_experts": (_grouped_experts_configs, _run_grouped_experts,
                         False),
     "delta_state_step": (_delta_step_configs, _run_delta_step, False),
+    "kda_state_step": (_kda_step_configs, _run_kda_step, False),
     "pallas_lrn": (_pallas2d_configs, _run_lrn, False),
     "pallas_bn_inference": (_pallas2d_configs, _run_bn_inference, False),
     "pallas_bn_training": (_pallas2d_configs, _run_bn_training, False),

@@ -54,6 +54,11 @@ SPIES = {
         "delta_chunk": (dr.DeltaRuleHelper, "chunked"),
         "delta_stepwise": (dr, "stepwise"),
         "delta_kernel": (dr.DeltaRuleHelper, "step_slots")},
+    "kda": {
+        "kda_step": (dr, "kda_single_step"),
+        "kda_chunk": (dr.DeltaRuleHelper, "kda_chunked"),
+        "kda_stepwise": (dr, "kda_stepwise"),
+        "kda_kernel": (dr.DeltaRuleHelper, "kda_step_slots")},
 }
 FAMILY = {path: family for family, paths in SPIES.items() for path in paths}
 
@@ -83,6 +88,12 @@ def _olmo():
     return toy_net()[0]
 
 
+def _ling():
+    from tests.test_ling import toy_net
+
+    return toy_net()[0]
+
+
 # by path: (net, its layers' kind, program, whether the program starts at
 # position 0, the helper withheld or None, what stands in for the TPU:
 # "paged" its Pallas attention kernels, "delta" the delta rule's kernel)
@@ -104,6 +115,10 @@ CASES = {
     "delta_chunk": (_olmo, "recurrent", 16, True, None, None),
     "delta_stepwise": (_olmo, "recurrent", 16, True, "delta_rule", None),
     "delta_kernel": (_olmo, "recurrent", "decode", False, None, "delta"),
+    "kda_step": (_ling, "recurrent", "decode", False, None, None),
+    "kda_chunk": (_ling, "recurrent", 16, True, None, None),
+    "kda_stepwise": (_ling, "recurrent", 16, True, "delta_rule", None),
+    "kda_kernel": (_ling, "recurrent", "decode", False, None, "delta"),
 }
 
 

@@ -38,6 +38,7 @@ _KWARGS = {
     "HyperStreamExpand": dict(n_in=4, streams=2),
     "HyperStreamReduce": dict(n_in=8, streams=2),
     "LSTM": dict(n_in=3, n_out=4),
+    "KimiDeltaAttentionLayer": dict(n_in=4, n_out=4, n_heads=2, d_k=3, d_v=2),
     "LatentAttentionLayer": dict(n_in=8, n_out=8, n_heads=2, q_rank=6,
                                  kv_rank=4, nope_dim=4, rope_dim=2, v_dim=4),
     "LayerNorm": dict(n_in=5),
@@ -73,6 +74,7 @@ _INPUTS = {
     "HyperStreamExpand": (2, 5, 4),
     "HyperStreamReduce": (2, 5, 8),
     "LSTM": (2, 5, 3),
+    "KimiDeltaAttentionLayer": (2, 5, 4),
     "LatentAttentionLayer": (2, 5, 8),
     "LayerNorm": (2, 5),
     "LocalResponseNormalization": (2, 4, 4, 3),

@@ -1278,3 +1278,46 @@ def test_the_delta_rule_kernel_steps_the_slots_in_place_on_v5e(v5e_chip,
         state + r"(copy|gather|scatter|dynamic-update-slice|fusion)\(", text)
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < 129 * 2_211_840
+
+
+def test_the_kda_kernel_steps_the_slots_in_place_on_v5e(v5e_chip,
+                                                        monkeypatch):
+    """``KimiDeltaAttentionLayer`` at ``ling-3.0-flash-ep8``'s widths (32
+    heads x [128, 128], one head a row of whole lanes) over 256 state
+    slots, compiled for the described chip with the pools donated: one
+    ``kda_state_step`` call, the pool aliased to its result, no pool-sized
+    ``copy``, gather, scatter or update of ``sh`` beside it."""
+    import re
+
+    from deeplearning4j_tpu.helpers import delta_rule
+    from deeplearning4j_tpu.nn.layers import KimiDeltaAttentionLayer
+
+    monkeypatch.setattr(delta_rule, "_interpret", lambda: False)
+    layer = KimiDeltaAttentionLayer(n_in=2560, n_out=2560, n_heads=32,
+                                    d_k=128, d_v=128, name="k")
+    assert layer.path(1) == "kda_kernel"
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    with jax.enable_x64(False):
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+        pool = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                lambda: layer.init_paged_cache(2, 64, jnp.bfloat16,
+                                               state_slots=256)))
+
+        def run(p, u, pool, lanes, pos):
+            carry = {**pool, "pos": pos, "lanes": lanes > 0}
+            y, _, new = layer.apply_with_carry(p, {}, u, carry)
+            return y, {k: new[k] for k in pool}
+
+        compiled = jax.jit(run, donate_argnums=(2,)).lower(
+            params, sds((256, 1, 2560), jnp.bfloat16), pool,
+            sds((256,), jnp.int32), sds((256,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"= \(f32\[257,32,128,128\][^=]*custom-call\(", text)
+    assert len(calls) == 1 and "kda_state_step" in text
+    state = r"f32\[(257|256),32,128,128\][^ ]* "
+    assert not re.findall(
+        state + r"(copy|gather|scatter|dynamic-update-slice|fusion)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 257 * 2_097_152
