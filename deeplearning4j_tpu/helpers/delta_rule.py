@@ -69,7 +69,12 @@ factor at most 1, the second at most 1 off the diagonal sub-blocks and at
 most ``exp(KDA_SUB |g_min|)`` on them (``exp(80)`` at the safe gate's floor
 of -5, finite).  ``kda_single_step`` and the kernel ``kda_step_slots``
 scale each ``d_k`` row of the state by its own factor, then step it as
-above.
+above.  ``kda_chunk_kernel`` is ``kda_chunked`` as one Pallas kernel
+(``kda_chunk``) for heads of whole lanes, offered with the decode kernel
+(``DeltaRuleHelper.kernel``): the chunks in order over a grid, each head's
+state in VMEM from the first to the last, each chunk's ``(I + A)^-1`` made
+there by blocks of ``KDA_SUB`` (``_unit_lower_inverse``) in place of the
+triangular solve, the same mathematics and precision.
 """
 
 from __future__ import annotations
@@ -90,6 +95,8 @@ DELTA_CHUNK = 64
 # positions a sub-block of the per-channel WY form: at a decay of at least
 # exp(-5) a position, exp(16 * 5) stays inside float32
 KDA_SUB = 16
+# heads a grid step of the per-channel chunk kernel, at most
+KDA_CHUNK_HEADS = 4
 _HIGHEST = lax.Precision.HIGHEST
 _LANES = 128
 # VMEM for the step kernel's own temporaries on top of its pipeline's rows
@@ -421,12 +428,7 @@ def kda_chunked(q, k, v, g, beta, s0, live=None, chunk=None):
     c = min(chunk, -(-t // sub) * sub)
     n = -(-t // c)
     pad = n * c - t
-    if live is None and pad:
-        live = jnp.full((bsz,), t, jnp.int32)
-    if pad:
-        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-                            for x in (q, k, v, g, beta))
-    g, beta = mask_padding(g, beta, live)
+    q, k, v, g, beta = _pad_to_chunks(pad, live, q, k, v, g, beta)
 
     def chunks(x):          # [B, n c, H, ...] -> [n, B, H, c, ...]
         x = x.reshape((bsz, n, c) + x.shape[2:])
@@ -461,6 +463,192 @@ def kda_chunked(q, k, v, g, beta, s0, live=None, chunk=None):
     s, o = lax.scan(trip, s0, (w, u, qg, qk, kd, last))  # o [n, B, H, c, dv]
     o = jnp.moveaxis(o, 0, 1)                             # [B, n, H, c, dv]
     o = jnp.moveaxis(o, 3, 2).reshape(bsz, n * c, h, dv)
+    return (o[:, :t] if pad else o), s
+
+
+def kda_chunk_fits(d_k: int, d_v: int) -> bool:
+    """Whether ``kda_chunk_kernel`` takes heads of these widths: whole lanes
+    of 128, so that a head's chunk is a whole ``[DELTA_CHUNK, d]`` block of
+    the ``[T, H d]`` rows."""
+    return d_k % _LANES == 0 and d_v % _LANES == 0
+
+
+def _dot(a, b, contract=((1,), (0,))):
+    return lax.dot_general(a, b, (contract, ((), ())), precision=_HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _block_of(i, size: int, count: int):
+    """``i // size`` for ``i`` in ``[0, size * count)``, by comparisons."""
+    return sum((i >= m * size).astype(jnp.int32) for m in range(1, count))
+
+
+def _unit_lower_inverse(a, sub: int):
+    """``(I + a)^-1`` for ``a`` [c, c] strictly lower triangular, ``c`` a
+    power-of-two multiple of ``sub`` (FLA's ``solve_tril``): the ``sub x
+    sub`` diagonal blocks inverted by forward substitution, all blocks at
+    once (step ``j`` subtracts column ``j`` of each block times the block's
+    finished row ``j``), then merged to the whole by block products, a level
+    of blocks twice the size a trip: with ``X`` the inverse of the blocks
+    of ``size`` and ``E`` the part of ``a`` below them inside the blocks of
+    ``2 size``, ``(I + a)^-1`` there is ``X - X E X``."""
+    c = a.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    blk = _block_of(row, sub, c // sub)
+    offset = col - sub * blk             # a column's place in its row's block
+    x = (row == col).astype(jnp.float32)
+    for j in range(sub - 1):
+        a_j = jnp.sum(jnp.where(offset == j, a, 0.0), axis=1,
+                      keepdims=True)                      # [c, 1]
+        x_j = x[j:j + 1]
+        for n in range(1, c // sub):
+            x_j = jnp.where(blk == n, x[n * sub + j:n * sub + j + 1], x_j)
+        x = x - a_j * x_j
+    size = sub
+    while size < c:
+        rb, cb = (_block_of(i, size, c // size) for i in (row, col))
+        odd = rb == 2 * _block_of(row, 2 * size, c // (2 * size)) + 1
+        below = odd & (cb == rb - 1)
+        x = x - _dot(x, _dot(jnp.where(below, a, 0.0), x))
+        size *= 2
+    return x
+
+
+def _pad_to_chunks(pad, live, q, k, v, g, beta):
+    """``q``, ``k``, ``v``, ``g``, ``beta`` [B, T, ...] with ``pad``
+    positions more that keep the state, and those at or past ``live``
+    masked (``mask_padding``)."""
+    if live is None and pad:
+        live = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
+    if pad:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+    g, beta = mask_padding(g, beta, live)
+    return q, k, v, g, beta
+
+
+def _kda_chunk_math(q, k, v, g, b, st, sub: int):
+    """One chunk of one head of ``kda_chunked``'s WY form, every array in
+    float32: ``q``, ``k``, ``g`` [c, d_k], ``v`` [c, d_v], ``b`` [c, 1] and
+    the state TRANSPOSED, ``st = S^T`` [d_v, d_k] (so that ``exp(G_C) S``
+    scales its lanes).  Returns ``(o [c, d_v], S'^T)``."""
+    c, dk = k.shape
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    cum = _dot((row >= col).astype(jnp.float32), g)       # G [c, d_k]
+    rows = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    kk, qk = [], []
+    for n in range(c // sub):
+        part = slice(n * sub, (n + 1) * sub)
+        ref = cum[n * sub:n * sub + 1]                    # R [1, d_k]
+        later = k * jnp.exp(jnp.where(rows < (n + 1) * sub, ref - cum,
+                                      -jnp.inf))          # k exp(R - G)
+        rel = jnp.exp(cum[part] - ref)
+        m = _dot(jnp.concatenate([k[part] * rel, q[part] * rel]), later,
+                 ((1,), (1,)))                            # [2 sub, c]
+        kk.append(m[:sub])
+        qk.append(m[sub:])
+    a = jnp.where(row > col, b * jnp.concatenate(kk), 0.0)
+    eg = jnp.exp(cum)
+    wu = _dot(_unit_lower_inverse(a, sub),
+              jnp.concatenate([b * eg * k, b * v], axis=1))
+    d = wu[:, dk:] - _dot(wu[:, :dk], st, ((1,), (1,)))   # U - W S
+    o = (_dot(q * eg, st, ((1,), (1,)))
+         + _dot(jnp.where(row >= col, jnp.concatenate(qk), 0.0), d))
+    last = cum[c - 1:c]
+    st = jnp.exp(last) * st + _dot(d, k * jnp.exp(last - cum), ((0,), (0,)))
+    return o, st
+
+
+def _kda_chunk_kernel(b_ref, q_ref, k_ref, v_ref, g_ref, s0_ref, o_ref,
+                      s_ref, st_ref, *, d_k, d_v, sub):
+    """Grid ``(row, block of heads, chunk)``, the chunks in order: the
+    block's heads' states live transposed in ``st_ref`` [heads, d_v, d_k]
+    from the first chunk (``s0``) to the last (written out once); ``q_ref``
+    .. ``g_ref`` [c, heads d] a chunk of the block's heads, ``b_ref`` [c,
+    H] every head's ``beta``."""
+    heads = st_ref.shape[0]
+    blk, chunk = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(chunk == 0)
+    def _():
+        for j in range(heads):
+            st_ref[j] = s0_ref[j].T
+
+    beta = b_ref[...]
+    lane = lax.broadcasted_iota(jnp.int32, beta.shape, 1)
+    for j in range(heads):
+        b = jnp.sum(jnp.where(lane == blk * heads + j, beta, 0.0), axis=1,
+                    keepdims=True)
+        kc, vc = slice(j * d_k, (j + 1) * d_k), slice(j * d_v, (j + 1) * d_v)
+        o, st_ref[j] = _kda_chunk_math(q_ref[:, kc], k_ref[:, kc],
+                                       v_ref[:, vc], g_ref[:, kc], b,
+                                       st_ref[j], sub)
+        o_ref[:, vc] = o
+
+    @pl.when(chunk == pl.num_programs(2) - 1)
+    def _():
+        for j in range(heads):
+            s_ref[j] = st_ref[j].T
+
+
+def chunk_heads(heads: int) -> int:
+    """Heads a grid step of ``kda_chunk_kernel``: the most, up to
+    ``KDA_CHUNK_HEADS``, that divide ``heads``."""
+    return max(m for m in range(1, min(heads, KDA_CHUNK_HEADS) + 1)
+               if heads % m == 0)
+
+
+# jitted so that the layers of a program share one trace and one lowering
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_kda_chunk(q, k, v, g, beta, s0, interpret):
+    bsz, t, heads, dk = q.shape
+    dv = v.shape[-1]
+    c, hb = DELTA_CHUNK, chunk_heads(heads)
+
+    def rows(width):
+        return pl.BlockSpec((None, c, hb * width),
+                            lambda b, h, n: (b, n, h))
+
+    state = pl.BlockSpec((None, hb, dk, dv), lambda b, h, n: (b, h, 0, 0))
+    o, s = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, d_k=dk, d_v=dv, sub=KDA_SUB),
+        grid=(bsz, heads // hb, t // c),
+        in_specs=[pl.BlockSpec((None, c, heads), lambda b, h, n: (b, n, 0)),
+                  rows(dk), rows(dk), rows(dv), rows(dk), state],
+        out_specs=[rows(dv), state],
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, heads * dv), jnp.float32),
+                   jax.ShapeDtypeStruct(s0.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_chunk",
+    )(beta, *(x.reshape(bsz, t, -1) for x in (q, k, v, g)), s0)
+    return o.reshape(bsz, t, heads, dv), s
+
+
+def kda_chunk_kernel(q, k, v, g, beta, s0, live=None, *, interpret=None):
+    """``kda_chunked`` as ONE Pallas kernel (``kda_chunk``): a grid over
+    the chunks of ``DELTA_CHUNK`` positions in order for each block of
+    ``chunk_heads`` heads, the heads' states in VMEM from ``s0`` to the
+    last chunk, each chunk's products by sub-blocks of ``KDA_SUB``, its
+    ``(I + A)^-1`` (``_unit_lower_inverse``) and its state products made
+    there, every product float32 at ``highest``.  ``q``, ``k``, ``v``,
+    ``g`` are read and ``o`` written once; ``d_k`` and ``d_v`` whole lanes
+    (``kda_chunk_fits``).  A length that the chunk does not divide is
+    padded with positions that keep the state."""
+    t, dk = q.shape[1], q.shape[-1]
+    if not kda_chunk_fits(dk, v.shape[-1]) or s0.dtype != jnp.float32:
+        raise ValueError(
+            f"kda_chunk_kernel: heads of [{dk}, {v.shape[-1]}] {s0.dtype} "
+            f"are not whole lanes of {_LANES} in float32")
+    pad = -t % DELTA_CHUNK
+    q, k, v, g, beta = _pad_to_chunks(pad, live, q, k, v, g, beta)
+    if interpret is None:
+        interpret = _interpret()
+    o, s = _pallas_kda_chunk(q, k, v, g, beta, s0, interpret)
     return (o[:, :t] if pad else o), s
 
 
@@ -528,11 +716,14 @@ def kda_step_slots(q, k, v, g, beta, sh, fresh, lanes, *, interpret=None):
 class DeltaRuleHelper:
     """The seam's object: ``chunked`` for a chunk or a sequence, the ``lax``
     WY form on every backend; ``step_slots``, the decode step on the state
-    slots in one kernel, where ``kernel`` offers it."""
+    slots in one kernel, and ``kda_chunk_kernel``, the per-channel chunked
+    form in one kernel for heads of whole lanes, where ``kernel`` offers
+    them."""
 
     @property
     def kernel(self) -> bool:
-        """Whether the decode step on state slots runs as ``step_slots``:
+        """Whether the decode step on state slots runs as ``step_slots`` and
+        a KDA chunk of heads of whole lanes as ``kda_chunk_kernel``:
         compiled, on the TPU (``delta_net.delta_rule_path``)."""
         return not _interpret()
 
@@ -548,9 +739,16 @@ class DeltaRuleHelper:
     def kda_chunked(self, q, k, v, g, beta, s0, live=None):
         return kda_chunked(q, k, v, g, beta, s0, live)
 
-    def describe(self, t: int) -> str:
-        """How a program of ``t`` positions a row is chunked, for the
-        warm-up's log."""
+    def kda_chunk_kernel(self, q, k, v, g, beta, s0, live=None):
+        return kda_chunk_kernel(q, k, v, g, beta, s0, live)
+
+    def describe(self, t: int, path: str = "") -> str:
+        """How a program of ``t`` positions a row is chunked on ``path``,
+        for the warm-up's log."""
         c = max(1, min(DELTA_CHUNK, t))
+        if path == "kda_chunk_kernel":
+            return (f"Pallas kernel kda_chunk, {-(-t // DELTA_CHUNK)} chunks "
+                    f"of {DELTA_CHUNK} positions in order, the state in "
+                    "VMEM")
         return (f"lax WY form, {-(-t // c)} chunks of {c} positions "
                 f"(a triangular solve and matrix products a chunk)")

@@ -101,9 +101,9 @@ class SelectiveScanHelper:
     def scan(self, x, dt, a, b, c, h0, live=None):
         return chunked_scan(x, dt, a, b, c, h0, live)
 
-    def describe(self, t: int) -> str:
+    def describe(self, t: int, path: str = "") -> str:
         """How a program of ``t`` positions a row is chunked, for the
-        warm-up's log."""
+        warm-up's log (one form for every ``path``)."""
         chunk = max(1, min(SCAN_CHUNK, t))
         return (f"lax chunked scan, {-(-t // chunk)} trips of {chunk} time "
                 f"steps")

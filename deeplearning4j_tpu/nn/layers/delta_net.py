@@ -68,8 +68,10 @@ from deeplearning4j_tpu.nn.layers.state_space import describe_slots
 
 DELTA_RULE_PATHS = ("delta_step", "delta_chunk", "delta_kernel",
                     "delta_stepwise")
-# the same four paths of the per-channel decay (KimiDeltaAttentionLayer)
-KDA_PATHS = ("kda_step", "kda_chunk", "kda_kernel", "kda_stepwise")
+# the same four paths of the per-channel decay (KimiDeltaAttentionLayer),
+# and a fifth: its chunked form as the seam's kernel
+KDA_PATHS = ("kda_step", "kda_chunk", "kda_kernel", "kda_stepwise",
+             "kda_chunk_kernel")
 # FLA's init: A uniform in (0, A_INIT_MAX], the step log-uniform in this range
 A_INIT_MAX = 16.0
 DT_INIT_MIN, DT_INIT_MAX = 1e-3, 1e-1
@@ -77,7 +79,7 @@ L2_EPS = 1e-6
 
 
 def delta_rule_path(t: int, kernel: bool = False, seam: bool = True,
-                    paths=DELTA_RULE_PATHS) -> str:
+                    paths=DELTA_RULE_PATHS, chunk_kernel: bool = False) -> str:
     """Which of ``paths`` (``DELTA_RULE_PATHS``, or ``KDA_PATHS`` for the
     per-channel decay, in that order) a call of ``t`` positions a row on the
     state slots takes: for a single token ``"delta_kernel"`` where the
@@ -85,13 +87,19 @@ def delta_rule_path(t: int, kernel: bool = False, seam: bool = True,
     ``delta_rule.step_slots``), else ``"delta_step"`` (the slot layout in
     ``jnp``, no loop); for more, ``"delta_chunk"``, the seam's WY form,
     where the seam is there (``seam``), and ``"delta_stepwise"``, the plain
-    recurrence one position a trip, where it gives way.  Pure: the layer
-    branches on it while it is traced, the engine calls it on the host to
-    count ``dl4j_layer_path_steps_total``."""
-    step, chunk, kern, stepwise = paths
+    recurrence one position a trip, where it gives way.  Where ``paths``
+    names a fifth and ``chunk_kernel`` offers it (the seam's chunk kernel
+    takes the layer's widths), a call of more than one position takes that
+    one in place of the WY form: ``"kda_chunk_kernel"``, ``delta_rule.
+    kda_chunk_kernel``.  Pure: the layer branches on it while it is traced,
+    the engine calls it on the host to count
+    ``dl4j_layer_path_steps_total``."""
+    step, chunk, kern, stepwise = paths[:4]
     if t == 1:
         return kern if kernel else step
-    return chunk if seam else stepwise
+    if not seam:
+        return stepwise
+    return paths[4] if chunk_kernel and len(paths) > 4 else chunk
 
 
 def _l2_normalize(x):
@@ -188,13 +196,16 @@ class GatedDeltaNetLayer(Layer):
         state slots, as the process stands (the kernel only where the
         helper seam offers it)."""
         helper = helpers.get_helper("delta_rule")
-        return delta_rule_path(t, helper is not None and helper.kernel,
-                               helper is not None, self.PATHS)
+        kernel = helper is not None and helper.kernel
+        return delta_rule_path(t, kernel, helper is not None, self.PATHS,
+                               kernel and dr.kda_chunk_fits(self.d_k,
+                                                            self.d_v))
 
     def _forms(self):
         """The rule's forms: (the decode step in ``jnp`` on the slot layout,
         the plain recurrence, the seam's chunked form and its kernel, by
-        the helper's attribute names)."""
+        the helper's attribute names, and where ``PATHS`` names a fifth,
+        the seam's chunk kernel)."""
         return dr.single_step, dr.stepwise, "chunked", "step_slots"
 
     def serving_path(self, call) -> str:
@@ -265,7 +276,8 @@ class GatedDeltaNetLayer(Layer):
             z = u @ params["W_g"]
         window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
         x = self._conv(params, window)
-        single, plain, chunked, _ = self._forms()
+        forms = self._forms()
+        single, plain = forms[:2]
         with jax.named_scope("gdn_state" if t == 1 else "gdn_chunk"):
             q, k, v, g, beta = self._rule(params, x, a, b)
             if t == 1:
@@ -275,8 +287,11 @@ class GatedDeltaNetLayer(Layer):
                         else single(*one, s0))
                 o = o[:, None]
             else:
-                rule = (getattr(helpers.get_helper("delta_rule"), chunked)
-                        if self.path(t) == self.PATHS[1] else plain)
+                path = self.path(t)
+                form = (forms[2] if path == self.PATHS[1]
+                        else forms[4] if path in self.PATHS[4:] else None)
+                rule = (plain if form is None
+                        else getattr(helpers.get_helper("delta_rule"), form))
                 o, s = rule(q, k, v, g, beta, dr.to_heads(s0, self.n_heads),
                             live)
                 s = dr.to_slots(s, self.group)
@@ -394,7 +409,8 @@ class KimiDeltaAttentionLayer(GatedDeltaNetLayer):
       ``dt_bias`` [H d_k], ``A_log`` [H]).
     - per head, ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} +
       beta_t k_t v_t^T``; ``o_t = S_t^T q_t`` (``helpers/delta_rule.py``'s
-      ``kda_`` forms, paths ``KDA_PATHS``).
+      ``kda_`` forms, paths ``KDA_PATHS``; a prefill of heads of whole
+      lanes takes the chunk kernel where the seam offers its kernels).
     - ``y_h = RMSNorm_{d_v}(o_h) w_h sigmoid(u W_g)_h``: a norm over each
       head's ``d_v`` with a gain of its own (``o_norm`` [H d_v]) and one
       gate a head (``W_g`` [n_in, H]); then ``y W_o``.
@@ -434,7 +450,7 @@ class KimiDeltaAttentionLayer(GatedDeltaNetLayer):
 
     def _forms(self):
         return (dr.kda_single_step, dr.kda_stepwise, "kda_chunked",
-                "kda_step_slots")
+                "kda_step_slots", "kda_chunk_kernel")
 
     def _rule(self, params, x, a, b):
         """Step 3 with the safe gate: ``g`` [B, T, H, d_k]."""

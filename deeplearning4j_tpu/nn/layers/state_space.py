@@ -84,11 +84,11 @@ def state_space_path(t: int, seam: bool = True) -> str:
 def describe_slots(call, label: str, path: str, helper, entries: int,
                    d_conv: int) -> str:
     """The warm-up line of a layer that keeps state slots: how ``call``'s
-    program runs its recurrence (``helper.describe`` for the seam's
-    chunked form) and what a slot's state weighs."""
+    program runs its recurrence on ``path`` (``helper.describe`` for the
+    seam's chunked forms) and what a slot's state weighs."""
     t = call.t
     how = ("one pass over the rows' states" if t == 1
-           else helper.describe(t) if helper is not None
+           else helper.describe(t, path) if helper is not None
            else f"lax scan, {t} trips of 1 time step")
     return (f"{label} ({path}): {how}; {call.slots} state slots + the trash "
             f"row, {entries * 4 / 1e3:.1f} kB of float32 state and a tail "

@@ -3,7 +3,7 @@
 The repo's fused kernels (Pallas flash attention, the fused LRN/BN
 passes, the paged-attention decode path, the streamed experts of a decode
 step and the sorted experts of a prefill, the delta rule's decode step on
-state slots) were validated by their unit
+state slots, the per-channel chunked rule of a prefill) were validated by their unit
 tests — which is trust by sampling.  This module is trust by SWEEP: run
 every fused kernel against an independent float64 numpy reference over
 a shape × dtype × masking grid, record per-config max-abs / max-rel
@@ -609,6 +609,44 @@ def _run_kda_step(cfg) -> Tuple[Any, np.ndarray]:
     ref = np.concatenate([x.reshape(-1) for x in (ro, rs, ro, rs)])
     return out, ref
 
+def _kda_chunk_configs(full: bool):
+    # two rows of a length the chunk does not divide, and a prefill bucket
+    # of Ling-3.0-flash's heads [32 x 128, 128]
+    grids = [{"shape": [2, 100, 2, 128]}, {"shape": [1, 1024, 32, 128]}]
+    if full:
+        grids += [{"shape": [1, 64, 8, 128]}]    # two blocks of heads
+    for g in grids:
+        yield dict(g, dtype="float32")
+
+def _run_kda_chunk(cfg) -> Tuple[Any, np.ndarray]:
+    """The per-channel chunked rule of a prefill both ways behind the seam
+    (the ``lax`` WY form ``kda_chunked`` and the interpreted Pallas kernel
+    ``kda_chunk_kernel``) against the f64 recurrence a position at a time
+    (``_np_kda_step`` over the sequence), outputs and final state in one
+    flat comparison, from a nonzero state, decays over the safe gate's
+    range (-5, 0) a channel."""
+    from deeplearning4j_tpu.helpers import delta_rule as dr
+    b, t, h, d = cfg["shape"]
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(_rng(b, t, h, d, dtype=jnp.float32, seed=70)) * d ** -0.5
+    k = unit(_rng(b, t, h, d, dtype=jnp.float32, seed=71))
+    v = _rng(b, t, h, d, dtype=jnp.float32, seed=72)
+    g = -5.0 * jax.nn.sigmoid(3.0 * _rng(b, t, h, d, dtype=jnp.float32,
+                                         seed=73))
+    beta = jax.nn.sigmoid(_rng(b, t, h, dtype=jnp.float32, seed=74))
+    s0 = _rng(b, h, d, d, dtype=jnp.float32, seed=75)
+    o_lax, s_lax = dr.kda_chunked(q, k, v, g, beta, s0)
+    o_pl, s_pl = dr.kda_chunk_kernel(q, k, v, g, beta, s0, interpret=True)
+    s = np.asarray(s0, np.float64)
+    ro = np.empty((b, t, h, d))
+    for i in range(t):
+        ro[:, i], s = _np_kda_step(q[:, i], k[:, i], v[:, i], g[:, i],
+                                   beta[:, i], s)
+    out = jnp.concatenate([x.reshape(-1) for x in
+                           (o_lax, s_lax, o_pl, s_pl)])
+    ref = np.concatenate([x.reshape(-1) for x in (ro, s, ro, s)])
+    return out, ref
+
 def _pallas2d_configs(full: bool):
     shapes = [(32, 24)]
     if full:
@@ -659,6 +697,7 @@ KERNELS: Dict[str, Tuple[Callable, Callable, bool]] = {
                         False),
     "delta_state_step": (_delta_step_configs, _run_delta_step, False),
     "kda_state_step": (_kda_step_configs, _run_kda_step, False),
+    "kda_chunk": (_kda_chunk_configs, _run_kda_chunk, False),
     "pallas_lrn": (_pallas2d_configs, _run_lrn, False),
     "pallas_bn_inference": (_pallas2d_configs, _run_bn_inference, False),
     "pallas_bn_training": (_pallas2d_configs, _run_bn_training, False),

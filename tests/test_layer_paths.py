@@ -58,7 +58,8 @@ SPIES = {
         "kda_step": (dr, "kda_single_step"),
         "kda_chunk": (dr.DeltaRuleHelper, "kda_chunked"),
         "kda_stepwise": (dr, "kda_stepwise"),
-        "kda_kernel": (dr.DeltaRuleHelper, "kda_step_slots")},
+        "kda_kernel": (dr.DeltaRuleHelper, "kda_step_slots"),
+        "kda_chunk_kernel": (dr.DeltaRuleHelper, "kda_chunk_kernel")},
 }
 FAMILY = {path: family for family, paths in SPIES.items() for path in paths}
 
@@ -94,6 +95,13 @@ def _ling():
     return toy_net()[0]
 
 
+def _ling_lanes():
+    """The toy at KDA heads of whole lanes, which the chunk kernel takes."""
+    from tests.test_ling import toy_net
+
+    return toy_net(head_dim=128)[0]
+
+
 # by path: (net, its layers' kind, program, whether the program starts at
 # position 0, the helper withheld or None, what stands in for the TPU:
 # "paged" its Pallas attention kernels, "delta" the delta rule's kernel)
@@ -119,6 +127,7 @@ CASES = {
     "kda_chunk": (_ling, "recurrent", 16, True, None, None),
     "kda_stepwise": (_ling, "recurrent", 16, True, "delta_rule", None),
     "kda_kernel": (_ling, "recurrent", "decode", False, None, "delta"),
+    "kda_chunk_kernel": (_ling_lanes, "recurrent", 16, True, None, "delta"),
 }
 
 

@@ -30,7 +30,9 @@ from deeplearning4j_tpu.nn.layers import (
     RoutedMoELayer,
 )
 from deeplearning4j_tpu.nn.layers.base import layer_from_dict
-from deeplearning4j_tpu.nn.layers.delta_net import KDA_PATHS, delta_rule_path
+from deeplearning4j_tpu.nn.layers.delta_net import (
+    DELTA_RULE_PATHS, KDA_PATHS, delta_rule_path,
+)
 from deeplearning4j_tpu.nn.layers.moe import limit_to_groups
 from deeplearning4j_tpu.observability.metrics import MetricsRegistry
 
@@ -208,16 +210,79 @@ def test_the_kda_kernel_steps_the_slots_as_the_jnp_step(b, h, dk, dv):
         dr.kda_step_slots(*one, sh.astype(jnp.bfloat16), fresh, lanes)
 
 
-def test_the_kda_paths_are_named_apart_from_the_delta_rules():
+# (the cases of the chunk kernel: rows, length, heads, the row's live
+# length or None, every log decay at the safe gate's floor)
+CHUNK_CASES = {"whole_chunks": (2, 128, 2, None, False),
+               "part_chunk": (2, 100, 2, None, False),
+               "live_shorter": (2, 150, 2, (90, 150), False),
+               "at_the_floor": (1, 128, 2, None, True),
+               "heads_of_a_block": (1, 70, 3, None, False),
+               "blocks_of_heads": (1, 64, 8, None, False)}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_the_chunk_kernel_is_the_per_channel_chunked_form(case):
+    """``kda_chunk_kernel`` (the Pallas kernel, interpreted) at whole lanes
+    (d_k = d_v = 128) from a nonzero state: one output and one state with
+    the ``lax`` WY form and the step recurrence, and the reference's own
+    recurrence on one row; past ``live`` the state is left alone."""
+    b, t, h, live, floor = CHUNK_CASES[case]
+    q, k, v, g, beta, s0 = kda_inputs(b=b, t=t, h=h, dk=128, dv=128,
+                                      seed=t + h)
+    if floor:
+        g = jnp.full(g.shape, -4.99, jnp.float32)
+    assert float(jnp.abs(s0).min()) > 0
+    live = None if live is None else jnp.array(live)
+    got_o, got_s = dr.kda_chunk_kernel(q, k, v, g, beta, s0, live)
+    assert bool(jnp.isfinite(got_o).all() & jnp.isfinite(got_s).all())
+    for form in (dr.kda_chunked, dr.kda_stepwise):
+        want_o, want_s = form(q, k, v, g, beta, s0, live)
+        assert _gap(got_s, want_s) < 1e-5
+        if live is None:
+            assert _gap(got_o, want_o) < 1e-5
+        else:
+            for row, n in enumerate(np.asarray(live)):
+                assert _gap(got_o[row, :n], want_o[row, :n]) < 1e-5
+    n = t if live is None else int(live[0])
+    ro, rs = ref.kda_rule(*(x[0, :n] for x in (q, k, v, g, beta)), s0[0])
+    assert _gap(got_o[0, :n], ro) < 1e-5 and _gap(got_s[0], rs) < 1e-5
+
+
+def test_the_chunk_kernel_takes_whole_lanes_alone():
+    assert dr.kda_chunk_fits(128, 128) and dr.kda_chunk_fits(256, 128)
+    assert not dr.kda_chunk_fits(32, 32) and not dr.kda_chunk_fits(128, 64)
+    assert [dr.chunk_heads(h) for h in (32, 8, 3, 5, 1)] == [4, 4, 3, 1, 1]
+    q, k, v, g, beta, s0 = kda_inputs(b=1, t=8, h=2, dk=32, dv=32)
+    with pytest.raises(ValueError, match="whole lanes"):
+        dr.kda_chunk_kernel(q, k, v, g, beta, s0)
+
+
+def test_the_kda_paths_are_named_apart_from_the_delta_rules(monkeypatch):
     took = [delta_rule_path(t, k, seam, KDA_PATHS) for t, k, seam in
             ((1, False, True), (2, False, True), (1, True, True),
              (2, False, False))]
     assert took == ["kda_step", "kda_chunk", "kda_kernel", "kda_stepwise"]
+    # the fifth, where the chunk kernel takes the widths; never for Olmo's
+    assert delta_rule_path(2, True, True, KDA_PATHS, True) == \
+        "kda_chunk_kernel"
+    assert delta_rule_path(2, True, False, KDA_PATHS, True) == "kda_stepwise"
+    assert delta_rule_path(1, True, True, KDA_PATHS, True) == "kda_kernel"
+    assert delta_rule_path(2, True, True, DELTA_RULE_PATHS, True) == \
+        "delta_chunk"
+    assert KDA_PATHS[:4] == ("kda_step", "kda_chunk", "kda_kernel",
+                             "kda_stepwise")
     layer = mixer_and_leaves(0)[0]
     assert isinstance(layer, KimiDeltaAttentionLayer)
     assert (layer.path(1), layer.path(64)) == ("kda_step", "kda_chunk")
     olmo = GatedDeltaNetLayer(n_in=8, n_out=8, n_heads=2, d_k=4, d_v=4)
     assert (olmo.path(1), olmo.path(64)) == ("delta_step", "delta_chunk")
+    monkeypatch.setattr(dr.DeltaRuleHelper, "kernel", True)
+    lanes = KimiDeltaAttentionLayer(n_in=8, n_heads=2, d_k=128, d_v=128)
+    assert (lanes.path(1), lanes.path(64)) == ("kda_kernel",
+                                               "kda_chunk_kernel")
+    assert (layer.path(1), layer.path(64)) == ("kda_kernel", "kda_chunk")
+    wide = GatedDeltaNetLayer(n_in=8, n_heads=2, d_k=128, d_v=128)
+    assert (wide.path(1), wide.path(64)) == ("delta_kernel", "delta_chunk")
 
 
 # ------------------------------------------------------- (b) the KDA layer
@@ -454,6 +519,31 @@ def test_engine_serves_the_reference_through_the_kda_kernel(monkeypatch):
                          stage="decode", path="kda_step") is None
     assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
                          stage="prefill", path="kda_chunk") == 5
+
+
+def test_engine_serves_the_reference_through_the_kda_chunk_kernel(
+        monkeypatch):
+    """The toy at KDA heads of whole lanes (d_k = d_v = 128) with the seam
+    made to offer its kernels (interpreted off the chip): every prefill's
+    KDA layers run ``kda_chunk_kernel``, the served tokens stay the
+    reference's, the counter reads ``kda_chunk_kernel`` once a prefill and
+    ``kda_chunk`` not at all, ``kda_kernel`` once a dispatched decode
+    step."""
+    monkeypatch.setattr(dr.DeltaRuleHelper, "kernel", True)
+    net, cfg = toy_net(head_dim=128)
+    assert net.layers[1].layers[1].state_shape() == (4, 128, 128)
+    eng, gaps = served_gaps(net, cfg, some_requests(count=4, seed=5))
+    assert gaps.max() < TOL, gaps
+    reg = eng.metrics.registry
+    dispatched = sum(reg.get_value("dl4j_decode_dispatch_total", mode=m) or 0
+                     for m in ("ahead", "sync"))
+    assert reg.get_value(
+        "dl4j_layer_path_steps_total", kind="recurrent", stage="decode",
+        path="kda_kernel") == dispatched > 0
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="prefill", path="kda_chunk_kernel") == 4
+    assert reg.get_value("dl4j_layer_path_steps_total", kind="recurrent",
+                         stage="prefill", path="kda_chunk") is None
 
 
 @pytest.mark.parametrize("fault", ["token_altered", "state_not_reset"])

@@ -1321,3 +1321,47 @@ def test_the_kda_kernel_steps_the_slots_in_place_on_v5e(v5e_chip,
     assert not re.findall(
         state + r"(copy|gather|scatter|dynamic-update-slice|fusion)\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 257 * 2_097_152
+
+
+@pytest.mark.parametrize("t", [1024, 4096])
+def test_the_kda_chunk_kernel_runs_a_prefill_on_v5e(v5e_chip, monkeypatch,
+                                                    t):
+    """A prefill bucket of ``KimiDeltaAttentionLayer`` at
+    ``ling-3.0-flash-ep8``'s widths where the seam offers its kernels (as
+    on the chip), compiled for the described chip: the chunked rule is ONE
+    ``kda_chunk`` call (the ``kda_chunk_kernel`` path), the chip's
+    triangular solve and the ``lax`` form's loop over the chunks are gone,
+    and the state pool is neither copied nor gathered whole."""
+    import re
+
+    from deeplearning4j_tpu.helpers import delta_rule
+    from deeplearning4j_tpu.nn.layers import KimiDeltaAttentionLayer
+
+    monkeypatch.setattr(delta_rule, "_interpret", lambda: False)
+    layer = KimiDeltaAttentionLayer(n_in=2560, n_out=2560, n_heads=32,
+                                    d_k=128, d_v=128, name="k")
+    assert layer.path(t) == "kda_chunk_kernel"
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=v5e_chip)
+    with jax.enable_x64(False):
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, jnp.bfloat16),
+            jax.eval_shape(layer.init, jax.random.PRNGKey(0)))
+        pool = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                lambda: layer.init_paged_cache(2, 64, jnp.bfloat16,
+                                               state_slots=256)))
+
+        def run(p, u, pool, rows, pos):
+            carry = {**pool, "pos": pos, "rows": rows, "live": pos + t - 5}
+            y, _, new = layer.apply_with_carry(p, {}, u, carry)
+            return y, {k: new[k] for k in pool}
+
+        compiled = jax.jit(run, donate_argnums=(2,)).lower(
+            params, sds((1, t, 2560), jnp.bfloat16), pool,
+            sds((1,), jnp.int32), sds((1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom-call\([^\n]*kda_chunk", text)) == 1
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert "while(" not in text
+    state = r"f32\[(257|256),32,128,128\][^ ]* "
+    assert not re.findall(state + r"(copy|gather)\(", text)
